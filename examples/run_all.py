@@ -11,6 +11,10 @@ is the quick after-install smoke; this is the long tier (the
 reference runs it weekly).
 
     python examples/run_all.py           # ~10-15 min on CPU
+
+A CPU tool: this process and the CLI children it starts are pinned to
+the CPU backend (a correctness sweep, not a measurement; the chip's
+entry point is chip_smoke.py).
 """
 
 import os
@@ -22,6 +26,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 import jax
 
+jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_enable_x64", True)
 
 import numpy as np

@@ -109,8 +109,8 @@ class ExtensiveForm(SPBase):
           gap, typically ~1-2%), fully on the accelerator."""
         factors = qp_setup(self.ef_data, q_ref=self.c_ef)
         st = qp_cold_state(factors, self.ef_data)
-        # segmented: watchdog-bounded device executions AND host-side
-        # rho adaptation on backends whose in-jit f64 adaptation is
+        # segmented: bounded device executions AND host-side rho
+        # adaptation on backends whose in-jit f64 adaptation is
         # disabled (see qp_solver._device_f64_linalg_trusted)
         st, x_ef, _, _ = qp_solve_segmented(
             factors, self.ef_data, self.c_ef, st, max_iter=max_iter,

@@ -65,8 +65,8 @@ def _dive_once(factors, data, q, state, imask, round_offset,
     def solve(lb_, ub_, st_, tight=False):
         d = data._replace(lb=jnp.asarray(lb_), ub=jnp.asarray(ub_))
         e = eps if tight else eps_mid
-        # segmented: a dive round can run thousands of iterations, and
-        # single long device executions trip accelerator watchdogs
+        # segmented: a dive round can run thousands of iterations —
+        # bounded executions with host-side progress control
         return qp_solve_segmented(factors, d, q, st_, max_iter=max_iter,
                                   eps_abs=e, eps_rel=e,
                                   polish_chunk=polish_chunk)
@@ -231,7 +231,7 @@ def dive_integers(factors, data, q, c0, state, integer_mask,
             | (Ax > np.where(np.isfinite(u_h), u_h, np.inf) + tol_row)
         # column-touch through A's support, computed ON DEVICE: the big
         # representations (SplitMatrix / ScaledView) must not be pulled
-        # dense to host (GB-scale d2h on tunneled links)
+        # dense to host (a GB-scale d2h and a dense host copy)
         touch = np.asarray(support_touch(data.A, viol))
         bad = ~np.asarray(feasible)
         unpin = (touch > 0.5) & np.asarray(imask) & bad[:, None]

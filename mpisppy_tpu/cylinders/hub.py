@@ -872,7 +872,9 @@ class CrossScenarioHub(PHHub):
         # wire format carries REAL scenarios (see _hub_arrays)
         S, K = getattr(self.opt, "_S_orig", self.opt.batch.S), \
             self.opt.batch.K
-        for i in self.cut_spoke_indices:
+        # a snapshot: a rejected payload below can quarantine the spoke,
+        # and the supervisor then drops it from this very set
+        for i in sorted(self.cut_spoke_indices):
             sp = self.spokes[i]
             res = self._consume_window(i, sp)
             if res is None:

@@ -660,7 +660,9 @@ def streaming_summary(run: Run) -> dict | None:
              e.get("counter_deltas", {}).get("stream.synth_chunks", 0),
          "compacted_transitions":
              e.get("counter_deltas", {}).get(
-                 "stream.compacted_transitions", 0)}
+                 "stream.compacted_transitions", 0),
+         "direct_fetches":
+             e.get("counter_deltas", {}).get("stream.direct_fetches", 0)}
         for e in iteration_rows(run)]
     # steady state starts after the LAST compacted re-block (ISSUE 17
     # shrink×stream): a transition legitimately changes the shipped
@@ -671,7 +673,13 @@ def streaming_summary(run: Run) -> dict | None:
     for i, r_ in enumerate(per_iter):
         if r_["compacted_transitions"]:
             start = max(start, i + 1)
-    steady = [r["device_put_bytes"] for r in per_iter[start:]]
+    # ... and on the in-order pipeline only: an iteration that paid a
+    # DIRECT fetch (a chunk retry restaging its one chunk — the
+    # documented exceptional path, booked as stream.direct_fetches)
+    # legitimately ships that chunk twice. A leak grows every
+    # iteration, so judging the retry-free ones still catches it.
+    steady = [r["device_put_bytes"] for r in per_iter[start:]
+              if not r["direct_fetches"]]
     return {
         "source": source,
         "chunks_shipped": chunks,

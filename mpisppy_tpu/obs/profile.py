@@ -52,22 +52,38 @@ from . import counter_add, event, gauge_set
 
 UNATTRIBUTED = "(unattributed)"
 
-# Peak device throughput table by device_kind substring: (peak FLOP/s
-# at the engine's working precision, peak HBM GB/s). TPU rows are the
-# published bf16 peaks (bench.py's V5E_PEAK_BF16 matches the v5e row).
-# The CPU tier gets documented NOMINAL placeholders so CPU-tier MFU is
-# finite (doc/roofline.md states those rows are CPU-tier, not
-# meaningful absolute utilization). Override either peak with
-# MPISPPY_TPU_PEAK_FLOPS / MPISPPY_TPU_PEAK_HBM_GBPS.
-_PEAKS_BY_KIND = (
-    ("v6e", 918e12, 1640.0),
-    ("v5p", 459e12, 2765.0),
-    ("v5e", 197e12, 819.0),
-    ("v5", 459e12, 2765.0),
-    ("v4", 275e12, 1228.0),
-    ("cpu", 1e11, 50.0),
-)
-_CPU_NOMINAL = (1e11, 50.0)
+# Peak device throughput by the EXACT ``device_kind`` string jax
+# reports (lower-cased): (peak FLOP/s, peak HBM GB/s). One row per kind
+# a machine has actually reported: the attached v5e says ``TPU v5
+# lite`` (published bf16 peak and HBM bandwidth, Google Cloud TPU v5e
+# documentation; bench.py's V5E_PEAK_BF16 is the same number). Any
+# other accelerator is an ERROR, never a default — add its row when a
+# machine reports its name, or set BOTH MPISPPY_TPU_PEAK_FLOPS and
+# MPISPPY_TPU_PEAK_HBM_GBPS. The CPU row is a documented NOMINAL
+# placeholder so CPU-tier MFU is finite (doc/roofline.md: CPU-tier
+# rows are not absolute utilization).
+_PEAKS_BY_KIND = {
+    "tpu v5 lite": (197e12, 819.0),
+    "cpu": (1e11, 50.0),
+}
+
+
+def peaks_for_kind(kind: str, platform: str = ""):
+    """(peak_flops, peak_hbm_gbps) of one device, by its exact
+    ``device_kind`` (case-insensitive). ``platform == "cpu"`` resolves
+    to the CPU-tier nominal row whatever the host CPU calls itself;
+    anything else outside the table raises."""
+    if platform == "cpu":
+        return _PEAKS_BY_KIND["cpu"]
+    row = _PEAKS_BY_KIND.get(str(kind).strip().lower())
+    if row is None:
+        raise ValueError(
+            f"no peak FLOP/s / HBM bandwidth row for device_kind "
+            f"{kind!r} (platform {platform!r}) in obs/profile."
+            f"_PEAKS_BY_KIND; known: {sorted(_PEAKS_BY_KIND)}. Add the "
+            "published peaks there, or set BOTH MPISPPY_TPU_PEAK_FLOPS "
+            "and MPISPPY_TPU_PEAK_HBM_GBPS")
+    return row
 
 
 class _State:
@@ -133,46 +149,35 @@ def _state() -> _State | None:
 # ---------------- peaks ----------------
 
 def _resolve_peaks(s: _State):
-    """(peak_flops, peak_hbm_gbps, source, device_kind) — env override
-    > device_kind table > nominal CPU default. Emits the one-shot
-    ``profile.device`` event so jax-free consumers (analyze) read the
-    resolved peaks from the stream."""
+    """(peak_flops, peak_hbm_gbps, source, device_kind) — the env pair
+    (both or neither) > the exact-``device_kind`` table; an accelerator
+    outside the table with no override raises (peaks_for_kind). Emits
+    the one-shot ``profile.device`` event so jax-free consumers
+    (analyze) read the resolved peaks from the stream."""
     if s.peaks is not None:
         return s.peaks
-    kind = "unknown"
-    try:
-        import jax
-        kind = str(jax.devices()[0].device_kind)
-    except Exception:
-        pass
-    flops = gbps = None
-    source = "table"
-    lk = kind.lower()
-    for sub, f, g in _PEAKS_BY_KIND:
-        if sub in lk:
-            flops, gbps = f, g
-            break
-    if flops is None:
-        flops, gbps = _CPU_NOMINAL
-        source = "default"
+    import jax
+
+    dev = jax.devices()[0]
+    kind, platform = str(dev.device_kind), str(dev.platform)
     env_f = os.environ.get("MPISPPY_TPU_PEAK_FLOPS")
     env_g = os.environ.get("MPISPPY_TPU_PEAK_HBM_GBPS")
-    try:
-        if env_f:
-            flops = float(env_f)
-            source = "env"
-        if env_g:
-            gbps = float(env_g)
-            source = "env"
-    except ValueError:
-        pass
+    if bool(env_f) != bool(env_g):
+        raise ValueError(
+            "MPISPPY_TPU_PEAK_FLOPS and MPISPPY_TPU_PEAK_HBM_GBPS "
+            "override the peaks table TOGETHER; only one of them is set")
+    if env_f:
+        flops, gbps, source = float(env_f), float(env_g), "env"
+    else:
+        flops, gbps = peaks_for_kind(kind, platform)
+        source = "table"
     s.peaks = (flops, gbps, source, kind)
     if not s.device_emitted:
         s.device_emitted = True
         event("profile.device", {
             "device_kind": kind, "peak_flops": flops,
             "peak_hbm_gbps": gbps, "source": source,
-            "cpu_tier": "cpu" in lk or kind == "unknown"})
+            "cpu_tier": platform == "cpu"})
     return s.peaks
 
 

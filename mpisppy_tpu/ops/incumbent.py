@@ -5,8 +5,8 @@ commercial B&B solver (ref. mpisppy/cylinders/xhatshufflelooper_bounder.py
 :108 uses solved MIP subproblem first stages); the TPU port's host analog
 (utils/host_oracle.OraclePool) pays per-scenario HiGHS subprocesses — at
 reference UC scale that host wall is the binding constraint on
-time-to-gap (BENCH_r05: the uc1024 incumbent sat 7.4% off for 841 s while
-oracle MILPs ground away). SURVEY.md ranks "batched MIP-quality
+time-to-gap (the round-5 chip run: the uc1024 incumbent sat 7.4% off for
+841 s while oracle MILPs ground away). SURVEY.md ranks "batched MIP-quality
 incumbents without a B&B solver" the #1 hard part.
 
 This module is the device answer (doc/incumbents.md): manufacture a POOL

@@ -12,8 +12,10 @@ engine options; anatomy in doc/kernels.md):
                  tail + polish) as one device program. Backends:
                  ``reference`` (XLA fused-scan — default everywhere,
                  the correctness oracle; reference.py) and ``pallas``
-                 (the TPU VMEM-resident iteration block, exercised on
-                 CPU via ``interpret=True``; pallas_kernel.py);
+                 (the opt-in TPU VMEM-resident iteration block for
+                 small dense f32 shapes; a solve outside its scope is
+                 a config error, never a silent demotion;
+                 pallas_kernel.py);
   ``auto``       fused wherever the solve is eligible (see
                  resolve_mode), segmented otherwise — the default.
 
@@ -52,34 +54,17 @@ from .reference import (BF16_GATE_REL, bf16_gate, bf16_packed,
                         fused_mixed_solve, l_inv_profitable)
 
 
-# the measured TPU per-execution watchdog ceiling for f64-involving
-# device programs (qp_solve_segmented's raison d'être: hard worker
-# crashes on UC-size solves above ~500 f64 iterations per call; the
-# f32 bulk is exempt — "the measured watchdog ceiling binds
-# f64-involving executions only", qp_solve_mixed). ``auto`` refuses to
-# fuse a longer f64 stretch on TPU; explicit ``fused`` is the
-# driver-run experiment knob (fusion removes the per-iteration host
-# syncs, which may change the wall-time-per-execution math — that is
-# exactly what the chip run measures).
-WATCHDOG_F64_ITERS = 500
-
-
-def resolve_mode(mode: str, factors, *, f64_stretch=0) -> str:
-    """``auto`` resolution. A solve is fused-eligible unless (a) its
-    rho adaptation must run on the HOST (non-shared f64 factors on a
+def resolve_mode(mode: str, factors) -> str:
+    """``auto`` resolution. A solve is fused-eligible unless its rho
+    adaptation must run on the HOST (non-shared f64 factors on a
     backend with untrusted f64 device linalg — qp_solver
     ._needs_host_factor): the fused program cannot call back out for
-    the host-exact refactorization mid-loop; or (b) on TPU, its
-    longest single-program f64 iteration stretch would exceed the
-    measured watchdog ceiling (WATCHDOG_F64_ITERS)."""
-    if mode == "segmented":
-        return "segmented"
+    the host-exact refactorization mid-loop. Program length is no
+    criterion: one program of 36,794 f64 matmul iterations ran 59.7 s
+    to completion on the attached v5e (CHANGES.md PR 24)."""
     if mode == "fused":
         return "fused"
-    if _needs_host_factor(factors):
-        return "segmented"
-    if f64_stretch > WATCHDOG_F64_ITERS \
-            and jax.default_backend() == "tpu":
+    if mode == "segmented" or _needs_host_factor(factors):
         return "segmented"
     return "fused"
 
@@ -136,22 +121,15 @@ def prepare(factors, *, mode="auto", backend="reference",
     if mode == "fused" and _needs_host_factor(factors):
         # explicit fused cannot serve these factors: the tail handoff
         # and in-loop rho adaptation would call _factorize in-trace on
-        # non-shared f64 KKTs whose device inverse is garbage on this
-        # backend (qp_solver._device_f64_linalg_trusted — measured
-        # |M@inv - I|max = 0.9, iterates -> 1e33 -> NaN). A config
+        # non-shared f64 KKTs whose device inverse is not trusted on
+        # this backend (qp_solver._device_f64_linalg_trusted). A config
         # error here beats NaN solves deep inside the jit.
         raise ValueError(
             "kernel mode 'fused' cannot serve non-shared f64 factors "
             "whose rho adaptation must refactorize on the host "
             "(untrusted f64 device linalg on this backend); use "
             "'segmented', or 'auto' which falls back automatically")
-    # the f64 stretch one fused program would run without a host
-    # dispatch: the whole budget for a native-f64 solve, only the tail
-    # for precision-escalated solves (the bulk iterates in f32)
-    f64_stretch = int(tail_iter) if precision in ("mixed", "df32") else (
-        int(bulk_iter)
-        if getattr(factors.A_s, "dtype", None) == jnp.float64 else 0)
-    if resolve_mode(mode, factors, f64_stretch=f64_stretch) == "segmented":
+    if resolve_mode(mode, factors) == "segmented":
         return SEGMENTED_PLAN
     split = isinstance(factors.A_s, SplitMatrix)
     use_linv = False
@@ -198,22 +176,27 @@ def prepare(factors, *, mode="auto", backend="reference",
             # non-split mixed: the bulk casts the dense operand
             # in-trace, exactly as qp_solve_mixed does eagerly
             A_lo = factors.A_s
-    eff_backend = backend
     if backend == "pallas" and not (
-            pallas_kernel.HAVE_PALLAS
-            and precision == "native"
+            precision == "native"
             and getattr(factors.A_s, "ndim", 0) == 2
             and not isinstance(factors.A_s, (SplitMatrix, PackedMatrix))):
-        # outside the pallas block's scope (see pallas_kernel), or no
-        # pallas in this environment: the reference backend is the
-        # default stand-in everywhere
-        eff_backend = "reference"
+        # a backend that was ASKED for and cannot serve the solve is a
+        # config error, like explicit ``fused`` above — never a demotion
+        # to ``reference`` that leaves the run reporting a kernel it
+        # did not run
+        raise ValueError(
+            "kernel backend 'pallas' serves native-precision solves "
+            "over one shared dense A only (see ops/kernels/"
+            f"pallas_kernel.py); got precision={precision!r}, A_s="
+            f"{type(factors.A_s).__name__} ndim="
+            f"{getattr(factors.A_s, 'ndim', None)}. Use the default "
+            "'reference' backend")
     # host copy of sigma, read once here (prepare is host+eager by
     # contract) so the per-solve pallas launch never pays a scalar
     # D2H; partial factor stubs (scope tests) simply carry None and
     # fused_admm_block's direct-caller fallback covers them
     sig = getattr(factors, "sigma", None)
-    return KernelPlan(mode="fused", backend=eff_backend,
+    return KernelPlan(mode="fused", backend=backend,
                       precision=precision, l_inv=use_linv,
                       block_dtype=bdt, A_lo=A_lo, bf16_err=err,
                       sigma_host=None if sig is None else float(sig))
@@ -232,17 +215,22 @@ def kernel_solve(plan: KernelPlan, factors, data, q, state, *,
     pools statistics over rows that include INFEASIBLE candidates
     (doc/incumbents.md)."""
     t0 = time.perf_counter()
-    if plan.backend == "pallas" and precision not in ("mixed", "df32") \
-            and not pallas_kernel.pallas_supported(factors, state):
-        # the state-dependent half of the scope check (the solve
-        # operator must be an explicit inverse — prepare() only sees
-        # the factors): demote the CACHED plan so phase_timing / the
-        # bench row / analyze report the backend that actually runs,
-        # not the one that was asked for
-        plan.backend = "reference"
-        obs.event("kernel.pallas_demotion",
-                  {"reason": "solve operator not an explicit inverse"})
+    if plan.backend == "pallas":
+        # the state-dependent half of the scope check (operator form,
+        # dtypes, VMEM estimate — prepare() only sees the factors)
+        why = pallas_kernel.pallas_scope_reason(factors, state)
+        if why is not None:
+            raise ValueError(
+                f"kernel backend 'pallas' cannot serve this solve: {why}"
+                ". Use the default 'reference' backend")
     if precision in ("mixed", "df32"):
+        # the split (df32) representation never polishes (_solve_impl
+        # forces it off), so the flag must not reach the jit as a
+        # STATIC: iter-0 (polish on) and hot (polish off) solves would
+        # compile the same UC-width program twice — measured 14.5-19 s
+        # and ~11 GiB of host memory for the second copy on the chip
+        # machine (CHANGES.md PR 24)
+        polish = polish and not isinstance(factors.A_s, SplitMatrix)
         st, x, yA, yB = fused_mixed_solve(
             factors, plan.A_lo, data, q, state, bulk_iter=max_iter,
             tail_iter=tail_iter, check_every=check_every, eps_abs=e_pri,

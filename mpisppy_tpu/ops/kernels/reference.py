@@ -59,6 +59,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ... import obs
+from ...utils.runtime import compile_serialized
 from ..packed import Packed
 from ..qp_solver import (LInv, PackedMatrix, QPData, QPState, SplitMatrix,
                          _cast_floats, _factorize, _make_l_inv,
@@ -147,8 +148,9 @@ def _fused_mixed_impl(factors, A_lo, data, q, iterates, aux,
     handoff, same budget split) with the host segment loops replaced by
     the in-jit while_loops ``_solve_impl`` already owns.
 
-    ``iterates`` = (x, yA, yB, zA, zB) — donated by the donating twin;
-    ``aux`` = (L, rho_scale, iters) — NEVER donated: the df32 chunked
+    ``iterates`` = (x, yA, yB, zA, zB) — DONATED (see
+    _fused_mixed_jit_donated); ``aux`` = (L, rho_scale, iters) — NEVER
+    donated: the df32 chunked
     loop deliberately shares one flowed factor across every chunk state
     (core/ph pass-3 unify), so L is not uniquely owned and must be
     copied, exactly as qp_solve_mixed's ``owned_lo = donate and not
@@ -235,15 +237,20 @@ def _fused_mixed_impl(factors, A_lo, data, q, iterates, aux,
     return st, x_un, yA_un, yB_un
 
 
-_FUSED_STATICS = ("bulk_iter", "tail_iter", "check_every", "adaptive_rho",
-                  "polish", "polish_iters", "polish_chunk", "stall_rel",
+# ``adaptive_rho`` is deliberately NOT a static, and there is no
+# non-donating twin: at UC width every distinct program is minutes of
+# compile and ~11 GiB of host memory (CHANGES.md PR 24), so ONE
+# executable serves the hub's first pass and its donating hot passes,
+# the Lagrangian spoke and the frozen-rho incumbent pool. It consumes
+# the ITERATE buffers only (see _fused_mixed_impl on why aux must be
+# copied); a caller that keeps its state passes private copies of those
+# (S, n + m)-sized arrays (fused_mixed_solve, ``donate=False``).
+_FUSED_STATICS = ("bulk_iter", "tail_iter", "check_every", "polish",
+                  "polish_iters", "polish_chunk", "stall_rel",
                   "ir_sweeps", "l_inv", "alpha")
-_fused_mixed_jit = jax.jit(_fused_mixed_impl, static_argnames=_FUSED_STATICS)
-# donated twin: consumes the ITERATE buffers only (see _fused_mixed_impl
-# on why aux must be copied)
-_fused_mixed_jit_donated = jax.jit(_fused_mixed_impl,
-                                   static_argnames=_FUSED_STATICS,
-                                   donate_argnames=("iterates",))
+_fused_mixed_jit_donated = compile_serialized(
+    jax.jit(_fused_mixed_impl, static_argnames=_FUSED_STATICS,
+            donate_argnames=("iterates",)), _FUSED_STATICS)
 
 
 def fused_mixed_solve(factors, A_lo, data, q, state, *, bulk_iter,
@@ -262,11 +269,13 @@ def fused_mixed_solve(factors, A_lo, data, q, state, *, bulk_iter,
             obs.counter_add("kernel.l_inv_factorizations")
             state = state._replace(L=make_l_inv(L))
     iterates = (state.x, state.yA, state.yB, state.zA, state.zB)
+    if not donate:
+        iterates = tuple(jnp.copy(a) for a in iterates)
     aux = (state.L, state.rho_scale, state.iters)
-    fn = _fused_mixed_jit_donated if donate else _fused_mixed_jit
+    fn = _fused_mixed_jit_donated
     kw = dict(bulk_iter=int(bulk_iter), tail_iter=int(tail_iter),
               check_every=int(check_every),
-              adaptive_rho=bool(adaptive_rho), polish=bool(polish),
+              adaptive_rho=np.bool_(adaptive_rho), polish=bool(polish),
               polish_iters=int(polish_iters),
               polish_chunk=int(polish_chunk), stall_rel=float(stall_rel),
               ir_sweeps=int(ir_sweeps), l_inv=bool(l_inv))

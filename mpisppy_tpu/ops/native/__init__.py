@@ -22,14 +22,27 @@ _lib = None
 def _build():
     import sys
 
-    cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-o", _SO,
+    # build beside the target and rename into place: several processes
+    # (test workers, spawned spokes) may find the library missing at
+    # once, and none may load a half-written file
+    tmp = f"{_SO}.{os.getpid()}.tmp"
+    cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-o", tmp,
            _SRC]
     if sys.platform.startswith("linux"):
         # shm_open/shm_unlink live in librt on pre-2.34 glibc (the flag
         # is harmless where they moved into libc); macOS has no librt
         # and keeps them in libc, so the flag must stay Linux-only
         cmd.append("-lrt")
-    subprocess.run(cmd, check=True, capture_output=True)
+    try:
+        r = subprocess.run(cmd, capture_output=True, text=True)
+        if r.returncode != 0:
+            raise RuntimeError(
+                f"building {_SO} failed (rc {r.returncode}): "
+                f"{' '.join(cmd)}\n{r.stdout}{r.stderr}")
+        os.replace(tmp, _SO)
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
 
 
 def load():

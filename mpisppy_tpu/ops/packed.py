@@ -7,7 +7,8 @@ the ~101k-nonzero sparse matrix directly (ref. examples/uc/2013-05-11:
 formulation (ops/qp_solver._Ax) instead reads the full (m, n) f32 pair
 from HBM on every pass — at reference-UC scale that is ~2.7 GB per
 split matvec and ~80% of the hot loop's memory traffic, which is why
-BENCH_r04 measured 3.8% MFU (the chip spends its bandwidth on zeros).
+the round-4 dense kernel measured 3.8% MFU (the chip spends its
+bandwidth on zeros).
 
 TPUs have no efficient general gather/scatter sparse matmul, but SP
 constraint matrices are not generally sparse — they are STRUCTURED:

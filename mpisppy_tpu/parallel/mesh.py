@@ -113,7 +113,6 @@ class ShardedScenarioOps:
     """
 
     def __init__(self, mesh: Mesh, tree, slot_bounds, S: int):
-        from jax.experimental.shard_map import shard_map  # noqa: F401
         self.mesh = mesh
         self.n_devices = int(mesh.devices.size)
         if S % self.n_devices:
@@ -135,9 +134,10 @@ class ShardedScenarioOps:
 
     # ---- builders (cached shard_map programs) ----
     def _shard_map(self, body, in_specs, out_specs):
-        from jax.experimental.shard_map import shard_map
-        return jax.jit(shard_map(body, mesh=self.mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False))
+        return jax.jit(jax.shard_map(body, mesh=self.mesh,
+                                     in_specs=in_specs,
+                                     out_specs=out_specs,
+                                     check_vma=False))
 
     def _spec(self, ndim, sharded=True):
         if not sharded:

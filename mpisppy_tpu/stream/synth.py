@@ -12,7 +12,11 @@ data: the resident/streamed twins used by the equivalence tests are
 built by materializing the SAME generator on host
 (:func:`materialize`, jax's threefry PRNG is bit-identical across
 backends), so synthesized == resident is exact by construction — not a
-tolerance accident.
+tolerance accident. The bits DO follow jax's threefry mode
+(``jax_threefry_partitionable``, default on since jax 0.5): a family's
+data is a function of (seed, scenario id, that mode), so a jax upgrade
+that flips the mode draws a different — equally valid — instance
+family; nothing in the program pins the mode.
 
 Contract for ``SynthSpec.fn`` (model modules export it through
 ``scenario_synth_spec``, e.g. models/farmer.py, models/uc.py):

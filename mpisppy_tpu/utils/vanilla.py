@@ -174,21 +174,21 @@ def hub_dict(cfg: RunConfig, batch=None):
             # (doc/sharding.md): 0 = every visible device (the whole
             # slice — or the whole pod when
             # utils/runtime.maybe_init_distributed ran first)
-            import warnings
-
             import jax
 
             from ..parallel.mesh import make_mesh
             n_vis = len(jax.devices())
             if cfg.mesh_devices > n_vis:
-                warnings.warn(
+                # never a narrower mesh than the one asked for: a run
+                # that believes it is on 4 chips and sits on 1 reports
+                # numbers for the wrong machine
+                raise ValueError(
                     f"mesh_devices={cfg.mesh_devices} exceeds the "
-                    f"{n_vis} visible device(s) — sharding over all "
-                    f"{n_vis} (multi-host runs need the coordinator "
-                    "knob so jax sees the global set, doc/sharding.md)",
-                    RuntimeWarning, stacklevel=2)
+                    f"{n_vis} visible device(s) (multi-host runs need "
+                    "the coordinator knob so jax sees the global set, "
+                    "doc/sharding.md)")
             opt_kwargs["mesh"] = make_mesh(
-                n_devices=min(cfg.mesh_devices, n_vis) or None)
+                n_devices=cfg.mesh_devices or None)
         else:
             # the lshaped hub and the cross-scenario cut engine keep
             # the unsharded path (the cut store is not sharding-

@@ -1,0 +1,222 @@
+"""Compile the main path's kernels and step programs for the chip —
+from a sandbox that has none (on-chip-measurement guide §2.3).
+
+The TPU compiler is installed here and compiles for a chip that is
+DESCRIBED (``v5e:2x2``), not attached: what it refuses here, the chip's
+compiler refuses there. Nothing runs, so these tests say nothing about
+results or times; a compile that passes is not a chip run.
+
+The topology is described inside a module-scoped fixture that skips
+when it cannot be — never at import, never in a ``skipif`` /
+``parametrize`` argument, never in conftest.py: only one process may
+load the TPU library, so only the xdist worker that is handed THIS file
+may touch it (all of these tests live in this one file for the same
+reason), and every compile happens in the test's own process. The
+persistent compile cache is switched off around them: an entry compiled
+for a described chip cannot be read back without one.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
+
+from mpisppy_tpu.ops.kernels import pallas_kernel as pk
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _on(tree, sharding_of):
+    """Every array leaf -> a ShapeDtypeStruct placed by ``sharding_of``
+    (shapes only: there is no device to hold an array)."""
+    def leaf(a):
+        if hasattr(a, "shape") and hasattr(a, "dtype"):
+            return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                        sharding=sharding_of(a))
+        return a
+    return jax.tree.map(leaf, tree)
+
+
+# ---------------- the Pallas block ----------------
+
+def _block_shapes(S, n, m, sharding, dt=jnp.float32):
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+    #       A          F          Ps      g       q_s        l_s
+    return (sds(m, n), sds(n, n), sds(n), sds(n), sds(S, n), sds(S, m),
+            # u_s      lb_s       ub_s       rA      rB      Einv
+            sds(S, m), sds(S, n), sds(S, n), sds(m), sds(n), sds(m),
+            # Ebinv Dinv_c  D       x          yA         yB
+            sds(n), sds(n), sds(n), sds(S, n), sds(S, m), sds(S, n),
+            # zA       zB
+            sds(S, m), sds(S, n))
+
+
+def _compile_block(S, n, m, tile, sharding):
+    return pk._block_call.lower(
+        *_block_shapes(S, n, m, sharding), sigma=1e-6, n_steps=50,
+        alpha=1.6, interpret=False, l_inv_pair=True,
+        scen_tile=tile).compile()
+
+
+@pytest.mark.parametrize("S,n,m,tile", [
+    (8, 128, 256, 0),         # untiled small
+    (256, 128, 256, 128),     # the grid: S=256 in 128-row tiles
+    (64, 384, 768, 0),        # about the widest the limit admits
+    (512, 256, 512, 128),     # the same on the grid
+])
+def test_pallas_block_compiles_for_v5e(one_chip, no_persistent_cache,
+                                       S, n, m, tile):
+    tiled = 0 < tile < S
+    assert pk.vmem_bytes_estimate(tile if tiled else S, n, m,
+                                  tiled=tiled) <= pk.VMEM_LIMIT_BYTES
+    compiled = _compile_block(S, n, m, tile, one_chip)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_pallas_scope_refuses_what_mosaic_refuses(one_chip,
+                                                  no_persistent_cache):
+    """The bytes-vs-VMEM estimate and the dtype check refuse, by name,
+    the operands the chip's compiler refuses — before it has to."""
+    import types
+
+    from mpisppy_tpu.ops.qp_solver import LInv
+
+    def operands(S, n, m, dt):
+        f = types.SimpleNamespace(A_s=jnp.zeros((m, n), dt))
+        F = jnp.zeros((n, n), dt)
+        return f, types.SimpleNamespace(L=LInv(F, F),
+                                        x=jnp.zeros((S, n), dt))
+
+    # over VMEM — far over (128 rows at n=1024 / m=2048, untiled) and
+    # just over (8 rows at n=512 / m=1024: the compiler asks 18.6 MB)
+    for S, n, m in ((128, 1024, 2048), (8, 512, 1024)):
+        why = pk.pallas_scope_reason(*operands(S, n, m, jnp.float32),
+                                     scen_tile=0)
+        assert why is not None and "VMEM" in why
+        with pytest.raises(Exception, match="vmem"):
+            _compile_block(S, n, m, 0, one_chip)
+    # f64: Mosaic has no such type
+    why = pk.pallas_scope_reason(*operands(8, 128, 256, jnp.float64))
+    assert why is not None and "f32 only" in why
+    # in scope: the same small shape in f32
+    assert pk.pallas_supported(*operands(8, 128, 256, jnp.float32))
+
+
+# ---------------- the df32 chunk solve and the consensus reduce --------
+
+@pytest.fixture(scope="module")
+def uc_calls():
+    """One chunked df32 PH pass of a mid-width UC on the CPU, recording
+    the arguments core/ph hands the fused df32 chunk solve
+    (ops/kernels/reference) and the consensus reduce (_ph_chunk_objs +
+    _ph_combine) — the programs chip_smoke.py runs at full width."""
+    import mpisppy_tpu.core.ph as phmod
+    import mpisppy_tpu.ops.kernels.reference as ref
+    import mpisppy_tpu.ops.qp_solver as qps
+    from mpisppy_tpu.ir.batch import build_batch
+    from mpisppy_tpu.models import uc
+
+    calls = {}
+    mp = pytest.MonkeyPatch()
+
+    def record(mod, name):
+        fn = getattr(mod, name)
+
+        def wrapper(*a, **kw):
+            calls.setdefault(name, (fn, a, kw))
+            return fn(*a, **kw)
+        mp.setattr(mod, name, wrapper)
+
+    record(qps, "_cold_state_jit")
+    record(ref, "_fused_mixed_jit_donated")
+    record(phmod, "_ph_chunk_objs")
+    record(phmod, "_ph_combine")
+    try:
+        batch = build_batch(
+            uc.scenario_creator, uc.make_tree(8),
+            creator_kwargs=dict(num_gens=8, num_hours=8,
+                                min_up_down=True, ramping=True,
+                                t0_state=True,
+                                startup_shutdown_ramps=True,
+                                relax_integrality=False),
+            vector_patch=uc.scenario_vector_patch)
+        ph = phmod.PHBase(
+            batch, {"defaultPHrho": 100.0,
+                    "subproblem_precision": "df32",
+                    "subproblem_max_iter": 50, "subproblem_eps": 1e-5,
+                    "subproblem_tail_iter": 25,
+                    "subproblem_hospital": False,
+                    "subproblem_chunk": 4, "iter0_feas_tol": 1.0},
+            dtype=jnp.float64)
+        ph.solve_loop(w_on=False, prox_on=False)
+        ph.W = ph.W_new
+        ph.solve_loop(w_on=True, prox_on=True)
+    finally:
+        mp.undo()
+    return calls
+
+
+@pytest.mark.parametrize("name", ["_cold_state_jit",
+                                  "_fused_mixed_jit_donated",
+                                  "_ph_chunk_objs", "_ph_combine"])
+def test_uc_df32_step_programs_compile_for_v5e(uc_calls, one_chip,
+                                               no_persistent_cache, name):
+    fn, args, kw = uc_calls[name]
+    compiled = fn.lower(*_on(args, lambda a: one_chip), **kw).compile()
+    mem = compiled.memory_analysis()
+    need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes + mem.generated_code_size_in_bytes)
+    assert need < 16e9        # one v5e chip's HBM
+
+
+def test_sharded_df32_chunk_solve_compiles_over_four_chips(
+        uc_calls, topo, no_persistent_cache):
+    """The same chunk solve as ONE program over a 4-chip mesh of the
+    described devices, scenario rows sharded and shared operands
+    replicated the way core/spbase places them: the compiler must put
+    the termination tests' cross-shard reduction in as a collective."""
+    from jax.sharding import Mesh
+
+    from mpisppy_tpu.parallel.mesh import SCEN_AXIS
+
+    fn, args, kw = uc_calls["_fused_mixed_jit_donated"]
+    mesh = Mesh(np.asarray(topo.devices[:4]), (SCEN_AXIS,))
+    rows = args[4][0].shape[0]           # iterates[0] = x: (chunk, n)
+
+    def place(a):
+        lead = a.ndim >= 1 and a.shape[0] == rows
+        spec = PartitionSpec(SCEN_AXIS, *([None] * (a.ndim - 1))) \
+            if lead else PartitionSpec()
+        return NamedSharding(mesh, spec)
+
+    compiled = fn.lower(*_on(args, place), **kw).compile()
+    assert "all-reduce" in compiled.as_text()

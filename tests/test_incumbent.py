@@ -423,11 +423,11 @@ _DEVICE_WHEEL = r"""
 import sys
 import jax
 jax.config.update("jax_platforms", "cpu")
-jax.config.update("jax_enable_x64", True)
-# share the suite's persistent compile cache (tests/conftest.py): the
-# fresh interpreter re-lowers but skips the XLA compiles
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+# x64 + the suite's persistent compile cache (tests/conftest.py uses
+# the same owner): the fresh interpreter re-lowers but skips the XLA
+# compiles
+from mpisppy_tpu.utils.runtime import setup_jax_runtime
+setup_jax_runtime()
 import numpy as np
 from mpisppy_tpu.ir.batch import build_batch
 from mpisppy_tpu.core.ph import PH, PHBase

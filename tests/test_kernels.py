@@ -30,17 +30,19 @@ from mpisppy_tpu.parallel.mesh import make_mesh
 
 # ---------------- fixtures ----------------
 
-def _tiny_qp(S=3, m=6, n=4, seed=0):
+def _tiny_qp(S=3, m=6, n=4, seed=0, dtype=jnp.float64):
     """Small well-posed box-constrained QP with shared structure (the
     representation every kernel backend supports)."""
     rng = np.random.default_rng(seed)
-    A = jnp.asarray(rng.normal(size=(m, n)))
-    P = jnp.asarray(np.abs(rng.normal(size=n)) + 0.5)
+    A = jnp.asarray(rng.normal(size=(m, n)), dtype)
+    P = jnp.asarray(np.abs(rng.normal(size=n)) + 0.5, dtype)
     mid = rng.normal(size=(S, m))
     d = QPData(P_diag=P, A=A,
-               l=jnp.asarray(mid - 3.0), u=jnp.asarray(mid + 3.0),
-               lb=jnp.full((S, n), -5.0), ub=jnp.full((S, n), 5.0))
-    q = jnp.asarray(rng.normal(size=(S, n)))
+               l=jnp.asarray(mid - 3.0, dtype),
+               u=jnp.asarray(mid + 3.0, dtype),
+               lb=jnp.full((S, n), -5.0, dtype),
+               ub=jnp.full((S, n), 5.0, dtype))
+    q = jnp.asarray(rng.normal(size=(S, n)), dtype)
     fac = qp_setup(d, q_ref=q)
     return fac, d, q, qp_cold_state(fac, d)
 
@@ -106,6 +108,36 @@ def test_micro_parity_fused_mixed_vs_mixed_driver():
     assert int(st_f.iters) == int(st_m.iters)
 
 
+def test_one_fused_program_serves_donating_and_frozen_rho_callers():
+    """At UC width every distinct fused program is minutes of compile
+    and GiBs of host memory, so the hot loop's donating passes, a first
+    pass that keeps its state, and the incumbent pool's frozen-rho
+    solves must all run ONE executable: ``adaptive_rho`` is traced, and
+    ``donate=False`` hands the donating program private copies."""
+    from mpisppy_tpu.ops.kernels import reference as ref
+
+    fac, d, q, st = _tiny_qp(seed=2)
+    plan = kernels.prepare(fac, mode="fused", precision="mixed")
+    kw = dict(bulk_iter=40, tail_iter=40, check_every=10, eps_abs=1e-9,
+              eps_rel=1e-9, eps_abs_dua=1e-9, eps_rel_dua=1e-9,
+              polish=False, polish_iters=12, polish_chunk=0,
+              stall_rel=0.0, ir_sweeps=1, l_inv=False)
+    jitted = ref._fused_mixed_jit_donated.__wrapped__
+    st_a, x_a, _, _ = fused_mixed_solve(fac, plan.A_lo, d, q, st, **kw)
+    size = jitted._cache_size()
+    np.asarray(st.x)             # donate=False left the caller's state
+    st_f, x_f, _, _ = fused_mixed_solve(fac, plan.A_lo, d, q, st,
+                                        adaptive_rho=False, **kw)
+    st_d, x_d, _, _ = fused_mixed_solve(fac, plan.A_lo, d, q, st_a,
+                                        donate=True, **kw)
+    assert jitted._cache_size() == size
+    with pytest.raises(RuntimeError, match="deleted"):
+        np.asarray(st_a.x)       # donate=True consumed the iterates
+    # the traced flag really freezes the stepsize
+    assert float(st_f.rho_scale) == float(st.rho_scale)
+    assert np.isfinite(np.asarray(x_d)).all()
+
+
 # ---------------- PH-level fused-vs-segmented equivalence ----------------
 
 def test_fused_matches_segmented_ph_uc_chunked():
@@ -159,10 +191,19 @@ def test_fused_matches_segmented_ph_uc_df32_with_pathological_chunk():
     and land the same blacklist decisions."""
     from mpisppy_tpu.ops.qp_solver import _factorize
 
+    # bulk budget 450 = three whole 150-iteration segments: both
+    # drivers then stop at the SAME cap (at 400 the segmented driver
+    # overshoots to 450 while the fused program stops at 400, and one
+    # healthy chunk's capped residual lands within 3% of the 1e-2 retry
+    # gate — which side it falls is decided by f32 rounding order, so
+    # the blacklist comparison below compared rounding, not recovery).
+    # Tail 600 for the same reason: at 150 the capped solves sit at the
+    # df32 noise floor and conv after the recovery differs 3x between
+    # the two drivers; at 600 they agree to 10%.
     opts = {"defaultPHrho": 50.0, "subproblem_precision": "df32",
-            "subproblem_max_iter": 400, "subproblem_eps": 1e-5,
+            "subproblem_max_iter": 450, "subproblem_eps": 1e-5,
             "subproblem_eps_hot": 1e-4, "subproblem_eps_dua_hot": 1e-2,
-            "subproblem_stall_rel": 1.5e-3, "subproblem_tail_iter": 150,
+            "subproblem_stall_rel": 1.5e-3, "subproblem_tail_iter": 600,
             "subproblem_segment": 150, "subproblem_polish_hot": False,
             "subproblem_hospital": False, "subproblem_chunk": 2}
 
@@ -267,13 +308,59 @@ def test_l_inv_profitability_check():
                                         tail_iter=100, ir_sweeps=1)
 
 
+def test_host_factor_path_matches_device_path(monkeypatch):
+    """The TPU's path for non-shared f64 factors (its batched f64
+    device inverse is shape-dependently wrong — doc/tpu_numerics.md):
+    host inversion, rho adaptation on the host between segments. Forced
+    on here, farmer (per-scenario A) must walk the same PH trajectory
+    as the device path, and the host refactorization's scatter must be
+    ONE program however many rows moved (the serving layer's
+    compile-once contract rides on it)."""
+    import mpisppy_tpu.ops.qp_solver as qps
+
+    def batch():
+        return build_batch(farmer.scenario_creator, farmer.make_tree(3))
+
+    opts = {"defaultPHrho": 1.0, "subproblem_max_iter": 3000,
+            "subproblem_eps": 1e-8, "subproblem_segment": 100}
+    ph_dev = _run_ph(batch, opts, iters=4)
+    monkeypatch.setattr(qps, "_device_f64_linalg_trusted", lambda: False)
+    obs.configure(out_dir=None)
+    try:
+        ph_host = _run_ph(batch, opts, iters=4)
+        moved = obs.counter_value("qp.host_rho_refactors")
+        fac, _ = ph_host._get_factors(True)
+        assert qps._needs_host_factor(fac)
+        assert ph_host.phase_timing(True) is None \
+            or ph_host.phase_timing(True)["kernel"]["mode"] == "segmented"
+        # rows-moved counts of 1, 2 and 3 share one scatter program
+        st = ph_host._qp_states[True]
+        def adapt(k):
+            pr = np.where(np.arange(3) < k, 1e-2, 1e-9)
+            qps._host_adapt_rho(fac, st._replace(
+                pri_rel=jnp.asarray(pr),
+                dua_rel=jnp.asarray(np.full(3, 1e-9))))
+
+        adapt(3)
+        compiles = obs.counter_value("jax.compiles")
+        adapt(1)
+        adapt(2)
+        assert obs.counter_value("qp.host_rho_refactors") == moved + 6
+        assert obs.counter_value("jax.compiles") == compiles
+    finally:
+        obs.shutdown()
+    assert moved > 0
+    assert ph_host.conv == pytest.approx(ph_dev.conv, abs=1e-6)
+    np.testing.assert_allclose(np.asarray(ph_host.xbar),
+                               np.asarray(ph_dev.xbar), atol=1e-4)
+
+
 def test_fused_mode_eligibility_guards(monkeypatch):
     """Explicit 'fused' on factors whose rho adaptation must
     refactorize on the host is a config error (the in-trace _factorize
-    would produce the measured garbage device inverse); 'auto' falls
-    back. On TPU, 'auto' also refuses to fuse an f64 stretch above the
-    measured ~500-iteration per-execution watchdog ceiling — explicit
-    'fused' stays the driver-run experiment knob."""
+    would produce an untrusted device inverse); 'auto' falls back.
+    Nothing else makes 'auto' refuse: a long f64 budget fuses on every
+    backend."""
     fac, d, q, st = _tiny_qp()
     monkeypatch.setattr(kernels, "_needs_host_factor", lambda f: True)
     with pytest.raises(ValueError, match="host"):
@@ -281,20 +368,15 @@ def test_fused_mode_eligibility_guards(monkeypatch):
     assert kernels.prepare(fac, mode="auto",
                            precision="native").mode == "segmented"
     monkeypatch.setattr(kernels, "_needs_host_factor", lambda f: False)
-    monkeypatch.setattr(kernels.jax, "default_backend", lambda: "tpu")
-    assert kernels.prepare(fac, mode="auto", precision="native",
-                           bulk_iter=5000).mode == "segmented"
-    assert kernels.prepare(fac, mode="auto", precision="native",
-                           bulk_iter=400).mode == "fused"
-    # precision-escalated solves count only the f64 TAIL against the
-    # ceiling (the f32 bulk is exempt — qp_solve_mixed's record)
-    assert kernels.prepare(fac, mode="auto", precision="mixed",
-                           bulk_iter=5000, tail_iter=150).mode == "fused"
-    assert kernels.prepare(fac, mode="fused", precision="native",
-                           bulk_iter=5000).mode == "fused"
-    monkeypatch.setattr(kernels.jax, "default_backend", lambda: "cpu")
-    assert kernels.prepare(fac, mode="auto", precision="native",
-                           bulk_iter=5000).mode == "fused"
+    for backend in ("tpu", "cpu"):
+        monkeypatch.setattr(kernels.jax, "default_backend",
+                            lambda b=backend: b)
+        for mode in ("auto", "fused"):
+            assert kernels.prepare(fac, mode=mode, precision="native",
+                                   bulk_iter=5000).mode == "fused"
+        assert kernels.prepare(fac, mode="auto", precision="mixed",
+                               bulk_iter=5000,
+                               tail_iter=1500).mode == "fused"
 
 
 # ---------------- the bf16 block trade ----------------
@@ -360,9 +442,7 @@ def test_pallas_interpret_block_parity_vs_reference():
     fixed-rho iterations from a cold state agree with the reference
     solver to roundoff (scaled iterates and unscaled residual maxima
     alike)."""
-    assert pallas_kernel.HAVE_PALLAS
     fac, d, q, st = _tiny_qp(seed=2)
-    assert pallas_kernel.pallas_supported(fac, st)
     x, yA, yB, zA, zB, pri, dua = pallas_kernel.fused_admm_block(
         fac, d, q, st, n_steps=20, interpret=True)
     st_r, _, _, _ = qp_solve(fac, d, q, st, max_iter=20, check_every=20,
@@ -376,36 +456,58 @@ def test_pallas_interpret_block_parity_vs_reference():
                                atol=1e-9)
 
 
-def test_pallas_backend_solve_through_kernel_layer():
-    """End-to-end pallas-backed kernel_solve on the tiny QP: the block
-    runs the budget at fixed rho, the oracle finisher polishes, and
-    the result converges the problem (functional contract — exact
-    parity is the block test above)."""
-    fac, d, q, st = _tiny_qp(seed=4)
+def test_pallas_backend_solve_through_kernel_layer(monkeypatch):
+    """End-to-end pallas-backed kernel_solve on the tiny QP, on the
+    operands the TPU kernel's scope admits (f32, explicit L⁻¹): the
+    block runs the budget at fixed rho, the oracle finisher polishes,
+    and the result converges the problem (functional contract — exact
+    parity is the block test above). The program never interprets the
+    kernel; on the CPU tier the TEST does."""
+    from functools import partial
+    monkeypatch.setattr(
+        pallas_kernel, "fused_admm_block",
+        partial(pallas_kernel.fused_admm_block, interpret=True))
+    fac, d, q, st = _tiny_qp(seed=4, dtype=jnp.float32)
+    st = st._replace(L=make_l_inv(st.L))
+    assert pallas_kernel.pallas_supported(fac, st)
     plan = kernels.prepare(fac, mode="fused", backend="pallas",
                            precision="native")
     assert plan.backend == "pallas"
     st_p, x_p, _, _ = kernels.kernel_solve(
         plan, fac, d, q, st, precision="native", max_iter=400,
-        tail_iter=0, e_pri=1e-8, e_dua=1e-8, stall_rel=0.0, polish=True,
+        tail_iter=0, e_pri=1e-4, e_dua=1e-4, stall_rel=0.0, polish=True,
         polish_chunk=0, ir_sweeps=1)
     st_r, x_r, _, _ = qp_solve(fac, d, q, st, max_iter=400,
-                               eps_abs=1e-8, eps_rel=1e-8, polish=True)
-    assert float(np.asarray(st_p.pri_rel).max()) < 1e-6
+                               eps_abs=1e-4, eps_rel=1e-4, polish=True)
+    assert float(np.asarray(st_p.pri_rel).max()) < 1e-3
     np.testing.assert_allclose(np.asarray(x_p), np.asarray(x_r),
-                               rtol=1e-5, atol=1e-7)
+                               rtol=1e-3, atol=1e-3)
 
 
-def test_pallas_out_of_scope_falls_back_to_reference():
-    """Non-shared / split / mixed operands are outside the pallas
-    block's scope: prepare demotes the backend to reference instead of
-    failing at solve time."""
+def test_pallas_out_of_scope_is_a_config_error():
+    """A pallas backend that was asked for and cannot serve the solve
+    raises, naming the reason — it is never demoted to reference
+    mid-run. Static scope (split / mixed operands) at prepare();
+    state-dependent scope (f64 M⁻¹, a Cholesky factor, a working set
+    over the VMEM limit) at the solve."""
     hi = jnp.asarray(np.ones((6, 4)), jnp.float32)
     sm = SplitMatrix(hi, jnp.zeros_like(hi))
-    fac = types.SimpleNamespace(A_s=sm)
+    with pytest.raises(ValueError, match="pallas"):
+        kernels.prepare(types.SimpleNamespace(A_s=sm), mode="fused",
+                        backend="pallas", precision="df32", l_inv="off")
+    # f64 operands: Mosaic has no f64 type
+    fac, d, q, st = _tiny_qp(seed=4)
+    assert "f64" in pallas_kernel.pallas_scope_reason(fac, st)
     plan = kernels.prepare(fac, mode="fused", backend="pallas",
-                           precision="df32", l_inv="off")
-    assert plan.backend == "reference"
+                           precision="native")
+    with pytest.raises(ValueError, match="f64"):
+        kernels.kernel_solve(
+            plan, fac, d, q, st, precision="native", max_iter=10,
+            tail_iter=0, e_pri=1e-4, e_dua=1e-4, stall_rel=0.0,
+            polish=False, polish_chunk=0, ir_sweeps=1)
+    # f32 with a raw Cholesky factor: not an explicit inverse
+    fac, d, q, st = _tiny_qp(seed=4, dtype=jnp.float32)
+    assert "explicit inverse" in pallas_kernel.pallas_scope_reason(fac, st)
 
 
 # ---------------- config validation (the small fix) ----------------

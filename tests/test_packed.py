@@ -1,8 +1,8 @@
 """Structure-packed matvec (ops/packed.py): exactness against the dense
 paths and end-to-end df32 solves through the packed representation.
 
-The packed form is the r5 hot-loop representation (BENCH_r04 measured
-3.8% MFU with dense A-passes streaming ~99.6% zeros at reference-UC
+The packed form is the r5 hot-loop representation (the round-4 kernel
+measured 3.8% MFU with dense A-passes streaming ~99.6% zeros at reference-UC
 scale); these tests pin (a) the discovery/pack/apply pipeline against
 dense ground truth on a real UC matrix, and (b) that a df32 engine
 solving through it reproduces the unpacked engine's results."""

@@ -426,11 +426,22 @@ def test_transplant_poisoned_rows_zeroed_to_cold(telemetry):
 
 # ---------------- df32 compacted gather (ISSUE 17) ----------------
 
+# Solver-grade df32, NOT the bench recipe's budget caps (tail 100-150,
+# stall exit at 1.5e-3): at those caps the df32 solves of this toy sit
+# at their ~1e-3 residual floor, and which slots reach the fixer's 1e-2
+# consensus band within 10 iterations is decided by f32 rounding order
+# — measured under jax 0.9: 2..29 of 36 slots fixed across 1e-7..1e-4
+# cost perturbations and across arithmetically equivalent paths (LInv
+# vs triangular solves agree per call to the solve tolerance, the PH
+# trajectories built on them do not). With the tail given its budget
+# (1500, no stall exit) the full-width, compacted and sharded
+# trajectories are reproducible to the bands below, so these tests
+# exercise the compacted df32 gather, not the rounding of the day.
 DF32_OPTS = dict(UC_OPTS, subproblem_precision="df32",
                  subproblem_eps=1e-5, subproblem_eps_hot=1e-4,
                  subproblem_eps_dua_hot=1e-2,
-                 subproblem_stall_rel=1.5e-3,
-                 subproblem_tail_iter=150)
+                 subproblem_stall_rel=0.0,
+                 subproblem_tail_iter=1500)
 
 
 def test_df32_compacted_roundtrip_matches_fullwidth(telemetry):
@@ -591,9 +602,9 @@ def test_pallas_scen_tiling_parity():
     factors, d = ph._get_factors(False)
     st = ph._ensure_state(False)
     out_full = pk.fused_admm_block(factors, d, ph.c, st, n_steps=30,
-                                   scen_tile=0)
+                                   scen_tile=0, interpret=True)
     out_tiled = pk.fused_admm_block(factors, d, ph.c, st, n_steps=30,
-                                    scen_tile=2)
+                                    scen_tile=2, interpret=True)
     for a, t in zip(out_full, out_tiled):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(t))
 

@@ -476,7 +476,11 @@ def run_chaos(requests=12, faults=4, seed=7, num_scens=3,
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(
         description="randomized fault schedule against a 2-process "
-                    "serve fleet; verifies zero requests are lost")
+                    "serve fleet; verifies zero requests are lost. "
+                    "A CPU tool: the fleet it starts runs with "
+                    "JAX_PLATFORMS=cpu unless the caller's environment "
+                    "says otherwise (two server processes cannot share "
+                    "one chip).")
     p.add_argument("--requests", type=int, default=12)
     p.add_argument("--faults", type=int, default=4,
                    help="process faults (SIGTERM/SIGKILL) to fire")

@@ -599,7 +599,12 @@ def run_forensics_smoke(fresh: str) -> int:
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(
         description="tier-1 perf regression gate "
-                    "(bench + analyze --compare vs committed golden)")
+                    "(bench + analyze --compare vs committed golden). "
+                    "A CPU tool: every stage's child runs with "
+                    "JAX_PLATFORMS=cpu unless the caller's environment "
+                    "says otherwise, and the golden it compares against "
+                    "is CPU-tier telemetry; it never times a chip "
+                    "(chip_smoke.py is the chip's entry point).")
     p.add_argument("--golden", default=GOLDEN,
                    help=f"golden telemetry dir (default {GOLDEN})")
     p.add_argument("--threshold", type=float, default=3.0,

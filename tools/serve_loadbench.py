@@ -208,7 +208,11 @@ def recommend(rows) -> dict:
 
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(
-        description="serve load benchmark: requests/s vs wheel width")
+        description="serve load benchmark: requests/s vs wheel width. "
+                    "A CPU tool: the servers it starts run with "
+                    "JAX_PLATFORMS=cpu unless the caller's environment "
+                    "says otherwise; it never times a chip "
+                    "(chip_smoke.py is the chip's entry point).")
     p.add_argument("--wheels", default="1,2",
                    help="comma-separated --max-wheels grid")
     p.add_argument("--batch", default="1,8",
