@@ -67,7 +67,6 @@ aph_use_lag; async_frac_needed / async_sleep_secs are accepted and ignored
 
 from __future__ import annotations
 
-import time as _time
 from functools import partial
 
 import jax
@@ -249,6 +248,103 @@ class APH(PHBase):
         self._last_dispatch[mask] = self._iter
         self._dispatched = mask
 
+    def _aph_iteration(self, it, spcomm):
+        """One APH iteration (ref. aph.py:704-815 APH_iterk): projective
+        step, the stacked gate, termination tests, dispatch and solve.
+        Returns False when a termination test ended the run."""
+        nu, gamma = self.nu, self.gamma
+        S, S_real = self.batch.S, self._S_orig
+        xn = self.nonants_of(self.x)
+        # Update_y on the previously dispatched set (ref. aph.py:157-186;
+        # y ≡ 0 at iter 1 — "iter 1 is iter 0 post-solves")
+        if it > 1:
+            W_y = self._W_lag if self.use_lag else self.W
+            z_y = self._z_lag if self.use_lag else self.z
+            y_new = W_y + self.rho * (xn - z_y)
+            self.y_aph = jnp.where(jnp.asarray(self._dispatched)[:, None],
+                                   y_new, self.y_aph)
+        # FirstReduce + projective step, fused
+        xbar = self.compute_xbar(xn)
+        xsqbar = self.compute_xbar(xn * xn)
+        ybar = self.compute_xbar(self.y_aph)
+        (self.W, self.z, tau, phi, theta, conv, phis,
+         pusq, pvsq, pwsq, pzsq) = _aph_update(
+            xn, self.W, self.y_aph, self.z, self.rho, self.prob,
+            xbar, ybar, nu, gamma, iter1=(it == 1))
+        self.xbar, self.xsqbar, self.ybar = xbar, xsqbar, ybar
+        self.phis = phis   # stays on device; the gate ships stats
+        # dispatch & solve (frac forced to 1 at iter 1 "to get a decent
+        # w for everyone", ref. aph.py:783-786). Selection runs on
+        # device and rides the SAME packed gate as the projective
+        # scalars: the iteration's entire host traffic is one row.
+        frac = 1.0 if it == 1 else self.dispatch_frac
+        scnt = max(1, int(np.ceil(S_real * frac)))
+        full = scnt >= S_real
+        if full:
+            gate = scalar_gate(tau, phi, theta, conv, phis,
+                               S_real=S_real)
+        else:
+            gate = dispatch_gate(tau, phi, theta, conv, phis,
+                                 jnp.asarray(self._last_dispatch),
+                                 scnt=scnt, S_real=S_real)
+        # lint: ok[SYNC001] THE stacked APH gate: one D2H per iteration carries scalars + phi stats + dispatch mask (aph.gate_syncs)
+        g = np.asarray(gate)
+        obs.counter_add("aph.gate_syncs")
+        (self.tau, self.phi, self.theta, self.conv,
+         phi_min, phi_max, phi_neg) = g[:GATE_HEAD].tolist()
+        self._phi_stats = {"phi_min": phi_min, "phi_max": phi_max,
+                           "phi_neg": int(phi_neg)}
+        if full:
+            mask = np.zeros(S, bool)
+            mask[:S_real] = True
+        else:
+            mask = g[GATE_HEAD:] != 0
+
+        if self.verbose and (it % 10 == 0 or it == 1):
+            global_toc(f"APH iter {it}: conv={self.conv:.6e} "
+                       f"tau={self.tau:.3e} phi={self.phi:.3e} "
+                       f"theta={self.theta:.3e}")
+        if spcomm is not None:
+            spcomm.sync()
+            if spcomm.is_converged():
+                global_toc(f"APH iter {it}: hub termination", self.verbose)
+                return False
+        if self.converger is not None and self.converger.is_converged():
+            global_toc(f"APH iter {it}: converger termination", self.verbose)
+            return False
+        if self.conv is not None and self.conv < self.convthresh:
+            global_toc(f"APH iter {it}: conv={self.conv:.3e} < thresh",
+                       self.verbose)
+            return False
+        self._ext("miditer")
+        cur_bucket = self._shrink.bucket \
+            if self._shrink is not None else None
+        if not full \
+                and cur_bucket != getattr(self, "_aph_shrink_bucket",
+                                          None):
+            # a compaction bucket transition landed in this
+            # miditer: the solve width changed and every warm
+            # store rebuilds cold (ops/shrink _compact_invalidate)
+            # — dispatch everyone this ONE iteration (the same
+            # warm-up rule as iter 1) so the duals re-materialize
+            # at the new width; partial dispatch resumes next
+            # iteration (doc/aph.md §composition)
+            full = True
+            mask = np.zeros(S, bool)
+            mask[:S_real] = True
+        self._aph_shrink_bucket = cur_bucket
+        didx = None
+        if not full and self._dispatch_capable():
+            didx = np.flatnonzero(mask)
+        self._aph_solve(mask, didx=didx)
+        self._aph_status = {
+            "frac": frac, "scnt": scnt, "S_real": S_real,
+            "dispatched": int(mask.sum()),
+            "solve_path": "chunked-skip" if didx is not None
+            else ("full" if full else "masked-accept"),
+            **(self._phi_stats or {})}
+        return True
+
     # ---- main loop (ref. aph.py:704-815 APH_iterk, :818 APH_main) ----
     def APH_main(self, spcomm=None, finalize=True):
         if spcomm is not None:
@@ -276,111 +372,22 @@ class APH(PHBase):
             self._W_lag = self.W
             self._z_lag = self.z
 
-        nu, gamma = self.nu, self.gamma
-        S, S_real = self.batch.S, self._S_orig
         for it in range(1, self.max_iterations + 1):
             self._iter = it
             rec_on = obs.enabled()
             if rec_on:
                 pt0 = self._phase_totals()
                 ctr0 = obs.counters_snapshot()
-            t_it = _time.perf_counter()
-            xn = self.nonants_of(self.x)
-            # Update_y on the previously dispatched set (ref. aph.py:157-186;
-            # y ≡ 0 at iter 1 — "iter 1 is iter 0 post-solves")
-            if it > 1:
-                W_y = self._W_lag if self.use_lag else self.W
-                z_y = self._z_lag if self.use_lag else self.z
-                y_new = W_y + self.rho * (xn - z_y)
-                self.y_aph = jnp.where(jnp.asarray(self._dispatched)[:, None],
-                                       y_new, self.y_aph)
-            # FirstReduce + projective step, fused
-            xbar = self.compute_xbar(xn)
-            xsqbar = self.compute_xbar(xn * xn)
-            ybar = self.compute_xbar(self.y_aph)
-            (self.W, self.z, tau, phi, theta, conv, phis,
-             pusq, pvsq, pwsq, pzsq) = _aph_update(
-                xn, self.W, self.y_aph, self.z, self.rho, self.prob,
-                xbar, ybar, nu, gamma, iter1=(it == 1))
-            self.xbar, self.xsqbar, self.ybar = xbar, xsqbar, ybar
-            self.phis = phis   # stays on device; the gate ships stats
-            # dispatch & solve (frac forced to 1 at iter 1 "to get a decent
-            # w for everyone", ref. aph.py:783-786). Selection runs on
-            # device and rides the SAME packed gate as the projective
-            # scalars: the iteration's entire host traffic is one row.
-            frac = 1.0 if it == 1 else self.dispatch_frac
-            scnt = max(1, int(np.ceil(S_real * frac)))
-            full = scnt >= S_real
-            if full:
-                gate = scalar_gate(tau, phi, theta, conv, phis,
-                                   S_real=S_real)
-            else:
-                gate = dispatch_gate(tau, phi, theta, conv, phis,
-                                     jnp.asarray(self._last_dispatch),
-                                     scnt=scnt, S_real=S_real)
-            # lint: ok[SYNC001] THE stacked APH gate: one D2H per iteration carries scalars + phi stats + dispatch mask (aph.gate_syncs)
-            g = np.asarray(gate)
-            obs.counter_add("aph.gate_syncs")
-            (self.tau, self.phi, self.theta, self.conv,
-             phi_min, phi_max, phi_neg) = g[:GATE_HEAD].tolist()
-            self._phi_stats = {"phi_min": phi_min, "phi_max": phi_max,
-                               "phi_neg": int(phi_neg)}
-            if full:
-                mask = np.zeros(S, bool)
-                mask[:S_real] = True
-            else:
-                mask = g[GATE_HEAD:] != 0
-
-            if self.verbose and (it % 10 == 0 or it == 1):
-                global_toc(f"APH iter {it}: conv={self.conv:.6e} "
-                           f"tau={self.tau:.3e} phi={self.phi:.3e} "
-                           f"theta={self.theta:.3e}")
-            if spcomm is not None:
-                spcomm.sync()
-                if spcomm.is_converged():
-                    global_toc(f"APH iter {it}: hub termination", self.verbose)
-                    break
-            if self.converger is not None and self.converger.is_converged():
-                global_toc(f"APH iter {it}: converger termination", self.verbose)
+            sp_args = {"iter": it} if rec_on else None
+            with obs.span("ph.iteration", cat="ph", args=sp_args) as sp_it:
+                go_on = self._aph_iteration(it, spcomm)
+            if not go_on:
                 break
-            if self.conv is not None and self.conv < self.convthresh:
-                global_toc(f"APH iter {it}: conv={self.conv:.3e} < thresh",
-                           self.verbose)
-                break
-            self._ext("miditer")
-            cur_bucket = self._shrink.bucket \
-                if self._shrink is not None else None
-            if not full \
-                    and cur_bucket != getattr(self, "_aph_shrink_bucket",
-                                              None):
-                # a compaction bucket transition landed in this
-                # miditer: the solve width changed and every warm
-                # store rebuilds cold (ops/shrink _compact_invalidate)
-                # — dispatch everyone this ONE iteration (the same
-                # warm-up rule as iter 1) so the duals re-materialize
-                # at the new width; partial dispatch resumes next
-                # iteration (doc/aph.md §composition)
-                full = True
-                mask = np.zeros(S, bool)
-                mask[:S_real] = True
-            self._aph_shrink_bucket = cur_bucket
-            didx = None
-            if not full and self._dispatch_capable():
-                didx = np.flatnonzero(mask)
-            self._aph_solve(mask, didx=didx)
-            self._aph_status = {
-                "frac": frac, "scnt": scnt, "S_real": S_real,
-                "dispatched": int(mask.sum()),
-                "solve_path": "chunked-skip" if didx is not None
-                else ("full" if full else "masked-accept"),
-                **(self._phi_stats or {})}
             if rec_on:
-                t_end = _time.perf_counter()
-                obs.complete_span("ph.iteration", t_it, t_end, cat="ph",
-                                  args={"iter": it})
-                obs.histogram_observe("ph.iteration_seconds", t_end - t_it)
+                obs.histogram_observe("ph.iteration_seconds",
+                                      sp_it.seconds)
                 obs.event("ph.iteration", self.iteration_record(
-                    it, t_end - t_it, pt0, ctr0))
+                    it, sp_it.seconds, pt0, ctr0))
             self._ext("enditer")
 
         if finalize:

@@ -48,10 +48,52 @@ from .spbase import SPBase, compute_xbar
 
 _log = logging.getLogger("mpisppy_tpu.ph")
 
-# phase -> telemetry span name, precomputed so the disabled-telemetry
-# hot loop's per-lap cost is a dict read, never a string allocation
+# phase -> span name, precomputed so the hot loop's per-lap cost is a
+# dict read, never a string allocation
 _PHASE_SPAN = {"assemble": "ph.assemble", "solve": "ph.solve",
                "gate": "ph.gate", "reduce": "ph.reduce"}
+
+
+def _new_phase_entry():
+    """One solve mode's ``_phase_times`` entry: the seconds and the
+    ADMM iterations of the SAME solve passes, reset together."""
+    return {"acc": {"assemble": 0.0, "solve": 0.0, "gate": 0.0,
+                    "reduce": 0.0},
+            "admm": {"bulk": 0, "tail": 0},
+            "calls": 0, "gate_syncs": 0, "devices": 1, "mode": "host"}
+
+
+class _PhaseClock:
+    """One solve pass's phase anatomy, on both clocks at once. Each
+    phase is an ``obs.span`` opened by NAME at its start (the
+    profiler's ``TraceMe`` needs the name then), and the span's own
+    ``perf_counter`` marks are what ``acc`` accumulates: the seconds
+    have one source, so the session's span totals equal
+    ``phase_timing`` totals and a ``jax.profiler`` capture shows the
+    same phases with no session at all. A pass that raises leaves its
+    open phase to the ``TraceMe``'s destructor and books nothing for
+    it."""
+
+    __slots__ = ("_acc", "_args", "_phase", "_span")
+
+    def __init__(self, acc, args, phase="assemble"):
+        self._acc = acc
+        self._args = args
+        self._open(phase)
+
+    def _open(self, phase):
+        self._phase = phase
+        self._span = obs.span(_PHASE_SPAN[phase], cat="ph",
+                              args=self._args).__enter__()
+
+    def lap(self, nxt=None):
+        """Close the open phase and open ``nxt`` (None: the pass is
+        over)."""
+        sp = self._span
+        sp.__exit__(None, None, None)
+        self._acc[self._phase] += sp.seconds
+        if nxt is not None:
+            self._open(nxt)
 
 
 def _mode_str(key):
@@ -275,13 +317,35 @@ def _solver_call(factors, d, q, qp_state, *, prox_on, precision,
                               adaptive_rho=adaptive_rho, donate=donate)
 
 
+def _book_admm_iters(admm, states, fused):
+    """Book the ADMM iterations of the solves that produced ``states``
+    into ``admm`` (the "admm" dict of a mode's ``_phase_times`` entry):
+    ``bulk`` = the low-precision phase's (``QPState.iters_lo``),
+    ``tail`` = the rest — the work of exactly the solves the solve lap
+    times, with or without a telemetry session. No new device wait:
+    fused plans' callers sit AFTER the phase-honesty block they pay
+    anyway (scalar copies, not stalls), and the segmented drivers hand
+    back HOST scalars (they read their counts segment by segment), for
+    which ``device_get`` is the identity."""
+    its = jax.device_get([(st.iters, st.iters_lo) for st in states])
+    total = sum(int(t) for t, _ in its)
+    bulk = sum(int(b) for _, b in its)
+    admm["bulk"] += bulk
+    admm["tail"] += total - bulk
+    if obs.enabled():
+        obs.counter_add("kernel.bulk_iters", bulk)
+        obs.counter_add("kernel.tail_iters", total - bulk)
+        if fused:
+            obs.counter_add("kernel.fused_iters", total)
+
+
 def _ph_step(qp_state, factors, data, c, c0, P0, prob, xbar_w, memberships,
              idx, W, xbar, rho, fixed_mask, fixed_vals, wscale=None, *,
              w_on, prox_on, slot_slices, sub_max_iter, sub_eps,
              polish_chunk, precision="native", tail_iter=1000,
              sub_eps_hot=None, sub_eps_dua_hot=None, stall_rel=0.0,
              segment=500, polish_hot=True, segment_lo=None, ir_sweeps=1,
-             lap=None, combine_fn=None, kernel=None):
+             lap=None, combine_fn=None, kernel=None, admm=None):
     """The PH iteration: batched subproblem solve + Compute_Xbar +
     Update_W + convergence + objectives + certified dual bound, staged as
     THREE jitted programs (assemble / solve / reduce) rather than one
@@ -302,11 +366,12 @@ def _ph_step(qp_state, factors, data, c, c0, P0, prob, xbar_w, memberships,
                              prox_on=prox_on)
     d = data._replace(lb=bl, ub=bu)
     if lap is not None:
-        # phase-anatomy hook (telemetry): the fused path books the same
-        # assemble/solve/reduce laps as the chunked loop. Dispatch is
-        # async, so "assemble"/"reduce" book enqueue cost while "solve"
-        # absorbs the device wait (segment iteration readbacks block).
-        lap("assemble")
+        # phase-anatomy hook (``_PhaseClock.lap``): the fused path
+        # books the same assemble/solve/reduce laps as the chunked
+        # loop. Dispatch is async, so "assemble"/"reduce" book enqueue
+        # cost while "solve" absorbs the device wait (segment iteration
+        # readbacks block).
+        lap("solve")
     qp_state, x, yA, yB = _solver_call(
         factors, d, q, qp_state, prox_on=prox_on, precision=precision,
         sub_max_iter=sub_max_iter, sub_eps=sub_eps,
@@ -314,15 +379,9 @@ def _ph_step(qp_state, factors, data, c, c0, P0, prob, xbar_w, memberships,
         tail_iter=tail_iter, stall_rel=stall_rel, segment=segment,
         polish_hot=polish_hot, polish_chunk=polish_chunk,
         segment_lo=segment_lo, ir_sweeps=ir_sweeps, kernel=kernel)
-    if kernel is not None and kernel.mode == "fused" and obs.enabled():
-        # kernel.fused_iters is booked HERE, not inside kernel_solve:
-        # the scalar iters read blocks on the whole fused program, and
-        # this is the one place the fused path pays that wait anyway
-        # (phase honesty below) — booking earlier would serialize the
-        # solve with its caller's next dispatch
-        obs.counter_add("kernel.fused_iters", int(qp_state.iters))
     if lap is not None:
-        if kernel is not None and kernel.mode == "fused":
+        fused = kernel is not None and kernel.mode == "fused"
+        if fused:
             # phase honesty: a fused program never blocks mid-solve
             # (the segmented drivers' iteration readbacks did), so the
             # device wait would otherwise escape the lap anatomy
@@ -330,7 +389,10 @@ def _ph_step(qp_state, factors, data, c, c0, P0, prob, xbar_w, memberships,
             # outside every phase
             # lint: ok[SYNC001] phase honesty: the fused wait must land inside the solve lap (see comment above)
             jax.block_until_ready(qp_state.pri_rel)
-        lap("solve")
+        # the solve's ADMM iterations beside its seconds (``admm``
+        # comes with ``lap``: the same ``_phase_times`` entry)
+        _book_admm_iters(admm, [qp_state], fused)
+        lap("reduce")
     wmask = None if wscale is None else wscale > 0
     if combine_fn is None:
         (xn, xbar_new, xsqbar_new, W_new, conv, base_obj, solved_obj,
@@ -345,8 +407,6 @@ def _ph_step(qp_state, factors, data, c, c0, P0, prob, xbar_w, memberships,
             x, yA, yB, d, q, c, c0, P0, idx, W, w_on=w_on)
         xbar_new, xsqbar_new, W_new, conv = combine_fn(
             xn, prob, xbar_w, W, rho, wmask)
-    if lap is not None:
-        lap("reduce")
     return qp_state, x, yA, yB, xn, xbar_new, xsqbar_new, W_new, \
         conv, base_obj, solved_obj, dual_obj
 
@@ -1796,33 +1856,18 @@ class PHBase(SPBase):
         if donate:
             self._chunk_dirty.add(key)   # cleared after pass 3 stores
             obs.counter_add("qp.donated_passes")
-        ent = self._phase_times.setdefault(
-            key, {"acc": {"assemble": 0.0, "solve": 0.0, "gate": 0.0,
-                          "reduce": 0.0},
-                  "calls": 0, "gate_syncs": 0, "devices": 1,
-                  "mode": "host"})
-        acc = ent["acc"]
+        ent = self._phase_times.setdefault(key, _new_phase_entry())
         ent["calls"] += 1
         ent["devices"] = ops.n_devices if sharded else 1
         ent["mode"] = "sharded" if sharded else "host"
         ent["kernel"] = plan.descriptor()
+        ent["shape"] = self._solve_shape(factors, plan, rows_per_call)
         gate_syncs = 0
         # one shared args dict per call (never mutated): lets trace
         # consumers split phase spans by solve mode, allocated only
         # when telemetry is on
         sp_args = {"mode": _mode_str(key)} if obs.enabled() else None
-        t_mark = _time.perf_counter()
-
-        def _lap(phase):
-            nonlocal t_mark
-            now = _time.perf_counter()
-            acc[phase] += now - t_mark
-            # the span shares _lap's own perf_counter marks, so the
-            # Chrome trace totals are EXACTLY phase_timing's (no-op +
-            # no allocation with telemetry disabled)
-            obs.complete_span(_PHASE_SPAN[phase], t_mark, now, cat="ph",
-                              args=sp_args)
-            t_mark = now
+        clock = _PhaseClock(ent["acc"], sp_args)
 
         # record layout (indices 0-3 are the _hospitalize contract):
         #  [st, x, yA, yB, d_c, q_c, factors]
@@ -1903,7 +1948,7 @@ class PHBase(SPBase):
         else:
             inputs = [_assemble(ci) for ci in range(len(slices))] \
                 if pipeline else None
-        _lap("assemble")
+        clock.lap("solve")
 
         # pass 1 — SOLVE. (Segmented solves sync on their own iteration
         # counters internally; the three-pass split buys a SINGLE
@@ -1917,26 +1962,21 @@ class PHBase(SPBase):
                 # (or is shipping it) — assembly cost books under
                 # "assemble" exactly like the sequential opt-out so
                 # the phase anatomy stays honest
-                t_a = _time.perf_counter()
+                clock.lap("assemble")
                 d_c, q_c, _ = _stream_assemble(ci)
-                dt_a = _time.perf_counter() - t_a
-                acc["assemble"] += dt_a
-                t_mark += dt_a
+                clock.lap("solve")
             elif pipeline:
                 d_c, q_c = inputs[ci]
             else:
                 # sequential opt-out: assembly stays interleaved on
                 # the critical path, but its wall-clock books under
-                # "assemble" (advancing t_mark keeps it out of
-                # "solve") so the seq-vs-pipelined anatomy the
-                # instrumentation exists for compares honestly
-                t_a = _time.perf_counter()
+                # "assemble" (its own span between two "solve" spans)
+                # so the seq-vs-pipelined anatomy the instrumentation
+                # exists for compares honestly
+                clock.lap("assemble")
                 d_c, q_c = _assemble(ci)
-                dt_a = _time.perf_counter() - t_a
-                acc["assemble"] += dt_a
-                t_mark += dt_a
+                clock.lap("solve")
             st_in = states[ci]
-            t_c = _time.perf_counter()
             if split_mode and prev_st is not None:
                 # df32: chunks FLOW one (rho_scale, factor) pair
                 # through the sequential loop (the in-jit adaptation
@@ -1952,14 +1992,12 @@ class PHBase(SPBase):
             # sharded: ONE SPMD chunk solve over all devices (lc
             # scenarios each, psum-reduced termination tests inside
             # the jit); host-chunked: the single-device program
-            st, x, yA, yB = _solver_call(factors, d_c, q_c, st_in,
-                                         donate=donate, **kw)
-            if obs.enabled():
-                obs.complete_span(
-                    "ph.solve.chunk", t_c, _time.perf_counter(),
-                    cat="ph", args={"chunk": ci,
-                                    "mode": sp_args["mode"],
-                                    "devices": ent["devices"]})
+            ck_args = None if sp_args is None else {
+                "chunk": ci, "mode": sp_args["mode"],
+                "devices": ent["devices"]}
+            with obs.span("ph.solve.chunk", cat="ph", args=ck_args):
+                st, x, yA, yB = _solver_call(factors, d_c, q_c, st_in,
+                                             donate=donate, **kw)
             prev_st = st
             if split_mode:
                 # record a STRIPPED state: keeping each chunk's L
@@ -1989,15 +2027,14 @@ class PHBase(SPBase):
             # lint: ok[SYNC001] phase honesty for fused plans: every chunk already enqueued, the wait adds no serialization (see comment above)
             jax.block_until_ready([rec[0].pri_rel
                                    for rec in solved_chunks])
-            if obs.enabled():
-                # booked post-block (a scalar copy per chunk, not a
-                # stall) rather than inside kernel_solve, where the
-                # read would serialize chunk k's solve with chunk
-                # k+1's dispatch
-                obs.counter_add(
-                    "kernel.fused_iters",
-                    sum(int(rec[0].iters) for rec in solved_chunks))
-        _lap("solve")
+        # the pass-1 solves' ADMM iterations, beside their seconds:
+        # booked post-block (a scalar copy per chunk, not a stall)
+        # rather than inside kernel_solve, where the read would
+        # serialize chunk k's solve with chunk k+1's dispatch. Retries
+        # keep their own counter (ph.chunk_retries).
+        _book_admm_iters(ent["admm"], [rec[0] for rec in solved_chunks],
+                         plan.mode == "fused")
+        clock.lap("gate")
         # pass 2 — bounded recovery: a chunk whose warm-started rho
         # trajectory went pathological (per-chunk shared rho adapts on
         # chunk statistics) can exhaust its budget far from
@@ -2181,7 +2218,7 @@ class PHBase(SPBase):
                     worst_pri_rel=pr_w)
         ent["gate_syncs"] += gate_syncs
         obs.counter_add("ph.gate_syncs", gate_syncs)
-        _lap("gate")
+        clock.lap("reduce")
         # pass 3 — per-chunk objectives on the accepted solutions.
         # Streamed sources restage each chunk through a SECOND in-order
         # pipeline pass (the records dropped the data blocks — see the
@@ -2319,7 +2356,7 @@ class PHBase(SPBase):
                 cat["solved"])
             self._last_dual_obj = scatter_rows(
                 jnp.asarray(self._last_dual_obj), ids_dev, cat["dual"])
-            _lap("reduce")
+            clock.lap()
             self._ext("post_solve")
             return self._last_solved_obj
         self._chunk_donatable.add(key)
@@ -2359,13 +2396,14 @@ class PHBase(SPBase):
         self._last_base_obj = cat["base"]
         self._last_solved_obj = cat["solved"]
         self._last_dual_obj = cat["dual"]
-        _lap("reduce")
+        clock.lap()
         self._ext("post_solve")
         return cat["solved"]
 
     def reset_phase_timing(self):
-        """Zero the per-phase wall-clock accumulators (bench timing
-        windows). Telemetry COUNTERS (obs: ph.gate_syncs and friends)
+        """Zero the per-phase wall-clock accumulators and the ADMM
+        iteration counts booked beside them (bench timing windows).
+        Telemetry COUNTERS (obs: ph.gate_syncs and friends)
         are process-cumulative and deliberately survive this reset —
         invariant tests read them as pure before/after deltas."""
         self._phase_times.clear()
@@ -2402,7 +2440,29 @@ class PHBase(SPBase):
             # .descriptor(), doc/kernels.md); None on engines predating
             # a kernel-plan build
             "kernel": ent.get("kernel"),
+            # the ADMM work of the SAME solve passes the solve seconds
+            # cover (pass-1 solves; reset with them): f32 bulk and
+            # refinement-tail iterations per solve_loop call, summed
+            # over its chunk solves. A solve with no low-precision
+            # phase books every iteration as tail.
+            "admm_iters_per_call": {k: v / n
+                                    for k, v in ent["admm"].items()},
+            # what one solve call of the last pass streams: keyword
+            # for keyword the facts a bytes-per-iteration model prices
+            # (ops/kernels.est_hbm_bytes_per_iter)
+            "solve_shape": ent.get("shape"),
         }
+
+    def _solve_shape(self, factors, plan, rows_per_call):
+        A_s = factors.A_s
+        m, n = (int(v) for v in A_s.shape[-2:])
+        pk = None
+        if getattr(A_s, "pk_hi", None) is not None:
+            from ..ops.packed import pk_nbytes
+            pk = pk_nbytes(A_s.pk_hi) + pk_nbytes(A_s.pk_lo)
+        return {"n": n, "m": m, "s_chunk": int(rows_per_call),
+                "ir_sweeps": int(self.sub_ir_sweeps),
+                "pk_pass_bytes": pk, "block_dtype": plan.block_dtype}
 
     def _phase_totals(self):
         """Accumulated per-phase wall-clock summed over every solve
@@ -2877,17 +2937,13 @@ class PHBase(SPBase):
         # the fused path books the same per-phase anatomy as the
         # chunked loop (gate stays 0 — there is no recovery gate here),
         # so phase_timing()/telemetry spans exist for EVERY engine, not
-        # only chunked ones. t_mark starts after the factor fetch: a
+        # only chunked ones. The clock starts after the factor fetch: a
         # first-call factorization is setup, not iteration anatomy.
         skey = ("fixed", bool(prox_on)) if fixed else bool(prox_on)
         # a full-width pass supersedes this mode's dispatch store (its
         # rows would go stale the moment the fused solve lands)
         self._qp_states.pop(("dispatch", skey), None)
-        ent = self._phase_times.setdefault(
-            skey, {"acc": {"assemble": 0.0, "solve": 0.0, "gate": 0.0,
-                           "reduce": 0.0},
-                   "calls": 0, "gate_syncs": 0, "devices": 1,
-                   "mode": "host"})
+        ent = self._phase_times.setdefault(skey, _new_phase_entry())
         ent["calls"] += 1
         ent["devices"] = sh.n_devices if sh is not None else 1
         ent["mode"] = "sharded" if sh is not None else "host"
@@ -2898,17 +2954,11 @@ class PHBase(SPBase):
             skey, factors,
             sh.shard_size if sh is not None else self.batch.S)
         ent["kernel"] = plan.descriptor()
-        acc = ent["acc"]
+        ent["shape"] = self._solve_shape(
+            factors, plan,
+            sh.shard_size if sh is not None else self.batch.S)
         sp_args = {"mode": _mode_str(skey)} if obs.enabled() else None
-        t_mark = _time.perf_counter()
-
-        def _lap(phase):
-            nonlocal t_mark
-            now = _time.perf_counter()
-            acc[phase] += now - t_mark
-            obs.complete_span(_PHASE_SPAN[phase], t_mark, now, cat="ph",
-                              args=sp_args)
-            t_mark = now
+        clock = _PhaseClock(ent["acc"], sp_args)
 
         combine_fn = sh.combine if sh is not None else None
 
@@ -2927,7 +2977,7 @@ class PHBase(SPBase):
                 self._fixed_mask[:, fs], self._fixed_vals[:, fs], ws,
                 w_on=bool(w_on), prox_on=bool(prox_on))
             d_c = data._replace(lb=bl_c, ub=bu_c)
-            _lap("assemble")
+            clock.lap("solve")
             qp_state, x_c, yA, yB = _solver_call(
                 factors, d_c, q_c, qp_state, prox_on=bool(prox_on),
                 precision=self.sub_precision,
@@ -2942,14 +2992,13 @@ class PHBase(SPBase):
                 segment_lo=self.sub_segment_lo,
                 ir_sweeps=self.sub_ir_sweeps, kernel=plan)
             if plan.mode == "fused":
-                if obs.enabled():
-                    obs.counter_add("kernel.fused_iters",
-                                    int(qp_state.iters))
                 # phase honesty (see _ph_step): the fused wait must
                 # land inside the solve lap
                 # lint: ok[SYNC001] phase honesty for fused plans, same site contract as _ph_step
                 jax.block_until_ready(qp_state.pri_rel)
-            _lap("solve")
+            _book_admm_iters(ent["admm"], [qp_state],
+                             plan.mode == "fused")
+            clock.lap("reduce")
             x = expand_solution(x_c, shrink.fixed_colvals,
                                 shrink.keep_cols, shrink.fixed_cols,
                                 self.c[0])
@@ -2969,7 +3018,7 @@ class PHBase(SPBase):
                 xbar_new, xsqbar_new, W_new, conv = combine_fn(
                     xn, self.prob, self.xbar_weights, self.W, self.rho,
                     wmask)
-            _lap("reduce")
+            clock.lap()
             self._qp_states[skey] = qp_state
             self.x, self.yA, self.yB = x, yA, yB
             if update:
@@ -3007,8 +3056,9 @@ class PHBase(SPBase):
             stall_rel=self.sub_stall_rel, segment=self.sub_segment,
             polish_hot=self.sub_polish_hot,
             segment_lo=self.sub_segment_lo,
-            ir_sweeps=self.sub_ir_sweeps, lap=_lap,
-            combine_fn=combine_fn, kernel=plan)
+            ir_sweeps=self.sub_ir_sweeps, lap=clock.lap,
+            combine_fn=combine_fn, kernel=plan, admm=ent["admm"])
+        clock.lap()
         self._qp_states[skey] = qp_state
         self.x, self.yA, self.yB = x, yA, yB
         if update:
@@ -3610,16 +3660,15 @@ class PH(PHBase):
                 # record and the next top-of-loop snapshot.
                 pt0 = self._phase_totals()
                 ctr0 = obs.counters_snapshot()
-            t_it = _time.perf_counter()
-            self.solve_loop(w_on=True, prox_on=True)
-            self.W = self.W_new
+            sp_args = {"iter": it} if rec_on else None
+            with obs.span("ph.iteration", cat="ph", args=sp_args) as sp_it:
+                self.solve_loop(w_on=True, prox_on=True)
+                self.W = self.W_new
             if rec_on:
-                t_end = _time.perf_counter()
-                obs.complete_span("ph.iteration", t_it, t_end, cat="ph",
-                                  args={"iter": it})
-                obs.histogram_observe("ph.iteration_seconds", t_end - t_it)
+                obs.histogram_observe("ph.iteration_seconds",
+                                      sp_it.seconds)
                 obs.event("ph.iteration", self.iteration_record(
-                    it, t_end - t_it, pt0, ctr0))
+                    it, sp_it.seconds, pt0, ctr0))
                 pt0 = self._phase_totals()
                 ctr0 = obs.counters_snapshot()
                 # device memory watermark gauges (guarded no-op on

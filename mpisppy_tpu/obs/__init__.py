@@ -14,10 +14,13 @@ stamps, bench one-off JSON) with three coherent artifacts:
 This module is the FACADE the rest of the codebase calls: module-level
 functions that forward to the process-wide :class:`Recorder` when one
 is configured and do (almost) nothing when not. The disabled path is a
-single global read + ``is None`` test per call and allocates nothing —
-``span(...)`` returns a shared no-op singleton — so instrumentation
-can live permanently on the PH hot loop (the <2% disabled-overhead
-budget in ISSUE 3's acceptance criteria).
+single global read + ``is None`` test per call and allocates nothing,
+so instrumentation can live permanently on the PH hot loop (the <2%
+disabled-overhead budget in ISSUE 3's acceptance criteria). The one
+exception is ``span(...)``: a span ALWAYS enters the profiler's
+``TraceMe`` (obs/trace.py), so any ``jax.profiler`` capture shows the
+program's phases with or without a session; with no capture running
+that is a flag test, and the span object dies at its exit.
 
 Usage::
 
@@ -48,24 +51,8 @@ from .recorder import Recorder                         # noqa: F401
 _REC: Recorder | None = None
 
 
-class _NullSpan:
-    """Shared no-op context manager: the disabled-mode ``span()``
-    result. A singleton so disabled spans allocate nothing."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
-
 def configure(out_dir=None, run_id=None, config=None,
-              jax_annotations=False, role=None) -> Recorder:
+              role=None) -> Recorder:
     """Start (or replace) the process-wide telemetry session. The old
     session, if any, is closed first. ``out_dir=None`` records
     in-memory only (events tail + metrics; no files) — useful in tests
@@ -77,7 +64,7 @@ def configure(out_dir=None, run_id=None, config=None,
     if _REC is not None:
         _REC.close()
     _REC = Recorder(out_dir=out_dir, run_id=run_id, config=config,
-                    jax_annotations=jax_annotations, role=role)
+                    role=role)
     return _REC
 
 
@@ -139,16 +126,11 @@ def histogram_observe(name, value):
 
 
 def span(name, cat="host", args=None, lane=None):
+    """THE span primitive (obs/trace.Span): always a ``TraceMe`` on the
+    profiler's clock, and a record in the session's trace when one is
+    configured. ``args`` reach the session only."""
     r = _REC
-    if r is None:
-        return _NULL_SPAN
-    return r.span(name, cat=cat, args=args, lane=lane)
-
-
-def complete_span(name, t0, t1, cat="host", args=None, lane=None):
-    r = _REC
-    if r is not None:
-        r.trace.complete(name, t0, t1, cat=cat, args=args, lane=lane)
+    return Span(r.trace if r is not None else None, name, cat, args, lane)
 
 
 def counters_snapshot() -> dict:

@@ -181,6 +181,13 @@ def phase_breakdown(run: Run) -> dict:
                                 {"seconds": 0.0, "calls": 0})
             ph["seconds"] += ev.get("dur", 0.0) / 1e6
             ph["calls"] += 1
+        for ent in out.values():
+            # a solve_loop call closes with exactly one ph.reduce span;
+            # the sequential and streamed opt-outs cut assemble/solve
+            # into several spans per call
+            n = ent.get("reduce", {}).get("calls")
+            for ph in ent.values():
+                ph["calls"] = n or ph["calls"]
     if not out:
         for e in run.of("ph.iteration"):
             ps = e.get("phase_seconds")

@@ -38,7 +38,7 @@ def _suffixed(name, ext, role):
 
 class Recorder:
     def __init__(self, out_dir=None, run_id=None, config=None,
-                 jax_annotations=False, role=None):
+                 role=None):
         self.out_dir = out_dir
         self.role = role
         if out_dir:
@@ -52,7 +52,7 @@ class Recorder:
         self.trace = TraceBuffer(
             path=os.path.join(out_dir, _suffixed("trace", ".json", role))
             if out_dir else None,
-            run_id=self.run_id, jax_annotations=jax_annotations, role=role)
+            run_id=self.run_id, role=role)
         self._closed = False
         # resource accounting (obs/resource.py): process-global JAX
         # compile hooks, installed once per process on the first
@@ -61,7 +61,7 @@ class Recorder:
         from . import resource
         resource.install()
 
-    # thin sink forwarding — these five are the whole hot-path surface
+    # thin sink forwarding — the whole hot-path surface
     def event(self, etype, fields=None, t=None):
         return self.events.event(etype, fields, t=t)
 
@@ -76,10 +76,6 @@ class Recorder:
 
     def span(self, name, cat="host", args=None, lane=None):
         return self.trace.span(name, cat=cat, args=args, lane=lane)
-
-    def complete_span(self, name, t0, t1, cat="host", args=None,
-                      lane=None):
-        self.trace.complete(name, t0, t1, cat=cat, args=args, lane=lane)
 
     def flush(self, nonblocking=False):
         """Persist trace.json + metrics.json. ``nonblocking`` is for

@@ -1,21 +1,28 @@
 """Span recording + Chrome trace-event export.
 
-Spans are host wall-clock intervals (``time.perf_counter`` pairs)
-buffered as Chrome trace-event "X" (complete) records and written as
-one ``trace.json`` loadable in Perfetto / chrome://tracing. The PH
-pipeline phases (assemble/solve/gate/reduce), per-chunk solves and
-per-chunk lanes all land here; lanes map to Chrome ``tid`` so
-concurrent work renders as parallel tracks.
+A span is ONE primitive with two sinks:
 
-Two recording styles:
- - ``complete(name, t0, t1)`` — the hot-loop style: the caller already
-   holds the perf_counter marks (PH's ``_lap`` accounting), so the span
-   costs one list append and stays EXACTLY consistent with
-   ``PHBase.phase_timing`` (same timestamps, same totals).
- - ``span(name)`` — a context manager for code that isn't already
-   timing itself. With ``jax_annotations=True`` it also enters a
-   ``jax.profiler.TraceAnnotation`` so host spans line up with XLA
-   device activity inside a ``jax.profiler.trace`` capture.
+ - the profiler's own clock, always: :class:`Span` enters a
+   ``jax.profiler.TraceAnnotation`` (a ``TraceMe``) by NAME when it
+   opens, so any ``jax.profiler`` capture — an operator's
+   ``jax.profiler.trace``, the benchmark's ``--trace 1`` — holds the
+   program's phases in the same xplane, on the same clock, as the
+   device ops. No switch: with no capture running a ``TraceMe`` costs
+   a flag test.
+ - the session's :class:`TraceBuffer`, when a telemetry session is
+   configured: the span's own ``perf_counter`` marks buffered as a
+   Chrome trace-event "X" (complete) record and written as one
+   ``trace.json`` loadable in Perfetto / chrome://tracing. Lanes map to
+   Chrome ``tid`` so concurrent work renders as parallel tracks.
+
+The marks are public (``t0``/``t1``/``seconds``): PH's phase accounting
+adds ``span.seconds`` to the accumulators ``PHBase.phase_timing``
+reads, so the seconds have one source and span totals equal
+``phase_timing`` totals exactly. Span ``args`` go to the session's
+sink only — the ``TraceMe`` carries the bare name, which is what
+``benchmarks/trace_reduce`` matches. ``TraceBuffer.complete`` is the
+sink's record method (also used for durations the runtime reports
+after the fact, obs/resource.py's ``jax.compile``); it cannot annotate.
 """
 
 from __future__ import annotations
@@ -25,51 +32,78 @@ import os
 import threading
 import time
 
+_TRACE_ME = None      # jax.profiler.TraceAnnotation, bound at the first span
+
+
+class _NoTraceMe:
+    """Stand-in where jax cannot be imported (the declared jax-free
+    service plane): the span then records into the session only."""
+
+    __slots__ = ()
+
+    def __init__(self, name):
+        pass
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def _trace_me(name):
+    global _TRACE_ME
+    if _TRACE_ME is None:
+        try:
+            from jax.profiler import TraceAnnotation
+            _TRACE_ME = TraceAnnotation
+        except ImportError:
+            _TRACE_ME = _NoTraceMe
+    return _TRACE_ME(name)
+
 
 class Span:
-    """Context-manager span; records a complete event on exit."""
+    """Context-manager span: a ``TraceMe`` for its whole extent, and a
+    complete event in ``buf`` (the session's TraceBuffer, or None) on
+    exit. A span abandoned by an exception is closed by the
+    ``TraceMe``'s destructor and never reaches the session."""
 
-    __slots__ = ("_buf", "name", "cat", "args", "lane", "_t0", "_ann")
+    __slots__ = ("_buf", "name", "cat", "args", "lane", "t0", "t1", "_ann")
 
-    def __init__(self, buf, name, cat, args, lane, jax_annotation=False):
+    def __init__(self, buf, name, cat="host", args=None, lane=None):
         self._buf = buf
         self.name = name
         self.cat = cat
         self.args = args
         self.lane = lane
-        self._t0 = None
-        self._ann = None
-        if jax_annotation:
-            try:
-                from jax.profiler import TraceAnnotation
-                self._ann = TraceAnnotation(name)
-            except Exception:   # profiler unavailable: host span only
-                self._ann = None
+        self.t0 = self.t1 = None
+        self._ann = _trace_me(name)
 
     def __enter__(self):
-        if self._ann is not None:
-            self._ann.__enter__()
-        self._t0 = time.perf_counter()
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        t1 = time.perf_counter()
-        if self._ann is not None:
-            self._ann.__exit__(*exc)
-        self._buf.complete(self.name, self._t0, t1, cat=self.cat,
-                           args=self.args, lane=self.lane)
+        self.t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        if self._buf is not None:
+            self._buf.complete(self.name, self.t0, self.t1, cat=self.cat,
+                               args=self.args, lane=self.lane)
         return False
+
+    @property
+    def seconds(self):
+        return self.t1 - self.t0
 
 
 class TraceBuffer:
     """In-memory Chrome trace-event buffer, flushed to one JSON file."""
 
-    def __init__(self, path=None, run_id=None, jax_annotations=False,
-                 role=None):
+    def __init__(self, path=None, run_id=None, role=None):
         self.path = path
         self.run_id = run_id
         self.role = role
-        self.jax_annotations = bool(jax_annotations)
         self._lock = threading.Lock()
         self._events = []
         self._pid = os.getpid()
@@ -113,18 +147,8 @@ class TraceBuffer:
                 ev["args"] = args
             self._events.append(ev)
 
-    def instant(self, name, cat="host", args=None, lane=None):
-        ev = {"name": name, "ph": "i", "s": "t", "cat": cat,
-              "ts": time.perf_counter() * 1e6, "pid": self._pid}
-        with self._lock:
-            ev["tid"] = self._tid(lane)
-            if args:
-                ev["args"] = args
-            self._events.append(ev)
-
     def span(self, name, cat="host", args=None, lane=None):
-        return Span(self, name, cat, args, lane,
-                    jax_annotation=self.jax_annotations)
+        return Span(self, name, cat, args, lane)
 
     def to_json(self, nonblocking=False):
         """Trace dict, or None when ``nonblocking`` and the lock is

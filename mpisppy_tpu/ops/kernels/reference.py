@@ -161,7 +161,8 @@ def _fused_mixed_impl(factors, A_lo, data, q, iterates, aux,
     dt_hi = x.dtype
     inf0 = jnp.full((S,), jnp.inf, dt_hi)
     state = QPState(x=x, yA=yA, yB=yB, zA=zA, zB=zB, L=L,
-                    rho_scale=rho_scale, iters=iters0, pri_res=inf0,
+                    rho_scale=rho_scale, iters=iters0,
+                    iters_lo=jnp.zeros((), jnp.int32), pri_res=inf0,
                     dua_res=inf0, pri_rel=inf0, dua_rel=inf0)
     lo = jnp.float32
     split = isinstance(factors.A_s, SplitMatrix)
@@ -198,42 +199,47 @@ def _fused_mixed_impl(factors, A_lo, data, q, iterates, aux,
     eps_lo = jnp.maximum(jnp.asarray(eps_abs, lo), 1e-4)
     eps_rel_lo = jnp.maximum(jnp.asarray(eps_rel, lo), 1e-3)
     eps_rel_lo_dua = jnp.maximum(jnp.asarray(eps_rel_dua, lo), 1e-2)
-    st_lo, _, _, _ = _solve_impl(
-        f_lo, d_lo, q.astype(lo), st_lo, bulk_iter, check_every,
-        eps_lo, eps_rel_lo, alpha, adaptive_rho, False, polish_iters, 0,
-        eps_lo, eps_rel_lo_dua, stall_rel)
+    # qp.bulk / qp.handoff / qp.tail: op metadata only (see the note at
+    # qp_solver._Ax) — the fused program's phases, named for xprof
+    with jax.named_scope("qp.bulk"):
+        st_lo, _, _, _ = _solve_impl(
+            f_lo, d_lo, q.astype(lo), st_lo, bulk_iter, check_every,
+            eps_lo, eps_rel_lo, alpha, adaptive_rho, False, polish_iters,
+            0, eps_lo, eps_rel_lo_dua, stall_rel)
 
     # handoff: rho and (in split mode) the f32 factor carry over — the
     # factorization's (n, n) transients are the biggest allocations in
     # the whole solve path, so the tail must not rebuild one the bulk
     # already holds
-    rho_hi = st_lo.rho_scale.astype(dt_hi)
-    L_lo = st_lo.L
-    st_hi = _cast_floats(st_lo._replace(L=jnp.zeros((), lo)), dt_hi)
-    if split:
-        L_hi = L_lo
-        if l_inv and not isinstance(L_hi, LInv):
-            # the bulk carries the raw factor (stripped above), so THIS
-            # is where the tail's explicit inverse comes from. The
-            # factor is a pure function of rho_scale, so when the
-            # bulk's rho adaptation never moved it the flowed inverse
-            # from the chunk chain is still exact — reuse it; build a
-            # fresh one (once per solve, not once per in-bulk
-            # refactorization) only when rho actually changed.
-            if isinstance(L_lo0, LInv):
-                L_hi = jax.lax.cond(
-                    jnp.all(st_lo.rho_scale == rho_lo0),
-                    lambda: L_lo0, lambda: _make_l_inv(L_lo))
-            else:
-                L_hi = _make_l_inv(L_hi)
-    else:
-        L_hi = _factorize(factors, rho_hi)
-    st_hi = st_hi._replace(L=L_hi, rho_scale=rho_hi)
-    st, x_un, yA_un, yB_un = _solve_impl(
-        factors, data, q, st_hi, tail_iter, check_every, eps_abs,
-        eps_rel, alpha, adaptive_rho, polish, polish_iters, polish_chunk,
-        eps_abs_dua, eps_rel_dua, stall_rel, ir_sweeps)
-    st = st._replace(iters=st_lo.iters + st.iters)
+    with jax.named_scope("qp.handoff"):
+        rho_hi = st_lo.rho_scale.astype(dt_hi)
+        L_lo = st_lo.L
+        st_hi = _cast_floats(st_lo._replace(L=jnp.zeros((), lo)), dt_hi)
+        if split:
+            L_hi = L_lo
+            if l_inv and not isinstance(L_hi, LInv):
+                # the bulk carries the raw factor (stripped above), so THIS
+                # is where the tail's explicit inverse comes from. The
+                # factor is a pure function of rho_scale, so when the
+                # bulk's rho adaptation never moved it the flowed inverse
+                # from the chunk chain is still exact — reuse it; build a
+                # fresh one (once per solve, not once per in-bulk
+                # refactorization) only when rho actually changed.
+                if isinstance(L_lo0, LInv):
+                    L_hi = jax.lax.cond(
+                        jnp.all(st_lo.rho_scale == rho_lo0),
+                        lambda: L_lo0, lambda: _make_l_inv(L_lo))
+                else:
+                    L_hi = _make_l_inv(L_hi)
+        else:
+            L_hi = _factorize(factors, rho_hi)
+        st_hi = st_hi._replace(L=L_hi, rho_scale=rho_hi)
+    with jax.named_scope("qp.tail"):
+        st, x_un, yA_un, yB_un = _solve_impl(
+            factors, data, q, st_hi, tail_iter, check_every, eps_abs,
+            eps_rel, alpha, adaptive_rho, polish, polish_iters,
+            polish_chunk, eps_abs_dua, eps_rel_dua, stall_rel, ir_sweeps)
+    st = st._replace(iters=st_lo.iters + st.iters, iters_lo=st_lo.iters)
     return st, x_un, yA_un, yB_un
 
 

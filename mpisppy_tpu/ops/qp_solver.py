@@ -296,12 +296,20 @@ class QPState(NamedTuple):
     L: jax.Array          # (S,n,n)|(n,n) KKT inverse (f64) / Cholesky (f32)
     rho_scale: jax.Array  # (S,) | () multiplier on the rho patterns
     iters: jax.Array      # scalar total ADMM iterations in last solve
+    iters_lo: jax.Array   # scalar: of those, the low-precision (f32 bulk)
+    #                       phase's; iters - iters_lo is the accurate
+    #                       tail's. 0 for a solve with no bulk phase.
     pri_res: jax.Array    # (S,) unscaled
     dua_res: jax.Array    # (S,) unscaled
     pri_rel: jax.Array    # (S,) pri_res / problem scale (feasibility metric)
     dua_rel: jax.Array    # (S,) dua_res / dual scale (drives host rho adapt)
 
 
+# ``qp.*`` named scopes (here and in _solve_impl / the fused program):
+# op METADATA only — no op, shape or output changes — so an xprof or
+# Perfetto view of a capture taken with the HLO proto groups the
+# program's anonymous ``fusion.N``s by solver phase (doc/observability.md).
+@jax.named_scope("qp.Ax")
 def _Ax(A, x):
     """A x with A (m,n) shared, (S,m,n) batched, SplitMatrix (df32),
     PackedMatrix, or ScaledView; x (S,n) -> (S,m). The split path runs
@@ -326,6 +334,7 @@ def _Ax(A, x):
     return jnp.einsum("smn,sn->sm", A, x)
 
 
+@jax.named_scope("qp.ATy")
 def _ATy(A, y):
     """Aᵀ y with A (m,n) shared, (S,m,n) batched, SplitMatrix,
     PackedMatrix, or ScaledView; y (S,m) -> (S,n)."""
@@ -815,6 +824,7 @@ def _zero_state(factors: QPFactors, data: QPData, L) -> QPState:
                    yB=jnp.zeros((S, n), dt), zA=jnp.zeros((S, m), dt),
                    zB=jnp.zeros((S, n), dt), L=L, rho_scale=rho_scale,
                    iters=jnp.zeros((), jnp.int32),
+                   iters_lo=jnp.zeros((), jnp.int32),
                    pri_res=jnp.full((S,), jnp.inf, dt),
                    dua_res=jnp.full((S,), jnp.inf, dt),
                    pri_rel=jnp.full((S,), jnp.inf, dt),
@@ -955,7 +965,8 @@ def _solve_impl(factors: QPFactors, data: QPData, q, state: QPState,
 
         x = _chol_solve(L, rhs)
         for _ in range(ir_sweeps):
-            x = x + _chol_solve(L, rhs - m_apply(x))
+            with jax.named_scope("qp.ir_sweep"):
+                x = x + _chol_solve(L, rhs - m_apply(x))
         return x
 
     def admm_chunk(x, yA, yB, zA, zB, L, rA, rB):
@@ -968,9 +979,10 @@ def _solve_impl(factors: QPFactors, data: QPData, q, state: QPState,
             # un-refined solves must NOT use an explicit L⁻¹ (see LInv:
             # the inverse is licensed only under IR contraction) — an
             # LInv carry hands its raw factor to this branch
-            x_t = _m_solve_ir(L, rhs, rA, rB) if split_mode \
-                else _chol_solve(L.tri if isinstance(L, LInv) else L,
-                                 rhs)
+            with jax.named_scope("qp.kkt_solve"):
+                x_t = _m_solve_ir(L, rhs, rA, rB) if split_mode \
+                    else _chol_solve(L.tri if isinstance(L, LInv) else L,
+                                     rhs)
             x_new = alpha * x_t + (1 - alpha) * x
             zA_t = _Ax(A_s, x_t)
             zA_mix = alpha * zA_t + (1 - alpha) * zA
@@ -999,10 +1011,11 @@ def _solve_impl(factors: QPFactors, data: QPData, q, state: QPState,
          stall_ct) = carry
         rA, rB = rho_of(rho_scale)
         x, yA, yB, zA, zB = admm_chunk(x, yA, yB, zA, zB, L, rA, rB)
-        pri, dua, pri_sc, dua_sc = residuals(x, yA, yB, zA, zB)
-        conv_ok = jnp.logical_and(
-            pri <= eps_abs + eps_rel * pri_sc,
-            dua <= eps_abs_dua + eps_rel_dua * dua_sc)
+        with jax.named_scope("qp.check"):
+            pri, dua, pri_sc, dua_sc = residuals(x, yA, yB, zA, zB)
+            conv_ok = jnp.logical_and(
+                pri <= eps_abs + eps_rel * pri_sc,
+                dua <= eps_abs_dua + eps_rel_dua * dua_sc)
         # stall exit (window-based, oscillation-robust): a scenario whose
         # BEST residual pair hasn't improved 5% in 4 consecutive checks
         # while its primal passes the coarse gate is plateaued — the
@@ -1017,42 +1030,43 @@ def _solve_impl(factors: QPFactors, data: QPData, q, state: QPState,
         # df32 program: one executable serves the hot loop and the
         # frozen-rho incumbent pool)
         if adaptive_rho is not False:
-            # OSQP-style infrequent adaptation: every 4th residual check;
-            # adopt only when the ideal rho moved by > 5x. In shared mode
-            # the scale is a single scalar (geometric mean of the
-            # per-scenario ideals) so the factor stays shared.
-            adapt_now = ((it // check_every) % 4) == 3
-            not_conv = jnp.logical_not(jnp.all(conv_ok))
-            ratio_s = jnp.sqrt((pri / pri_sc)
-                               / jnp.maximum(dua / dua_sc, 1e-30))
-            if shared:
-                ratio = jnp.exp(jnp.mean(jnp.log(
-                    jnp.clip(ratio_s, 1e-6, 1e6))))
-                new_scale = jnp.clip(rho_scale * ratio, 1e-6, 1e6)
-                change = jnp.maximum(new_scale / rho_scale,
-                                     rho_scale / new_scale)
-                upd = (change > 5.0) & adapt_now & not_conv & adaptive_rho
-                rho_scale = jnp.where(upd, new_scale, rho_scale)
-                need = upd
-                # one shared scalar: a refactorize resets every
-                # scenario's stall window (their stepsize DID change)
-                rho_changed = jnp.broadcast_to(need, conv_ok.shape)
-            else:
-                new_scale = jnp.clip(rho_scale * ratio_s, 1e-6, 1e6)
-                change = jnp.maximum(new_scale / rho_scale,
-                                     rho_scale / new_scale)
-                mask = (change > 5.0) & adapt_now & not_conv \
-                    & adaptive_rho
-                rho_scale = jnp.where(mask, new_scale, rho_scale)
-                need = jnp.any(mask)
-                # per-scenario rho: only the scenarios whose rho moved
-                # restart their stall window — an unrelated scenario's
-                # refactorize must not postpone another's plateau exit
-                # (ADVICE r2)
-                rho_changed = mask
-            L = jax.lax.cond(need,
-                             lambda: _refactor_like(factors, rho_scale, L),
-                             lambda: L)
+            with jax.named_scope("qp.rho_adapt"):
+                # OSQP-style infrequent adaptation: every 4th residual check;
+                # adopt only when the ideal rho moved by > 5x. In shared mode
+                # the scale is a single scalar (geometric mean of the
+                # per-scenario ideals) so the factor stays shared.
+                adapt_now = ((it // check_every) % 4) == 3
+                not_conv = jnp.logical_not(jnp.all(conv_ok))
+                ratio_s = jnp.sqrt((pri / pri_sc)
+                                   / jnp.maximum(dua / dua_sc, 1e-30))
+                if shared:
+                    ratio = jnp.exp(jnp.mean(jnp.log(
+                        jnp.clip(ratio_s, 1e-6, 1e6))))
+                    new_scale = jnp.clip(rho_scale * ratio, 1e-6, 1e6)
+                    change = jnp.maximum(new_scale / rho_scale,
+                                         rho_scale / new_scale)
+                    upd = (change > 5.0) & adapt_now & not_conv & adaptive_rho
+                    rho_scale = jnp.where(upd, new_scale, rho_scale)
+                    need = upd
+                    # one shared scalar: a refactorize resets every
+                    # scenario's stall window (their stepsize DID change)
+                    rho_changed = jnp.broadcast_to(need, conv_ok.shape)
+                else:
+                    new_scale = jnp.clip(rho_scale * ratio_s, 1e-6, 1e6)
+                    change = jnp.maximum(new_scale / rho_scale,
+                                         rho_scale / new_scale)
+                    mask = (change > 5.0) & adapt_now & not_conv \
+                        & adaptive_rho
+                    rho_scale = jnp.where(mask, new_scale, rho_scale)
+                    need = jnp.any(mask)
+                    # per-scenario rho: only the scenarios whose rho moved
+                    # restart their stall window — an unrelated scenario's
+                    # refactorize must not postpone another's plateau exit
+                    # (ADVICE r2)
+                    rho_changed = mask
+                L = jax.lax.cond(need,
+                                 lambda: _refactor_like(factors, rho_scale, L),
+                                 lambda: L)
         if stall_rel:
             # a rho refactorize resets the window (the residual jump is
             # expected, not a plateau)
@@ -1079,6 +1093,7 @@ def _solve_impl(factors: QPFactors, data: QPData, q, state: QPState,
     # next q moves it)
     new_state = QPState(x=x, yA=yA, yB=yB, zA=zA, zB=zB, L=L,
                         rho_scale=rho_scale, iters=it,
+                        iters_lo=jnp.zeros((), jnp.int32),
                         pri_res=pri, dua_res=dua, pri_rel=pri / pri_sc,
                         dua_rel=dua / dua_sc)
 
@@ -1094,6 +1109,7 @@ def _solve_impl(factors: QPFactors, data: QPData, q, state: QPState,
         per.update(A_s=A_s, P_s=P_s, D=D, E=E, Eb=Eb, cs=cs,
                    Pd=data.P_diag, A_raw=data.A)
 
+    @jax.named_scope("qp.polish")
     def tail(ps):
         A_l = ps.get("A_s", A_s)
         P_l = ps.get("P_s", P_s)
@@ -1253,13 +1269,16 @@ def qp_solve_segmented(factors: QPFactors, data: QPData, q, state: QPState,
         # compile path); overshoot is bounded by one segment and the
         # convergence/stall exit stops early anyway
         t_seg = time.perf_counter()
-        state, _, _, _ = qp_solve(factors, data, q, state,
-                                  max_iter=segment, polish=False,
-                                  donate=owned, _segmented_caller=True,
-                                  **kw)
-        owned = True
-        _trace_seg("hi-seg", t_seg, state)
-        ran = int(state.iters)
+        # one device call plus its read-back: the grain a profiler
+        # slice shorter than the solve phase still holds whole
+        with obs.span("qp.segment", cat="qp"):
+            state, _, _, _ = qp_solve(factors, data, q, state,
+                                      max_iter=segment, polish=False,
+                                      donate=owned,
+                                      _segmented_caller=True, **kw)
+            owned = True
+            _trace_seg("hi-seg", t_seg, state)
+            ran = int(state.iters)
         total += ran
         if ran < segment:   # early exit: converged or stalled
             break
@@ -1271,12 +1290,17 @@ def qp_solve_segmented(factors: QPFactors, data: QPData, q, state: QPState,
             # a huge DUAL residual at rho_scale=1 (measured on farmer:
             # primal 1e-14 but dual objectives thousands of times too
             # loose), poisoning every certified bound.
-            state = _host_adapt_rho(factors, state)
+            with obs.span("qp.host_rho_adapt", cat="qp"):
+                state = _host_adapt_rho(factors, state)
     # final call: loop skipped (max_iter=0), polish runs
-    state, x, yA, yB = qp_solve(factors, data, q, state, max_iter=0,
-                                polish=final_polish, donate=owned,
-                                _segmented_caller=True, **kw)
-    state = state._replace(iters=jnp.asarray(total, jnp.int32))
+    with obs.span("qp.polish_call", cat="qp"):
+        state, x, yA, yB = qp_solve(factors, data, q, state, max_iter=0,
+                                    polish=final_polish, donate=owned,
+                                    _segmented_caller=True, **kw)
+    # HOST scalars: the driver holds these counts already, so a caller
+    # that books them (core/ph._book_admm_iters) reads no device buffer
+    # — the polish call above stays in flight behind it
+    state = state._replace(iters=np.int32(total), iters_lo=np.int32(0))
     return state, x, yA, yB
 
 
@@ -1415,23 +1439,24 @@ def qp_solve_mixed(factors: QPFactors, data: QPData, q, state: QPState,
         # remainder must not become a fresh static max_iter
         t_seg = time.perf_counter()
         fn_lo = _solve_lo_jit_donated if owned_lo else _solve_lo_jit
-        if obs.enabled():
-            from ..obs import profile as _profile
-            st_lo, _, _, _ = _profile.call(
-                "qp.solve_lo", fn_lo, f_lo, d_lo, q_lo, st_lo,
-                seg_lo, check_every, eps_lo, eps_rel_lo, alpha,
-                adaptive_rho, polish_iters, eps_rel_lo_dua,
-                stall_rel)
-        else:
-            st_lo, _, _, _ = fn_lo(f_lo, d_lo, q_lo, st_lo,
-                                   seg_lo, check_every, eps_lo,
-                                   eps_rel_lo, alpha, adaptive_rho,
-                                   polish_iters, eps_rel_lo_dua,
-                                   stall_rel)
-        owned_lo = True
-        lo_ran = True
-        _trace_seg("lo-seg", t_seg, st_lo)
-        ran = int(st_lo.iters)
+        with obs.span("qp.segment", cat="qp"):
+            if obs.enabled():
+                from ..obs import profile as _profile
+                st_lo, _, _, _ = _profile.call(
+                    "qp.solve_lo", fn_lo, f_lo, d_lo, q_lo, st_lo,
+                    seg_lo, check_every, eps_lo, eps_rel_lo, alpha,
+                    adaptive_rho, polish_iters, eps_rel_lo_dua,
+                    stall_rel)
+            else:
+                st_lo, _, _, _ = fn_lo(f_lo, d_lo, q_lo, st_lo,
+                                       seg_lo, check_every, eps_lo,
+                                       eps_rel_lo, alpha, adaptive_rho,
+                                       polish_iters, eps_rel_lo_dua,
+                                       stall_rel)
+            owned_lo = True
+            lo_ran = True
+            _trace_seg("lo-seg", t_seg, st_lo)
+            ran = int(st_lo.iters)
         lo_total += ran
         if ran < seg_lo:
             break
@@ -1468,9 +1493,10 @@ def qp_solve_mixed(factors: QPFactors, data: QPData, q, state: QPState,
         eps_abs_dua=eps_abs_dua, eps_rel_dua=eps_rel_dua,
         stall_rel=stall_rel, ir_sweeps=ir_sweeps,
         donate=lo_ran or (donate and not split))
-    # total iteration count across both phases
-    st_hi = st_hi._replace(iters=jnp.asarray(lo_total, jnp.int32)
-                           + st_hi.iters)
+    # total iteration count across both phases, and the bulk's share
+    # (host scalars, like qp_solve_segmented's)
+    st_hi = st_hi._replace(iters=np.int32(lo_total + int(st_hi.iters)),
+                           iters_lo=np.int32(lo_total))
     return st_hi, x, yA, yB
 
 

@@ -992,9 +992,10 @@ class ServeService:
         fingerprint = config_fingerprint(
             {"bucket": bucket, "stack": [r.id for r in group]}
             if gid else {"bucket": bucket, "request": group[0].id})
-        stacked, blocks = sbatch.stack_instances(
-            [sbatch.apply_patch(base, r.payload.get("patch"))
-             for r in group])
+        with obs.span("serve.stack", cat="serve"):
+            stacked, blocks = sbatch.stack_instances(
+                [sbatch.apply_patch(base, r.payload.get("patch"))
+                 for r in group])
         wheel = self._run_wheel(ns, bucket, len(group), stacked,
                                 group[0].payload, fingerprint,
                                 resume_from,
@@ -1057,7 +1058,21 @@ class ServeService:
         """One wheel over a (possibly warm) engine: checkout/install
         or build+admit, hub-only cylinder with checkpointing under the
         request namespace, per-request deadline timer, results from
-        the consensus. Returns the wheel record."""
+        the consensus. Returns the wheel record.
+
+        The wheel and its five steps are spans (``serve.wheel`` >
+        ``.engine`` / ``.hub_setup`` / ``.main`` / ``.finalize`` /
+        ``.results``), all on this worker thread, so the engine's
+        ``ph.*`` spans nest under ``serve.wheel.main``."""
+        with obs.span("serve.wheel", cat="serve",
+                      args={"stack": stack, "bucket": bucket}
+                      if obs.enabled() else None):
+            return self._wheel_steps(ns, bucket, stack, stacked, payload,
+                                     fingerprint, resume_from, deadline,
+                                     solo_incumbent)
+
+    def _wheel_steps(self, ns, bucket, stack, stacked, payload,
+                     fingerprint, resume_from, deadline, solo_incumbent):
         from ..cylinders.hub import PHHub
         from ..cylinders.supervisor import WheelDeadline
 
@@ -1075,17 +1090,18 @@ class ServeService:
             # worker behind another tenant's wheel (the documented
             # lease semantics — the jit caches are process-global, so
             # the twin only re-pays the factorization)
-            leased = self.cache.checkout(ekey, wait=False)
-            cache_hit = leased is not None
-            if leased is None:
-                engine = build_engine(stacked, algo.to_options())
-                ent = self.cache.admit(ekey, engine,
-                                       meta={"model":
-                                             payload.get("model"),
-                                             "stack": stack})
-            else:
-                ent = leased
-                engine = install_batch(ent.engine, stacked)
+            with obs.span("serve.wheel.engine", cat="serve"):
+                leased = self.cache.checkout(ekey, wait=False)
+                cache_hit = leased is not None
+                if leased is None:
+                    engine = build_engine(stacked, algo.to_options())
+                    ent = self.cache.admit(ekey, engine,
+                                           meta={"model":
+                                                 payload.get("model"),
+                                                 "stack": stack})
+                else:
+                    ent = leased
+                    engine = install_batch(ent.engine, stacked)
             hub_opts = {"checkpoint_dir": self._ckpt_ns(ns),
                         "checkpoint_interval":
                             self.cfg.checkpoint_interval,
@@ -1096,9 +1112,10 @@ class ServeService:
                 hub_opts["resume_from"] = resume_from
             if deadline is not None:
                 hub_opts["wheel_deadline"] = max(0.1, float(deadline))
-            hub = PHHub(engine, spokes=[], options=hub_opts)
-            hub.make_windows()
-            hub.setup_hub()
+            with obs.span("serve.wheel.hub_setup", cat="serve"):
+                hub = PHHub(engine, spokes=[], options=hub_opts)
+                hub.make_windows()
+                hub.setup_hub()
             with self._hub_lock:
                 self._active_hubs[ns] = hub
             if deadline is not None:
@@ -1115,8 +1132,10 @@ class ServeService:
                 # iteration
                 self._fault_injector.on_wheel_start()
             resumed_iter = int(getattr(engine, "_iter", 0) or 0)
-            hub.main()
-            outer, inner = hub.hub_finalize()
+            with obs.span("serve.wheel.main", cat="serve"):
+                hub.main()
+            with obs.span("serve.wheel.finalize", cat="serve"):
+                outer, inner = hub.hub_finalize()
             preempted = bool(hub._preempted)
             deadline_missed = bool(hub._watchdog_fired) \
                 and not preempted
@@ -1125,13 +1144,14 @@ class ServeService:
             # this engine the moment it frees
             results = []
             if not (preempted or deadline_missed):
-                if solo_incumbent is not None:
-                    results = [solo_incumbent(engine)]
-                else:
-                    blocks = [slice(k * (stacked.S // stack),
-                                    (k + 1) * (stacked.S // stack))
-                              for k in range(stack)]
-                    results = consensus_results(engine, blocks)
+                with obs.span("serve.wheel.results", cat="serve"):
+                    if solo_incumbent is not None:
+                        results = [solo_incumbent(engine)]
+                    else:
+                        blocks = [slice(k * (stacked.S // stack),
+                                        (k + 1) * (stacked.S // stack))
+                                  for k in range(stack)]
+                        results = consensus_results(engine, blocks)
             final_iter = int(getattr(engine, "_iter", 0) or 0)
             final_conv = obs.finite_or_none(
                 float(engine.conv) if engine.conv is not None else None)

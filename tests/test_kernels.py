@@ -108,6 +108,71 @@ def test_micro_parity_fused_mixed_vs_mixed_driver():
     assert int(st_f.iters) == int(st_m.iters)
 
 
+@pytest.mark.parametrize("driver", ["fused", "segmented", "native"])
+def test_bulk_tail_split_rule(driver):
+    """One rule for the bulk/tail split of a solve's ADMM iterations
+    (``QPState.iters_lo`` of ``iters``), whichever driver ran it: the
+    low-precision phase's iterations are the bulk, everything else the
+    tail; a solve with no low-precision phase is all tail."""
+    fac, d, q, st = _tiny_qp(seed=1)
+    kw = dict(eps_abs=1e-9, eps_rel=1e-9, polish=True)
+    st_m, _, _, _ = qp_solve_mixed(fac, d, q, st, max_iter=50,
+                                   tail_iter=50, segment=50, **kw)
+    bulk, total = int(st_m.iters_lo), int(st_m.iters)
+    assert 0 < bulk <= 50 and 0 < total - bulk <= 50
+    if driver == "fused":
+        plan = kernels.prepare(fac, mode="fused", precision="mixed")
+        st_f, _, _, _ = fused_mixed_solve(
+            fac, plan.A_lo, d, q, st, bulk_iter=50, tail_iter=50,
+            check_every=25, eps_abs=1e-9, eps_rel=1e-9, eps_abs_dua=1e-9,
+            eps_rel_dua=1e-9, polish=True, polish_iters=12,
+            polish_chunk=0, stall_rel=0.0, ir_sweeps=1, l_inv=False)
+        assert (int(st_f.iters_lo), int(st_f.iters)) == (bulk, total)
+    elif driver == "segmented":
+        # the same budgets cut into two segments a phase (another
+        # trajectory: a boundary resets the stall window): the host's
+        # lo_total is what lands in iters_lo, whole segments of it
+        st_s, _, _, _ = qp_solve_mixed(fac, d, q, st, max_iter=50,
+                                       tail_iter=50, segment=25, **kw)
+        lo, hi = int(st_s.iters_lo), int(st_s.iters) - int(st_s.iters_lo)
+        assert lo in (25, 50) and 0 < hi <= 50
+    else:
+        st_n, _, _, _ = qp_solve_segmented(fac, d, q, st, max_iter=50,
+                                           segment=25, **kw)
+        assert int(st_n.iters_lo) == 0 and int(st_n.iters) > 0
+        plan = kernels.prepare(fac, mode="fused", precision="native")
+        st_k, _, _, _ = kernels.kernel_solve(
+            plan, fac, d, q, st, precision="native", max_iter=50,
+            tail_iter=0, e_pri=1e-9, e_dua=1e-9, stall_rel=0.0,
+            polish=True, polish_chunk=0, ir_sweeps=1)
+        assert int(st_k.iters_lo) == 0 and int(st_k.iters) > 0
+
+
+def test_fused_program_carries_named_scopes():
+    """The fused program's phases and the solver's steps are named in
+    op metadata (jax.named_scope: no op, shape or output changes), so
+    an xprof view groups its anonymous fusions by ``qp.*`` scope."""
+    from mpisppy_tpu.ops.kernels.reference import (_FUSED_STATICS,
+                                                   _fused_mixed_impl)
+    fac, d, q, st = _tiny_qp(seed=1)
+    plan = kernels.prepare(fac, mode="fused", precision="mixed")
+    iterates = (st.x, st.yA, st.yB, st.zA, st.zB)
+    aux = (st.L, st.rho_scale, st.iters)
+    lowered = jax.jit(_fused_mixed_impl,
+                      static_argnames=_FUSED_STATICS).lower(
+        fac, plan.A_lo, d, q, iterates, aux, 1e-9, 1e-9, 1e-9, 1e-9,
+        bulk_iter=50, tail_iter=50, check_every=25,
+        adaptive_rho=np.bool_(True), polish=True, polish_iters=12,
+        polish_chunk=0, stall_rel=0.0, ir_sweeps=1, l_inv=False)
+    text = lowered.as_text(debug_info=True)
+    for scope in ("qp.bulk", "qp.handoff", "qp.tail", "qp.kkt_solve",
+                  "qp.Ax", "qp.ATy", "qp.check", "qp.rho_adapt",
+                  "qp.polish"):
+        assert scope in text, scope
+    assert "qp.tail/" in text and "qp.kkt_solve" in text.split(
+        "qp.tail/", 1)[1]          # the steps nest under the phase
+
+
 def test_one_fused_program_serves_donating_and_frozen_rho_callers():
     """At UC width every distinct fused program is minutes of compile
     and GiBs of host memory, so the hot loop's donating passes, a first

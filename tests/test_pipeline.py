@@ -91,6 +91,58 @@ def test_fused_gate_one_sync_per_iteration():
     assert 0.0 < pt_pip["occupancy"] <= 1.0
 
 
+_DF32_OPTS = {"defaultPHrho": 50.0, "subproblem_precision": "df32",
+              "subproblem_max_iter": 400, "subproblem_eps": 1e-5,
+              "subproblem_eps_hot": 1e-4, "subproblem_eps_dua_hot": 1e-2,
+              "subproblem_stall_rel": 1.5e-3, "subproblem_tail_iter": 150,
+              "subproblem_segment": 150, "subproblem_polish_hot": False,
+              "subproblem_hospital": False, "subproblem_chunk": 2}
+
+
+@pytest.mark.parametrize("recipe", ["native", "df32", "df32-segmented"])
+def test_admm_iteration_counts_beside_the_seconds_without_session(recipe):
+    """phase_timing carries the ADMM work of the SAME solve passes its
+    seconds cover, with NO telemetry session: bulk + tail per call is
+    the sum of the chunk states' iteration counts, a native solve books
+    everything as tail, a df32 one splits at the handoff, the reset
+    zeroes both, and the gate still costs one D2H per call."""
+    assert not obs.enabled()
+    opts, S = (_OPTS, 8) if recipe == "native" else (_DF32_OPTS, 4)
+    if recipe == "df32-segmented":
+        opts = {**opts, "subproblem_kernel_mode": "segmented"}
+    ph = _run(lambda: _uc_batch(S), opts, iters=2)
+    ph.reset_phase_timing()
+    assert ph.phase_timing(True) is None            # counts went with it
+    calls = 2
+    total = 0
+    for _ in range(calls):
+        ph.solve_loop(w_on=True, prox_on=True)
+        ph.W = ph.W_new
+        total += sum(int(st.iters)
+                     for st in ph._qp_states[("chunks", True)])
+    pt = ph.phase_timing(True)
+    admm = pt["admm_iters_per_call"]
+    assert pt["calls"] == calls
+    assert admm["bulk"] + admm["tail"] == pytest.approx(total / calls)
+    n_chunks = len(ph._qp_states[("chunks", True)])
+    if recipe == "native":
+        assert admm["bulk"] == 0 and admm["tail"] > 0
+    else:
+        assert admm["bulk"] > 0
+        assert 0 <= admm["tail"] <= n_chunks * (
+            opts["subproblem_tail_iter"] + opts["subproblem_segment"])
+    assert pt["gate_d2h_syncs_per_call"] == 1.0
+    assert pt["kernel"]["mode"] == (
+        "segmented" if recipe == "df32-segmented" else "fused")
+    shape = pt["solve_shape"]
+    assert (shape["n"], shape["m"]) == (ph.batch.n, ph.batch.m)
+    assert shape["s_chunk"] == opts["subproblem_chunk"]
+    assert shape["ir_sweeps"] == 1 and shape["block_dtype"] == "f32"
+    # keyword for keyword what the bytes model prices
+    from mpisppy_tpu.ops.kernels import est_hbm_bytes_per_iter
+    assert est_hbm_bytes_per_iter(**shape)["tail"] > 0
+
+
 def test_pipeline_recovery_matches_sequential_on_pathological_chunk():
     """A chunk whose warm-started rho trajectory is forced pathological
     must be recovered by the fused gate exactly like the sequential

@@ -454,6 +454,43 @@ def test_service_stacked_wheel_matches_solo_runs(tmp_path, mem_obs):
         svc.stop()
 
 
+def test_profiler_capture_holds_serve_wheel_spans(tmp_path,
+                                                  profiler_capture):
+    """A jax.profiler capture of one served farmer request, NO
+    telemetry session: the stacking step, the wheel and its five
+    steps are in it, bare-named, nested, on the worker's thread, with
+    the engine's ph.* spans under serve.wheel.main."""
+    assert not obs.enabled()
+    svc = _service(tmp_path).start()
+    try:
+        with profiler_capture as cap:
+            r = svc.submit(_payload(algo={"max_iterations": 3},
+                                    batchable=False))
+            rec = _wait(svc, r.id)
+        assert rec["status"] == "done", rec
+    finally:
+        svc.stop()
+    spans = cap.spans()
+    names = {e[0] for e in spans}
+    steps = {"serve.wheel.engine", "serve.wheel.hub_setup",
+             "serve.wheel.main", "serve.wheel.finalize",
+             "serve.wheel.results"}
+    assert {"serve.stack", "serve.wheel", "ph.iteration", "ph.assemble",
+            "ph.solve", "ph.reduce"} | steps <= names, names
+    import re
+    assert all(re.match(r"^[a-z_]+\.[\w.\-]+$", n) for n in names), names
+    wheel_thread = {e[1] for e in spans if e[0] == "serve.wheel"}
+    assert len(wheel_thread) == 1
+    assert {e[1] for e in spans} == wheel_thread
+    for step in steps:
+        assert cap.inside(step, "serve.wheel"), step
+    assert sum(e[0] == "serve.wheel" for e in spans) == 1
+    for child in ("ph.iteration", "ph.solve"):
+        kids = [e for e in spans if e[0] == child]
+        main, = [e for e in spans if e[0] == "serve.wheel.main"]
+        assert any(main[2] <= k[2] and k[3] <= main[3] for k in kids)
+
+
 def test_service_chain_warm_starts_each_step(tmp_path, mem_obs):
     svc = _service(tmp_path).start()
     try:
