@@ -59,7 +59,7 @@ def _new_phase_entry():
     ADMM iterations of the SAME solve passes, reset together."""
     return {"acc": {"assemble": 0.0, "solve": 0.0, "gate": 0.0,
                     "reduce": 0.0},
-            "admm": {"bulk": 0, "tail": 0},
+            "admm": {"bulk": 0, "tail": 0, "refactors": 0},
             "calls": 0, "gate_syncs": 0, "devices": 1, "mode": "host"}
 
 
@@ -321,20 +321,24 @@ def _book_admm_iters(admm, states, fused):
     """Book the ADMM iterations of the solves that produced ``states``
     into ``admm`` (the "admm" dict of a mode's ``_phase_times`` entry):
     ``bulk`` = the low-precision phase's (``QPState.iters_lo``),
-    ``tail`` = the rest — the work of exactly the solves the solve lap
+    ``tail`` = the rest, ``refactors`` = their in-loop rho
+    refactorizations (``QPState.refactors``: how often the factor was
+    prepared anew) — the work of exactly the solves the solve lap
     times, with or without a telemetry session. No new device wait:
     fused plans' callers sit AFTER the phase-honesty block they pay
     anyway (scalar copies, not stalls), and the segmented drivers hand
     back HOST scalars (they read their counts segment by segment), for
     which ``device_get`` is the identity."""
-    its = jax.device_get([(st.iters, st.iters_lo) for st in states])
-    total = sum(int(t) for t, _ in its)
-    bulk = sum(int(b) for _, b in its)
+    its = jax.device_get([(st.iters, st.iters_lo, st.refactors)
+                          for st in states])
+    total, bulk, refs = (sum(int(v) for v in col) for col in zip(*its))
     admm["bulk"] += bulk
     admm["tail"] += total - bulk
+    admm["refactors"] += refs
     if obs.enabled():
         obs.counter_add("kernel.bulk_iters", bulk)
         obs.counter_add("kernel.tail_iters", total - bulk)
+        obs.counter_add("kernel.factor_prepares", refs)
         if fused:
             obs.counter_add("kernel.fused_iters", total)
 
@@ -2444,7 +2448,9 @@ class PHBase(SPBase):
             # cover (pass-1 solves; reset with them): f32 bulk and
             # refinement-tail iterations per solve_loop call, summed
             # over its chunk solves. A solve with no low-precision
-            # phase books every iteration as tail.
+            # phase books every iteration as tail. ``refactors``: the
+            # same solves' in-loop rho refactorizations, i.e. how often
+            # the factor was prepared anew (qp_solver.PreparedFactor).
             "admm_iters_per_call": {k: v / n
                                     for k, v in ent["admm"].items()},
             # what one solve call of the last pass streams: keyword

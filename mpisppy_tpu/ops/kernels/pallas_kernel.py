@@ -57,7 +57,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ..qp_solver import LInv, _scaled_problem
+from ..qp_solver import LInv, _raw_factor, _scaled_problem
 
 from jax.experimental import pallas as pl
 
@@ -314,7 +314,7 @@ def fused_admm_block(factors, data, q, state, n_steps, interpret=False,
     Dinv_c = 1.0 / (factors.D * csx)
     L = state.L
     l_inv_pair = isinstance(L, LInv)
-    F = L.inv if l_inv_pair else L
+    F = L.inv if l_inv_pair else _raw_factor(L)
     if scen_tile is None:
         scen_tile = pick_scen_tile(state.x.shape[0])
     return _block_call(factors.A_s, F, factors.P_s, g, q_s,

@@ -63,7 +63,8 @@ from ...utils.runtime import compile_serialized
 from ..packed import Packed
 from ..qp_solver import (LInv, PackedMatrix, QPData, QPState, SplitMatrix,
                          _cast_floats, _factorize, _make_l_inv,
-                         _solve_impl, make_l_inv)
+                         _prepare_factor, _raw_factor, _solve_impl,
+                         make_l_inv)
 
 __all__ = ["fused_mixed_solve", "l_inv_profitable", "bf16_gate",
            "bf16_packed", "BF16_GATE_REL"]
@@ -162,7 +163,8 @@ def _fused_mixed_impl(factors, A_lo, data, q, iterates, aux,
     inf0 = jnp.full((S,), jnp.inf, dt_hi)
     state = QPState(x=x, yA=yA, yB=yB, zA=zA, zB=zB, L=L,
                     rho_scale=rho_scale, iters=iters0,
-                    iters_lo=jnp.zeros((), jnp.int32), pri_res=inf0,
+                    iters_lo=jnp.zeros((), jnp.int32),
+                    refactors=jnp.zeros((), jnp.int32), pri_res=inf0,
                     dua_res=inf0, pri_rel=inf0, dua_rel=inf0)
     lo = jnp.float32
     split = isinstance(factors.A_s, SplitMatrix)
@@ -190,8 +192,10 @@ def _fused_mixed_impl(factors, A_lo, data, q, iterates, aux,
         # would make every in-bulk rho refactorization rebuild an n-RHS
         # inverse it immediately discards. The handoff below restores
         # the flowed inverse when rho never moved, and builds a fresh
-        # one exactly once when it did.
-        st_lo = st_lo._replace(L=L_lo0.tri)
+        # one exactly once when it did. The raw factor is PREPARED for
+        # the bulk's substitution (qp_solver.PreparedFactor: its
+        # diagonal blocks inverted once per solve, not per iteration).
+        st_lo = st_lo._replace(L=_prepare_factor(L_lo0.tri))
     if not split:
         st_lo = st_lo._replace(L=_factorize(f_lo, st_lo.rho_scale))
     # the f32 phase is a WARM START for the tail: same noise-floor
@@ -239,7 +243,8 @@ def _fused_mixed_impl(factors, A_lo, data, q, iterates, aux,
             factors, data, q, st_hi, tail_iter, check_every, eps_abs,
             eps_rel, alpha, adaptive_rho, polish, polish_iters,
             polish_chunk, eps_abs_dua, eps_rel_dua, stall_rel, ir_sweeps)
-    st = st._replace(iters=st_lo.iters + st.iters, iters_lo=st_lo.iters)
+    st = st._replace(iters=st_lo.iters + st.iters, iters_lo=st_lo.iters,
+                     refactors=st_lo.refactors + st.refactors)
     return st, x_un, yA_un, yB_un
 
 
@@ -265,12 +270,12 @@ def fused_mixed_solve(factors, A_lo, data, q, state, *, bulk_iter,
                       polish_chunk, stall_rel, ir_sweeps, l_inv,
                       adaptive_rho=True, donate=False):
     """One fused mixed/df32 solve call (see _fused_mixed_impl).
-    ``l_inv`` states arriving with a raw 2-D f32 Cholesky factor are
-    wrapped to LInv EAGERLY so the jit sees one pytree structure for the
-    whole chunk chain (a mid-chain structure flip would recompile the
-    UC-sized program)."""
+    ``l_inv`` states arriving with a 2-D f32 Cholesky factor (prepared
+    or bare) are wrapped to LInv EAGERLY so the jit sees one pytree
+    structure for the whole chunk chain (a mid-chain structure flip
+    would recompile the UC-sized program)."""
     if l_inv and not isinstance(state.L, LInv):
-        L = state.L
+        L = _raw_factor(state.L)
         if getattr(L, "ndim", 0) == 2 and L.dtype == jnp.float32:
             obs.counter_add("kernel.l_inv_factorizations")
             state = state._replace(L=make_l_inv(L))

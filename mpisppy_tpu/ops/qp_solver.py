@@ -293,12 +293,18 @@ class QPState(NamedTuple):
     yB: jax.Array         # (S, n) scaled bound duals
     zA: jax.Array         # (S, m) scaled row slacks
     zB: jax.Array         # (S, n) scaled bound slacks
-    L: jax.Array          # (S,n,n)|(n,n) KKT inverse (f64) / Cholesky (f32)
+    L: jax.Array          # (S,n,n)|(n,n) KKT inverse (f64) / Cholesky (f32);
+    #                       the shared f32 factor rides in a container:
+    #                       PreparedFactor, or LInv under the L⁻¹ trade
     rho_scale: jax.Array  # (S,) | () multiplier on the rho patterns
     iters: jax.Array      # scalar total ADMM iterations in last solve
     iters_lo: jax.Array   # scalar: of those, the low-precision (f32 bulk)
     #                       phase's; iters - iters_lo is the accurate
     #                       tail's. 0 for a solve with no bulk phase.
+    refactors: jax.Array  # scalar: in-loop rho refactorizations of the
+    #                       last solve (= factor preparations; the host
+    #                       refactorizations of untrusted-f64 backends
+    #                       count under qp.host_rho_refactors instead)
     pri_res: jax.Array    # (S,) unscaled
     dua_res: jax.Array    # (S,) unscaled
     pri_rel: jax.Array    # (S,) pri_res / problem scale (feasibility metric)
@@ -408,8 +414,11 @@ def _factorize(factors: QPFactors, rho_scale):
     error (~1e-1 on UC-class conditioning) destabilizes the iteration —
     measured NaN blowups at S=256 — so the f32 path keeps the Cholesky
     factor and pays the triangular solves. _chol_solve dispatches on the
-    stored matrix's dtype. The ill-conditioned penalty systems in the
-    POLISH always use honest Cholesky solves."""
+    stored matrix's dtype. The shared (2-D) f32 factor comes back
+    PREPARED (PreparedFactor: the factor plus its inverted diagonal
+    blocks, doc/kernels.md §prepared factor); batched f32 factors stay
+    raw. The ill-conditioned penalty systems in the POLISH always use
+    honest Cholesky solves."""
     A_s, P_s = factors.A_s, factors.P_s
     g = factors.Eb * factors.D
     n = A_s.shape[-1]
@@ -428,7 +437,7 @@ def _factorize(factors: QPFactors, rho_scale):
         M = M + jnp.diag(P_s + factors.sigma + g * g * rB)
         L = jnp.linalg.cholesky(M)
         if not invert:
-            return L
+            return _prepare_factor(L)
         eye = jnp.eye(n, dtype=A_s.dtype)
         w = jax.lax.linalg.triangular_solve(L, eye, left_side=True,
                                             lower=True)
@@ -462,7 +471,12 @@ def _factorize_split(factors: QPFactors, rho_scale):
     corrected by the refinement — the classic IR contraction argument
     (error × κ·eps32 per sweep) that Newton–Schulz on an explicit
     inverse does NOT enjoy here (measured: split-product cancellation
-    noise ~κ·1e-7 makes Newton DEGRADE a 2e-5 seed to 7e-3)."""
+    noise ~κ·1e-7 makes Newton DEGRADE a 2e-5 seed to 7e-3).
+
+    Returns the factor PREPARED for the x-update's substitution
+    (PreparedFactor, doc/kernels.md §prepared factor): what the solve
+    needs from L alone is built here, once per (re)factorization, not
+    once per ADMM iteration."""
     A_s, P_s = factors.A_s, factors.P_s
     f32 = jnp.float32
     g32 = (factors.Eb * factors.D).astype(f32)
@@ -471,7 +485,7 @@ def _factorize_split(factors: QPFactors, rho_scale):
     M32 = A_s.hi.T @ (rA32[:, None] * A_s.hi)
     M32 = M32 + jnp.diag(P_s.astype(f32) + jnp.asarray(factors.sigma, f32)
                          + g32 * g32 * rB32)
-    return jnp.linalg.cholesky(M32)
+    return _prepare_factor(jnp.linalg.cholesky(M32))
 
 
 def _device_f64_linalg_trusted():
@@ -550,6 +564,117 @@ def _tri_solve(L, b):
     return x[..., 0]
 
 
+# Block size of the prepared substitution: the one XLA's triangular-
+# solve expander uses on the TPU, so the prepared solve runs the SAME
+# blocked algorithm the expander ran (doc/kernels.md §prepared factor).
+_TRI_BLOCK = 128
+
+
+class PreparedFactor(NamedTuple):
+    """A shared (2-D) f32 Cholesky factor together with everything the
+    x-update's two triangular solves need from L ALONE, built once per
+    (re)factorization and carried in QPState.L the way LInv is.
+
+    Why: ``lax.linalg.triangular_solve`` re-derives two things from L
+    on EVERY call — a masked (n, n) copy of its triangle and the
+    inverses of its 128 x 128 diagonal blocks — and a while_loop carry
+    that a ``lax.cond`` may refactorize hides their loop-invariance
+    from the compiler. At UC width (n = 13,056) those were 47% of the
+    device's busy time in the f32 bulk (ledger, PR 26; PERF.md §6).
+    ``_prepared_solve`` runs the same blocked substitution from the
+    stored blocks and reads only the computed half of L: no mask, no
+    per-call inversion, no transpose (the Lᵀ solve walks L's column
+    panels). It is NOT the LInv trade: the off-diagonal part is still
+    substituted block row by block row, so un-refined solves (the f32
+    bulk) keep the accuracy they had (residuals equal to the pair's on
+    the chip, on the UC factor). Off the TPU triangular_solve is a
+    library call with nothing to hoist, and _chol_solve keeps the pair
+    on ``tri`` there. doc/kernels.md §prepared factor."""
+    tri: jax.Array          # (n, n) = L, lower Cholesky factor
+    dinv: jax.Array         # (ceil(n/bs), bs, bs), bs = min(128, n):
+    #                         inverses of L's diagonal blocks (a short
+    #                         last block is padded with an identity)
+
+
+def _tri_cuts(n, bs):
+    return [(j, min(j + bs, n)) for j in range(0, n, bs)]
+
+
+def _prepare_factor(L) -> PreparedFactor:
+    """Traceable L -> PreparedFactor: slice the diagonal blocks and
+    invert them with ONE batched triangular solve (on the TPU the same
+    ``InvertDiagBlocksLowerTriangular`` the expander called per
+    solve)."""
+    n = L.shape[-1]
+    bs = min(_TRI_BLOCK, n)
+    blocks = []
+    for j0, j1 in _tri_cuts(n, bs):
+        d = L[j0:j1, j0:j1]
+        if j1 - j0 < bs:
+            d = jnp.eye(bs, dtype=L.dtype).at[:j1 - j0, :j1 - j0].set(d)
+        blocks.append(d)
+    diag = jnp.stack(blocks)
+    eye = jnp.broadcast_to(jnp.eye(bs, dtype=L.dtype), diag.shape)
+    return PreparedFactor(L, jax.lax.linalg.triangular_solve(
+        diag, eye, left_side=True, lower=True))
+
+
+def _raw_factor(L):
+    """The bare (n, n) Cholesky factor of a PreparedFactor (identity on
+    anything else)."""
+    return L.tri if isinstance(L, PreparedFactor) else L
+
+
+# The substitution's matmuls state their precision: the triangular-
+# solve pair they replace was never governed by the process's
+# jax_default_matmul_precision (the TPU expander multiplies at
+# HIGHEST), so the factor solve's accuracy must not hang on that flag
+# either (bf16-pass substitution stalls near 1e-1, utils/runtime).
+_TRI_PRECISION = jax.lax.Precision.HIGHEST
+
+
+def _dot_nt(a, b):
+    """a @ b.T without a transpose op (contract both minor axes)."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               precision=_TRI_PRECISION)
+
+
+def _prepared_solve(F: PreparedFactor, b):
+    """Rows of x solve L Lᵀ x_i = b_i; b (S, n) in the factor's dtype.
+    Blocked substitution over static slices, matmuls at HIGHEST:
+    forward y_j = (b_j − y[:j] L[j, :j]ᵀ) D_j⁻ᵀ down L's block rows,
+    backward x_j = (y_j − x[j+1:] L[j+1:, j]) D_j⁻¹ up its block
+    columns, each block written in place."""
+    L, dinv = F.tri, F.dinv
+    n = L.shape[-1]
+    cuts = _tri_cuts(n, dinv.shape[-1])
+    y = b
+    for k, (j0, j1) in enumerate(cuts):
+        r = y[:, j0:j1]
+        if j0:
+            r = r - _dot_nt(y[:, :j0], L[j0:j1, :j0])
+        y = y.at[:, j0:j1].set(_dot_nt(r, dinv[k, :j1 - j0, :j1 - j0]))
+    x = y
+    for k, (j0, j1) in reversed(list(enumerate(cuts))):
+        r = x[:, j0:j1]
+        if j1 < n:
+            r = r - jnp.matmul(x[:, j1:], L[j1:, j0:j1],
+                               precision=_TRI_PRECISION)
+        x = x.at[:, j0:j1].set(jnp.matmul(r, dinv[k, :j1 - j0, :j1 - j0],
+                                          precision=_TRI_PRECISION))
+    return x
+
+
+def _pair_solve(L, b):
+    """Rows of x solve L Lᵀ x_i = b_i with the
+    ``lax.linalg.triangular_solve`` pair on the bare (n, n) factor."""
+    y = jax.lax.linalg.triangular_solve(L, b.T, left_side=True,
+                                        lower=True, transpose_a=False)
+    x = jax.lax.linalg.triangular_solve(L, y, left_side=True,
+                                        lower=True, transpose_a=True)
+    return x.T
+
+
 class LInv(NamedTuple):
     """EXPLICIT inverse of a (shared, 2-D) Cholesky factor, carried in
     QPState.L alongside the factor itself: the x-update's M⁻¹ apply
@@ -595,7 +720,8 @@ class LInv(NamedTuple):
 
 def _make_l_inv(L) -> LInv:
     """Traceable L -> (L⁻¹, L) (one n-RHS triangular solve,
-    MXU-blocked)."""
+    MXU-blocked); a PreparedFactor contributes its raw factor."""
+    L = _raw_factor(L)
     eye = jnp.eye(L.shape[-1], dtype=L.dtype)
     return LInv(jax.lax.linalg.triangular_solve(
         L, eye, left_side=True, lower=True), L)
@@ -608,8 +734,11 @@ def _refactor_like(factors, rho_scale, like):
     """In-loop refactorization that preserves the CONTAINER of the
     carried factor: a state running the L⁻¹-matmul x-update must get a
     fresh L⁻¹ when rho adaptation refactorizes mid-solve, or the
-    while_loop carry would change pytree structure. The isinstance test
-    is trace-time (pytree structure is static)."""
+    while_loop carry would change pytree structure. A PreparedFactor
+    carry needs no help: _factorize returns a freshly prepared one
+    (that IS the hoist — the preparation runs here, once per
+    refactorization). The isinstance test is trace-time (pytree
+    structure is static)."""
     L_new = _factorize(factors, rho_scale)
     if isinstance(like, LInv):
         return _make_l_inv(L_new)
@@ -621,26 +750,35 @@ def _chol_solve(F, b):
     f64 (one MXU matmul — M⁻¹ is symmetric) or a Cholesky factor in f32
     (triangular solves; see _factorize's docstring for why), or an LInv
     (explicit L⁻¹: two MXU matmuls of the same bytes as the triangular
-    solves — the ops/kernels roofline trade). An f64 b against an f32
+    solves — the ops/kernels roofline trade), or a PreparedFactor (the
+    shared f32 factor as _factorize hands it out: on the TPU the
+    blocked substitution from stored diagonal-block inverses, elsewhere
+    the triangular_solve pair on its ``tri``,
+    doc/kernels.md §prepared factor). A bare f32 factor takes the
+    lax.linalg.triangular_solve pair. An f64 b against an f32
     factor (the df32 x-update seed) solves in f32 and returns f64 — the
     refinement sweeps in _m_solve_ir own the accuracy."""
     if isinstance(F, LInv):
         out_dt = b.dtype
         u = b.astype(F.inv.dtype) @ F.inv.T     # u = L⁻¹ b (rows)
         return (u @ F.inv).astype(out_dt)       # x = L⁻ᵀ u
+    if isinstance(F, PreparedFactor):
+        # The prepared substitution IS the TPU expander's algorithm
+        # minus its per-call preparations. Where triangular_solve is a
+        # library call with nothing to hoist (LAPACK / cuBLAS trsm),
+        # the pair on F.tri stays, so every backend keeps the
+        # arithmetic it had. Chosen at lowering time, per platform: a
+        # program compiled HERE for a described TPU takes the TPU form.
+        return jax.lax.platform_dependent(
+            F, b.astype(F.tri.dtype), tpu=_prepared_solve,
+            default=lambda F, b: _pair_solve(F.tri, b)).astype(b.dtype)
     if F.dtype == jnp.float64:
         if F.ndim == 2:
             return b @ F
         return jnp.einsum("sij,sj->si", F, b)
-    out_dt = b.dtype
-    b = b.astype(F.dtype)
     if F.ndim == 2:
-        y = jax.lax.linalg.triangular_solve(F, b.T, left_side=True,
-                                            lower=True, transpose_a=False)
-        x = jax.lax.linalg.triangular_solve(F, y, left_side=True,
-                                            lower=True, transpose_a=True)
-        return x.T.astype(out_dt)
-    return _tri_solve(F, b).astype(out_dt)
+        return _pair_solve(F, b.astype(F.dtype)).astype(b.dtype)
+    return _tri_solve(F, b.astype(F.dtype)).astype(b.dtype)
 
 
 @partial(jax.jit, static_argnames=("eq_boost", "shared"))
@@ -825,6 +963,7 @@ def _zero_state(factors: QPFactors, data: QPData, L) -> QPState:
                    zB=jnp.zeros((S, n), dt), L=L, rho_scale=rho_scale,
                    iters=jnp.zeros((), jnp.int32),
                    iters_lo=jnp.zeros((), jnp.int32),
+                   refactors=jnp.zeros((), jnp.int32),
                    pri_res=jnp.full((S,), jnp.inf, dt),
                    dua_res=jnp.full((S,), jnp.inf, dt),
                    pri_rel=jnp.full((S,), jnp.inf, dt),
@@ -971,18 +1110,20 @@ def _solve_impl(factors: QPFactors, data: QPData, q, state: QPState,
 
     def admm_chunk(x, yA, yB, zA, zB, L, rA, rB):
         split_mode = isinstance(A_s, SplitMatrix)
+        # un-refined solves must NOT use an explicit L⁻¹ (see LInv: the
+        # inverse is licensed only under IR contraction) — an LInv
+        # carry hands them its raw factor, prepared HERE, once per
+        # check_every iterations and not inside the scan
+        F_plain = _prepare_factor(L.tri) \
+            if isinstance(L, LInv) and not split_mode else L
 
         def one(carry, _):
             x, yA, yB, zA, zB = carry
             rhs = sigma * x - q_s + _ATy(A_s, rA * zA - yA) \
                 + g * (rB * zB - yB)
-            # un-refined solves must NOT use an explicit L⁻¹ (see LInv:
-            # the inverse is licensed only under IR contraction) — an
-            # LInv carry hands its raw factor to this branch
             with jax.named_scope("qp.kkt_solve"):
                 x_t = _m_solve_ir(L, rhs, rA, rB) if split_mode \
-                    else _chol_solve(L.tri if isinstance(L, LInv) else L,
-                                     rhs)
+                    else _chol_solve(F_plain, rhs)
             x_new = alpha * x_t + (1 - alpha) * x
             zA_t = _Ax(A_s, x_t)
             zA_mix = alpha * zA_t + (1 - alpha) * zA
@@ -1008,7 +1149,7 @@ def _solve_impl(factors: QPFactors, data: QPData, q, state: QPState,
 
     def body(carry):
         (x, yA, yB, zA, zB, L, rho_scale, it, _, best_pri, best_dua,
-         stall_ct) = carry
+         stall_ct, nref) = carry
         rA, rB = rho_of(rho_scale)
         x, yA, yB, zA, zB = admm_chunk(x, yA, yB, zA, zB, L, rA, rB)
         with jax.named_scope("qp.check"):
@@ -1067,6 +1208,7 @@ def _solve_impl(factors: QPFactors, data: QPData, q, state: QPState,
                 L = jax.lax.cond(need,
                                  lambda: _refactor_like(factors, rho_scale, L),
                                  lambda: L)
+                nref = nref + need.astype(nref.dtype)
         if stall_rel:
             # a rho refactorize resets the window (the residual jump is
             # expected, not a plateau)
@@ -1076,16 +1218,17 @@ def _solve_impl(factors: QPFactors, data: QPData, q, state: QPState,
             stalled = jnp.zeros_like(conv_ok)
         done = jnp.all(conv_ok | stalled)
         return (x, yA, yB, zA, zB, L, rho_scale, it + check_every, done,
-                best_pri, best_dua, stall_ct)
+                best_pri, best_dua, stall_ct, nref)
 
     S_ = data.l.shape[0]
     inf0 = jnp.full((S_,), jnp.inf, dt)
     ct0 = jnp.zeros((S_,), jnp.int32)
-    x, yA, yB, zA, zB, L, rho_scale, it, _, _, _, _ = jax.lax.while_loop(
-        cond, body,
-        (state.x, state.yA, state.yB, state.zA, state.zB, state.L,
-         state.rho_scale, jnp.zeros((), jnp.int32), jnp.array(False),
-         inf0, inf0, ct0))
+    x, yA, yB, zA, zB, L, rho_scale, it, _, _, _, _, nref = \
+        jax.lax.while_loop(
+            cond, body,
+            (state.x, state.yA, state.yB, state.zA, state.zB, state.L,
+             state.rho_scale, jnp.zeros((), jnp.int32), jnp.array(False),
+             inf0, inf0, ct0, jnp.zeros((), jnp.int32)))
 
     pri, dua, pri_sc, dua_sc = residuals(x, yA, yB, zA, zB)
     # the ADMM iterates are what the NEXT solve warm-starts from (the
@@ -1093,7 +1236,7 @@ def _solve_impl(factors: QPFactors, data: QPData, q, state: QPState,
     # next q moves it)
     new_state = QPState(x=x, yA=yA, yB=yB, zA=zA, zB=zB, L=L,
                         rho_scale=rho_scale, iters=it,
-                        iters_lo=jnp.zeros((), jnp.int32),
+                        iters_lo=jnp.zeros((), jnp.int32), refactors=nref,
                         pri_res=pri, dua_res=dua, pri_rel=pri / pri_sc,
                         dua_rel=dua / dua_sc)
 
@@ -1260,7 +1403,7 @@ def qp_solve_segmented(factors: QPFactors, data: QPData, q, state: QPState,
     so per-segment factor copies die even for non-donating callers)."""
     final_polish = kw.pop("polish", True)
     host_adapt = kw.get("adaptive_rho", True) and _needs_host_factor(factors)
-    total = 0
+    total = refs = 0
     owned = donate
     while total < max_iter:
         # always run FULL segments: max_iter is a static jit arg, so a
@@ -1278,8 +1421,9 @@ def qp_solve_segmented(factors: QPFactors, data: QPData, q, state: QPState,
                                       _segmented_caller=True, **kw)
             owned = True
             _trace_seg("hi-seg", t_seg, state)
-            ran = int(state.iters)
+            ran, ref = _segment_counts(state)
         total += ran
+        refs += ref
         if ran < segment:   # early exit: converged or stalled
             break
         if host_adapt:
@@ -1300,8 +1444,15 @@ def qp_solve_segmented(factors: QPFactors, data: QPData, q, state: QPState,
     # HOST scalars: the driver holds these counts already, so a caller
     # that books them (core/ph._book_admm_iters) reads no device buffer
     # — the polish call above stays in flight behind it
-    state = state._replace(iters=np.int32(total), iters_lo=np.int32(0))
+    state = state._replace(iters=np.int32(total), iters_lo=np.int32(0),
+                           refactors=np.int32(refs))
     return state, x, yA, yB
+
+
+def _segment_counts(state):
+    """(iters, refactors) of a finished segment as host ints — the
+    segmented drivers' one blocking read per segment."""
+    return (int(v) for v in jax.device_get((state.iters, state.refactors)))
 
 
 def _host_adapt_rho(factors: QPFactors, state: QPState) -> QPState:
@@ -1433,7 +1584,7 @@ def qp_solve_mixed(factors: QPFactors, data: QPData, q, state: QPState,
     owned_lo = donate and not split
     lo_ran = False
     q_lo = q.astype(lo)
-    lo_total = 0
+    lo_total = lo_refs = 0
     while lo_total < max_iter:
         # constant segment size — see qp_solve_segmented on why the
         # remainder must not become a fresh static max_iter
@@ -1456,8 +1607,9 @@ def qp_solve_mixed(factors: QPFactors, data: QPData, q, state: QPState,
             owned_lo = True
             lo_ran = True
             _trace_seg("lo-seg", t_seg, st_lo)
-            ran = int(st_lo.iters)
+            ran, ref = _segment_counts(st_lo)
         lo_total += ran
+        lo_refs += ref
         if ran < seg_lo:
             break
     dt_hi = state.x.dtype
@@ -1496,7 +1648,9 @@ def qp_solve_mixed(factors: QPFactors, data: QPData, q, state: QPState,
     # total iteration count across both phases, and the bulk's share
     # (host scalars, like qp_solve_segmented's)
     st_hi = st_hi._replace(iters=np.int32(lo_total + int(st_hi.iters)),
-                           iters_lo=np.int32(lo_total))
+                           iters_lo=np.int32(lo_total),
+                           refactors=np.int32(lo_refs
+                                              + int(st_hi.refactors)))
     return st_hi, x, yA, yB
 
 

@@ -114,16 +114,18 @@ def test_admm_iteration_counts_beside_the_seconds_without_session(recipe):
     ph.reset_phase_timing()
     assert ph.phase_timing(True) is None            # counts went with it
     calls = 2
-    total = 0
+    total = refs = 0
     for _ in range(calls):
         ph.solve_loop(w_on=True, prox_on=True)
         ph.W = ph.W_new
-        total += sum(int(st.iters)
-                     for st in ph._qp_states[("chunks", True)])
+        states = ph._qp_states[("chunks", True)]
+        total += sum(int(st.iters) for st in states)
+        refs += sum(int(st.refactors) for st in states)
     pt = ph.phase_timing(True)
     admm = pt["admm_iters_per_call"]
     assert pt["calls"] == calls
     assert admm["bulk"] + admm["tail"] == pytest.approx(total / calls)
+    assert admm["refactors"] == pytest.approx(refs / calls)
     n_chunks = len(ph._qp_states[("chunks", True)])
     if recipe == "native":
         assert admm["bulk"] == 0 and admm["tail"] > 0

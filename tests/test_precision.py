@@ -14,9 +14,10 @@ import pytest
 from mpisppy_tpu.ir.batch import build_batch
 from mpisppy_tpu.core.ph import PHBase, PH
 from mpisppy_tpu.models import uc, farmer
-from mpisppy_tpu.ops.qp_solver import (QPData, qp_setup, qp_solve,
-                                       qp_solve_mixed, qp_solve_segmented,
-                                       qp_cold_state, _factorize)
+from mpisppy_tpu.ops.qp_solver import (PreparedFactor, QPData, qp_setup,
+                                       qp_solve, qp_solve_mixed,
+                                       qp_solve_segmented, qp_cold_state,
+                                       _factorize)
 
 
 def _uc_batch(S=4, G=3, T=6, integer=False):
@@ -52,7 +53,11 @@ def test_factorize_dtype_dispatch():
             err = jnp.max(jnp.abs(F @ M - jnp.eye(n, dtype=dtype)))
             assert float(err) < 1e-8
         else:
-            err = jnp.max(jnp.abs(F @ F.T - M)) / jnp.max(jnp.abs(M))
+            # the shared f32 factor comes PREPARED for the x-update's
+            # substitution (qp_solver.PreparedFactor): .tri is L itself
+            assert isinstance(F, PreparedFactor) and F.tri.dtype == dtype
+            L = F.tri
+            err = jnp.max(jnp.abs(L @ L.T - M)) / jnp.max(jnp.abs(M))
             assert float(err) < 1e-4
 
 
@@ -192,9 +197,11 @@ def test_df32_factorize_is_f32_preconditioner():
 
     b = _uc_batch()
     data, q, factors = _split_qp(b)
-    L = _factorize(factors, jnp.ones((), jnp.float64))
-    assert L.dtype == jnp.float32
-    assert bool(jnp.isfinite(L).all())
+    F = _factorize(factors, jnp.ones((), jnp.float64))
+    assert isinstance(F, PreparedFactor) \
+        and F.tri.dtype == jnp.float32
+    assert all(bool(jnp.isfinite(a).all()) for a in F)
+    L = F.tri
     A_s64 = np.asarray(merged64(factors.A_s))
     g = np.asarray(factors.Eb * factors.D)
     M = A_s64.T @ (np.asarray(factors.rho_A)[:, None] * A_s64) \
