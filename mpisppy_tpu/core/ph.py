@@ -55,11 +55,13 @@ _PHASE_SPAN = {"assemble": "ph.assemble", "solve": "ph.solve",
 
 
 def _new_phase_entry():
-    """One solve mode's ``_phase_times`` entry: the seconds and the
-    ADMM iterations of the SAME solve passes, reset together."""
+    """One solve mode's ``_phase_times`` entry: the seconds, the ADMM
+    iterations and the cross-chip combines of the SAME solve passes,
+    reset together."""
     return {"acc": {"assemble": 0.0, "solve": 0.0, "gate": 0.0,
                     "reduce": 0.0},
             "admm": {"bulk": 0, "tail": 0, "refactors": 0},
+            "collective": {"combines": 0, "bytes": 0},
             "calls": 0, "gate_syncs": 0, "devices": 1, "mode": "host"}
 
 
@@ -1302,6 +1304,42 @@ class PHBase(SPBase):
         return {"x": x_n, "yA": yA_n, "yB": yB_n,
                 "zA": zA_n, "zB": zB_n}
 
+    def _mesh_combine(self, ent, xn, prob, xbar_w, W, rho, wmask):
+        """A sharded engine's consensus reduce (``_ph_combine``'s
+        contract through ``ShardedScenarioOps.combine``), under its own
+        span inside ``ph.reduce`` and booked in ``ent["collective"]``
+        beside the call's seconds: one combine and the bytes its psums
+        reduce (``combine_collective_bytes``), with or without a
+        telemetry session."""
+        ops = self._shard_ops
+        with obs.span("ph.reduce.combine", cat="ph"):
+            out = ops.combine(xn, prob, xbar_w, W, rho, wmask)
+        ent["collective"]["combines"] += 1
+        ent["collective"]["bytes"] += ops.combine_collective_bytes(
+            xn.dtype.itemsize)
+        return out
+
+    def _cold_state(self, factors, d):
+        """``qp_cold_state`` with the placement every later solve hands
+        back. On a mesh the solve programs return their per-row fields
+        row-sharded over "scen"; a cold state's zeros come out of their
+        own jit REPLICATED, and a solve program called once with each
+        is lowered and compiled twice (at UC width on a four-chip v5e
+        host 111 s and 83 s, with ~11 GiB of host memory each: the
+        fused program's second compile was a quarter of a cold
+        set-up). So the rows are sharded here, a local slice per
+        device; the factor, rho and the counters stay replicated, as
+        the solves return them. One-device engines and row counts the
+        mesh does not divide (the hospital's few rows) pass through
+        untouched."""
+        st = qp_cold_state(factors, d)
+        ops = self._shard_ops
+        if ops is None or st.x.shape[0] % ops.n_devices:
+            return st
+        from ..parallel.mesh import shard_arrays
+        return st._replace(**shard_arrays(
+            ops.mesh, {f: getattr(st, f) for f in _ChunkStateView._FIELDS}))
+
     def _ensure_state(self, prox_on=True, fixed=False):
         """Per-mode solver state (the KKT factor depends on the prox term);
         x/y/z warm-start across modes. Always returns a genuine QPState:
@@ -1314,7 +1352,7 @@ class PHBase(SPBase):
         st = self._qp_states.get(key)
         if isinstance(st, _ChunkStateView):
             factors, d = self._get_factors(prox_on, fixed)
-            cold = qp_cold_state(factors, d)
+            cold = self._cold_state(factors, d)
             if st.x.shape[-1] == cold.x.shape[-1] \
                     and st.zA.shape[-1] == cold.zA.shape[-1]:
                 st = cold._replace(
@@ -1335,7 +1373,7 @@ class PHBase(SPBase):
             return st
         if key not in self._qp_states:
             factors, d = self._get_factors(prox_on, fixed)
-            st = qp_cold_state(factors, d)
+            st = self._cold_state(factors, d)
             other = next((v for k, v in self._qp_states.items()
                           if k != key and k not in self._chunk_dirty
                           and isinstance(v, (QPState, _ChunkStateView))),
@@ -1436,7 +1474,7 @@ class PHBase(SPBase):
                 idx0 = slices[0][0]
                 d0 = data._replace(l=data.l[idx0], u=data.u[idx0],
                                    lb=data.lb[idx0], ub=data.ub[idx0])
-            st0 = qp_cold_state(factors, d0)
+            st0 = self._cold_state(factors, d0)
             oth_ch = None
             transplant = other is not None \
                 and other.x.shape[0] == self.batch.S \
@@ -1691,12 +1729,22 @@ class PHBase(SPBase):
         stream = self._stream_source
         ops = self._shard_ops
         sharded = ops is not None
+        # one shared args dict per call (never mutated): lets trace
+        # consumers split phase spans by solve mode, allocated only
+        # when telemetry is on
+        sp_args = {"mode": _mode_str(key)} if obs.enabled() else None
+        restage_s = 0.0
         if sharded:
             lc = self._local_chunk(chunk)
             slices = self._sharded_chunk_slices(lc)
-            chs = self._chunked_inputs(data, lc, shrink=shrink,
-                                       c0fold=c0fold,
-                                       stream=stream is not None)
+            # the mesh's restaging IS assembly: a ph.assemble span of
+            # its own (the pass's clock starts below, after the state
+            # and plan look-ups), booked with the pass's assemble seconds
+            with obs.span("ph.assemble", cat="ph", args=sp_args) as sp:
+                chs = self._chunked_inputs(data, lc, shrink=shrink,
+                                           c0fold=c0fold,
+                                           stream=stream is not None)
+            restage_s = sp.seconds
         else:
             lc, chs = None, None
             if dispatch is None:
@@ -1867,10 +1915,7 @@ class PHBase(SPBase):
         ent["kernel"] = plan.descriptor()
         ent["shape"] = self._solve_shape(factors, plan, rows_per_call)
         gate_syncs = 0
-        # one shared args dict per call (never mutated): lets trace
-        # consumers split phase spans by solve mode, allocated only
-        # when telemetry is on
-        sp_args = {"mode": _mode_str(key)} if obs.enabled() else None
+        ent["acc"]["assemble"] += restage_s
         clock = _PhaseClock(ent["acc"], sp_args)
 
         # record layout (indices 0-3 are the _hospitalize contract):
@@ -2113,7 +2158,7 @@ class PHBase(SPBase):
             if is_nan:
                 # NaN blowup: the iterates themselves are poison — a
                 # rho reset would re-iterate NaNs; restart cold
-                st_r = qp_cold_state(fac_c, d_r)
+                st_r = self._cold_state(fac_c, d_r)
             else:
                 # plateaued far out: keep the iterates, reset the
                 # stepsize trajectory
@@ -2384,8 +2429,8 @@ class PHBase(SPBase):
             if sharded:
                 # Compute_Xbar / Update_W / convergence as segment-sum
                 # + psum over the named axis (doc/sharding.md)
-                xbar_new, xsqbar_new, W_new, conv = ops.combine(
-                    cat["xn"], self.prob, self.xbar_weights, self.W,
+                xbar_new, xsqbar_new, W_new, conv = self._mesh_combine(
+                    ent, cat["xn"], self.prob, self.xbar_weights, self.W,
                     self.rho, wmask)
             else:
                 xbar_new, xsqbar_new, W_new, conv = _ph_combine(
@@ -2453,6 +2498,11 @@ class PHBase(SPBase):
             # the factor was prepared anew (qp_solver.PreparedFactor).
             "admm_iters_per_call": {k: v / n
                                     for k, v in ent["admm"].items()},
+            # a sharded engine's consensus reduces per call and the
+            # bytes their psums move between the chips (_mesh_combine);
+            # zeros on one device
+            "collective": {k: v / n
+                           for k, v in ent["collective"].items()},
             # what one solve call of the last pass streams: keyword
             # for keyword the facts a bytes-per-iteration model prices
             # (ops/kernels.est_hbm_bytes_per_iter)
@@ -2966,7 +3016,8 @@ class PHBase(SPBase):
         sp_args = {"mode": _mode_str(skey)} if obs.enabled() else None
         clock = _PhaseClock(ent["acc"], sp_args)
 
-        combine_fn = sh.combine if sh is not None else None
+        combine_fn = partial(self._mesh_combine, ent) \
+            if sh is not None else None
 
         shrink = self._shrink if not fixed else None
         if shrink is not None:
@@ -3364,7 +3415,7 @@ class PHBase(SPBase):
         else:
             # the cached hot-loop state is compacted — dive from a
             # full-width cold state instead of clobbering it
-            st = qp_cold_state(factors, d)
+            st = self._cold_state(factors, d)
         # aggressiveness knobs for reference-scale dives (VERDICT r4
         # #5): pin_frac=2 pins half the remaining columns per round
         # (~11 rounds on 4320 commitments vs ~60 at the default 8);

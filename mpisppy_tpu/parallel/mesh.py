@@ -28,6 +28,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .. import obs
+
 SCEN_AXIS = "scen"
 
 
@@ -167,7 +169,8 @@ class ShardedScenarioOps:
                 if want_sq:
                     parts.append(jops.segment_sum(wt * xt * xt, ni,
                                                   num_segments=N))
-                parts = jax.lax.psum(tuple(parts), SCEN_AXIS)
+                with jax.named_scope("ph.psum"):
+                    parts = jax.lax.psum(tuple(parts), SCEN_AXIS)
                 outs.append((parts[0] / parts[1])[ni])
                 if want_sq:
                     outs_sq.append((parts[2] / parts[1])[ni])
@@ -182,9 +185,10 @@ class ShardedScenarioOps:
                 W_new = W + rho * (xn - xbar)
                 if has_wmask:
                     W_new = jnp.where(wmask, W_new, 0.0)
-                conv = jax.lax.psum(
-                    jnp.dot(prob, jnp.sum(jnp.abs(xn - xbar), axis=1)),
-                    SCEN_AXIS) / K
+                with jax.named_scope("ph.psum"):
+                    conv = jax.lax.psum(
+                        jnp.dot(prob, jnp.sum(jnp.abs(xn - xbar), axis=1)),
+                        SCEN_AXIS) / K
                 return xbar, xsqbar, W_new, conv
 
             n_idx = len(self.node_idx)
@@ -210,7 +214,6 @@ class ShardedScenarioOps:
         of the collective entry points is counted — a call site that
         forgot its own counter_add would silently undercount the
         analyze sharding section's collective-traffic totals."""
-        from .. import obs
         if obs.enabled():
             obs.counter_add(
                 "xfer.collective_bytes",
@@ -287,7 +290,8 @@ class ShardedScenarioOps:
                               for v in leaves)
             fn = self._shard_map(body, in_specs, out_specs)
             self._fns[key] = fn
-        return jax.tree.unflatten(treedef, fn(*leaves))
+        with obs.span("mesh.to_chunks", cat="ph"):
+            return jax.tree.unflatten(treedef, fn(*leaves))
 
     def from_chunks(self, parts):
         """Concatenate per-chunk (lc·n_dev, ...) sharded arrays back to
@@ -302,7 +306,8 @@ class ShardedScenarioOps:
             in_specs = tuple(self._spec(p.ndim) for p in parts)
             fn = self._shard_map(body, in_specs, self._spec(parts[0].ndim))
             self._fns[key] = fn
-        return fn(*parts)
+        with obs.span("mesh.from_chunks", cat="ph"):
+            return fn(*parts)
 
 
 def pad_batch_for_mesh(batch, n_shards: int):

@@ -645,7 +645,18 @@ def test_analyze_compare_fused_vs_segmented_reports_pass(tmp_path):
     assert kernel_summary(a)["mode"] == "segmented"
     assert kernel_summary(b)["mode"] == "fused"
     assert kernel_summary(b)["fused_iters"] > 0
-    text, passed = compare(a, b)
+    # every time row is a ratio of two CPU wall times of a few
+    # milliseconds to tenths of a second, and the suite runs under six
+    # xdist workers that share the cores: one side descheduled for a
+    # moment passes the default 1.5x / 1 ms rule (the driver's run of
+    # PR 27's tree failed here, the builder's own passed). What this
+    # test is for does not ride on the clock: the kernel row naming both
+    # modes, and the count and convergence rows. So the time rows get a
+    # rule this test's own noise cannot reach (a minute's difference AND
+    # 50x); count and convergence rows keep theirs.
+    text, passed = compare(a, b, threshold=50.0, abs_floor=60.0)
     assert "kernel: A=segmented" in text and "B=fused" in text
+    for count_row in ("gate_syncs_per_solve_call", "xla_compiles_total"):
+        assert f"  {count_row}: " in text, text
     assert "per-iteration verdict [PASS]" in text
     assert passed, text
