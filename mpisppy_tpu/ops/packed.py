@@ -25,11 +25,14 @@ generically, with no model-specific code: rows above an nnz threshold
 go global, the rest partition into connected components of shared
 columns. The packed form is then
 
-    A x  =  scatter_rows( einsum over (C, mr, nc) component blocks )
-          + scatter_rows( G @ x )            with G the (R, n) global rows
+    A x  =  [ einsum over (C, mr, nc) component blocks | G @ x | 0 ][row_src]
+    Aᵀ y =  [ einsum over the same blocks | 0 ][col_src]  +  y[g_rows] @ G
 
-— one small batched MXU matmul plus one thin dense matmul plus two
-gathers/scatters, all XLA-native. On the 90x48 UC instance the packed
+with G the (R, n) global rows — one small batched MXU matmul plus one
+thin dense matmul plus two gathers, all XLA-native: one gather brings
+the operand into block slots, one PLACES the block results through an
+inverse index (``row_src`` / ``col_src``: for every output element, the
+slot that holds it, or the trailing zero slot). On the 90x48 UC instance the packed
 operand set is ~1.5% of the dense matrix's bytes (C=90 components of
 286 x 144 plus 96 global rows), turning every A-pass from ~3.4 ms of
 HBM streaming into ~0.2 ms of mostly-MXU work. Models without local
@@ -40,6 +43,18 @@ bounding boxes over disjoint row/column sets; global rows are disjoint
 from local rows), so packed apply equals dense apply up to f32 summation
 order. df32 callers accumulate the three split passes in f64 exactly as
 the dense path does (ops/qp_solver.SplitMatrix).
+
+The same disjointness is why the results are PLACED, not accumulated:
+every output row (column) is owned by at most one block slot or global
+row, so scattering the slots into a zero vector with ``.at[].add`` only
+ever adds one value to a zero. Written that way it is a serial loop on
+the TPU, and for the df32 tail's f64 results a variadic scatter over the
+emulated (hi, lo) f32 pair with a two-sum combiner: 73 ns an index, four
+times per tail ADMM iteration (doc/kernels.md §3b). The inverse index is
+the same permutation read from the output's side, so the placement is a
+plain gather. ``structure_from_lists`` builds it on the host and refuses
+a skeleton whose blocks overlap: there a gather would silently drop what
+the accumulation summed.
 """
 
 from __future__ import annotations
@@ -59,6 +74,9 @@ class PackStructure(NamedTuple):
     g_rows: jax.Array      # (R,) int32 global-row indices (may be empty)
     l_rows: jax.Array      # (C, mr) int32, -1 padded
     l_cols: jax.Array      # (C, nc) int32, -1 padded
+    # inverse index: where each output element of a matvec is read from
+    row_src: jax.Array     # (m,) int32 into [l_rows slots | g_rows | zero]
+    col_src: jax.Array     # (n,) int32 into [l_cols slots | zero]
 
 
 class Packed(NamedTuple):
@@ -69,6 +87,8 @@ class Packed(NamedTuple):
     l_rows: jax.Array      # (C, mr) int32, padding clamped to 0
     l_cols: jax.Array      # (C, nc) int32, padding clamped to 0
     l_vals: jax.Array      # (C, mr, nc), padded rows/cols zeroed
+    row_src: jax.Array     # (m,) int32, as PackStructure's
+    col_src: jax.Array     # (n,) int32, as PackStructure's
 
 
 def analyze_structure(rows, cols, m, n, nnz_thresholds=None,
@@ -150,15 +170,53 @@ def analyze_structure(rows, cols, m, n, nnz_thresholds=None,
         packed_elems = C * mr * nc + R * n
         if packed_elems > max_traffic_ratio * m * n:
             continue
-        l_rows = np.full((C, mr), -1, np.int32)
-        l_cols = np.full((C, nc), -1, np.int32)
-        for i, (rl, cl) in enumerate(zip(row_lists, col_lists)):
-            l_rows[i, :len(rl)] = rl
-            l_cols[i, :len(cl)] = cl
-        return PackStructure(
-            g_rows=jnp.asarray(np.flatnonzero(g_mask).astype(np.int32)),
-            l_rows=jnp.asarray(l_rows), l_cols=jnp.asarray(l_cols))
+        return structure_from_lists(row_lists, col_lists,
+                                    np.flatnonzero(g_mask), m, n)
     return None
+
+
+def _padded(lists):
+    out = np.full((len(lists), max(len(x) for x in lists)), -1, np.int32)
+    for i, x in enumerate(lists):
+        out[i, :len(x)] = x
+    return out
+
+
+def _inverse_index(owners, size, what):
+    """(size,) int32: for each output element, the position in
+    ``owners`` (flat, -1 = padding) of the one slot that holds it, or
+    ``owners.size`` (the zero slot appended by the matvecs) where no
+    slot does. An element two slots claim makes the placement wrong
+    where the accumulation it replaces was right: refused here, once."""
+    slots = np.flatnonzero(owners >= 0)
+    if slots.size and owners[slots].max() >= size:
+        raise ValueError(f"packed structure names {what} beyond {size}")
+    claimed = np.bincount(owners[slots], minlength=size)
+    if (claimed > 1).any():
+        raise ValueError(
+            f"packed structure is not disjoint: {what} "
+            f"{np.flatnonzero(claimed > 1)[:8].tolist()} of {size} are "
+            "claimed by more than one block slot or global row")
+    src = np.full(size, owners.size, np.int32)
+    src[owners[slots]] = slots
+    return src
+
+
+def structure_from_lists(row_lists, col_lists, g_rows, m, n):
+    """PackStructure of C components given as lists of row and column
+    indices plus the global rows, with the inverse index the matvecs
+    place their results by. Raises ValueError unless the components'
+    row sets, their column sets and the global rows are disjoint."""
+    l_rows = _padded(row_lists)
+    l_cols = _padded(col_lists)
+    g_rows = np.asarray(g_rows, np.int32)
+    row_src = _inverse_index(
+        np.concatenate([l_rows.reshape(-1), g_rows]), m, "rows")
+    col_src = _inverse_index(l_cols.reshape(-1), n, "columns")
+    return PackStructure(
+        g_rows=jnp.asarray(g_rows), l_rows=jnp.asarray(l_rows),
+        l_cols=jnp.asarray(l_cols), row_src=jnp.asarray(row_src),
+        col_src=jnp.asarray(col_src))
 
 
 def pk_nbytes(pk: Packed) -> int:
@@ -189,7 +247,8 @@ def pack(structure: PackStructure, dense) -> Packed:
         & (structure.l_cols >= 0)[:, None, :]
     vals = jnp.where(mask, vals, 0)
     return Packed(g_rows=structure.g_rows, g_vals=dense[structure.g_rows],
-                  l_rows=lr, l_cols=lc, l_vals=vals)
+                  l_rows=lr, l_cols=lc, l_vals=vals,
+                  row_src=structure.row_src, col_src=structure.col_src)
 
 
 def _pk_einsum(spec, a, vals):
@@ -211,38 +270,46 @@ def _pk_gmat(a, g_vals):
     return a @ g_vals
 
 
-def pk_Ax(pk: Packed, x, m):
+def _place(slots, src):
+    """Block results (S, slots) -> output order: element j of the
+    result is ``[slots | 0][:, src[j]]``. ``src`` is the structure's
+    host-built inverse index (in bounds by construction; its length is
+    the output's width), so this is a plain gather; no value is added
+    to another."""
+    S = slots.shape[0]
+    padded = jnp.concatenate([slots, jnp.zeros((S, 1), slots.dtype)], axis=1)
+    return padded.at[:, src].get(mode="promise_in_bounds")
+
+
+def pk_Ax(pk: Packed, x):
     """A x via the packed form: x (S, n) -> (S, m). Low-precision value
     storage (bf16 blocks) accumulates in x's dtype (see _pk_einsum)."""
     S = x.shape[0]
     xg = x[:, pk.l_cols]                          # (S, C, nc)
-    loc = _pk_einsum("scn,cmn->scm", xg, pk.l_vals)
-    out = jnp.zeros((S, m), x.dtype)
-    out = out.at[:, pk.l_rows.reshape(-1)].add(loc.reshape(S, -1))
+    loc = _pk_einsum("scn,cmn->scm", xg, pk.l_vals).reshape(S, -1)
     if pk.g_rows.size:
-        out = out.at[:, pk.g_rows].add(_pk_gmat(x, pk.g_vals.T))
-    return out
+        loc = jnp.concatenate([loc, _pk_gmat(x, pk.g_vals.T)], axis=1)
+    return _place(loc, pk.row_src)
 
 
-def pk_ATy(pk: Packed, y, n):
+def pk_ATy(pk: Packed, y):
     """Aᵀ y via the packed form: y (S, m) -> (S, n). Low-precision value
     storage (bf16 blocks) accumulates in y's dtype (see _pk_einsum)."""
     S = y.shape[0]
     yg = y[:, pk.l_rows]                          # (S, C, mr)
     loc = _pk_einsum("scm,cmn->scn", yg, pk.l_vals)
-    out = jnp.zeros((S, n), y.dtype)
-    out = out.at[:, pk.l_cols.reshape(-1)].add(loc.reshape(S, -1))
+    out = _place(loc.reshape(S, -1), pk.col_src)
     if pk.g_rows.size:
         out = out + _pk_gmat(y[:, pk.g_rows], pk.g_vals)
     return out
 
 
-def pk_Ax_split(pk_hi: Packed, pk_lo: Packed, xh, xl, m):
+def pk_Ax_split(pk_hi: Packed, pk_lo: Packed, xh, xl):
     """The df32 three-pass matvec (hi·xh + lo·xh + hi·xl, f64 accum —
     the SplitMatrix contract) through the packed form. hi and lo share
     one index skeleton, so x gathers once per operand and the three
-    f32 einsum results accumulate in f64 BEFORE a single scatter —
-    one f64 scatter instead of three f32 ones."""
+    f32 einsum results accumulate in f64 BEFORE a single placement —
+    one f64 gather instead of three f32 ones."""
     S = xh.shape[0]
     f64 = jnp.float64
     xgh = xh[:, pk_hi.l_cols]
@@ -250,17 +317,16 @@ def pk_Ax_split(pk_hi: Packed, pk_lo: Packed, xh, xl, m):
     loc = (jnp.einsum("scn,cmn->scm", xgh, pk_hi.l_vals).astype(f64)
            + jnp.einsum("scn,cmn->scm", xgh, pk_lo.l_vals).astype(f64)
            + jnp.einsum("scn,cmn->scm", xgl, pk_hi.l_vals).astype(f64))
-    out = jnp.zeros((S, m), f64)
-    out = out.at[:, pk_hi.l_rows.reshape(-1)].add(loc.reshape(S, -1))
+    loc = loc.reshape(S, -1)
     if pk_hi.g_rows.size:
         g = ((xh @ pk_hi.g_vals.T).astype(f64)
              + (xh @ pk_lo.g_vals.T).astype(f64)
              + (xl @ pk_hi.g_vals.T).astype(f64))
-        out = out.at[:, pk_hi.g_rows].add(g)
-    return out
+        loc = jnp.concatenate([loc, g], axis=1)
+    return _place(loc, pk_hi.row_src)
 
 
-def pk_ATy_split(pk_hi: Packed, pk_lo: Packed, yh, yl, n):
+def pk_ATy_split(pk_hi: Packed, pk_lo: Packed, yh, yl):
     """Transpose twin of pk_Ax_split."""
     S = yh.shape[0]
     f64 = jnp.float64
@@ -269,8 +335,7 @@ def pk_ATy_split(pk_hi: Packed, pk_lo: Packed, yh, yl, n):
     loc = (jnp.einsum("scm,cmn->scn", ygh, pk_hi.l_vals).astype(f64)
            + jnp.einsum("scm,cmn->scn", ygh, pk_lo.l_vals).astype(f64)
            + jnp.einsum("scm,cmn->scn", ygl, pk_hi.l_vals).astype(f64))
-    out = jnp.zeros((S, n), f64)
-    out = out.at[:, pk_hi.l_cols.reshape(-1)].add(loc.reshape(S, -1))
+    out = _place(loc.reshape(S, -1), pk_hi.col_src)
     if pk_hi.g_rows.size:
         g = ((yh[:, pk_hi.g_rows] @ pk_hi.g_vals).astype(f64)
              + (yh[:, pk_hi.g_rows] @ pk_lo.g_vals).astype(f64)

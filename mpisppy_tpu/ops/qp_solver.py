@@ -325,13 +325,13 @@ def _Ax(A, x):
         return _Ax(A.A_s, x / A.D) / A.E
     if isinstance(A, PackedMatrix):
         from .packed import pk_Ax
-        return pk_Ax(A.pk, x, A.dense.shape[0])
+        return pk_Ax(A.pk, x)
     if isinstance(A, SplitMatrix):
         xh = x.astype(jnp.float32)
         xl = (x - xh.astype(jnp.float64)).astype(jnp.float32)
         if A.pk_hi is not None:
             from .packed import pk_Ax_split
-            return pk_Ax_split(A.pk_hi, A.pk_lo, xh, xl, A.hi.shape[0])
+            return pk_Ax_split(A.pk_hi, A.pk_lo, xh, xl)
         f64 = jnp.float64
         return ((xh @ A.hi.T).astype(f64) + (xh @ A.lo.T).astype(f64)
                 + (xl @ A.hi.T).astype(f64))
@@ -348,13 +348,13 @@ def _ATy(A, y):
         return _ATy(A.A_s, y / A.E) / A.D
     if isinstance(A, PackedMatrix):
         from .packed import pk_ATy
-        return pk_ATy(A.pk, y, A.dense.shape[1])
+        return pk_ATy(A.pk, y)
     if isinstance(A, SplitMatrix):
         yh = y.astype(jnp.float32)
         yl = (y - yh.astype(jnp.float64)).astype(jnp.float32)
         if A.pk_hi is not None:
             from .packed import pk_ATy_split
-            return pk_ATy_split(A.pk_hi, A.pk_lo, yh, yl, A.hi.shape[1])
+            return pk_ATy_split(A.pk_hi, A.pk_lo, yh, yl)
         f64 = jnp.float64
         return ((yh @ A.hi).astype(f64) + (yh @ A.lo).astype(f64)
                 + (yl @ A.hi).astype(f64))

@@ -17,6 +17,7 @@ for a described chip cannot be read back without one.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -185,6 +186,25 @@ def uc_calls():
     return calls
 
 
+def _hlo_lines(hlo, opcode):
+    """The instructions of a compiled module's text with this opcode."""
+    return [ln for ln in hlo.splitlines()
+            if re.search(rf"\s{re.escape(opcode)}\(", ln)]
+
+
+def _assert_matvecs_place_by_gather(hlo):
+    """ISSUE 29: no packed matvec of the fused program scatters (on the
+    chip the df32 tail's was a serial variadic scatter over the
+    emulated f64's (hi, lo) pair, 73 ns an index); each places its
+    result with a gather through the inverse index."""
+    scatters = _hlo_lines(hlo, "scatter")
+    assert not [ln for ln in scatters if "qp.Ax" in ln or "qp.ATy" in ln]
+    gathers = _hlo_lines(hlo, "gather")
+    assert [ln for ln in gathers if "qp.tail" in ln and "qp.Ax" in ln]
+    assert [ln for ln in gathers if "qp.tail" in ln and "qp.ATy" in ln]
+    assert [ln for ln in gathers if "qp.bulk" in ln and "qp.Ax" in ln]
+
+
 @pytest.mark.parametrize("name", ["_cold_state_jit",
                                   "_fused_mixed_jit_donated",
                                   "_ph_chunk_objs", "_ph_combine"])
@@ -196,6 +216,10 @@ def test_uc_df32_step_programs_compile_for_v5e(uc_calls, one_chip,
     need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes + mem.generated_code_size_in_bytes)
     assert need < 16e9        # one v5e chip's HBM
+    if name == "_fused_mixed_jit_donated":
+        hlo = compiled.as_text()
+        _assert_matvecs_place_by_gather(hlo)
+        assert not _hlo_lines(hlo, "all-reduce")
 
 
 def test_sharded_df32_chunk_solve_compiles_over_four_chips(
@@ -203,7 +227,11 @@ def test_sharded_df32_chunk_solve_compiles_over_four_chips(
     """The same chunk solve as ONE program over a 4-chip mesh of the
     described devices, scenario rows sharded and shared operands
     replicated the way core/spbase places them: the compiler must put
-    the termination tests' cross-shard reduction in as a collective."""
+    the termination tests' cross-shard reduction in as a collective,
+    and nothing else: a few all-reduces of scalars (at the UC cell's
+    recipe six, three in each phase's per-check body: PERF.md §5). The
+    placement gathers run along the unsharded column axis of the local
+    block through a replicated index and need none."""
     from jax.sharding import Mesh
 
     from mpisppy_tpu.parallel.mesh import SCEN_AXIS
@@ -218,5 +246,13 @@ def test_sharded_df32_chunk_solve_compiles_over_four_chips(
             if lead else PartitionSpec()
         return NamedSharding(mesh, spec)
 
-    compiled = fn.lower(*_on(args, place), **kw).compile()
-    assert "all-reduce" in compiled.as_text()
+    hlo = fn.lower(*_on(args, place), **kw).compile().as_text()
+    _assert_matvecs_place_by_gather(hlo)
+    reduces = _hlo_lines(hlo, "all-reduce")
+    assert 1 <= len(reduces) <= 6
+    for ln in reduces:           # scalars, or the f64 pair's four slots
+        assert re.search(r"= \(?(u32|f32)\[4?\]", ln), ln
+    for other in ("all-gather", "reduce-scatter", "collective-permute",
+                  "all-to-all", "all-reduce-start", "all-gather-start",
+                  "collective-permute-start"):
+        assert not _hlo_lines(hlo, other), other

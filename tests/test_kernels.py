@@ -455,7 +455,9 @@ def _mini_packed(flush_entry=False):
                   g_vals=jnp.zeros((0, 4), jnp.float32),
                   l_rows=jnp.zeros((2, 3), jnp.int32),
                   l_cols=jnp.zeros((2, 4), jnp.int32),
-                  l_vals=jnp.asarray(vals))
+                  l_vals=jnp.asarray(vals),
+                  row_src=jnp.zeros((6,), jnp.int32),
+                  col_src=jnp.zeros((4,), jnp.int32))
 
 
 def test_bf16_gate_normal_blocks_pass_flush_blocks_trip():

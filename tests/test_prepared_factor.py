@@ -81,12 +81,12 @@ def test_chol_solve_picks_the_substitution_per_platform():
     assert x64.dtype == jnp.float64
     np.testing.assert_array_equal(np.asarray(x64, np.float32),
                                   np.asarray(x))
-    switches = [e for e, _ in _walk(jax.make_jaxpr(_chol_solve)(F, b).jaxpr)
+    switches = [e for e, *_ in _walk(jax.make_jaxpr(_chol_solve)(F, b).jaxpr)
                 if e.params.get("branches_platforms")]
     assert len(switches) == 1
     for plats, br in zip(switches[0].params["branches_platforms"],
                          switches[0].params["branches"]):
-        prims = {e.primitive.name for e, _ in _walk(br.jaxpr)}
+        prims = {e.primitive.name for e, *_ in _walk(br.jaxpr)}
         if plats == ("tpu",):
             assert "dot_general" in prims and "triangular_solve" not in prims
         else:
@@ -164,12 +164,15 @@ def _sub_jaxprs(eqn):
                 yield u                 # Jaxpr
 
 
-def _walk(jaxpr, in_scan=False, platform=None):
-    """(eqn, inside_a_scan_body) for every equation, recursively; with
-    ``platform``, only that platform's branch of a
-    ``lax.platform_dependent`` switch (what its lowering keeps)."""
+def _walk(jaxpr, in_scan=False, platform=None, scope=""):
+    """(eqn, inside_a_scan_body, named scopes down to it) for every
+    equation, recursively (a sub-jaxpr's name stacks are relative to
+    the equation that holds it); with ``platform``, only that
+    platform's branch of a ``lax.platform_dependent`` switch (what its
+    lowering keeps)."""
     for eqn in jaxpr.eqns:
-        yield eqn, in_scan
+        here = f"{scope}/{eqn.source_info.name_stack}"
+        yield eqn, in_scan, here
         subs = list(_sub_jaxprs(eqn))
         plats = eqn.params.get("branches_platforms")
         if platform and plats:
@@ -178,19 +181,12 @@ def _walk(jaxpr, in_scan=False, platform=None):
             subs = [eqn.params["branches"][i].jaxpr for i in pick]
         for sub in subs:
             yield from _walk(sub, in_scan or eqn.primitive.name == "scan",
-                             platform)
+                             platform, here)
 
 
-def test_fused_scan_body_holds_no_per_iteration_factor_pass(monkeypatch):
-    """What the fused df32 program (a UC whose factor spans several
-    128-blocks) lowers to ON THE TPU: its ADMM scan body does no per-
-    iteration work on L as a whole: no ``triangular_solve`` (so none
-    with ``transpose_a``: the expander's triangle mask and diagonal-
-    block inversion went with it), no (n, n)-shaped transpose, no
-    equation with an (n, n) result at all. The preparation lives
-    outside the scan, under the in-loop refactorization's ``cond``. On
-    the default (CPU) branch the scan body keeps the pair, as it always
-    had."""
+def _fused_jaxpr(monkeypatch):
+    """(jaxpr, aux L) of ``_fused_mixed_impl`` as a packed df32 UC
+    engine calls it (a factor that spans several 128-blocks)."""
     import mpisppy_tpu.ops.kernels.reference as ref
 
     calls = {}
@@ -203,17 +199,31 @@ def test_fused_scan_body_holds_no_per_iteration_factor_pass(monkeypatch):
     ph = PHBase(_uc_batch(), dict(_DF32), dtype=jnp.float64)
     ph.solve_loop(w_on=False, prox_on=False)
     args, kw = calls["args"]
-    L = args[5][0]                       # aux = (L, rho_scale, iters)
-    assert isinstance(L, PreparedFactor)
-    n = L.tri.shape[-1]
-    assert n > 2 * 128 and L.dinv.shape[0] == -(-n // 128) >= 3
+    assert args[0].A_s.pk_hi is not None         # factors: packed split A
     statics = {k: kw.pop(k) for k in ref._FUSED_STATICS if k in kw}
     jaxpr = jax.make_jaxpr(partial(ref._fused_mixed_impl, **statics))(
         *args, **kw).jaxpr
+    return jaxpr, args[5][0]             # aux = (L, rho_scale, iters)
+
+
+def test_fused_scan_body_holds_no_per_iteration_factor_pass(monkeypatch):
+    """What the fused df32 program (a UC whose factor spans several
+    128-blocks) lowers to ON THE TPU: its ADMM scan body does no per-
+    iteration work on L as a whole: no ``triangular_solve`` (so none
+    with ``transpose_a``: the expander's triangle mask and diagonal-
+    block inversion went with it), no (n, n)-shaped transpose, no
+    equation with an (n, n) result at all. The preparation lives
+    outside the scan, under the in-loop refactorization's ``cond``. On
+    the default (CPU) branch the scan body keeps the pair, as it always
+    had."""
+    jaxpr, L = _fused_jaxpr(monkeypatch)
+    assert isinstance(L, PreparedFactor)
+    n = L.tri.shape[-1]
+    assert n > 2 * 128 and L.dinv.shape[0] == -(-n // 128) >= 3
     assert any(in_scan and eqn.primitive.name == "triangular_solve"
-               for eqn, in_scan in _walk(jaxpr, platform="cpu"))
+               for eqn, in_scan, _ in _walk(jaxpr, platform="cpu"))
     seen_scan = prepared_outside = False
-    for eqn, in_scan in _walk(jaxpr, platform="tpu"):
+    for eqn, in_scan, _ in _walk(jaxpr, platform="tpu"):
         name = eqn.primitive.name
         if name == "triangular_solve" and not in_scan:
             prepared_outside = True
@@ -224,6 +234,32 @@ def test_fused_scan_body_holds_no_per_iteration_factor_pass(monkeypatch):
         for v in eqn.outvars:
             assert getattr(v.aval, "shape", ()) != (n, n), eqn
     assert seen_scan and prepared_outside
+
+
+def test_fused_program_places_its_matvecs_without_a_scatter(monkeypatch):
+    """ISSUE 29: the packed matvecs place their block results with a
+    gather through the structure's inverse index, so on every platform
+    the fused program accumulates nothing by index: no ``scatter-add``
+    inside either ADMM scan body (f32 bulk and df32 tail) or under
+    ``qp.check``, and no scatter of any kind under ``qp.Ax`` /
+    ``qp.ATy`` anywhere (the scan bodies' remaining ``scatter``s are the
+    prepared solve's static block writes under ``qp.kkt_solve``). The
+    matvecs are there, in both dtypes: each ends in a gather of its
+    (S, m) or (S, n) result."""
+    jaxpr, _ = _fused_jaxpr(monkeypatch)
+    placed = set()
+    for eqn, in_scan, scope in _walk(jaxpr):
+        name = eqn.primitive.name
+        matvec = "qp.Ax" in scope or "qp.ATy" in scope
+        if in_scan or "qp.check" in scope:
+            assert name != "scatter-add", (scope, eqn)
+        if matvec:
+            assert not name.startswith("scatter"), (scope, eqn)
+        if matvec and in_scan and name == "gather":
+            placed.add((scope.rstrip("/").rsplit("/", 1)[-1],
+                        str(eqn.outvars[0].aval.dtype)))
+    assert placed == {("qp.Ax", "float32"), ("qp.ATy", "float32"),
+                      ("qp.Ax", "float64"), ("qp.ATy", "float64")}
 
 
 # ---------------- the in-loop refactorization ----------------
@@ -295,7 +331,7 @@ def test_non_split_linv_carry_prepares_outside_the_scan():
     jaxpr = jax.make_jaxpr(partial(qs._solve_impl, **kw))(
         fac, d, q, st_i).jaxpr
     in_scan = [eqn.primitive.name
-               for eqn, inside in _walk(jaxpr, platform="tpu") if inside]
+               for eqn, inside, _ in _walk(jaxpr, platform="tpu") if inside]
     assert "dot_general" in in_scan and "triangular_solve" not in in_scan
 
 
