@@ -324,7 +324,7 @@ def bench_1024():
     # resolved kernel decisions + the roofline traffic model's
     # prediction (ISSUE 7): the next driver run diffs the measured
     # s/PH-iter against est_hbm_bytes_per_iter to confirm (or refute)
-    # the predicted traffic drop of the fused/L⁻¹/bf16 trades
+    # the predicted traffic drop of the fused/L⁻¹ trades
     kern = pt.get("kernel")
     est_hbm = None
     if kern is not None:
@@ -546,27 +546,6 @@ def _gap_rows(prefix, hub, t0, t_end, baseline_s, note, rel,
                           None)
             if src is not None:
                 rows[0]["stream"] = src.status()
-        except Exception:
-            pass    # a kill-path flush must never die on diagnostics
-    # measured-roofline stamp (ISSUE 18): the last iteration's MFU,
-    # HBM bandwidth, and FLOPs/iter from the XLA cost-model capture
-    # (obs/profile.py) — the measured successor to the estimate-only
-    # est_hbm_bytes_per_iter story. last_iteration() is one attribute
-    # read on a plain dict (no locks), so the SIGTERM flush stamps it
-    # too, unlike the counters_snapshot block below.
-    if rows:
-        try:
-            from mpisppy_tpu.obs import profile as _obs_profile
-            fig = _obs_profile.last_iteration()
-            if fig:
-                rows[0]["profile"] = {
-                    "mfu": fig.get("mfu"),
-                    "hbm_gbps": fig.get("hbm_gbps"),
-                    "hbm_util": fig.get("hbm_util"),
-                    "flops_per_iter": fig.get("flops_per_iter"),
-                    "hbm_bytes_per_iter":
-                        fig.get("hbm_bytes_per_iter"),
-                }
         except Exception:
             pass    # a kill-path flush must never die on diagnostics
     # wheel-forensics stamp (ISSUE 19): the current diagnosis verdict

@@ -108,8 +108,8 @@ def make_parser() -> argparse.ArgumentParser:
     p.add_argument("--stream-int8", action="store_true",
                    help="int8 delta-packed host storage for the "
                         "streamed source (explicit opt-in behind a "
-                        "host-side quantization gate, like the bf16 "
-                        "packed blocks — doc/streaming.md)")
+                        "host-side quantization gate — "
+                        "doc/streaming.md)")
     p.add_argument("--stream-int8-tol", type=float, default=1e-3,
                    help="int8 gate: max per-entry reconstruction error "
                         "relative to 1+|value| before a field falls "

@@ -488,30 +488,22 @@ class PHBase(SPBase):
         # tolerance; raise for pathologically conditioned models)
         self.sub_ir_sweeps = int(opts.get("subproblem_ir_sweeps", 1))
         self.sub_polish_hot = bool(opts.get("subproblem_polish_hot", True))
-        # kernel-backend selection (ops/kernels, doc/kernels.md):
+        # kernel-mode selection (ops/kernels, doc/kernels.md):
         # "segmented" = the host-segmented qp_solver drivers bit-for-bit,
         # "fused" = one device program per solve, "auto" (default) =
         # fused wherever the solve is eligible. Validated HERE so a
         # typo'd programmatic option fails at engine construction, not
         # as a silent segmented fallback; the fused+ir_sweeps band rule
         # mirrors utils/config.AlgoConfig.validate (the CLI surface).
-        from ..utils.config import (FUSED_IR_SWEEPS, KERNEL_BACKENDS,
-                                    KERNEL_BLOCK_DTYPES,
-                                    KERNEL_L_INV_MODES, KERNEL_MODES)
+        from ..utils.config import (FUSED_IR_SWEEPS, KERNEL_L_INV_MODES,
+                                    KERNEL_MODES)
         self.sub_kernel_mode = str(opts.get("subproblem_kernel_mode",
                                             "auto"))
-        self.sub_kernel_backend = str(opts.get("subproblem_kernel_backend",
-                                               "reference"))
         self.sub_kernel_l_inv = str(opts.get("subproblem_kernel_l_inv",
                                              "auto"))
-        self.sub_kernel_block_dtype = str(opts.get(
-            "subproblem_kernel_block_dtype", "auto"))
         for val, known, name in (
                 (self.sub_kernel_mode, KERNEL_MODES, "mode"),
-                (self.sub_kernel_backend, KERNEL_BACKENDS, "backend"),
-                (self.sub_kernel_l_inv, KERNEL_L_INV_MODES, "l_inv"),
-                (self.sub_kernel_block_dtype, KERNEL_BLOCK_DTYPES,
-                 "block_dtype")):
+                (self.sub_kernel_l_inv, KERNEL_L_INV_MODES, "l_inv")):
             if val not in known:
                 raise ValueError(f"unknown subproblem_kernel_{name} "
                                  f"{val!r}; known: {known}")
@@ -812,13 +804,12 @@ class PHBase(SPBase):
 
     def _kernel_plan(self, key, factors, s_chunk):
         """Cached ops/kernels plan for one mode's factors (resolved
-        mode, effective backend, L⁻¹ profitability verdict, the bulk
-        phase's bf16-or-f32 packed operand — doc/kernels.md). Keyed by
+        mode, L⁻¹ profitability verdict, the bulk phase's f32 packed
+        operand — doc/kernels.md). Keyed by
         (factor key, rows-per-solve-call): the L⁻¹ trade's
         profitability depends on how many RHS columns each fused
         program back-substitutes. Invalidated with the factor cache —
-        a plan holds (possibly quantized) views of the factors'
-        arrays."""
+        a plan holds views of the factors' arrays."""
         pk = (key, int(s_chunk))
         plan = self._kernel_plans.get(pk)
         if plan is None:
@@ -827,11 +818,9 @@ class PHBase(SPBase):
                 if self.sub_precision in ("mixed", "df32") else 0
             plan = kernels.prepare(
                 factors, mode=self.sub_kernel_mode,
-                backend=self.sub_kernel_backend,
                 l_inv=self.sub_kernel_l_inv,
-                block_dtype=self.sub_kernel_block_dtype,
                 precision=self.sub_precision,
-                bulk_iter=self.sub_max_iter, tail_iter=tail,
+                tail_iter=tail,
                 ir_sweeps=self.sub_ir_sweeps, s_chunk=s_chunk)
             self._kernel_plans[pk] = plan
         return plan
@@ -2518,7 +2507,8 @@ class PHBase(SPBase):
             pk = pk_nbytes(A_s.pk_hi) + pk_nbytes(A_s.pk_lo)
         return {"n": n, "m": m, "s_chunk": int(rows_per_call),
                 "ir_sweeps": int(self.sub_ir_sweeps),
-                "pk_pass_bytes": pk, "block_dtype": plan.block_dtype}
+                "pk_pass_bytes": pk,
+                "block_dtype": plan.descriptor()["block_dtype"]}
 
     def _phase_totals(self):
         """Accumulated per-phase wall-clock summed over every solve
@@ -2601,11 +2591,10 @@ class PHBase(SPBase):
                             # a record when something went wrong)
                             "xfer.collective_bytes",
                             "xfer.device_put_bytes",
-                            # kernel-backend activity (ops/kernels):
+                            # kernel-layer activity (ops/kernels):
                             # fused ADMM iterations this iteration, plus
-                            # the (rare) eager L⁻¹ builds and bf16 gate
-                            # trips — the analyze fused-vs-segmented
-                            # verdict row reads these
+                            # the (rare) eager L⁻¹ builds — the analyze
+                            # fused-vs-segmented verdict row reads these
                             # APH φ-dispatch (ops/dispatch, doc/aph.md):
                             # one gate sync per iteration, solved vs
                             # skipped scenario counts, and bucket
@@ -2618,7 +2607,6 @@ class PHBase(SPBase):
                             "dispatch.bucket.cache_hit",
                             "kernel.fused_iters",
                             "kernel.l_inv_factorizations",
-                            "kernel.bf16_fallbacks",
                             # scenario streaming (mpisppy_tpu/stream):
                             # chunks/bytes staged this iteration —
                             # analyze's streaming section asserts the
@@ -2646,15 +2634,7 @@ class PHBase(SPBase):
                             # row and its --compare REGRESSION read
                             # these
                             "shrink.transplants",
-                            "shrink.transplant_cold_fallbacks",
-                            # measured roofline (obs/profile.py,
-                            # doc/roofline.md): XLA cost-model FLOPs
-                            # and bytes-accessed booked by the
-                            # instrumented jit entries THIS iteration —
-                            # analyze joins these deltas against the
-                            # span timeline for MFU/HBM utilization
-                            "profile.flops",
-                            "profile.hbm_bytes")
+                            "shrink.transplant_cold_fallbacks")
 
     def iteration_record(self, it, seconds, phase_before, counters_before):
         """The structured per-iteration convergence record (the
@@ -2710,18 +2690,6 @@ class PHBase(SPBase):
             k: ctr.get(k, 0) - counters_before.get(k, 0)
             for k in self._ITER_DELTA_COUNTERS
             if ctr.get(k, 0) != counters_before.get(k, 0)}
-        deltas = rec["counter_deltas"]
-        if "profile.flops" in deltas or "profile.hbm_bytes" in deltas:
-            # measured roofline per iteration (obs/profile.py): MFU +
-            # HBM figures from this iteration's cost-model deltas;
-            # note_iteration also refreshes the profile.iter.* gauges
-            # and the signal-safe dict bench/the hub live plane read
-            from ..obs import profile as _obs_profile
-            fig = _obs_profile.note_iteration(
-                it, seconds, deltas.get("profile.flops", 0),
-                deltas.get("profile.hbm_bytes", 0))
-            if fig is not None:
-                rec["profile"] = fig
         if self._forensics_every > 0 \
                 and it % self._forensics_every == 0:
             # wheel forensics (ops/forensics.py, doc/forensics.md):

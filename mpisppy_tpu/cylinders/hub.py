@@ -549,11 +549,6 @@ class Hub(SPCommunicator):
                 "mode": pt.get("mode"),
                 "occupancy": pt.get("occupancy"),
                 "seconds_per_call": pt.get("seconds_per_call")}
-        # measured-roofline tile (obs/profile.py): the most recent
-        # iteration's MFU/HBM figures as a plain dict — analyze --watch
-        # renders this line (None until the first instrumented iter)
-        from ..obs import profile as _obs_profile
-        snap["roofline"] = _obs_profile.last_iteration()
         # wheel-forensics tile (obs/diagnose.py): the current verdict
         # + top culprit slot/scenario as a plain dict — analyze --watch
         # renders this line, serve /status + /metrics ship it per wheel

@@ -33,7 +33,7 @@ Usage::
     obs.shutdown()
 
 Environment: ``MPISPPY_TPU_TELEMETRY_DIR`` — when set, the first call
-to :func:`maybe_configure_from_env` (drivers, bench, profile) enables
+to :func:`maybe_configure_from_env` (the CLI, serve, children) enables
 telemetry into that directory without code changes.
 """
 

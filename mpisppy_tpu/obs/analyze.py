@@ -491,148 +491,6 @@ def truncated(run: Run) -> bool:
     return not run.of("run_footer", role="")
 
 
-def roofline_summary(run: Run) -> dict | None:
-    """The measured roofline (obs/profile.py, doc/roofline.md): device
-    peaks from the ``profile.device`` event, per-iteration /
-    per-bucket / per-mode MFU and HBM-bandwidth utilization from the
-    ``profile.flops`` / ``profile.hbm_bytes`` counter deltas joined
-    against the span timeline, the per-entry static cost models, and
-    the compile ledger (which must sum to ``jax.compiles``). None when
-    the run never profiled (telemetry off or pre-profile artifacts)."""
-    c = run.counters()
-    dev_events = run.of("profile.device")
-    entry_events = run.of("profile.entry")
-    if not dev_events and not entry_events \
-            and not any(k.startswith("profile.") for k in c):
-        return None
-    dev = {}
-    if dev_events:
-        dev = {k: v for k, v in dev_events[-1].items()
-               if k not in ("t", "type", "_role")}
-    peak_f = dev.get("peak_flops") or 0.0
-    peak_g = dev.get("peak_hbm_gbps") or 0.0
-    per_iter = []
-    per_bucket = {}
-    per_mode = {}
-    solve_flops = solve_secs = 0.0
-    tot_flops = tot_bytes = tot_secs = 0.0
-    for e in iteration_rows(run):
-        cd = e.get("counter_deltas") or {}
-        fl = float(cd.get("profile.flops", 0) or 0)
-        by = float(cd.get("profile.hbm_bytes", 0) or 0)
-        if not fl and not by:
-            continue
-        secs = e.get("seconds")
-        if not isinstance(secs, (int, float)) or secs <= 0:
-            continue
-        row = {"iter": e.get("iter"), "seconds": secs, "flops": fl,
-               "hbm_bytes": by,
-               "mfu": (fl / secs / peak_f) if peak_f else None,
-               "hbm_gbps": by / secs / 1e9,
-               "hbm_util": (by / secs / 1e9 / peak_g) if peak_g
-               else None}
-        per_iter.append(row)
-        tot_flops += fl
-        tot_bytes += by
-        tot_secs += secs
-        ps = e.get("phase_seconds") or {}
-        sv = ps.get("solve")
-        if isinstance(sv, (int, float)) and sv > 0:
-            solve_flops += fl
-            solve_secs += sv
-        # bucket = the shrink bucket active when the iteration ran
-        # (the shrink_summary grouping); 0.0 = the full-width system
-        shr = e.get("shrink") or {}
-        b = shr.get("bucket") or 0.0
-        ent = per_bucket.setdefault(
-            b, {"flops": 0.0, "hbm_bytes": 0.0, "seconds": 0.0,
-                "iters": 0, "est_hbm_bytes_per_iter": None})
-        ent["flops"] += fl
-        ent["hbm_bytes"] += by
-        ent["seconds"] += secs
-        ent["iters"] += 1
-        if ent["est_hbm_bytes_per_iter"] is None:
-            ent["est_hbm_bytes_per_iter"] = \
-                shr.get("est_hbm_bytes_per_iter")
-        # engine mode, classified the way kernel_summary does: a
-        # kernel.fused_iters delta marks a fused iteration
-        mode = "fused" if cd.get("kernel.fused_iters") else "segmented"
-        m = per_mode.setdefault(
-            mode, {"flops": 0.0, "hbm_bytes": 0.0, "seconds": 0.0,
-                   "iters": 0})
-        m["flops"] += fl
-        m["hbm_bytes"] += by
-        m["seconds"] += secs
-        m["iters"] += 1
-
-    def _figures(fl, by, secs):
-        if secs <= 0:
-            return {"mfu": None, "hbm_gbps": None, "hbm_util": None}
-        gbps = by / secs / 1e9
-        return {"mfu": (fl / secs / peak_f) if peak_f else None,
-                "hbm_gbps": gbps,
-                "hbm_util": (gbps / peak_g) if peak_g else None}
-
-    bucket_rows = []
-    for b, ent in sorted(per_bucket.items()):
-        row = {"bucket": b, "iters": ent["iters"],
-               "s_per_iter": ent["seconds"] / ent["iters"],
-               "flops_per_iter": ent["flops"] / ent["iters"],
-               "hbm_bytes_per_iter": ent["hbm_bytes"] / ent["iters"],
-               "est_hbm_bytes_per_iter": ent["est_hbm_bytes_per_iter"]}
-        row.update(_figures(ent["flops"], ent["hbm_bytes"],
-                            ent["seconds"]))
-        bucket_rows.append(row)
-    mode_rows = {}
-    for m, ent in sorted(per_mode.items()):
-        row = {"iters": ent["iters"],
-               "flops_per_iter": ent["flops"] / ent["iters"],
-               "hbm_bytes_per_iter": ent["hbm_bytes"] / ent["iters"]}
-        row.update(_figures(ent["flops"], ent["hbm_bytes"],
-                            ent["seconds"]))
-        mode_rows[m] = row
-    ledger = {}
-    for k, v in c.items():
-        if k.startswith("profile.ledger.compiles."):
-            key = k[len("profile.ledger.compiles."):]
-            ledger.setdefault(key, {"compiles": 0, "seconds": 0.0})
-            ledger[key]["compiles"] = int(v)
-        elif k.startswith("profile.ledger.seconds."):
-            key = k[len("profile.ledger.seconds."):]
-            ledger.setdefault(key, {"compiles": 0, "seconds": 0.0})
-            ledger[key]["seconds"] = float(v)
-    ledger_compiles = sum(e["compiles"] for e in ledger.values())
-    jax_compiles = int(c.get("jax.compiles", 0))
-    overall = {"flops_total": tot_flops, "hbm_bytes_total": tot_bytes,
-               "seconds_total": tot_secs, "iters": len(per_iter)}
-    overall.update(_figures(tot_flops, tot_bytes, tot_secs))
-    solve = {"flops_total": solve_flops, "seconds_total": solve_secs}
-    solve.update(_figures(solve_flops, 0.0, solve_secs))
-    solve.pop("hbm_gbps", None)
-    solve.pop("hbm_util", None)
-    unavailable = [{k: v for k, v in e.items()
-                    if k in ("entry", "reason")}
-                   for e in run.of("profile.unavailable")]
-    return {
-        "device": dev or None,
-        "overall": overall,
-        "solve_phase": solve if solve_secs > 0 else None,
-        "per_bucket": bucket_rows,
-        "per_mode": mode_rows,
-        "per_iteration": per_iter,
-        "entries": [{k: v for k, v in e.items()
-                     if k not in ("t", "type", "_role")}
-                    for e in entry_events],
-        "captures": int(c.get("profile.captures", 0)),
-        "ledger": ledger,
-        "ledger_compiles": ledger_compiles,
-        "jax_compiles": jax_compiles,
-        "ledger_matches": ledger_compiles == jax_compiles,
-        "unavailable_count": int(c.get("profile.unavailable", 0)),
-        "unavailable": unavailable,
-    }
-
-
 def streaming_summary(run: Run) -> dict | None:
     """Scenario-streaming activity (mpisppy_tpu/stream,
     doc/streaming.md): the source kind, bytes shipped vs chunks
@@ -1345,66 +1203,6 @@ def render_report(run: Run) -> str:
             for k, v in sorted(xfer.items())))
     L.append("")
 
-    rf = roofline_summary(run)
-    if rf is not None:
-        L.append("== roofline ==")
-        dev = rf.get("device") or {}
-        if dev:
-            tier = " [CPU-TIER — nominal peaks, not meaningful " \
-                   "absolute utilization]" if dev.get("cpu_tier") else ""
-            L.append(f"device {dev.get('device_kind')}  peaks "
-                     f"{dev.get('peak_flops', 0) / 1e12:.2f} TFLOP/s / "
-                     f"{dev.get('peak_hbm_gbps') or 0:.0f} GB/s "
-                     f"(source {dev.get('source')}){tier}")
-        ov = rf["overall"]
-        if ov["iters"]:
-            L.append(
-                f"measured: mfu {_fmt(ov['mfu'], 4)}  hbm "
-                f"{_fmt(ov['hbm_gbps'], 2)} GB/s "
-                f"(util {_fmt(ov['hbm_util'], 4)})  "
-                f"flops/iter {_fmt(ov['flops_total'] / ov['iters'])}  "
-                f"bytes/iter "
-                f"{_fmt_b(ov['hbm_bytes_total'] / ov['iters'])}  "
-                f"over {ov['iters']} iter(s)")
-        else:
-            L.append("(no instrumented iterations — profile counters "
-                     "present but no ph.iteration deltas)")
-        sp = rf.get("solve_phase")
-        if sp:
-            L.append(f"solve-phase mfu {_fmt(sp['mfu'], 4)} "
-                     f"({_fmt(sp['seconds_total'], 3)}s in solve)")
-        for m, row in sorted(rf["per_mode"].items()):
-            L.append(f"  mode {m}: mfu {_fmt(row['mfu'], 4)}  hbm "
-                     f"{_fmt(row['hbm_gbps'], 2)} GB/s  "
-                     f"{row['iters']} iter(s)")
-        if rf["per_bucket"]:
-            L.append("per-bucket measured vs predicted "
-                     "(doc/roofline.md's est_hbm column):")
-            for b in rf["per_bucket"]:
-                est = b.get("est_hbm_bytes_per_iter")
-                L.append(
-                    f"  bucket {b['bucket']:g}: mfu {_fmt(b['mfu'], 4)}"
-                    f"  hbm {_fmt(b['hbm_gbps'], 2)} GB/s "
-                    f"(util {_fmt(b['hbm_util'], 4)})  measured "
-                    f"{_fmt_b(b['hbm_bytes_per_iter'])}/iter"
-                    + (f" vs est {_fmt_b(est)}/iter" if est else "")
-                    + f"  over {b['iters']} iter(s)")
-        lg = rf["ledger"]
-        tick = "==" if rf["ledger_matches"] else "!="
-        L.append(f"compile ledger: {rf['ledger_compiles']} compile(s) "
-                 f"{tick} jax.compiles {rf['jax_compiles']}"
-                 + ("" if rf["ledger_matches"] else
-                    "  [MISMATCH — a compile escaped attribution]"))
-        for key, ent in sorted(lg.items(),
-                               key=lambda kv: -kv[1]["seconds"])[:10]:
-            L.append(f"  {key}: x{ent['compiles']} "
-                     f"{ent['seconds']:.2f}s")
-        if rf["unavailable_count"]:
-            reasons = {u.get("reason") for u in rf["unavailable"]}
-            L.append(f"profile.unavailable: {rf['unavailable_count']} "
-                     f"(reasons: {sorted(r for r in reasons if r)})")
-        L.append("")
-
     sh = sharding_summary(run)
     if sh is not None:
         L.append("== sharding ==")
@@ -1789,7 +1587,7 @@ def comparison_metrics(run: Run) -> dict:
 
 
 def kernel_summary(run: Run) -> dict:
-    """Kernel-backend activity of one run (the ops/kernels counters,
+    """Kernel-layer activity of one run (the ops/kernels counters,
     doc/kernels.md): which subproblem kernel mode actually executed and
     the trade volumes the fused-vs-segmented compare row reports."""
     c = run.counters()
@@ -1800,7 +1598,6 @@ def kernel_summary(run: Run) -> dict:
         "fused_iters": fused,
         "fused_iters_per_solve_call": (fused / calls) if calls else 0.0,
         "l_inv_factorizations": c.get("kernel.l_inv_factorizations", 0),
-        "bf16_fallbacks": c.get("kernel.bf16_fallbacks", 0),
     }
 
 
@@ -1863,12 +1660,10 @@ def compare(a: Run, b: Run, threshold=1.5,
         L.append(
             f"  kernel: A={ka['mode']} "
             f"({_fmt(ka['fused_iters_per_solve_call'])} fused "
-            f"iters/solve, l_inv={ka['l_inv_factorizations']}, "
-            f"bf16_fallbacks={ka['bf16_fallbacks']}) "
+            f"iters/solve, l_inv={ka['l_inv_factorizations']}) "
             f"B={kb['mode']} "
             f"({_fmt(kb['fused_iters_per_solve_call'])}, "
-            f"l_inv={kb['l_inv_factorizations']}, "
-            f"bf16_fallbacks={kb['bf16_fallbacks']}) — "
+            f"l_inv={kb['l_inv_factorizations']}) — "
             f"per-iteration verdict [{tag}]")
     # streaming verdict row (ISSUE 15, doc/streaming.md): for a run
     # with an active scenario source, the acceptance contract is FLAT
@@ -1977,43 +1772,6 @@ def compare(a: Run, b: Run, threshold=1.5,
                 f"  transplant: warm A={sha['transplants']} "
                 f"B={shb['transplants']}  cold A={ca} B={cb} — "
                 f"cold-fallback verdict [{verdict}]")
-    # measured-MFU verdict row (ISSUE 18, doc/roofline.md): when both
-    # sides carry profile captures, the roofline promise is that B's
-    # measured model-FLOP utilization did not collapse — the per-
-    # iteration time rows can stay flat while the work per iteration
-    # silently grew (shape-bucket drift, fallback kernels), and MFU is
-    # the one figure that catches it. A >1.25x drop with a real
-    # absolute delta books a regression; one-sided captures abstain,
-    # and so do runs whose FLOPs/iter differ materially — different
-    # arithmetic per iteration (e.g. segmented vs fused engines)
-    # makes MFU apples-to-oranges, not a regression.
-    ra, rb = roofline_summary(a), roofline_summary(b)
-    if ra is not None and rb is not None:
-        va = ra["overall"]["mfu"]
-        vb = rb["overall"]["mfu"]
-
-        def _fpi(r):
-            o = r["overall"]
-            return o["flops_total"] / o["iters"] if o["iters"] else 0.0
-
-        fa, fb = _fpi(ra), _fpi(rb)
-        same_work = (fa > 0 and fb > 0
-                     and 0.9 < fa / fb < 1.1111)
-        if va is not None and vb is not None and va > 0:
-            verdict = "PASS" if same_work else "skipped"
-            if same_work and (vb <= 0 or (va / max(vb, 1e-12) > 1.25
-                                          and (va - vb) > 1e-4)):
-                verdict = "REGRESSION"
-                regressions.append("profile_mfu")
-            L.append(
-                f"  roofline: mfu A={_fmt(va, 4)} B={_fmt(vb, 4)}  "
-                f"hbm A={_fmt(ra['overall']['hbm_gbps'], 2)} "
-                f"B={_fmt(rb['overall']['hbm_gbps'], 2)} GB/s  "
-                f"compiles A={ra['ledger_compiles']} "
-                f"B={rb['ledger_compiles']} — MFU verdict [{verdict}]")
-    elif ra is not None or rb is not None:
-        L.append("  roofline: profile captures on one side only — "
-                 "MFU verdict [skipped]")
     # forensics verdict row (ISSUE 19, doc/forensics.md): when a side
     # carries forensic data, restate its diagnosis as one explicit
     # line. A candidate whose wheel shows a stall signature the
@@ -2091,17 +1849,6 @@ def render_watch(path) -> tuple[str, bool]:
                      + "  ".join(f"{k} {_fmt(v, 3)}" for k, v in
                                  (ph.get("seconds_per_call")
                                   or {}).items()))
-        rf = live.get("roofline")
-        if rf:
-            # current-iteration measured roofline (obs/profile.py):
-            # one line — MFU + HBM utilization of the last completed
-            # iteration, straight off the live plane
-            L.append(
-                f"roofline iter {rf.get('iter')}: "
-                f"mfu {_fmt(rf.get('mfu'), 4)}  "
-                f"hbm {_fmt(rf.get('hbm_gbps'), 2)} GB/s "
-                f"(util {_fmt(rf.get('hbm_util'), 4)})  "
-                f"flops/iter {_fmt(rf.get('flops_per_iter'))}")
         fo = live.get("forensics")
         if fo:
             # wheel-forensics tile (obs/diagnose.py): the current
@@ -2284,8 +2031,6 @@ def main(argv=None) -> int:
                                    "b": streaming_summary(b)},
                      "aph": {"a": aph_summary(a),
                              "b": aph_summary(b)},
-                     "roofline": {"a": roofline_summary(a),
-                                  "b": roofline_summary(b)},
                      "forensics": {"a": forensics_summary(a),
                                    "b": forensics_summary(b)},
                      "truncated": {"a": truncated(a),
@@ -2309,7 +2054,6 @@ def main(argv=None) -> int:
                 "compile": {k: v for k, v in compile_summary(run).items()
                             if k != "entries"},
                 "sharding": sharding_summary(run),
-                "roofline": roofline_summary(run),
                 "truncated": truncated(run),
                 "shrink": shrink_summary(run),
                 "streaming": streaming_summary(run),

@@ -20,9 +20,8 @@ another scalar:
 
 Two consumption modes share ONE set of pure rule functions
 (:func:`diagnose` and the ``rule_*`` helpers take plain lists/dicts):
-the LIVE engine below (session-bound state in the ``obs/profile.py``
-mold — identity-checked against the active Recorder, rebind-don't-
-mutate snapshots so signal handlers and the hub status thread read
+the LIVE engine below (session-bound state — identity-checked
+against the active Recorder, rebind-don't-mutate snapshots so signal handlers and the hub status thread read
 without locks), and ``obs/analyze.py``'s post-mortem re-diagnosis over
 the recorded event streams. Emits ``forensics.*`` counters/gauges and
 the ``forensics.verdict`` transition event (doc/forensics.md has the
@@ -231,9 +230,9 @@ _MAX_CHECKS = 256
 
 
 class _State:
-    """Per-telemetry-session diagnosis state (the ``obs/profile.py``
-    mold: identity-checked against the active Recorder so tests that
-    reconfigure sessions never inherit stale history)."""
+    """Per-telemetry-session diagnosis state (identity-checked
+    against the active Recorder so tests that reconfigure sessions
+    never inherit stale history)."""
 
     __slots__ = ("rec", "lock", "samples", "bound_checks", "shrink",
                  "verdict", "last")

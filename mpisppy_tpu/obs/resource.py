@@ -81,12 +81,6 @@ def _on_duration(name, secs, **kw):
         # fat blocks interrupting the phase timeline in Perfetto
         now = time.perf_counter()
         r.trace.complete("jax.compile", now - secs, now, cat="resource")
-        # compile-ledger attribution (doc/roofline.md): every backend
-        # compile books to the instrumented entry in flight on this
-        # thread, or the unattributed bucket — the ledger sums to
-        # jax.compiles exactly because this is the same firing
-        from . import profile as _profile
-        _profile.note_compile(secs)
 
 
 class _CompileLogHandler(logging.Handler):

@@ -82,43 +82,26 @@ def _phi_stats(phis, S_real: int):
 
 
 @partial(jax.jit, static_argnames=("scnt", "S_real"))
-def _dispatch_gate_jit(tau, phi, theta, conv, phis, last_dispatch,
-                       scnt: int, S_real: int):
+def dispatch_gate(tau, phi, theta, conv, phis, last_dispatch,
+                  scnt: int, S_real: int):
+    """One packed device row for APH's per-iteration host read:
+    ``[τ, φ, θ, conv, φ_min, φ_max, φ_neg_count] ++ mask`` — the
+    projective-step scalars, the φ stats, and the dispatch selection,
+    concatenated so the host loop syncs exactly once (the PR 13
+    ``(3,)``-packed-stats discipline, scaled up)."""
     mask = dispatch_select(phis, last_dispatch, scnt=scnt, S_real=S_real)
     head = jnp.concatenate([jnp.stack([tau, phi, theta, conv]),
                             _phi_stats(phis, S_real)])
     return jnp.concatenate([head, mask.astype(head.dtype)])
 
 
-def dispatch_gate(*args, **kwargs):
-    """One packed device row for APH's per-iteration host read:
-    ``[τ, φ, θ, conv, φ_min, φ_max, φ_neg_count] ++ mask`` — the
-    projective-step scalars, the φ stats, and the dispatch selection,
-    concatenated so the host loop syncs exactly once (the PR 13
-    ``(3,)``-packed-stats discipline, scaled up)."""
-    if obs.enabled():
-        # measured-roofline capture (obs/profile.py) — zero-cost off
-        from ..obs import profile as _profile
-        return _profile.call("aph.dispatch_gate", _dispatch_gate_jit,
-                             *args, **kwargs)
-    return _dispatch_gate_jit(*args, **kwargs)
-
-
 @partial(jax.jit, static_argnames=("S_real",))
-def _scalar_gate_jit(tau, phi, theta, conv, phis, S_real: int):
-    return jnp.concatenate([jnp.stack([tau, phi, theta, conv]),
-                            _phi_stats(phis, S_real)])
-
-
-def scalar_gate(*args, **kwargs):
+def scalar_gate(tau, phi, theta, conv, phis, S_real: int):
     """The full-dispatch twin of :func:`dispatch_gate`: every real row
     dispatches, so only the scalar head ships — no selection runs and
     the trajectory stays bit-identical to the pre-dispatch engine."""
-    if obs.enabled():
-        from ..obs import profile as _profile
-        return _profile.call("aph.scalar_gate", _scalar_gate_jit,
-                             *args, **kwargs)
-    return _scalar_gate_jit(*args, **kwargs)
+    return jnp.concatenate([jnp.stack([tau, phi, theta, conv]),
+                            _phi_stats(phis, S_real)])
 
 
 GATE_HEAD = 7   # scalar head width of both gate spellings

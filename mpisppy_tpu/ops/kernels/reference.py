@@ -1,5 +1,5 @@
-"""XLA fused-scan REFERENCE backend of the kernel layer (and its
-correctness oracle): one full precision-escalated ADMM solve — f32 bulk
+"""The kernel layer's fused mixed/df32 program (an XLA fused scan):
+one full precision-escalated ADMM solve — f32 bulk
 phase, factor handoff, accurate tail, polish — traced as a SINGLE
 device program, so no iterate, factor, or residual ever round-trips
 through the host between phases.
@@ -17,42 +17,28 @@ What this removes, relative to the segmented driver it replaces
    in the pipelined PH loop instead of waiting on segment syncs.
 
 The MATH is deliberately not new: both phases call the same
-``_solve_impl`` body every segmented solve runs, so this backend is
+``_solve_impl`` body every segmented solve runs, so this program is
 bit-compatible with ``segmented`` whenever the iteration budget fits
 one segment (the micro-parity CI test pins that at 1e-10), and
 tolerance-equivalent beyond (segment boundaries reset the stall window
 and rho-adaptation cadence, which a continuous loop does not — see
 doc/kernels.md).
 
-Two roofline trades live here (doc/roofline.md §5 headroom item 1):
+One roofline trade lives here (doc/roofline.md §5 headroom item 1),
+``l_inv``: the df32 tail's two triangular solves become two MXU
+matmuls of the same bytes by carrying the EXPLICIT L⁻¹
+(qp_solver.LInv) in the solver state, behind ``l_inv_profitable``
+(the n-RHS inverse build must amortize over the iteration budget).
 
- - ``l_inv``: the df32 tail's two triangular solves become two MXU
-   matmuls of the same bytes by carrying the EXPLICIT L⁻¹
-   (qp_solver.LInv) in the solver state, behind ``l_inv_profitable``
-   (the n-RHS inverse build must amortize over the iteration budget);
- - bf16 packed blocks: the f32 bulk phase streams the structure-packed
-   A-blocks at half width with f32 accumulation (ops/packed), behind
-   ``bf16_gate`` (entries that bf16 would FLUSH — sub-normal-range
-   magnitudes, 100% relative error — force the f32 fallback).
-   EXPLICIT OPT-IN only: normal-range rounding is ≤ 2⁻⁸, which sounds
-   admissible for a 1e-3-plateau bulk phase, but measured on the UC LP
-   relaxation it relocates the DEGENERATE OPTIMUM by ~35% while the
-   residuals converge normally — the bulk's real job is picking the
-   vertex, and no residual gate can see a wrong-vertex answer. The
-   kernel layer's "auto" therefore never engages bf16 (see
-   prepare()); doc/kernels.md records the measurement.
-
-A solve that goes wrong under either trade is caught by the SAME df32
-gate machinery that already guards the segmented path: the chunked PH
-loop's quality gate retries flagged chunks in native precision through
-the segmented driver (core/ph._solve_loop_chunked pass 2), which uses
-neither bf16 blocks nor the fused program — the recovery path IS the
-full-precision fallback.
+A solve that goes wrong is caught by the SAME df32 gate machinery that
+already guards the segmented path: the chunked PH loop's quality gate
+retries flagged chunks in native precision through the segmented
+driver (core/ph._solve_loop_chunked pass 2), which does not use the
+fused program — the recovery path IS the full-precision fallback.
 """
 
 from __future__ import annotations
 
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -60,17 +46,15 @@ import numpy as np
 
 from ... import obs
 from ...utils.runtime import compile_serialized
-from ..packed import Packed
 from ..qp_solver import (LInv, PackedMatrix, QPData, QPState, SplitMatrix,
                          _cast_floats, _factorize, _make_l_inv,
                          _prepare_factor, _raw_factor, _solve_impl,
                          make_l_inv)
 
-__all__ = ["fused_mixed_solve", "l_inv_profitable", "bf16_gate",
-           "bf16_packed", "BF16_GATE_REL"]
+__all__ = ["fused_mixed_solve", "l_inv_profitable"]
 
 
-# ---------------- roofline trade guards ----------------
+# ---------------- roofline trade guard ----------------
 
 def l_inv_profitable(n, s_chunk, tail_iter, ir_sweeps=1):
     """Whether the explicit L⁻¹ build amortizes. The inverse
@@ -87,54 +71,6 @@ def l_inv_profitable(n, s_chunk, tail_iter, ir_sweeps=1):
     (n, n) inversion it never recoups."""
     applies = int(tail_iter) * (1 + int(ir_sweeps)) * max(int(s_chunk), 1)
     return applies >= int(n)
-
-
-# bf16 rounds normal-range values within 2⁻⁸ ≈ 3.9e-3 relative —
-# RESIDUAL-level noise the f32 bulk phase tolerates (though NOT
-# objective-level noise on degenerate LPs; that measured hazard is why
-# bf16 is opt-in — see the module docstring). What no consumer can
-# tolerate is INFORMATION LOSS: magnitudes below bf16's normal range
-# flush toward zero (up to 100% relative error), silently deleting
-# matrix entries. The gate measures the worst per-entry relative
-# quantization error and trips above this threshold — normal-range
-# blocks always pass, blocks with flush-range entries always trip.
-BF16_GATE_REL = 1e-2
-
-
-def _bf16_elem_err(vals):
-    """Max per-entry |v - bf16(v)| / |v| over the nonzero entries.
-
-    Measured on HOST via ml_dtypes, deliberately not through an XLA
-    cast: the flush-prone entries are f32 SUBNORMALS (f32 and bf16
-    share the 8-bit exponent, so every f32-normal value is bf16-normal
-    and rounds within 2⁻⁸), and XLA's flush-to-zero erases exactly
-    those entries before the device cast ever sees them — a jitted
-    gate measures 0 error on the blocks it exists to reject. The gate
-    runs once per factorization on small packed blocks, so the host
-    pull is noise."""
-    import ml_dtypes
-
-    v = np.asarray(vals, np.float32)
-    q = v.astype(ml_dtypes.bfloat16).astype(np.float32)
-    nz = np.abs(v) > 0
-    if not nz.any():
-        return 0.0
-    return float((np.abs(v - q)[nz] / np.abs(v)[nz]).max())
-
-
-def bf16_gate(pk: Packed, gate_rel=BF16_GATE_REL):
-    """(trips, measured_err) for bf16 storage of one packed block set."""
-    err = _bf16_elem_err(pk.l_vals)
-    if pk.g_rows.size:
-        err = max(err, _bf16_elem_err(pk.g_vals))
-    return err > gate_rel, err
-
-
-def bf16_packed(pk: Packed) -> Packed:
-    """bf16-storage twin of a packed f32 block set (indices shared; the
-    matvecs keep f32 accumulation — ops/packed._pk_einsum)."""
-    return pk._replace(g_vals=pk.g_vals.astype(jnp.bfloat16),
-                       l_vals=pk.l_vals.astype(jnp.bfloat16))
 
 
 # ---------------- the fused mixed/df32 program ----------------
@@ -172,12 +108,11 @@ def _fused_mixed_impl(factors, A_lo, data, q, iterates, aux,
             and getattr(A_lo, "dtype", lo) != lo:
         # non-split mixed: the plan stages the RAW dense operand and
         # the bulk casts it in-trace, exactly as qp_solve_mixed's eager
-        # _cast_floats does (a packed A_lo is already f32/bf16 storage)
+        # _cast_floats does (a packed A_lo is already f32 storage)
         A_lo = A_lo.astype(lo)
 
-    # lo-phase operands: factors cast around the pre-staged A_lo (cast
-    # AFTER detaching A_s — _cast_floats on a bf16 packed block would
-    # widen the very arrays the trade narrows)
+    # lo-phase operands: factors cast around the pre-staged A_lo (A_s
+    # detached first: a cast of it would be discarded by the next line)
     f_lo = _cast_floats(factors._replace(A_s=jnp.zeros((), lo)), lo)
     f_lo = f_lo._replace(A_s=A_lo)
     d_lo = QPData(P_diag=data.P_diag.astype(lo), A=A_lo,
@@ -290,12 +225,5 @@ def fused_mixed_solve(factors, A_lo, data, q, state, *, bulk_iter,
               polish_iters=int(polish_iters),
               polish_chunk=int(polish_chunk), stall_rel=float(stall_rel),
               ir_sweeps=int(ir_sweeps), l_inv=bool(l_inv))
-    if obs.enabled():
-        # measured-roofline capture + compile-ledger attribution
-        # (obs/profile.py) — zero-cost when telemetry is off
-        from ...obs import profile as _profile
-        return _profile.call("kernel.fused_mixed", fn, factors, A_lo,
-                             data, q, iterates, aux, eps_abs, eps_rel,
-                             eps_abs_dua, eps_rel_dua, **kw)
     return fn(factors, A_lo, data, q, iterates, aux,
               eps_abs, eps_rel, eps_abs_dua, eps_rel_dua, **kw)

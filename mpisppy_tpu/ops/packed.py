@@ -236,10 +236,7 @@ def pack(structure: PackStructure, dense) -> Packed:
     """Gather one dense (m, n) device matrix into packed form. Padded
     index slots (-1) clamp to 0 for the gather and their values are
     zeroed — position (0, c) holds real matrix data, which must not
-    leak into padding. Narrow-storage (bf16) twins of a packed set are
-    built from it by ops/kernels.reference.bf16_packed, behind that
-    layer's quantization gate; the matvecs below keep f32 ACCUMULATION
-    regardless of value-storage dtype (see _pk_einsum)."""
+    leak into padding."""
     lr = jnp.maximum(structure.l_rows, 0)
     lc = jnp.maximum(structure.l_cols, 0)
     vals = dense[lr[:, :, None], lc[:, None, :]]
@@ -249,25 +246,6 @@ def pack(structure: PackStructure, dense) -> Packed:
     return Packed(g_rows=structure.g_rows, g_vals=dense[structure.g_rows],
                   l_rows=lr, l_cols=lc, l_vals=vals,
                   row_src=structure.row_src, col_src=structure.col_src)
-
-
-def _pk_einsum(spec, a, vals):
-    """Block einsum with the accumulator pinned to the ACTIVATION dtype:
-    bf16-stored blocks stream half the bytes but must not accumulate in
-    bf16 (the MXU consumes narrow operands natively; XLA fuses the
-    widening into the dot read). Same-dtype operands keep the exact
-    historical spelling — bit-identical to the pre-bf16 path."""
-    if vals.dtype != a.dtype:
-        return jnp.einsum(spec, a, vals, preferred_element_type=a.dtype)
-    return jnp.einsum(spec, a, vals)
-
-
-def _pk_gmat(a, g_vals):
-    """Thin global-row matmul twin of _pk_einsum (a @ g_vals.T or
-    a @ g_vals spelled by the caller via pre-transposition)."""
-    if g_vals.dtype != a.dtype:
-        return jnp.matmul(a, g_vals, preferred_element_type=a.dtype)
-    return a @ g_vals
 
 
 def _place(slots, src):
@@ -282,25 +260,23 @@ def _place(slots, src):
 
 
 def pk_Ax(pk: Packed, x):
-    """A x via the packed form: x (S, n) -> (S, m). Low-precision value
-    storage (bf16 blocks) accumulates in x's dtype (see _pk_einsum)."""
+    """A x via the packed form: x (S, n) -> (S, m)."""
     S = x.shape[0]
     xg = x[:, pk.l_cols]                          # (S, C, nc)
-    loc = _pk_einsum("scn,cmn->scm", xg, pk.l_vals).reshape(S, -1)
+    loc = jnp.einsum("scn,cmn->scm", xg, pk.l_vals).reshape(S, -1)
     if pk.g_rows.size:
-        loc = jnp.concatenate([loc, _pk_gmat(x, pk.g_vals.T)], axis=1)
+        loc = jnp.concatenate([loc, x @ pk.g_vals.T], axis=1)
     return _place(loc, pk.row_src)
 
 
 def pk_ATy(pk: Packed, y):
-    """Aᵀ y via the packed form: y (S, m) -> (S, n). Low-precision value
-    storage (bf16 blocks) accumulates in y's dtype (see _pk_einsum)."""
+    """Aᵀ y via the packed form: y (S, m) -> (S, n)."""
     S = y.shape[0]
     yg = y[:, pk.l_rows]                          # (S, C, mr)
-    loc = _pk_einsum("scm,cmn->scn", yg, pk.l_vals)
+    loc = jnp.einsum("scm,cmn->scn", yg, pk.l_vals)
     out = _place(loc.reshape(S, -1), pk.col_src)
     if pk.g_rows.size:
-        out = out + _pk_gmat(y[:, pk.g_rows], pk.g_vals)
+        out = out + y[:, pk.g_rows] @ pk.g_vals
     return out
 
 

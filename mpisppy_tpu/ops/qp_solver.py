@@ -995,10 +995,7 @@ def qp_cold_state(factors: QPFactors, data: QPData) -> QPState:
 
 def _scaled_problem(factors: QPFactors, data: QPData, q):
     """The scaled problem vectors one solve iterates in:
-    (g, l_s, u_s, lb_s, ub_s, csx, q_s). Shared by _solve_impl and the
-    ops/kernels pallas driver — the two MUST scale identically, or the
-    kernel-backend parity tests would be comparing different problems
-    (a second copy of these six lines would silently drift)."""
+    (g, l_s, u_s, lb_s, ub_s, csx, q_s)."""
     _, D, E, Eb, cs, A_s, _, _, _ = factors
     shared = A_s.ndim == 2
     g = Eb * D
@@ -1367,12 +1364,6 @@ def qp_solve(factors: QPFactors, data: QPData, q, state: QPState,
     else:
         kw.pop("_segmented_caller", None)
     fn = _qp_solve_jit_donated if donate else _qp_solve_jit
-    if obs.enabled():
-        # measured-roofline capture + compile-ledger attribution
-        # (obs/profile.py) — zero-cost when telemetry is off
-        from ..obs import profile as _profile
-        return _profile.call("qp.solve", fn, factors, data, q, state,
-                             **kw)
     return fn(factors, data, q, state, **kw)
 
 
@@ -1591,19 +1582,10 @@ def qp_solve_mixed(factors: QPFactors, data: QPData, q, state: QPState,
         t_seg = time.perf_counter()
         fn_lo = _solve_lo_jit_donated if owned_lo else _solve_lo_jit
         with obs.span("qp.segment", cat="qp"):
-            if obs.enabled():
-                from ..obs import profile as _profile
-                st_lo, _, _, _ = _profile.call(
-                    "qp.solve_lo", fn_lo, f_lo, d_lo, q_lo, st_lo,
-                    seg_lo, check_every, eps_lo, eps_rel_lo, alpha,
-                    adaptive_rho, polish_iters, eps_rel_lo_dua,
-                    stall_rel)
-            else:
-                st_lo, _, _, _ = fn_lo(f_lo, d_lo, q_lo, st_lo,
-                                       seg_lo, check_every, eps_lo,
-                                       eps_rel_lo, alpha, adaptive_rho,
-                                       polish_iters, eps_rel_lo_dua,
-                                       stall_rel)
+            st_lo, _, _, _ = fn_lo(f_lo, d_lo, q_lo, st_lo, seg_lo,
+                                   check_every, eps_lo, eps_rel_lo, alpha,
+                                   adaptive_rho, polish_iters,
+                                   eps_rel_lo_dua, stall_rel)
             owned_lo = True
             lo_ran = True
             _trace_seg("lo-seg", t_seg, st_lo)

@@ -56,9 +56,15 @@ INT_NEVER = 2 ** 30
 # ---------------- device fixer counters ----------------
 
 @jax.jit
-def _fixer_update_jit(conv_count, lb_count, ub_count, fixed_mask,
-                      fixed_vals, xbar, xsqbar, xn, slot_lb, slot_ub,
-                      tol, boundtol, nbc, lbc, ubc, imask):
+def fixer_update(conv_count, lb_count, ub_count, fixed_mask,
+                 fixed_vals, xbar, xsqbar, xn, slot_lb, slot_ub,
+                 tol, boundtol, nbc, lbc, ubc, imask):
+    """One ``miditer`` of the WW fixer as a device op. Mirrors
+    extensions/fixer.py Fixer.miditer EXACTLY (the parity test pins
+    identical fix decisions): variance test per slot, parked-at-bound
+    streaks, lb > ub > nb precedence, integral snap, accumulate-only
+    fixing. Returns the updated counters/mask/values plus the fixed
+    slot count as a device scalar — the ONE number the host reads."""
     var = jnp.max(jnp.abs(xsqbar - xbar * xbar), axis=0)
     agree = var <= tol * tol + 1e-15
     conv_count = jnp.where(agree, conv_count + 1, 0)
@@ -79,26 +85,10 @@ def _fixer_update_jit(conv_count, lb_count, ub_count, fixed_mask,
     return conv_count, lb_count, ub_count, fixed_mask, fixed_vals, n_fixed
 
 
-def fixer_update(*args):
-    """One ``miditer`` of the WW fixer as a device op. Mirrors
-    extensions/fixer.py Fixer.miditer EXACTLY (the parity test pins
-    identical fix decisions): variance test per slot, parked-at-bound
-    streaks, lb > ub > nb precedence, integral snap, accumulate-only
-    fixing. Returns the updated counters/mask/values plus the fixed
-    slot count as a device scalar — the ONE number the host reads."""
-    if obs.enabled():
-        # measured-roofline capture (obs/profile.py) — zero-cost off
-        from ..obs import profile as _profile
-        return _profile.call("shrink.fixer_update", _fixer_update_jit,
-                             *args)
-    return _fixer_update_jit(*args)
-
-
 # ---------------- per-slot adaptive rho ----------------
 
 @jax.jit
-def _per_slot_rho_update_jit(rho, prob, xn, xbar, xbar_prev, mult,
-                             factor):
+def per_slot_rho_update(rho, prob, xn, xbar, xbar_prev, mult, factor):
     """Residual-balancing rho update PER NONANT SLOT (the vector
     analog of extensions/norm_rho_updater.py): prim_k is the
     probability-weighted primal residual of slot k, dual_k the
@@ -118,16 +108,6 @@ def _per_slot_rho_update_jit(rho, prob, xn, xbar, xbar_prev, mult,
     changed = jnp.any(up | down).astype(rho.dtype)
     stats = jnp.stack([changed, jnp.sum(prim), jnp.sum(dual)])
     return new_rho, stats
-
-
-def per_slot_rho_update(*args):
-    """See ``_per_slot_rho_update_jit`` — the public name adds the
-    measured-roofline capture when telemetry is on."""
-    if obs.enabled():
-        from ..obs import profile as _profile
-        return _profile.call("shrink.rho_update",
-                             _per_slot_rho_update_jit, *args)
-    return _per_slot_rho_update_jit(*args)
 
 
 # ---------------- active-set compaction ----------------

@@ -3,7 +3,7 @@
 The streamed scenario source (stream/source.py) holds the per-scenario
 vector blocks (l/u/lb/ub/c) on HOST and ships one chunk at a time;
 int8 packing quarters those bytes — host residency AND the H2D wire —
-the "halve resident bytes again after bf16" rung of ROADMAP item 3.
+the "halve resident bytes again" rung of ROADMAP item 3.
 
 Representation: per (scenario row, field) block, the stored value is
 the int8-quantized DELTA from the field's template row with a
@@ -17,17 +17,17 @@ delta quantization keeps the absolute error at (delta range)/254
 instead of (value range)/254, and an unperturbed row stores scale = 0
 exactly (bit-exact roundtrip).
 
-Quantization CHANGES the problem data, so the same double guard as the
-bf16 packed blocks applies (doc/kernels.md §4):
+Quantization CHANGES the problem data, so a double guard applies:
 
 - the gate (``quantize_field``) measures the worst per-entry
   reconstruction error ON HOST, reproducing the device's f32
   dequantization arithmetic exactly — a too-coarse block falls back to
   full-precision host storage and books ``stream.int8_fallbacks``;
 - int8 packing is EXPLICIT opt-in (``stream_int8`` — never engaged by
-  ``scenario_source='streamed'`` alone): like bf16, a residual-level
-  data perturbation can relocate a degenerate optimum no residual gate
-  can see.
+  ``scenario_source='streamed'`` alone): a residual-level data
+  perturbation can relocate a degenerate optimum no residual gate can
+  see (measured with bf16 packed A-blocks, removed for it:
+  doc/kernels.md §4).
 
 Non-finite entries (±inf constraint/box bounds) must come from the
 TEMPLATE: a scenario whose non-finite pattern differs from the

@@ -23,16 +23,13 @@ from dataclasses import dataclass, field, asdict
 
 KNOWN_MODELS = ("farmer", "sizes", "sslp", "netdes", "hydro", "uc",
                 "battery", "ccopf")
-# subproblem kernel-backend selection (ops/kernels, doc/kernels.md).
+# subproblem kernel-mode selection (ops/kernels, doc/kernels.md).
 # Defined HERE (not in ops.kernels) so validation never imports jax:
 # config validation runs in process workers and the jax-free analyze
 # CLI; ops.kernels imports these as its single source of truth.
 KERNEL_MODES = ("auto", "fused", "segmented")
-KERNEL_BACKENDS = ("reference", "pallas")
 KERNEL_L_INV_MODES = ("auto", "on", "off")
-KERNEL_BLOCK_DTYPES = ("auto", "bf16", "f32")
-# the fused program unrolls the df32 IR sweeps statically (and the
-# pallas block bakes them into its instruction stream): sweep counts
+# the fused program unrolls the df32 IR sweeps statically: sweep counts
 # outside this band must fail HERE as a config error, not as a deep
 # trace explosion inside the fused jit (ISSUE 7 small fix)
 FUSED_IR_SWEEPS = range(1, 5)
@@ -94,14 +91,12 @@ class AlgoConfig:
     # df32 x-update iterative-refinement sweeps (ops/qp_solver
     # ._m_solve_ir); validated against the kernel mode below
     subproblem_ir_sweeps: int = 1
-    # kernel-backend selection (ops/kernels, doc/kernels.md):
+    # kernel-mode selection (ops/kernels, doc/kernels.md):
     # "segmented" = today's host-segmented drivers bit-for-bit,
     # "fused" = one device program per solve, "auto" = fused wherever
     # the solve is eligible (the default)
     subproblem_kernel_mode: str = "auto"
-    subproblem_kernel_backend: str = "reference"
     subproblem_kernel_l_inv: str = "auto"       # explicit L⁻¹ matmuls
-    subproblem_kernel_block_dtype: str = "auto"  # bf16 packed blocks
     # pipelined chunk dispatch (doc/pipelining.md): pre-assembled
     # chunks + fused quality-gate sync + donated warm starts; 0 opts
     # back into the strictly sequential debug loop
@@ -150,10 +145,7 @@ class AlgoConfig:
             "subproblem_polish_chunk": self.subproblem_polish_chunk,
             "subproblem_ir_sweeps": self.subproblem_ir_sweeps,
             "subproblem_kernel_mode": self.subproblem_kernel_mode,
-            "subproblem_kernel_backend": self.subproblem_kernel_backend,
             "subproblem_kernel_l_inv": self.subproblem_kernel_l_inv,
-            "subproblem_kernel_block_dtype":
-                self.subproblem_kernel_block_dtype,
             "subproblem_pipeline": self.subproblem_pipeline,
             # shrink_* knobs ride to_options() so they reach the engine
             # AND the serve bucket fingerprint (serve/batch.bucket_key
@@ -200,21 +192,11 @@ class AlgoConfig:
             raise ValueError(
                 f"unknown subproblem_kernel_mode "
                 f"{self.subproblem_kernel_mode!r}; known: {KERNEL_MODES}")
-        if self.subproblem_kernel_backend not in KERNEL_BACKENDS:
-            raise ValueError(
-                f"unknown subproblem_kernel_backend "
-                f"{self.subproblem_kernel_backend!r}; known: "
-                f"{KERNEL_BACKENDS}")
         if self.subproblem_kernel_l_inv not in KERNEL_L_INV_MODES:
             raise ValueError(
                 f"unknown subproblem_kernel_l_inv "
                 f"{self.subproblem_kernel_l_inv!r}; known: "
                 f"{KERNEL_L_INV_MODES}")
-        if self.subproblem_kernel_block_dtype not in KERNEL_BLOCK_DTYPES:
-            raise ValueError(
-                f"unknown subproblem_kernel_block_dtype "
-                f"{self.subproblem_kernel_block_dtype!r}; known: "
-                f"{KERNEL_BLOCK_DTYPES}")
         if self.shrink_fix_iters < 1:
             raise ValueError("shrink_fix_iters must be >= 1")
         if self.shrink_fix_tol <= 0:

@@ -32,8 +32,7 @@ def enable_honest_f32():
     converges to ~1e-3 (measured: the f32 hub's iter-0 feasibility
     gate fails on TPU but passes on CPU with identical code). Solver
     math needs honest f32. ONE policy point: every entry path
-    (setup_jax_runtime, __graft_entry__.py, profile_hotloop.py) calls
-    this."""
+    (setup_jax_runtime, __graft_entry__.py) calls this."""
     import jax
 
     jax.config.update("jax_default_matmul_precision", "highest")

@@ -534,3 +534,52 @@ def test_analyze_no_sharding_section_on_unsharded_run(farmer_run_dir):
     assert "== sharding ==" not in analyze.render_report(r)
     assert ("collective_kbytes_per_solve_call", "count") \
         not in analyze.comparison_metrics(r)
+
+
+# ---------------- truncated runs (ISSUE 18 satellite) ----------------
+
+def _synth_run(path, run_id="synth", footer=True):
+    """Hand-written telemetry dir: three iterations, with or without
+    the ``run_footer`` a run killed before shutdown never writes."""
+    os.makedirs(path, exist_ok=True)
+    evs = [{"t": 0.0, "type": "run_header", "schema": 2,
+            "run_id": run_id, "role": None, "pid": 1,
+            "wall_time_unix": 1000.0, "clock": "perf_counter",
+            "config": {}}]
+    for it in range(1, 4):
+        evs.append({"t": it * 10.0, "type": "ph.iteration", "iter": it,
+                    "conv": 1e-3, "seconds": 2.0,
+                    "phase_seconds": {"solve": 1.6},
+                    "counter_deltas": {}})
+    counters = {"jax.compiles": 2, "ph.solve_loop_calls": 3}
+    if footer:
+        evs.append({"t": 40.0, "type": "run_footer",
+                    "metrics": {"counters": counters}})
+    with open(os.path.join(path, "events.jsonl"), "w",
+              encoding="utf-8") as fh:
+        for e in evs:
+            fh.write(json.dumps(e) + "\n")
+    with open(os.path.join(path, "metrics.json"), "w",
+              encoding="utf-8") as fh:
+        json.dump({"counters": counters, "gauges": {},
+                   "histograms": {}}, fh)
+
+
+def test_truncated_run_stamps_every_section(tmp_path):
+    """A run killed before run_footer renders EVERY section header with
+    the TRUNCATED RUN stamp plus one explicit notice — uniform
+    handling, not section-dependent silence — in the report and in
+    ``--compare``; a run with its footer carries no stamp."""
+    whole, cut = str(tmp_path / "a"), str(tmp_path / "c")
+    _synth_run(whole, run_id="a")
+    _synth_run(cut, run_id="c", footer=False)
+    ra, rc = analyze.load_run(whole), analyze.load_run(cut)
+    assert analyze.truncated(rc) and not analyze.truncated(ra)
+    assert "TRUNCATED" not in analyze.render_report(ra)
+    text = analyze.render_report(rc)
+    assert "TRUNCATED RUN: no run_footer" in text
+    heads = [ln for ln in text.splitlines() if ln.startswith("== ")]
+    assert heads and all("[TRUNCATED RUN]" in ln for ln in heads)
+    text, _ = analyze.compare(ra, rc)
+    assert "TRUNCATED RUN (B)" in text
+    assert "== compare ==  [TRUNCATED RUN]" in text

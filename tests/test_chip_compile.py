@@ -1,4 +1,4 @@
-"""Compile the main path's kernels and step programs for the chip —
+"""Compile the main path's step programs for the chip —
 from a sandbox that has none (on-chip-measurement guide §2.3).
 
 The TPU compiler is installed here and compiles for a chip that is
@@ -24,8 +24,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
-
-from mpisppy_tpu.ops.kernels import pallas_kernel as pk
 
 
 @pytest.fixture(scope="module")
@@ -64,72 +62,6 @@ def _on(tree, sharding_of):
                                         sharding=sharding_of(a))
         return a
     return jax.tree.map(leaf, tree)
-
-
-# ---------------- the Pallas block ----------------
-
-def _block_shapes(S, n, m, sharding, dt=jnp.float32):
-    def sds(*shape):
-        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
-    #       A          F          Ps      g       q_s        l_s
-    return (sds(m, n), sds(n, n), sds(n), sds(n), sds(S, n), sds(S, m),
-            # u_s      lb_s       ub_s       rA      rB      Einv
-            sds(S, m), sds(S, n), sds(S, n), sds(m), sds(n), sds(m),
-            # Ebinv Dinv_c  D       x          yA         yB
-            sds(n), sds(n), sds(n), sds(S, n), sds(S, m), sds(S, n),
-            # zA       zB
-            sds(S, m), sds(S, n))
-
-
-def _compile_block(S, n, m, tile, sharding):
-    return pk._block_call.lower(
-        *_block_shapes(S, n, m, sharding), sigma=1e-6, n_steps=50,
-        alpha=1.6, interpret=False, l_inv_pair=True,
-        scen_tile=tile).compile()
-
-
-@pytest.mark.parametrize("S,n,m,tile", [
-    (8, 128, 256, 0),         # untiled small
-    (256, 128, 256, 128),     # the grid: S=256 in 128-row tiles
-    (64, 384, 768, 0),        # about the widest the limit admits
-    (512, 256, 512, 128),     # the same on the grid
-])
-def test_pallas_block_compiles_for_v5e(one_chip, no_persistent_cache,
-                                       S, n, m, tile):
-    tiled = 0 < tile < S
-    assert pk.vmem_bytes_estimate(tile if tiled else S, n, m,
-                                  tiled=tiled) <= pk.VMEM_LIMIT_BYTES
-    compiled = _compile_block(S, n, m, tile, one_chip)
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_pallas_scope_refuses_what_mosaic_refuses(one_chip,
-                                                  no_persistent_cache):
-    """The bytes-vs-VMEM estimate and the dtype check refuse, by name,
-    the operands the chip's compiler refuses — before it has to."""
-    import types
-
-    from mpisppy_tpu.ops.qp_solver import LInv
-
-    def operands(S, n, m, dt):
-        f = types.SimpleNamespace(A_s=jnp.zeros((m, n), dt))
-        F = jnp.zeros((n, n), dt)
-        return f, types.SimpleNamespace(L=LInv(F, F),
-                                        x=jnp.zeros((S, n), dt))
-
-    # over VMEM — far over (128 rows at n=1024 / m=2048, untiled) and
-    # just over (8 rows at n=512 / m=1024: the compiler asks 18.6 MB)
-    for S, n, m in ((128, 1024, 2048), (8, 512, 1024)):
-        why = pk.pallas_scope_reason(*operands(S, n, m, jnp.float32),
-                                     scen_tile=0)
-        assert why is not None and "VMEM" in why
-        with pytest.raises(Exception, match="vmem"):
-            _compile_block(S, n, m, 0, one_chip)
-    # f64: Mosaic has no such type
-    why = pk.pallas_scope_reason(*operands(8, 128, 256, jnp.float64))
-    assert why is not None and "f32 only" in why
-    # in scope: the same small shape in f32
-    assert pk.pallas_supported(*operands(8, 128, 256, jnp.float32))
 
 
 # ---------------- the df32 chunk solve and the consensus reduce --------

@@ -1,8 +1,8 @@
-"""Kernel-backend layer (ops/kernels, ISSUE 7): fused-vs-segmented
+"""Kernel layer (ops/kernels, ISSUE 7): fused-vs-segmented
 equivalence at micro and PH level (farmer + uc shapes, f32 bulk and
-df32 tail, pathological-chunk recovery), the L⁻¹-matmul and bf16-block
-roofline trades' guards, Pallas interpret=True parity against the
-reference backend, mesh gate-sync invariants, and the combined
+df32 tail, pathological-chunk recovery), what ``prepare`` resolves for
+every mode / factor kind / sweep count, the L⁻¹-matmul roofline
+trade's guard, mesh gate-sync invariants, and the combined
 kernel-mode/ir-sweeps config validation."""
 
 import types
@@ -17,14 +17,13 @@ from mpisppy_tpu.core.ph import PHBase
 from mpisppy_tpu.ir.batch import build_batch
 from mpisppy_tpu.models import farmer, uc
 from mpisppy_tpu.ops import kernels
-from mpisppy_tpu.ops.kernels import pallas_kernel
-from mpisppy_tpu.ops.kernels.reference import (bf16_gate, bf16_packed,
-                                               fused_mixed_solve)
+from mpisppy_tpu.ops.kernels.reference import fused_mixed_solve
 from mpisppy_tpu.ops.packed import Packed
-from mpisppy_tpu.ops.qp_solver import (LInv, QPData, SplitMatrix,
-                                       make_l_inv, qp_cold_state, qp_setup,
-                                       qp_solve, qp_solve_mixed,
-                                       qp_solve_segmented, _chol_solve)
+from mpisppy_tpu.ops.qp_solver import (LInv, PackedMatrix, QPData,
+                                       SplitMatrix, make_l_inv,
+                                       qp_cold_state, qp_setup,
+                                       qp_solve_mixed, qp_solve_segmented,
+                                       _chol_solve)
 from mpisppy_tpu.parallel.mesh import make_mesh
 
 
@@ -74,7 +73,7 @@ def test_micro_parity_fused_native_vs_segmented():
     st_s, x_s, yA_s, yB_s = qp_solve_segmented(fac, d, q, st, max_iter=5,
                                                segment=5, **kw)
     plan = kernels.prepare(fac, mode="fused", precision="native")
-    assert plan.mode == "fused" and plan.backend == "reference"
+    assert plan.mode == "fused"
     st_f, x_f, yA_f, yB_f = kernels.kernel_solve(
         plan, fac, d, q, st, precision="native", max_iter=5, tail_iter=0,
         e_pri=0.0, e_dua=0.0, stall_rel=0.0, polish=False, polish_chunk=0,
@@ -424,8 +423,8 @@ def test_fused_mode_eligibility_guards(monkeypatch):
     """Explicit 'fused' on factors whose rho adaptation must
     refactorize on the host is a config error (the in-trace _factorize
     would produce an untrusted device inverse); 'auto' falls back.
-    Nothing else makes 'auto' refuse: a long f64 budget fuses on every
-    backend."""
+    Nothing else makes 'auto' refuse: every backend fuses, whatever
+    the budget."""
     fac, d, q, st = _tiny_qp()
     monkeypatch.setattr(kernels, "_needs_host_factor", lambda f: True)
     with pytest.raises(ValueError, match="host"):
@@ -434,23 +433,19 @@ def test_fused_mode_eligibility_guards(monkeypatch):
                            precision="native").mode == "segmented"
     monkeypatch.setattr(kernels, "_needs_host_factor", lambda f: False)
     for backend in ("tpu", "cpu"):
-        monkeypatch.setattr(kernels.jax, "default_backend",
-                            lambda b=backend: b)
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
         for mode in ("auto", "fused"):
-            assert kernels.prepare(fac, mode=mode, precision="native",
-                                   bulk_iter=5000).mode == "fused"
+            assert kernels.prepare(fac, mode=mode,
+                                   precision="native").mode == "fused"
         assert kernels.prepare(fac, mode="auto", precision="mixed",
-                               bulk_iter=5000,
                                tail_iter=1500).mode == "fused"
 
 
-# ---------------- the bf16 block trade ----------------
+# ---------------- what prepare() resolves ----------------
 
-def _mini_packed(flush_entry=False):
+def _mini_packed():
     rng = np.random.default_rng(3)
     vals = rng.uniform(0.5, 2.0, size=(2, 3, 4)).astype(np.float32)
-    if flush_entry:
-        vals[0, 0, 0] = 1e-41   # below bf16's SUBNORMAL floor: flushes
     return Packed(g_rows=jnp.zeros((0,), jnp.int32),
                   g_vals=jnp.zeros((0, 4), jnp.float32),
                   l_rows=jnp.zeros((2, 3), jnp.int32),
@@ -460,121 +455,85 @@ def _mini_packed(flush_entry=False):
                   col_src=jnp.zeros((4,), jnp.int32))
 
 
-def test_bf16_gate_normal_blocks_pass_flush_blocks_trip():
-    trips, err = bf16_gate(_mini_packed())
-    assert not trips and err <= 2.0 ** -8 + 1e-6
-    trips, err = bf16_gate(_mini_packed(flush_entry=True))
-    assert trips and err > 0.5
-    pk16 = bf16_packed(_mini_packed())
-    assert pk16.l_vals.dtype == jnp.bfloat16
+def _prepare_factors(kind):
+    """(factors, precision) of one factor kind prepare() tells apart."""
+    if kind == "split-df32":            # shared packed SplitMatrix
+        hi = jnp.asarray(np.ones((6, 4)), jnp.float32)
+        sm = SplitMatrix(hi, jnp.zeros_like(hi), struct=object(),
+                         pk_hi=_mini_packed(), pk_lo=_mini_packed())
+        return types.SimpleNamespace(A_s=sm), "df32"
+    if kind == "dense-mixed":           # one shared dense A
+        return _tiny_qp()[0], "mixed"
+    if kind == "dense-native":
+        return _tiny_qp()[0], "native"
+    # per-scenario f64 A on a backend whose batched f64 device inverse
+    # is not trusted (the chip; the test patches the trust off)
+    A = jnp.asarray(np.ones((3, 6, 4)), jnp.float64)
+    return types.SimpleNamespace(A_s=A), "native"
 
 
-def test_bf16_prepare_gate_trip_falls_back_to_f32():
-    """Explicit bf16 opt-in with a flush-range block: the plan falls
-    back to f32 storage and books the kernel.bf16_fallbacks counter;
-    'auto' never engages bf16 at all (the measured wrong-vertex hazard
-    — see ops/kernels.prepare)."""
-    hi = jnp.asarray(np.ones((6, 4)), jnp.float32)
-    sm_bad = SplitMatrix(hi, jnp.zeros_like(hi), struct=object(),
-                         pk_hi=_mini_packed(flush_entry=True),
-                         pk_lo=_mini_packed())
-    sm_ok = SplitMatrix(hi, jnp.zeros_like(hi), struct=object(),
-                        pk_hi=_mini_packed(), pk_lo=_mini_packed())
-    fac_bad = types.SimpleNamespace(A_s=sm_bad)
-    fac_ok = types.SimpleNamespace(A_s=sm_ok)
-    obs.configure(out_dir=None)
-    try:
-        plan = kernels.prepare(fac_bad, mode="fused", precision="df32",
-                               block_dtype="bf16", l_inv="off")
-        assert plan.block_dtype == "f32"
+# outcome of prepare(mode, ir_sweeps) by factor kind. "F": one fused
+# program; "FL": fused with the explicit inverse (tail 100 x 2 rows x 2
+# applies >= n = 4: l_inv_profitable); "S": the segmented drivers;
+# "E:<word>": a ValueError naming <word>
+_PREPARE_TABLE = {
+    "split-df32": {("auto", 1): "FL", ("fused", 1): "FL",
+                   ("segmented", 1): "S", ("auto", 5): "S",
+                   ("fused", 5): "E:ir_sweeps", ("segmented", 5): "S"},
+    "dense-mixed": {("auto", 1): "F", ("fused", 1): "F",
+                    ("segmented", 1): "S", ("auto", 5): "S",
+                    ("fused", 5): "E:ir_sweeps", ("segmented", 5): "S"},
+    "dense-native": {("auto", 1): "F", ("fused", 1): "F",
+                     ("segmented", 1): "S", ("auto", 5): "S",
+                     ("fused", 5): "E:ir_sweeps", ("segmented", 5): "S"},
+    "per-scenario-f64-host": {("auto", 1): "S", ("fused", 1): "E:host",
+                              ("segmented", 1): "S", ("auto", 5): "S",
+                              ("fused", 5): "E:ir_sweeps",
+                              ("segmented", 5): "S"},
+}
+
+
+@pytest.mark.parametrize("ir_sweeps", [1, 5])
+@pytest.mark.parametrize("kind", list(_PREPARE_TABLE))
+@pytest.mark.parametrize("mode", ["auto", "fused", "segmented"])
+def test_prepare_resolution(monkeypatch, mode, kind, ir_sweeps):
+    """All of what ``prepare`` decides: the mode a solve runs in (or
+    the config error), whether the tail carries the explicit inverse,
+    and the bulk phase's operand — f32, the factors' own arrays, never
+    a substitute. ``descriptor()`` is what ``phase_timing()["kernel"]``
+    and the benchmark's driver print."""
+    import mpisppy_tpu.ops.qp_solver as qps
+    if kind == "per-scenario-f64-host":
+        monkeypatch.setattr(qps, "_device_f64_linalg_trusted",
+                            lambda: False)
+    fac, precision = _prepare_factors(kind)
+    want = _PREPARE_TABLE[kind][mode, ir_sweeps]
+    kw = dict(mode=mode, precision=precision, ir_sweeps=ir_sweeps,
+              tail_iter=100, s_chunk=2)
+    if want.startswith("E:"):
+        with pytest.raises(ValueError, match=want[2:]):
+            kernels.prepare(fac, **kw)
+        return
+    plan = kernels.prepare(fac, **kw)
+    assert plan.descriptor() == {
+        "mode": "segmented" if want == "S" else "fused",
+        "backend": "reference", "l_inv": want == "FL",
+        "block_dtype": "f32"}
+    if want == "S":
+        assert plan is kernels.SEGMENTED_PLAN and plan.A_lo is None
+    elif kind == "split-df32":
+        assert isinstance(plan.A_lo, PackedMatrix)
+        assert plan.A_lo.dense is fac.A_s.hi
+        assert plan.A_lo.pk is fac.A_s.pk_hi
         assert plan.A_lo.pk.l_vals.dtype == jnp.float32
-        assert obs.counter_value("kernel.bf16_fallbacks") == 1
-        plan = kernels.prepare(fac_ok, mode="fused", precision="df32",
-                               block_dtype="bf16", l_inv="off")
-        assert plan.block_dtype == "bf16"
-        assert plan.A_lo.pk.l_vals.dtype == jnp.bfloat16
-        assert obs.counter_value("kernel.bf16_fallbacks") == 1
-        plan = kernels.prepare(fac_ok, mode="fused", precision="df32",
-                               block_dtype="auto", l_inv="off")
-        assert plan.block_dtype == "f32"
-    finally:
-        obs.shutdown()
-
-
-# ---------------- pallas backend ----------------
-
-def test_pallas_interpret_block_parity_vs_reference():
-    """The Pallas fused iteration block under interpret=True runs the
-    EXACT update + stacked residual reduction _solve_impl runs: 20
-    fixed-rho iterations from a cold state agree with the reference
-    solver to roundoff (scaled iterates and unscaled residual maxima
-    alike)."""
-    fac, d, q, st = _tiny_qp(seed=2)
-    x, yA, yB, zA, zB, pri, dua = pallas_kernel.fused_admm_block(
-        fac, d, q, st, n_steps=20, interpret=True)
-    st_r, _, _, _ = qp_solve(fac, d, q, st, max_iter=20, check_every=20,
-                             eps_abs=0.0, eps_rel=0.0, polish=False,
-                             adaptive_rho=False)
-    np.testing.assert_allclose(np.asarray(x), np.asarray(st_r.x),
-                               atol=1e-9)
-    np.testing.assert_allclose(np.asarray(zA), np.asarray(st_r.zA),
-                               atol=1e-9)
-    np.testing.assert_allclose(np.asarray(pri), np.asarray(st_r.pri_res),
-                               atol=1e-9)
-
-
-def test_pallas_backend_solve_through_kernel_layer(monkeypatch):
-    """End-to-end pallas-backed kernel_solve on the tiny QP, on the
-    operands the TPU kernel's scope admits (f32, explicit L⁻¹): the
-    block runs the budget at fixed rho, the oracle finisher polishes,
-    and the result converges the problem (functional contract — exact
-    parity is the block test above). The program never interprets the
-    kernel; on the CPU tier the TEST does."""
-    from functools import partial
-    monkeypatch.setattr(
-        pallas_kernel, "fused_admm_block",
-        partial(pallas_kernel.fused_admm_block, interpret=True))
-    fac, d, q, st = _tiny_qp(seed=4, dtype=jnp.float32)
-    st = st._replace(L=make_l_inv(st.L))
-    assert pallas_kernel.pallas_supported(fac, st)
-    plan = kernels.prepare(fac, mode="fused", backend="pallas",
-                           precision="native")
-    assert plan.backend == "pallas"
-    st_p, x_p, _, _ = kernels.kernel_solve(
-        plan, fac, d, q, st, precision="native", max_iter=400,
-        tail_iter=0, e_pri=1e-4, e_dua=1e-4, stall_rel=0.0, polish=True,
-        polish_chunk=0, ir_sweeps=1)
-    st_r, x_r, _, _ = qp_solve(fac, d, q, st, max_iter=400,
-                               eps_abs=1e-4, eps_rel=1e-4, polish=True)
-    assert float(np.asarray(st_p.pri_rel).max()) < 1e-3
-    np.testing.assert_allclose(np.asarray(x_p), np.asarray(x_r),
-                               rtol=1e-3, atol=1e-3)
-
-
-def test_pallas_out_of_scope_is_a_config_error():
-    """A pallas backend that was asked for and cannot serve the solve
-    raises, naming the reason — it is never demoted to reference
-    mid-run. Static scope (split / mixed operands) at prepare();
-    state-dependent scope (f64 M⁻¹, a Cholesky factor, a working set
-    over the VMEM limit) at the solve."""
-    hi = jnp.asarray(np.ones((6, 4)), jnp.float32)
-    sm = SplitMatrix(hi, jnp.zeros_like(hi))
-    with pytest.raises(ValueError, match="pallas"):
-        kernels.prepare(types.SimpleNamespace(A_s=sm), mode="fused",
-                        backend="pallas", precision="df32", l_inv="off")
-    # f64 operands: Mosaic has no f64 type
-    fac, d, q, st = _tiny_qp(seed=4)
-    assert "f64" in pallas_kernel.pallas_scope_reason(fac, st)
-    plan = kernels.prepare(fac, mode="fused", backend="pallas",
-                           precision="native")
-    with pytest.raises(ValueError, match="f64"):
-        kernels.kernel_solve(
-            plan, fac, d, q, st, precision="native", max_iter=10,
-            tail_iter=0, e_pri=1e-4, e_dua=1e-4, stall_rel=0.0,
-            polish=False, polish_chunk=0, ir_sweeps=1)
-    # f32 with a raw Cholesky factor: not an explicit inverse
-    fac, d, q, st = _tiny_qp(seed=4, dtype=jnp.float32)
-    assert "explicit inverse" in pallas_kernel.pallas_scope_reason(fac, st)
+        # the inverse is a rule the user can override either way
+        assert kernels.prepare(fac, **kw, l_inv="off").l_inv is False
+        assert kernels.prepare(fac, **{**kw, "tail_iter": 0},
+                               l_inv="on").l_inv is True
+    elif kind == "dense-mixed":
+        assert plan.A_lo is fac.A_s
+    else:
+        assert plan.A_lo is None
 
 
 # ---------------- config validation (the small fix) ----------------

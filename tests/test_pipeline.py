@@ -145,6 +145,53 @@ def test_admm_iteration_counts_beside_the_seconds_without_session(recipe):
     assert est_hbm_bytes_per_iter(**shape)["tail"] > 0
 
 
+@pytest.mark.parametrize("precision", ["df32", "mixed"])
+@pytest.mark.parametrize("layout", ["host-chunked", "sharded-2",
+                                    "sharded-4"])
+def test_phase_timing_contract(layout, precision):
+    """The dictionary the benchmark's ``solve.*`` readers consume
+    (``benchmarks/metrics/solve.{chunk_s,bulk_iters,tail_iters,
+    fused_mixed_roofline}.py``, printed by ``drivers/ph_hot.py``), in
+    the layouts its UC cells run: one device's chunked loop and the
+    scenario-sharded one, under the two precision-escalated recipes.
+    No kernel option is set, as in every cell: the fused program, f32
+    blocks, the explicit inverse left to its rule (off here: a tail of
+    10 x 2 rows x 2 applies does not repay an n-column inverse)."""
+    from mpisppy_tpu.ops.kernels import est_hbm_bytes_per_iter
+    ndev = {"host-chunked": 1, "sharded-2": 2, "sharded-4": 4}[layout]
+    opts = {**_DF32_OPTS, "subproblem_precision": precision,
+            "subproblem_tail_iter": 10}
+    assert not any(k.startswith("subproblem_kernel") for k in opts)
+    # >= 6 generators: the analyser finds the per-generator structure,
+    # so the df32 operand is packed as the cell's is
+    ph = _run(lambda: _uc_batch(8, G=6, T=8, min_up_down=True,
+                                ramping=True),
+              opts, iters=2, mesh=make_mesh(ndev) if ndev > 1 else None)
+    pt = ph.phase_timing(True)
+    assert pt["kernel"] == {"mode": "fused", "backend": "reference",
+                            "l_inv": False, "block_dtype": "f32"}
+    assert (pt["mode"], pt["devices"]) == (
+        "sharded" if ndev > 1 else "host", ndev)
+    shape = pt["solve_shape"]
+    assert set(shape) == {"n", "m", "s_chunk", "ir_sweeps",
+                          "pk_pass_bytes", "block_dtype"}
+    assert (shape["n"], shape["m"]) == (ph.batch.n, ph.batch.m)
+    assert shape["s_chunk"] == opts["subproblem_chunk"]   # per device
+    assert shape["ir_sweeps"] == 1 and shape["block_dtype"] == "f32"
+    if precision == "df32":
+        assert 0 < shape["pk_pass_bytes"] < 8 * ph.batch.n * ph.batch.m
+    else:
+        assert shape["pk_pass_bytes"] is None
+    priced = est_hbm_bytes_per_iter(**shape)
+    assert priced["tail"] > priced["bulk"] > 0
+    admm = pt["admm_iters_per_call"]
+    assert set(admm) == {"bulk", "tail", "refactors"}
+    assert admm["bulk"] > 0 and admm["tail"] >= 0
+    assert set(pt["seconds_per_call"]) == {"assemble", "solve", "gate",
+                                           "reduce"}
+    assert (pt["collective"]["bytes"] > 0) == (ndev > 1)
+
+
 def test_pipeline_recovery_matches_sequential_on_pathological_chunk():
     """A chunk whose warm-started rho trajectory is forced pathological
     must be recovered by the fused gate exactly like the sequential

@@ -1,6 +1,5 @@
 """Process start-up, device selection and the compile cache
-(utils/runtime, obs/profile peaks, utils/vanilla mesh sizing,
-ops/kernels backend scope) — pure-CPU unit tests of the places where a
+(utils/runtime, obs/profile peaks, utils/vanilla mesh sizing) — pure-CPU unit tests of the places where a
 fallback used to hide the device (ISSUE 24)."""
 
 import os
@@ -201,7 +200,7 @@ def test_cpu_session_resolves_table_row_cpu_tier():
         obs.shutdown()
 
 
-# ---------------- no narrower mesh, no demoted backend ----------------
+# ---------------- no narrower mesh ----------------
 
 def test_mesh_devices_beyond_visible_raises():
     from mpisppy_tpu.utils.vanilla import hub_dict
@@ -214,26 +213,3 @@ def test_mesh_devices_beyond_visible_raises():
     hd = hub_dict(RunConfig(model="farmer", num_scens=n_vis,
                             mesh_devices=n_vis).validate())
     assert hd["opt_kwargs"]["mesh"].devices.size == n_vis
-
-
-def test_explicit_pallas_backend_outside_scope_raises():
-    """An engine asked for the pallas backend on a solve it cannot
-    serve fails with the reason — at option time for df32/mixed
-    operands, at the first solve for a native f64 engine (Mosaic has
-    no f64) — instead of carrying on under ``reference``."""
-    from mpisppy_tpu.core.ph import PHBase
-    from mpisppy_tpu.ir.batch import build_batch
-    from mpisppy_tpu.models import farmer
-
-    def engine(**opts):
-        batch = build_batch(farmer.scenario_creator, farmer.make_tree(3))
-        return PHBase(batch, {"defaultPHrho": 1.0,
-                              "subproblem_kernel_mode": "fused",
-                              "subproblem_kernel_backend": "pallas",
-                              **opts}, dtype=jnp.float64)
-
-    with pytest.raises(ValueError, match="pallas"):
-        engine(subproblem_precision="mixed").solve_loop(
-            w_on=False, prox_on=False)
-    with pytest.raises(ValueError, match="pallas"):
-        engine().solve_loop(w_on=False, prox_on=False)

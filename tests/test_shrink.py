@@ -1,5 +1,5 @@
 """Progressive problem shrinking (ISSUE 14): device-native fixing,
-active-set compaction, per-slot adaptive rho, Pallas scenario tiling.
+active-set compaction, per-slot adaptive rho.
 
 Covers the ISSUE's test satellite: device-fixer vs host-Fixer parity
 on UC (identical fix decisions + final objective), compaction
@@ -576,37 +576,6 @@ def test_host_rho_updater_history_bounded():
     ph.ph_main()
     assert len(upd.prim_hist) == 3 and len(upd.dual_hist) == 3
     assert upd.prim_hist.maxlen == 3
-
-
-# ---------------- pallas scenario-axis grid tiling ----------------
-
-def test_pick_scen_tile():
-    from mpisppy_tpu.ops.kernels.pallas_kernel import pick_scen_tile
-    assert pick_scen_tile(8) == 8            # small S: one tile
-    assert pick_scen_tile(1024) == 128       # target divisor
-    assert pick_scen_tile(384) == 128
-    assert pick_scen_tile(257) == 1          # prime: row tiles
-    assert 384 % pick_scen_tile(384) == 0
-
-
-def test_pallas_scen_tiling_parity():
-    """doc/kernels.md production-tiling item: the grid-tiled block is
-    BIT-IDENTICAL to the untiled single program (scenario rows are
-    independent through the whole iteration block)."""
-    import jax.numpy as jnp
-    from mpisppy_tpu.core.ph import PHBase
-    from mpisppy_tpu.ops.kernels import pallas_kernel as pk
-    b = uc_batch(8, 2, 4)
-    ph = PHBase(b, {"subproblem_max_iter": 50,
-                    "subproblem_eps": 1e-8}, dtype=jnp.float64)
-    factors, d = ph._get_factors(False)
-    st = ph._ensure_state(False)
-    out_full = pk.fused_admm_block(factors, d, ph.c, st, n_steps=30,
-                                   scen_tile=0, interpret=True)
-    out_tiled = pk.fused_admm_block(factors, d, ph.c, st, n_steps=30,
-                                    scen_tile=2, interpret=True)
-    for a, t in zip(out_full, out_tiled):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(t))
 
 
 # ---------------- config / serve bucket identity ----------------

@@ -450,6 +450,142 @@ def test_iteration_record_schema(telemetry):
     assert snap["histograms"]["ph.iteration_seconds"]["count"] == 2
 
 
+# ---------------- a session changes no call path (ISSUE 30) ----------
+
+def _tiny_qp(seed=1):
+    """Small well-posed box-constrained QP over one shared dense A."""
+    from mpisppy_tpu.ops.qp_solver import QPData, qp_cold_state, qp_setup
+    rng = np.random.default_rng(seed)
+    S, m, n = 3, 6, 4
+    mid = rng.normal(size=(S, m))
+    d = QPData(P_diag=jnp.asarray(np.abs(rng.normal(size=n)) + 0.5),
+               A=jnp.asarray(rng.normal(size=(m, n))),
+               l=jnp.asarray(mid - 3.0), u=jnp.asarray(mid + 3.0),
+               lb=jnp.full((S, n), -5.0), ub=jnp.full((S, n), 5.0))
+    q = jnp.asarray(rng.normal(size=(S, n)))
+    fac = qp_setup(d, q_ref=q)
+    return fac, d, q, qp_cold_state(fac, d)
+
+
+def _entry_call(entry):
+    """A zero-argument call of one jitted entry point of the engine:
+    the seven that PR 18 routed through a cost-model wrapper while a
+    session was on."""
+    from mpisppy_tpu.ops import dispatch, kernels, qp_solver, shrink
+    from mpisppy_tpu.ops.kernels.reference import fused_mixed_solve
+    if entry.startswith(("qp.", "kernel.")):
+        fac, d, q, st = _tiny_qp()
+        if entry == "qp.solve":
+            return lambda: qp_solver.qp_solve(
+                fac, d, q, st, max_iter=20, check_every=10,
+                eps_abs=1e-6, eps_rel=1e-6, polish=False)
+        if entry == "qp.solve_lo":
+            return lambda: qp_solver.qp_solve_mixed(
+                fac, d, q, st, max_iter=50, tail_iter=50, segment=50,
+                eps_abs=1e-9, eps_rel=1e-9, polish=True)
+        plan = kernels.prepare(fac, mode="fused", precision="mixed")
+        return lambda: fused_mixed_solve(
+            fac, plan.A_lo, d, q, st, bulk_iter=50, tail_iter=50,
+            check_every=25, eps_abs=1e-9, eps_rel=1e-9, eps_abs_dua=1e-9,
+            eps_rel_dua=1e-9, polish=True, polish_iters=12,
+            polish_chunk=0, stall_rel=0.0, ir_sweeps=1, l_inv=False)
+    S, K = 4, 3
+    xbar = jnp.zeros((S, K))
+    if entry == "shrink.fixer_update":
+        cnt = jnp.zeros((K,), jnp.int32)
+        return lambda: shrink.fixer_update(
+            cnt, cnt, cnt, jnp.zeros((S, K), bool), xbar, xbar, xbar,
+            xbar, xbar - 1.0, xbar + 1.0, 1e-4, 1e-6, 2, 2, 2,
+            jnp.zeros((K,), bool))
+    if entry == "shrink.rho_update":
+        return lambda: shrink.per_slot_rho_update(
+            jnp.full((S, K), 2.0), jnp.full((S,), 0.25),
+            xbar.at[:, 0].add(8.0), xbar, xbar.at[:, 1].add(-10.0),
+            2.0, 3.0)
+    phis = jnp.asarray([-2.0, 0.5, -1.0, 3.0, 0.0, 0.0])
+    if entry == "aph.dispatch_gate":
+        last = jnp.asarray([5, 1, 2, 3, 0, 0])
+        return lambda: dispatch.dispatch_gate(
+            1.5, -0.25, 0.75, 2.0, phis, last, scnt=2, S_real=4)
+    assert entry == "aph.scalar_gate"
+    return lambda: dispatch.scalar_gate(1.5, -0.25, 0.75, 2.0, phis,
+                                        S_real=4)
+
+
+@pytest.mark.parametrize("entry", [
+    "qp.solve", "qp.solve_lo", "kernel.fused_mixed",
+    "shrink.fixer_update", "shrink.rho_update", "aph.dispatch_gate",
+    "aph.scalar_gate"])
+def test_session_adds_no_lowering(tmp_path, entry):
+    """A telemetry session wraps nothing around a jitted entry point:
+    once a call has compiled with the session off, the same call with a
+    session on traces, lowers and compiles nothing. (The cost-model
+    capture re-lowered every shape bucket once per session to read a
+    cost model the chip does not have: +0.8 s of the UC cell's set-up,
+    +37 s of the serve cell's, PERF.md section 6 PR 26.)"""
+    import jax
+    call = _entry_call(entry)
+    assert not obs.enabled()
+    jax.block_until_ready(call())
+    obs.configure(out_dir=str(tmp_path))
+    try:
+        before = obs.counters_snapshot()
+        for _ in range(3):
+            jax.block_until_ready(call())
+        after = obs.counters_snapshot()
+    finally:
+        obs.shutdown()
+    for k in ("jax.traces", "jax.lowerings", "jax.compiles"):
+        assert after.get(k, 0) == before.get(k, 0), k
+
+
+# ---------------- event-stream rotation (ISSUE 18 satellite) ----------
+
+def test_event_stream_rotation_mid_run(tmp_path, monkeypatch):
+    """A tiny byte cap forces mid-run rotation; analyze reads the
+    chain back as ONE logical stream (no phantom earlier_runs), the
+    newest file leads with a continuation header, and the merge
+    anchor survives."""
+    monkeypatch.setenv("MPISPPY_TPU_TELEMETRY_ROTATE_BYTES", "4096")
+    monkeypatch.setenv("MPISPPY_TPU_TELEMETRY_ROTATE_FILES", "4")
+    obs.configure(out_dir=str(tmp_path))
+    try:
+        for i in range(200):
+            obs.event("test.tick", {"i": i, "pad": "x" * 64})
+    finally:
+        obs.shutdown()
+    base = tmp_path / "events.jsonl"
+    assert (tmp_path / "events.jsonl.1").exists()
+    with open(base, encoding="utf-8") as fh:
+        first = json.loads(fh.readline())
+    assert first["type"] == "run_header" and first["rotated"] >= 1
+    from mpisppy_tpu.obs.analyze import load_run, truncated
+    run = load_run(str(tmp_path))
+    assert run.earlier_runs == 0
+    ticks = run.of("test.tick")
+    # the oldest generations may have dropped off the 4-file cap, but
+    # the retained chain must be contiguous and ordered
+    idx = [e["i"] for e in ticks]
+    assert idx == sorted(idx) and idx[-1] == 199
+    assert len(idx) == len(set(idx))
+    assert run.of("telemetry.rotated")
+    assert not truncated(run)          # footer in the newest file
+    from mpisppy_tpu.obs.merge import _anchor_from_events
+    anchor = _anchor_from_events(str(tmp_path), role="")
+    assert anchor is not None and anchor["wall_time_unix"] > 0
+
+
+def test_rotation_disabled_by_default(telemetry):
+    from mpisppy_tpu.obs.analyze import load_run
+    rec, path = telemetry
+    for i in range(50):
+        obs.event("test.tick", {"i": i})
+    obs.shutdown()
+    assert not os.path.exists(os.path.join(str(path),
+                                           "events.jsonl.1"))
+    assert len(load_run(str(path)).of("test.tick")) == 50
+
+
 # ---------------- cylinder wiring ----------------
 
 def test_hub_bound_events_monotonic_with_wall_anchor(telemetry):

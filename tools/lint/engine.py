@@ -263,14 +263,9 @@ SYNC_ALLOW_DEFAULT = {
     "mpisppy_tpu/ops/kernels/__init__.py": {
         "prepare":
             "plan preparation is host+eager once per factorization by "
-            "documented contract (reads sigma etc. exactly once)",
+            "documented contract",
         "KernelPlan.descriptor":
             "plan metadata for bench/telemetry: host bools on the plan",
-    },
-    "mpisppy_tpu/ops/kernels/reference.py": {
-        "_bf16_elem_err":
-            "the bf16 gate MUST run on host: XLA flush-to-zero erases "
-            "exactly the subnormals it exists to catch (doc/kernels.md)",
     },
     "mpisppy_tpu/ops/incumbent.py": {
         "build_pool":

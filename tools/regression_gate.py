@@ -36,14 +36,7 @@ telemetry must show stream activity AND flat steady-state
 ``xfer.device_put_bytes`` — analyze's streaming section is the judge,
 so a staging leak or a source regression trips the gate in-repo.
 
-Since ISSUE 18 a profile smoke rides after the compare stage
-(``--skip-profile-smoke`` opts out): the fresh bench dir must carry a
-non-empty compile ledger that sums to the observed ``jax.compiles``
-and a finite measured MFU (doc/roofline.md), and the disabled-mode
-zero-allocation test re-runs so the capture layer's zero-cost-when-off
-contract is gated, not just tested.
-
-Since ISSUE 19 a forensics smoke rides after the profile smoke
+Since ISSUE 19 a forensics smoke rides after the compare stage
 (``--skip-forensics-smoke`` opts out): the fresh bench dir must carry
 forensic samples and judge HEALTHY through analyze's forensics
 section, and a deliberately rho-starved farmer wheel (rho 1e-9 — the
@@ -487,56 +480,6 @@ def run_stream_smoke(work_dir: str) -> int:
     return 0
 
 
-def run_profile_smoke(fresh: str) -> int:
-    """The ISSUE 18 CI rider: the measured-roofline capture contract,
-    gated on the SAME fresh bench dir the compare stage just judged
-    (no extra wheel). Asserts through analyze's roofline section that
-    (a) the compile ledger is non-empty and sums to the observed
-    ``jax.compiles`` (every backend compile attributed), (b) the
-    measured MFU is finite and positive (the cost models landed and
-    joined the iteration timeline), and (c) the zero-cost-when-off
-    contract still holds — the disabled-mode allocation test re-runs
-    here so a hook that started allocating with telemetry off fails
-    the gate, not just the suite."""
-    from mpisppy_tpu.obs.analyze import load_run, roofline_summary
-    rf = roofline_summary(load_run(fresh))
-    if rf is None:
-        print("regression_gate: PROFILE SMOKE FAILURE — the fresh "
-              "bench produced no profile.* signal (capture hooks "
-              "never fired)")
-        return 3
-    if not rf["ledger"] or not rf["ledger_compiles"]:
-        print("regression_gate: PROFILE SMOKE FAILURE — the compile "
-              "ledger is empty (resource._on_duration -> "
-              "profile.note_compile wiring broken)")
-        return 3
-    if not rf["ledger_matches"]:
-        print("regression_gate: PROFILE SMOKE REGRESSION — compile "
-              f"ledger sums to {rf['ledger_compiles']} but the run "
-              f"observed jax.compiles={rf['jax_compiles']} (a compile "
-              "escaped attribution)")
-        return 3
-    mfu = rf["overall"]["mfu"]
-    if mfu is None or not (0.0 < mfu < float("inf")):
-        print("regression_gate: PROFILE SMOKE FAILURE — measured MFU "
-              f"is {mfu!r}, expected finite > 0 (cost capture or "
-              "iteration join broken)")
-        return 3
-    r = subprocess.run(
-        [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
-         "tests/test_telemetry.py::test_disabled_mode_allocates_nothing"],
-        cwd=REPO, timeout=300)
-    if r.returncode != 0:
-        print("regression_gate: PROFILE SMOKE REGRESSION — the "
-              "disabled-mode zero-allocation test failed (a profile "
-              "hook costs something with telemetry off)")
-        return 3
-    print(f"regression_gate: profile smoke ok (mfu {mfu:.3g}, ledger "
-          f"{rf['ledger_compiles']} compiles == jax.compiles, "
-          "disabled-mode overhead clean)")
-    return 0
-
-
 def run_forensics_smoke(fresh: str) -> int:
     """The ISSUE 19 CI rider: the diagnosis engine's verdict contract,
     gated from BOTH sides. The fresh golden-recipe bench (the dir the
@@ -636,11 +579,6 @@ def main(argv=None) -> int:
                    help="skip the streamed-farmer flat-transfer smoke "
                         "stage (doc/streaming.md); the bench + compare "
                         "gate still runs")
-    p.add_argument("--skip-profile-smoke", action="store_true",
-                   help="skip the measured-roofline smoke stage "
-                        "(doc/roofline.md: compile ledger + finite "
-                        "MFU + disabled-mode overhead); the bench + "
-                        "compare gate still runs")
     p.add_argument("--skip-forensics-smoke", action="store_true",
                    help="skip the diagnosis-engine smoke stage "
                         "(doc/forensics.md: golden recipe HEALTHY, "
@@ -714,12 +652,6 @@ def main(argv=None) -> int:
                   "--update-golden and commit the new golden dir.")
         if rc != 0:
             return rc
-        if not args.skip_profile_smoke:
-            # profile smoke (ISSUE 18): the measured-roofline capture
-            # contract judged on the fresh dir the compare just used
-            rc = run_profile_smoke(fresh)
-            if rc != 0:
-                return rc
         if not args.skip_forensics_smoke:
             # forensics smoke (ISSUE 19): the diagnosis-engine verdict
             # contract — the fresh dir must judge HEALTHY, a
