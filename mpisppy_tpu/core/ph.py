@@ -62,7 +62,8 @@ def _new_phase_entry():
                     "reduce": 0.0},
             "admm": {"bulk": 0, "tail": 0, "refactors": 0},
             "collective": {"combines": 0, "bytes": 0},
-            "calls": 0, "gate_syncs": 0, "devices": 1, "mode": "host"}
+            "calls": 0, "gate_syncs": 0, "assemble_programs": 0,
+            "devices": 1, "mode": "host"}
 
 
 class _PhaseClock:
@@ -106,10 +107,12 @@ def _mode_str(key):
     return "prox" if key else "noprox"
 
 
-@partial(jax.jit, static_argnames=("w_on", "prox_on"))
-def _ph_assemble(data, c, W, xbar, rho, idx, fixed_mask, fixed_vals,
-                 wscale, *, w_on, prox_on):
-    """Stage 1: objective-rewrite + nonant pinning (cheap elementwise).
+def _assemble_rows(lb, ub, c, W, xbar, rho, idx, fixed_mask, fixed_vals,
+                   wscale, w_on, prox_on):
+    """Stage 1's expression: objective-rewrite + nonant pinning (cheap
+    elementwise), traced by ``_ph_assemble`` (one chunk, or the whole
+    batch) and by the staging programs (every chunk at once), so there
+    is ONE formula.
 
     ``wscale`` ((S, K), or None for the uniform case) is the ratio
     variable-probability / scenario-probability. The W term enters each
@@ -127,16 +130,62 @@ def _ph_assemble(data, c, W, xbar, rho, idx, fixed_mask, fixed_vals,
         Weff if w_on else (-rho * xbar if prox_on else jnp.zeros_like(W)))
     q = c.at[:, idx].add(wvec)
     # fixed nonants: pin boxes (ref. phbase.py:413 _fix_nonants)
-    bl = data.lb.at[:, idx].set(
-        jnp.where(fixed_mask, fixed_vals, data.lb[:, idx]))
-    bu = data.ub.at[:, idx].set(
-        jnp.where(fixed_mask, fixed_vals, data.ub[:, idx]))
-    # return VECTORS only — the caller re-attaches them to its QPData
-    # eagerly. Returning data._replace(...) from this jit would pass
-    # the (possibly multi-GB) constraint matrix through the jit
-    # boundary, which XLA COPIES per call (measured +2.7 GB per chunk
-    # at reference-UC scale).
+    bl = lb.at[:, idx].set(jnp.where(fixed_mask, fixed_vals, lb[:, idx]))
+    bu = ub.at[:, idx].set(jnp.where(fixed_mask, fixed_vals, ub[:, idx]))
     return q, bl, bu
+
+
+@partial(jax.jit, static_argnames=("w_on", "prox_on"))
+def _ph_assemble(data, c, W, xbar, rho, idx, fixed_mask, fixed_vals,
+                 wscale, *, w_on, prox_on):
+    """Stage 1 as its own program (``_assemble_rows`` has the formula).
+    Returns VECTORS only — the caller re-attaches them to its QPData
+    eagerly. Returning data._replace(...) from this jit would pass
+    the (possibly multi-GB) constraint matrix through the jit
+    boundary, which XLA COPIES per call (measured +2.7 GB per chunk
+    at reference-UC scale)."""
+    return _assemble_rows(data.lb, data.ub, c, W, xbar, rho, idx,
+                          fixed_mask, fixed_vals, wscale, w_on, prox_on)
+
+
+# per-scenario operands the assembly consumes and no later pass reads
+_STAGE_CONSUMED = ("xbar", "rho", "fm", "fv", "ws")
+
+
+def _stage_chunk(rows, idx, *, w_on, prox_on):
+    """One chunk's staged operands from its rows of every per-scenario
+    vector (``PHBase._per_scen_operands``' names): ``l`` / ``u`` as
+    they are, ``lb`` / ``ub`` / ``q`` assembled by ``_assemble_rows``,
+    and what pass 3 evaluates the objectives against, so that nothing
+    is gathered a second time: ``c``, ``c0``, ``P0``, ``W`` (under a
+    shrink plan the full-width ``cF`` / ``WF`` and the fold operands
+    in place of the compacted ``c`` / ``W`` the assembly consumed)."""
+    q, bl, bu = _assemble_rows(
+        rows["lb"], rows["ub"], rows["c"], rows["W"], rows["xbar"],
+        rows["rho"], idx, rows["fm"], rows["fv"], rows.get("ws"),
+        w_on, prox_on)
+    drop = _STAGE_CONSUMED + (("c", "W") if "cF" in rows else ())
+    out = {k: v for k, v in rows.items() if k not in drop}
+    out.update(lb=bl, ub=bu, q=q)
+    return out
+
+
+@partial(jax.jit, static_argnames=("w_on", "prox_on"))
+def _ph_stage_chunks(per, idx, ids, *, w_on, prox_on):
+    """ASSEMBLE for every chunk of a resident host-chunked pass in ONE
+    device program (per chunk: ten eager gathers and ``_ph_assemble``,
+    ~22 launches of ~1 ms each with an idle device behind them, before
+    this). ``per``: the full-width per-scenario VECTORS by name — never
+    ``data`` or an (n, n) / packed leaf. ``ids``: the chunks' scenario
+    ids stacked (n_chunks, chunk), an OPERAND, so dispatch passes whose
+    ids change every iteration reuse the program per chunk COUNT.
+    Returns a TUPLE of per-chunk dicts (``_stage_chunk``): Python
+    unpacks it with no further launch, where a stacked result indexed
+    ``[ci]`` on the host would put the eager index ops straight back."""
+    return tuple(
+        _stage_chunk({k: v[ids[ci]] for k, v in per.items()}, idx,
+                     w_on=w_on, prox_on=prox_on)
+        for ci in range(ids.shape[0]))
 
 
 @partial(jax.jit, static_argnames=("w_on", "slot_slices"))
@@ -1424,16 +1473,29 @@ class PHBase(SPBase):
             self._chunk_idx_cache[(chunk, S)] = out
         return self._chunk_idx_cache[(chunk, S)]
 
+    def _chunk_ids(self, chunk):
+        """``_chunk_index``'s id arrays stacked (n_chunks, chunk) on the
+        device: the staging program's one ids operand (same cache, same
+        invalidation)."""
+        slices = self._chunk_index(chunk)
+        key = ("stacked", chunk, self.batch.S)
+        if key not in self._chunk_idx_cache:
+            self._chunk_idx_cache[key] = jnp.stack(
+                [idx for idx, _ in slices])
+        return self._chunk_idx_cache[key]
+
     def _ensure_chunk_states(self, key, factors, data, slices,
-                             chunks=None, lc=None, cold_data=None):
+                             lc=None, cold_data=None):
         """Per-chunk QPStates (each owns its L / rho_scale trajectory —
         cross-chunk sharing would let one chunk's rho adaptation corrupt
         another's warm start). Authoritative store for chunked mode;
         self._qp_states[key] holds a concatenated read-only view.
 
-        ``chunks``/``lc`` (sharded mode): the pre-chunked operand store
-        from _chunked_inputs — cold states and warm-start transplants
-        slice it locally instead of gathering strided global indices.
+        ``lc`` (sharded mode): the local chunk rows — warm-start
+        transplants restage through ``to_chunks`` and slice locally
+        instead of gathering strided global indices. ``cold_data``:
+        one chunk-shaped data block for the cold state's shape (the
+        mesh's chunk 0, or a streamed source's first block).
 
         New modes transplant iterates from any existing mode's
         concatenated view, exactly like _ensure_state: a cold prox-off
@@ -1452,13 +1514,11 @@ class PHBase(SPBase):
             # sharing safe — at df32 scale each per-chunk factor copy
             # would cost ~0.7 GB x chunk count
             if cold_data is not None:
-                # streamed/synthesized source: the caller staged one
-                # chunk-shaped block (data itself is a 2-row setup
-                # surrogate with nothing to slice)
+                # the caller staged one chunk-shaped block (a mesh's
+                # local slices; a streamed/synthesized source, whose
+                # data itself is a 2-row setup surrogate with nothing
+                # to slice)
                 d0 = cold_data
-            elif chunks is not None:
-                d0 = data._replace(l=chunks["l"][0], u=chunks["u"][0],
-                                   lb=chunks["lb"][0], ub=chunks["ub"][0])
             else:
                 idx0 = slices[0][0]
                 d0 = data._replace(l=data.l[idx0], u=data.u[idx0],
@@ -1484,11 +1544,11 @@ class PHBase(SPBase):
                         tp["x"].shape[-1] != st0.x.shape[-1]
                         or tp["zA"].shape[-1] != st0.zA.shape[-1]):
                     tp = None
-            if transplant and chunks is not None:
+            if transplant and lc is not None:
                 oth_ch = self._shard_ops.to_chunks(
                     {"x": other.x, "yA": other.yA, "yB": other.yB,
                      "zA": other.zA, "zB": other.zB}, lc)
-            elif tp is not None and chunks is not None:
+            elif tp is not None and lc is not None:
                 oth_ch = self._shard_ops.to_chunks(tp, lc)
             for ci, (idx, _) in enumerate(slices):
                 st = st0
@@ -1591,12 +1651,16 @@ class PHBase(SPBase):
                 for ci in range(n_chunks)]
         return self._chunk_idx_cache[key]
 
-    def _chunked_inputs(self, data, lc, shrink=None, c0fold=None,
-                        stream=False):
-        """Every per-scenario operand of one chunked sharded pass,
-        restaged as (n_chunks, lc*n_dev, ...) sharded arrays in ONE
-        jitted local reshape — no per-chunk device_put, no host
-        threads; ``chs[name][ci]`` is chunk ci's sharded slice.
+    def _per_scen_operands(self, data, shrink=None, c0fold=None,
+                           stream=False):
+        """Every per-scenario operand of one chunked pass by name, full
+        width over the scenario axis: VECTORS only (never ``data``, the
+        factor or a packed matrix), so the dict may cross a jit
+        boundary. A resident pipelined pass hands it to ONE staging
+        program (``_ph_stage_chunks``; on a mesh
+        ``ShardedScenarioOps.map_chunks``); the per-chunk paths take
+        chunk ci's rows name by name (host: ``per[name][ids]``;
+        sharded: ``to_chunks(per, lc)[name][ci]``).
 
         With an active shrink plan the assemble-side operands are the
         COMPACTED system (data is already compacted by
@@ -1631,7 +1695,7 @@ class PHBase(SPBase):
                      "fvcols": shrink.fixed_colvals})
                 if self._w_scale is not None:
                     per_scen["ws"] = self._w_scale[:, fs]
-            return self._shard_ops.to_chunks(per_scen, lc)
+            return per_scen
         per_scen = {"l": data.l, "u": data.u, "lb": data.lb,
                     "ub": data.ub, "c0": self.c0, "P0": self.P_diag}
         if shrink is None:
@@ -1653,7 +1717,7 @@ class PHBase(SPBase):
                  "fvcols": shrink.fixed_colvals})
             if self._w_scale is not None:
                 per_scen["ws"] = self._w_scale[:, fs]
-        return self._shard_ops.to_chunks(per_scen, lc)
+        return per_scen
 
     def _solve_loop_chunked(self, chunk, w_on, prox_on, update, fixed,
                             dispatch=None):
@@ -1718,11 +1782,20 @@ class PHBase(SPBase):
         stream = self._stream_source
         ops = self._shard_ops
         sharded = ops is not None
+        pipeline = bool(int(self.options.get("subproblem_pipeline", 1)))
+        # a resident pipelined pass stages EVERY chunk's operands with
+        # ONE device program (_ph_stage_chunks / ops.map_chunks). Two
+        # inputs keep the per-chunk spelling: a streamed source, whose
+        # double buffer bounds how many staged chunks exist, and the
+        # sequential opt-out, the tests' reference
+        stage_all = pipeline and stream is None
+        stage_kw = dict(w_on=bool(w_on), prox_on=bool(prox_on))
         # one shared args dict per call (never mutated): lets trace
         # consumers split phase spans by solve mode, allocated only
         # when telemetry is on
         sp_args = {"mode": _mode_str(key)} if obs.enabled() else None
         restage_s = 0.0
+        staged = chs = lc = None
         if sharded:
             lc = self._local_chunk(chunk)
             slices = self._sharded_chunk_slices(lc)
@@ -1730,12 +1803,17 @@ class PHBase(SPBase):
             # its own (the pass's clock starts below, after the state
             # and plan look-ups), booked with the pass's assemble seconds
             with obs.span("ph.assemble", cat="ph", args=sp_args) as sp:
-                chs = self._chunked_inputs(data, lc, shrink=shrink,
-                                           c0fold=c0fold,
-                                           stream=stream is not None)
+                per = self._per_scen_operands(data, shrink, c0fold,
+                                              stream is not None)
+                if stage_all:
+                    staged = ops.map_chunks(
+                        ("ph.stage", *stage_kw.values()),
+                        partial(_stage_chunk, **stage_kw), per, lc,
+                        idx_asm)
+                else:
+                    chs = ops.to_chunks(per, lc)
             restage_s = sp.seconds
         else:
-            lc, chs = None, None
             if dispatch is None:
                 slices = self._chunk_index(chunk)
             else:
@@ -1770,19 +1848,23 @@ class PHBase(SPBase):
                 obs.counter_add("dispatch.solved_scenarios", scnt)
                 obs.counter_add("dispatch.skipped_scenarios",
                                 max(self._S_orig - scnt, 0))
-            if shrink is not None:
-                fs = shrink.free_slots_dev
-                a_c, a_W = shrink.c_c, self.W[:, fs]
-                a_xbar, a_rho = self.xbar[:, fs], self.rho[:, fs]
-                a_fm = self._fixed_mask[:, fs]
-                a_fv = self._fixed_vals[:, fs]
-                a_ws = None if self._w_scale is None \
-                    else self._w_scale[:, fs]
-            else:
-                a_c, a_W, a_xbar, a_rho = (self.c, self.W, self.xbar,
-                                           self.rho)
-                a_fm, a_fv = self._fixed_mask, self._fixed_vals
-                a_ws = self._w_scale
+            per = self._per_scen_operands(data, shrink, c0fold,
+                                          stream is not None)
+
+        def rows(name, ci):
+            """Chunk ci's rows of per-scenario operand ``name``: a
+            tuple read on a staged pass, one eager index launch on the
+            per-chunk paths."""
+            if staged is not None:
+                return staged[ci][name]
+            return chs[name][ci] if sharded else per[name][slices[ci][0]]
+
+        def chunk_data(ci):
+            # chunk ci's data block (before the assembly pins lb / ub
+            # on the per-chunk paths, after it on a staged pass)
+            return data._replace(l=rows("l", ci), u=rows("u", ci),
+                                 lb=rows("lb", ci), ub=rows("ub", ci))
+
         cold_d = None
         if stream is not None:
             # bind the source to THIS layout: chunk ci's global
@@ -1843,9 +1925,12 @@ class PHBase(SPBase):
                 cold_d = data._replace(l=b0["l"], u=b0["u"],
                                        lb=b0["lb"], ub=b0["ub"])
             fresh_states = ("chunks", key) not in self._qp_states
+            if fresh_states and sharded and cold_d is None:
+                # the mesh's cold states take their shape from chunk
+                # 0's rows of the restaged store
+                cold_d = chunk_data(0)
             states = self._ensure_chunk_states(key, factors, data, slices,
-                                               chunks=chs, lc=lc,
-                                               cold_data=cold_d)
+                                               lc=lc, cold_data=cold_d)
             if fresh_states:
                 # rebuilt chunk states share cold-state buffers —
                 # donation must wait for the first completed pass to
@@ -1887,7 +1972,6 @@ class PHBase(SPBase):
                   polish_chunk=polish_chunk,
                   segment_lo=self.sub_segment_lo,
                   ir_sweeps=self.sub_ir_sweeps, kernel=plan)
-        pipeline = bool(int(self.options.get("subproblem_pipeline", 1)))
         # dispatch passes never donate: every gathered chunk state
         # aliases the dispatch store's single flowed factor, so the
         # first donated solve would delete the buffer chunk 2 needs
@@ -1904,6 +1988,10 @@ class PHBase(SPBase):
         ent["kernel"] = plan.descriptor()
         ent["shape"] = self._solve_shape(factors, plan, rows_per_call)
         gate_syncs = 0
+        # device programs the assemble phase launches for pass 1: ONE
+        # on a staged pass (the mesh launched it above), one
+        # _ph_assemble per chunk on the per-chunk paths
+        asm_programs = int(staged is not None)
         ent["acc"]["assemble"] += restage_s
         clock = _PhaseClock(ent["acc"], sp_args)
 
@@ -1912,28 +2000,20 @@ class PHBase(SPBase):
         # sharded chunks are mesh-placed end to end (solve outputs ARE
         # reduction inputs — no home/loc distinction survives the
         # spread path's retirement).
+        def _hub_rows(ci):
+            # _ph_assemble's operands after c, from the resident
+            # (S, K) hub state
+            return (rows("W", ci), rows("xbar", ci), rows("rho", ci),
+                    idx_asm, rows("fm", ci), rows("fv", ci),
+                    rows("ws", ci) if "ws" in per else None)
+
         def _assemble(ci):
-            if sharded:
-                # local slices of the pre-chunked store — elementwise
-                # jit on sharded operands, zero host gathers
-                d_c = data._replace(l=chs["l"][ci], u=chs["u"][ci],
-                                    lb=chs["lb"][ci], ub=chs["ub"][ci])
-                ws = chs["ws"][ci] if "ws" in chs else None
-                q_c, bl_c, bu_c = _ph_assemble(
-                    d_c, chs["c"][ci], chs["W"][ci], chs["xbar"][ci],
-                    chs["rho"][ci], idx_asm, chs["fm"][ci],
-                    chs["fv"][ci], ws, w_on=bool(w_on),
-                    prox_on=bool(prox_on))
-                return d_c._replace(lb=bl_c, ub=bu_c), q_c
-            idx_c, _ = slices[ci]
-            d_c = data._replace(l=data.l[idx_c], u=data.u[idx_c],
-                                lb=data.lb[idx_c], ub=data.ub[idx_c])
-            ws = None if a_ws is None else a_ws[idx_c]
+            """The sequential opt-out's per-chunk assembly (sharded:
+            local slices of the pre-chunked store; host: gathers by the
+            chunk's ids), through _ph_assemble."""
+            d_c = chunk_data(ci)
             q_c, bl_c, bu_c = _ph_assemble(
-                d_c, a_c[idx_c], a_W[idx_c], a_xbar[idx_c],
-                a_rho[idx_c], idx_asm,
-                a_fm[idx_c], a_fv[idx_c], ws,
-                w_on=bool(w_on), prox_on=bool(prox_on))
+                d_c, rows("c", ci), *_hub_rows(ci), **stage_kw)
             return d_c._replace(lb=bl_c, ub=bu_c), q_c
 
         def _stream_assemble(ci, direct=False):
@@ -1947,17 +2027,6 @@ class PHBase(SPBase):
             blk = stream.fetch(ci) if direct else stream.chunk(ci)
             d_c = data._replace(l=blk["l"], u=blk["u"],
                                 lb=blk["lb"], ub=blk["ub"])
-            if sharded:
-                W_c, xb_c, rho_c = (chs["W"][ci], chs["xbar"][ci],
-                                    chs["rho"][ci])
-                fm_c, fv_c = chs["fm"][ci], chs["fv"][ci]
-                ws = chs["ws"][ci] if "ws" in chs else None
-            else:
-                idx_c, _ = slices[ci]
-                W_c, xb_c, rho_c = (a_W[idx_c], a_xbar[idx_c],
-                                    a_rho[idx_c])
-                fm_c, fv_c = a_fm[idx_c], a_fv[idx_c]
-                ws = None if a_ws is None else a_ws[idx_c]
             # under an active shrink plan the source stages compacted
             # l/u/lb/ub but keeps c FULL width (install_compacted):
             # assembly gathers the kept columns — a pure gather, so
@@ -1968,24 +2037,27 @@ class PHBase(SPBase):
             c_asm = c_blk[:, shrink.keep_cols] if shrink is not None \
                 else c_blk
             q_c, bl_c, bu_c = _ph_assemble(
-                d_c, c_asm, W_c, xb_c, rho_c, idx_asm, fm_c, fv_c,
-                ws, w_on=bool(w_on), prox_on=bool(prox_on))
+                d_c, c_asm, *_hub_rows(ci), **stage_kw)
             return d_c._replace(lb=bl_c, ub=bu_c), q_c, c_blk
 
-        # ASSEMBLE — pipelined: enqueue every chunk's assembly now
-        # (async dispatch); the device interleaves this elementwise work
-        # with/ahead of the first solves and the host never again stops
-        # to assemble between chunks. Streamed sources rewind their
+        # ASSEMBLE — pipelined: every chunk's operands are staged now
+        # by ONE device program (the mesh ran its own above, inside the
+        # restage span), so the first chunk solve is enqueued one launch
+        # after the pass begins and the host never again stops to
+        # assemble between chunks. Streamed sources rewind their
         # prefetch pipeline first (the SOLVE pass) and their assembly
         # stays in the solve loop below — the double buffer bounds how
-        # many staged chunks exist, so enqueueing all of them up front
+        # many staged chunks exist, so staging all of them up front
         # would defeat the residency bound streaming exists for.
         if stream is not None:
             stream.begin_pass()
-            inputs = None
-        else:
-            inputs = [_assemble(ci) for ci in range(len(slices))] \
-                if pipeline else None
+        elif stage_all and not sharded:
+            # the ids are an operand: one H2D on a dispatch pass, whose
+            # set changes every iteration; cached on the device else
+            ids_stack = self._chunk_ids(chunk) if dispatch is None \
+                else jnp.asarray(ids_pad.reshape(n_dchunks, chunk))
+            staged = _ph_stage_chunks(per, idx_asm, ids_stack, **stage_kw)
+            asm_programs += 1
         clock.lap("solve")
 
         # pass 1 — SOLVE. (Segmented solves sync on their own iteration
@@ -2002,9 +2074,10 @@ class PHBase(SPBase):
                 # the phase anatomy stays honest
                 clock.lap("assemble")
                 d_c, q_c, _ = _stream_assemble(ci)
+                asm_programs += 1
                 clock.lap("solve")
-            elif pipeline:
-                d_c, q_c = inputs[ci]
+            elif staged is not None:
+                d_c, q_c = chunk_data(ci), rows("q", ci)
             else:
                 # sequential opt-out: assembly stays interleaved on
                 # the critical path, but its wall-clock books under
@@ -2013,6 +2086,7 @@ class PHBase(SPBase):
                 # exists for compares honestly
                 clock.lap("assemble")
                 d_c, q_c = _assemble(ci)
+                asm_programs += 1
                 clock.lap("solve")
             st_in = states[ci]
             if split_mode and prev_st is not None:
@@ -2072,6 +2146,8 @@ class PHBase(SPBase):
         # keep their own counter (ph.chunk_retries).
         _book_admm_iters(ent["admm"], [rec[0] for rec in solved_chunks],
                          plan.mode == "fused")
+        ent["assemble_programs"] += asm_programs
+        obs.counter_add("ph.assemble_programs", asm_programs)
         clock.lap("gate")
         # pass 2 — bounded recovery: a chunk whose warm-started rho
         # trajectory went pathological (per-chunk shared rho adapts on
@@ -2291,23 +2367,10 @@ class PHBase(SPBase):
                     d_h, q_h, cF_c = _stream_assemble(ci)
                     P0_c = jnp.broadcast_to(self.qp_data.P_diag,
                                             cF_c.shape)
-                    if sharded:
-                        fvc, WF_c = chs["fvcols"][ci], chs["WF"][ci]
-                        c0_c, c0f_c = chs["c0"][ci], chs["c0fold"][ci]
-                    else:
-                        fvc = shrink.fixed_colvals[idx_c]
-                        WF_c = self.W[idx_c]
-                        c0_c, c0f_c = self.c0[idx_c], c0fold[idx_c]
-                elif sharded:
-                    fvc, cF_c, WF_c = (chs["fvcols"][ci], chs["cF"][ci],
-                                       chs["WF"][ci])
-                    c0_c, P0_c = chs["c0"][ci], chs["P0"][ci]
-                    c0f_c = chs["c0fold"][ci]
                 else:
-                    fvc = shrink.fixed_colvals[idx_c]
-                    cF_c, WF_c = self.c[idx_c], self.W[idx_c]
-                    c0_c, P0_c = self.c0[idx_c], self.P_diag[idx_c]
-                    c0f_c = c0fold[idx_c]
+                    cF_c, P0_c = rows("cF", ci), rows("P0", ci)
+                fvc, WF_c = rows("fvcols", ci), rows("WF", ci)
+                c0_c, c0f_c = rows("c0", ci), rows("c0fold", ci)
                 x = expand_solution(x, fvc, shrink.keep_cols,
                                     shrink.fixed_cols, cF_c[0])
                 xn, base, solved = _shrink_objs(
@@ -2318,21 +2381,15 @@ class PHBase(SPBase):
             else:
                 if stream is not None:
                     d_h, q_h, c_c = _stream_assemble(ci)
-                    c0_c = chs["c0"][ci] if sharded else self.c0[idx_c]
-                    W_c = chs["W"][ci] if sharded else self.W[idx_c]
                     # the RAW shared P row broadcasts per chunk (the
                     # objective must not carry the prox rho that
                     # _data_with_prox added to ``data``'s diagonal)
                     P0_c = jnp.broadcast_to(self.qp_data.P_diag,
                                             c_c.shape)
-                elif sharded:
-                    c_c, c0_c, P0_c, W_c = (chs["c"][ci], chs["c0"][ci],
-                                            chs["P0"][ci], chs["W"][ci])
                 else:
-                    c_c, c0_c, P0_c, W_c = (self.c[idx_c],
-                                            self.c0[idx_c],
-                                            self.P_diag[idx_c],
-                                            self.W[idx_c])
+                    # a staged pass had these in hand since pass 1
+                    c_c, P0_c = rows("c", ci), rows("P0", ci)
+                c0_c, W_c = rows("c0", ci), rows("W", ci)
                 xn, base, solved, dual = _ph_chunk_objs(
                     x, yA, yB, d_h, q_h, c_c, c0_c, P0_c,
                     self.nonant_idx, W_c, w_on=bool(w_on))
@@ -2469,6 +2526,12 @@ class PHBase(SPBase):
             "seconds_per_call": per_call,
             "occupancy": (per_call["solve"] / total) if total > 0 else 0.0,
             "gate_d2h_syncs_per_call": ent["gate_syncs"] / n,
+            # device programs the assemble phase launched per call: 1
+            # where ONE program stages every chunk (the resident
+            # pipelined pass) or the batch is not chunked, the number
+            # of chunks where each is assembled by its own
+            # _ph_assemble (a streamed source, subproblem_pipeline=0)
+            "assemble_programs_per_call": ent["assemble_programs"] / n,
             "devices": ent["devices"],
             # "sharded": scenario-axis SPMD over the mesh;
             # "host": single-device dispatch (doc/sharding.md)
@@ -2969,6 +3032,8 @@ class PHBase(SPBase):
         self._qp_states.pop(("dispatch", skey), None)
         ent = self._phase_times.setdefault(skey, _new_phase_entry())
         ent["calls"] += 1
+        ent["assemble_programs"] += 1   # the one un-chunked assembly
+        obs.counter_add("ph.assemble_programs")
         ent["devices"] = sh.n_devices if sh is not None else 1
         ent["mode"] = "sharded" if sh is not None else "host"
         # per-device rows (see _solve_loop_chunked: the profitability

@@ -293,6 +293,53 @@ class ShardedScenarioOps:
         with obs.span("mesh.to_chunks", cat="ph"):
             return jax.tree.unflatten(treedef, fn(*leaves))
 
+    def _map_chunks_fn(self, key, fn, treedef, ndims, lc, n_rep):
+        """``map_chunks``' program for one (fn, tree structure, lc)."""
+        key = ("map_chunks", key, lc, treedef, ndims, n_rep)
+        prog = self._fns.get(key)
+        if prog is None:
+            n_chunks, _ = self.chunk_layout(lc)
+            n_leaves = len(ndims)
+
+            def body(*args):
+                chunked = [a.reshape((n_chunks, lc) + a.shape[1:])
+                           for a in args[:n_leaves]]
+                return tuple(
+                    fn(jax.tree.unflatten(treedef,
+                                          [a[ci] for a in chunked]),
+                       *args[n_leaves:])
+                    for ci in range(n_chunks))
+
+            in_specs = tuple(self._spec(nd) for nd in ndims) \
+                + (P(),) * n_rep
+            # out_shardings: the spec the eager ``chunks[name][ci]``
+            # index ops hand out, letter for letter (shard_map alone
+            # pads it with Nones: an equivalent placement, but a new
+            # entry in every consumer's jit cache)
+            prog = jax.jit(
+                jax.shard_map(body, mesh=self.mesh, in_specs=in_specs,
+                              out_specs=P(SCEN_AXIS), check_vma=False),
+                out_shardings=NamedSharding(self.mesh, P(SCEN_AXIS)))
+            self._fns[key] = prog
+        return prog
+
+    def map_chunks(self, key, fn, tree, lc, *rep):
+        """``to_chunks`` and what reads each chunk, as ONE program: the
+        local reshape of every (S, ...) leaf, chunk ci's slice of each
+        and ``fn(chunk ci's tree, *rep)`` for every ci (``rep``:
+        replicated operands), where ``to_chunks(tree)[name][ci]`` costs
+        an eager index launch per leaf per chunk before ``fn`` can run.
+        Returns a TUPLE of the per-chunk results, every leaf row-sharded
+        with the placement those index ops hand out (so a program that
+        took one takes the other without a second lowering). ``key``
+        names ``fn`` in the program cache."""
+        leaves, treedef = jax.tree.flatten(tree)
+        prog = self._map_chunks_fn(key, fn, treedef,
+                                   tuple(v.ndim for v in leaves), lc,
+                                   len(rep))
+        with obs.span("mesh.to_chunks", cat="ph"):
+            return prog(*leaves, *rep)
+
     def from_chunks(self, parts):
         """Concatenate per-chunk (lc·n_dev, ...) sharded arrays back to
         the natural-order (S, ...) batch — each device concatenates its

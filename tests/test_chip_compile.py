@@ -188,3 +188,78 @@ def test_sharded_df32_chunk_solve_compiles_over_four_chips(
                   "all-to-all", "all-reduce-start", "all-gather-start",
                   "collective-permute-start"):
         assert not _hlo_lines(hlo, other), other
+
+
+# ---------------- the chunk staging program (ISSUE 31) -----------------
+
+# the UC cells' widths (benchmarks/configs/uc90x48_df32.json): what ONE
+# staging call moves at S = 256 a chip in four chunks of 64
+_UC = dict(S=256, n=13056, m=26016, K=8640, chunk=64)
+
+
+def _stage_operands(S, place):
+    """``PHBase._per_scen_operands``' vectors at the cell's widths, as
+    shapes (float64 outer arithmetic, no shrink plan, no w_scale)."""
+    n, m, K = _UC["n"], _UC["m"], _UC["K"]
+    f8 = lambda *sh: jax.ShapeDtypeStruct(sh, jnp.float64,
+                                          sharding=place(len(sh)))
+    return {"l": f8(S, m), "u": f8(S, m), "lb": f8(S, n), "ub": f8(S, n),
+            "c0": f8(S), "P0": f8(S, n), "c": f8(S, n), "W": f8(S, K),
+            "xbar": f8(S, K), "rho": f8(S, K), "fv": f8(S, K),
+            "fm": jax.ShapeDtypeStruct((S, K), jnp.bool_,
+                                       sharding=place(2))}
+
+
+def test_chunk_staging_program_compiles_for_v5e(one_chip,
+                                                no_persistent_cache):
+    """Cell 1's ASSEMBLE as the one program it is since ISSUE 31: 256
+    rows in four chunks of 64, ids as an operand. Its arguments and
+    results are vectors (~0.2 GB each way), nowhere near a factor."""
+    from mpisppy_tpu.core.ph import _ph_stage_chunks
+    per = _stage_operands(_UC["S"], lambda nd: one_chip)
+    idx = jax.ShapeDtypeStruct((_UC["K"],), jnp.int32, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((4, _UC["chunk"]), jnp.int32,
+                               sharding=one_chip)
+    mem = _ph_stage_chunks.lower(per, idx, ids, w_on=True, prox_on=True) \
+        .compile().memory_analysis()
+    assert mem.argument_size_in_bytes < 0.3e9
+    assert mem.output_size_in_bytes + mem.temp_size_in_bytes < 0.6e9
+
+
+def test_sharded_chunk_staging_is_local_over_four_chips(
+        topo, no_persistent_cache):
+    """The mesh cell's ASSEMBLE (S = 1024 over four chips, four local
+    chunks of 64 rows a chip) as ONE shard_map program: a local reshape,
+    local slices and the element-wise assembly, so the compiler puts in
+    no collective at all and every result comes out row-sharded."""
+    from functools import partial
+
+    from jax.sharding import Mesh
+
+    from mpisppy_tpu.core.ph import _stage_chunk
+    from mpisppy_tpu.parallel.mesh import SCEN_AXIS, ShardedScenarioOps
+
+    mesh = Mesh(np.asarray(topo.devices[:4]), (SCEN_AXIS,))
+    # the staging builder reads the mesh and the shard size alone (the
+    # constructor would device_put the tree's node ids: no device here)
+    ops = object.__new__(ShardedScenarioOps)
+    ops.mesh, ops.n_devices, ops._fns = mesh, 4, {}
+    ops.S, ops.shard_size = 4 * _UC["S"], _UC["S"]
+    rows = lambda nd: NamedSharding(
+        mesh, PartitionSpec(SCEN_AXIS, *([None] * (nd - 1))))
+    per = _stage_operands(ops.S, rows)
+    leaves, treedef = jax.tree.flatten(per)
+    idx = jax.ShapeDtypeStruct((_UC["K"],), jnp.int32,
+                               sharding=NamedSharding(mesh,
+                                                      PartitionSpec()))
+    prog = ops._map_chunks_fn(
+        "ph.stage", partial(_stage_chunk, w_on=True, prox_on=True),
+        treedef, tuple(v.ndim for v in leaves), _UC["chunk"], 1)
+    compiled = prog.lower(*leaves, idx).compile()
+    hlo = compiled.as_text()
+    for coll in ("all-reduce", "all-gather", "reduce-scatter",
+                 "collective-permute", "all-to-all"):
+        assert not _hlo_lines(hlo, coll), coll
+    outs = jax.tree.leaves(compiled.output_shardings)
+    assert len(outs) == 4 * 9           # l u lb ub q c c0 P0 W, a chunk
+    assert all(s.spec == PartitionSpec(SCEN_AXIS) for s in outs)

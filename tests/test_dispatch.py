@@ -174,6 +174,27 @@ def test_solve_loop_dispatch_validation():
                               dispatch=np.array([0]))
 
 
+def test_dispatch_passes_share_the_staging_program_per_chunk_count():
+    """The chunks' ids are an OPERAND of the one staging program
+    (``core/ph._ph_stage_chunks``), so APH's dispatch passes, whose id
+    set changes every iteration, compile it once per chunk COUNT
+    exactly as their scatter-back does — not once per id set."""
+    from mpisppy_tpu.core.ph import _ph_stage_chunks
+    ph = _settled_ph(S=6, chunk=2)
+    kw = dict(w_on=True, prox_on=True, update=False)
+    # the jit's cache is the process's: count from empty (an earlier
+    # test's engine may have compiled these very shapes)
+    _ph_stage_chunks.clear_cache()
+    ph.solve_loop(dispatch=np.array([0, 1, 2]), **kw)   # 2 chunks, padded
+    assert _ph_stage_chunks._cache_size() == 1
+    ph.reset_phase_timing()
+    ph.solve_loop(dispatch=np.array([1, 3, 4, 5]), **kw)   # 2 chunks again
+    assert _ph_stage_chunks._cache_size() == 1
+    assert ph.phase_timing(True)["assemble_programs_per_call"] == 1
+    ph.solve_loop(dispatch=np.array([0, 5]), **kw)      # 1 chunk: a new one
+    assert _ph_stage_chunks._cache_size() == 2
+
+
 # ---------------- frac=1.0 bit-equality + determinism ----------------
 
 def test_full_dispatch_bit_equal_to_default():

@@ -134,7 +134,7 @@ def test_sharded_engine_compiles_the_fused_chunk_program_once(mesh_run):
 
 def _chunk0(ph):
     factors, data = ph._get_factors(True, False)
-    chs = ph._chunked_inputs(data, LC)
+    chs = ph._shard_ops.to_chunks(ph._per_scen_operands(data), LC)
     return factors, data._replace(l=chs["l"][0], u=chs["u"][0],
                                   lb=chs["lb"][0], ub=chs["ub"][0])
 

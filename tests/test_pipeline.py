@@ -4,6 +4,8 @@ recovery behavior under a forced-pathological chunk, donation semantics,
 and the SHARDED chunked path (scenario-axis SPMD over the mesh — the
 ISSUE 6 replacement of PR 2's round-robin chunk spreading)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,7 +13,7 @@ import pytest
 
 from mpisppy_tpu import obs
 from mpisppy_tpu.ir.batch import build_batch
-from mpisppy_tpu.core.ph import PHBase
+from mpisppy_tpu.core.ph import PHBase, _ph_chunk_objs
 from mpisppy_tpu.models import uc
 from mpisppy_tpu.parallel.mesh import make_mesh
 
@@ -355,3 +357,181 @@ def test_donated_solve_matches_copying_solve():
     assert np.isfinite(float(st_a.x[0, 0]))
     with pytest.raises(RuntimeError):
         _ = float(st_b.x[0, 0])
+
+
+# ---- ONE staging program for every chunk's operands (ISSUE 31) ----
+
+_STAGE_LAYOUTS = {
+    # S, chunk, devices: host-chunked leaves a RAGGED last chunk (8 rows
+    # in chunks of 3: the last is padded by repeating row 7); on the
+    # mesh 16 rows are 2 local chunks of 4 on each of 2 devices
+    "host-chunked": (8, 3, 1),
+    "sharded": (16, 4, 2),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _stage_engine(layout, w_scale):
+    """One settled engine per (layout, w_scale): two real iterations
+    (nonzero W and x-bar), then a few pinned nonants so the boxes move
+    too. The cases below only ever call it with ``update=False`` and a
+    stand-in solver, so W / x-bar / rho / the pins stay as they are."""
+    S, chunk, ndev = _STAGE_LAYOUTS[layout]
+    batch = _uc_batch(S)
+    vp = False
+    if w_scale:
+        rng = np.random.default_rng(5)
+        vp = np.asarray(batch.prob)[:, None] \
+            * rng.uniform(0.5, 1.5, (batch.S, batch.K))
+        vp[0, 0] = 0.0          # a zero-probability entry: no W pressure
+    ph = PHBase(batch, {**_OPTS, "subproblem_chunk": chunk},
+                dtype=jnp.float64, variable_probability=vp,
+                mesh=make_mesh(ndev) if ndev > 1 else None)
+    for it in range(2):
+        ph.solve_loop(w_on=(it > 0), prox_on=(it > 0))
+        ph.W = ph.W_new
+    mask = np.zeros((batch.S, batch.K), bool)
+    mask[1::3, ::4] = True
+    ph.fix_nonants(np.asarray(ph.xbar) + 0.25, mask=mask)
+    return ph
+
+
+_REAL_CHUNK_OBJS = _ph_chunk_objs   # the cases below wrap the module's
+
+
+def _chunk_operands(ph, monkeypatch, pipeline, w_on, prox_on):
+    """What one ``solve_loop(update=False)`` hands each chunk solve
+    (l, u, lb, ub, q) and each pass-3 objective call (c, c0, P0, W),
+    as host arrays, chunk by chunk; the solver is a stand-in that hands
+    the warm state back as solved."""
+    from mpisppy_tpu.core import ph as ph_mod
+    solves, objs = [], []
+
+    def solver(factors, d, q, st, **kw):
+        solves.append([np.asarray(a) for a in (d.l, d.u, d.lb, d.ub, q)])
+        return (st._replace(pri_rel=jnp.zeros_like(st.pri_rel)),
+                st.x, st.yA, st.yB)
+
+    def chunk_objs(x, yA, yB, d, q, c, c0, P0, idx, W, *, w_on):
+        objs.append([np.asarray(a) for a in (c, c0, P0, W)])
+        return _REAL_CHUNK_OBJS(x, yA, yB, d, q, c, c0, P0, idx, W,
+                                w_on=w_on)
+
+    monkeypatch.setattr(ph_mod, "_solver_call", solver)
+    monkeypatch.setattr(ph_mod, "_ph_chunk_objs", chunk_objs)
+    ph.options["subproblem_pipeline"] = pipeline
+    ph.reset_phase_timing()
+    ph.solve_loop(w_on=w_on, prox_on=prox_on, update=False)
+    key = bool(prox_on)
+    return (solves, objs,
+            ph.phase_timing(key)["assemble_programs_per_call"])
+
+
+@pytest.mark.parametrize("w_on,prox_on", [(True, True), (True, False),
+                                          (False, True), (False, False)])
+@pytest.mark.parametrize("w_scale", [False, True],
+                         ids=["uniform", "w_scale"])
+@pytest.mark.parametrize("layout", list(_STAGE_LAYOUTS))
+def test_staged_operands_equal_per_chunk_assembly_bit_for_bit(
+        layout, w_scale, w_on, prox_on, monkeypatch):
+    """The resident pipelined pass stages every chunk's operands with
+    ONE device program; the sequential opt-out keeps the per-chunk
+    spelling (eager gathers, then ``_ph_assemble``). Same state in,
+    same bits out, in every leaf the chunk solves and pass 3 read — no
+    tolerance: the staging body IS the per-chunk expression."""
+    ph = _stage_engine(layout, w_scale)
+    S, chunk, ndev = _STAGE_LAYOUTS[layout]
+    n_chunks = -(-(S // ndev) // chunk)
+    staged = _chunk_operands(ph, monkeypatch, 1, w_on, prox_on)
+    each = _chunk_operands(ph, monkeypatch, 0, w_on, prox_on)
+    assert (staged[2], each[2]) == (1.0, float(n_chunks))
+    for got, want in zip(staged[:2], each[:2]):
+        assert len(got) == len(want) == n_chunks
+        for ci in range(n_chunks):
+            for a, b in zip(got[ci], want[ci]):
+                assert a.dtype == b.dtype and a.shape == b.shape
+                assert a.tobytes() == b.tobytes(), f"chunk {ci}"
+    # the operands are not trivially equal: W and the pins are in them
+    q, lb = staged[0][0][4], staged[0][0][2]
+    c = staged[1][0][0]
+    assert (np.abs(q - c).max() > 0) == (w_on or prox_on)
+    ids0 = np.asarray(
+        ph._sharded_chunk_slices(ph._local_chunk(chunk))[0][0]
+        if ndev > 1 else ph._chunk_index(chunk)[0][0])
+    assert np.abs(lb - np.asarray(ph.qp_data.lb)[ids0]).max() > 0
+    if layout == "host-chunked":
+        # the ragged last chunk repeats scenario 7
+        last = staged[0][-1][4]
+        np.testing.assert_array_equal(last[1], last[2])
+
+
+@pytest.mark.parametrize("source,layout,expect", [
+    ("resident", "host-chunked", 1), ("resident", "sharded", 1),
+    ("sequential", "host-chunked", "n_chunks"),
+    ("sequential", "sharded", "n_chunks"),
+    ("streamed", "host-chunked", "n_chunks")])
+def test_assemble_programs_per_call(source, layout, expect):
+    """``phase_timing()``'s count of the device programs the assemble
+    phase launched: ONE where the staging program ran, one per chunk
+    where the input keeps the per-chunk path (the sequential opt-out; a
+    streamed source, whose double buffer bounds the staged chunks)."""
+    S, chunk, ndev = _STAGE_LAYOUTS[layout]
+    opts = {**_OPTS, "subproblem_chunk": chunk}
+    if source == "sequential":
+        opts["subproblem_pipeline"] = 0
+    if source == "streamed":
+        opts["scenario_source"] = "streamed"
+    ph = PHBase(_uc_batch(S), opts, dtype=jnp.float64,
+                mesh=make_mesh(ndev) if ndev > 1 else None)
+    try:
+        for it in range(2):
+            ph.solve_loop(w_on=(it > 0), prox_on=(it > 0))
+            ph.W = ph.W_new
+    finally:
+        if source == "streamed":
+            ph.close_stream()
+    n_chunks = -(-(S // ndev) // chunk)
+    want = n_chunks if expect == "n_chunks" else expect
+    for key in (True, False):
+        assert ph.phase_timing(key)["assemble_programs_per_call"] == want
+
+
+@pytest.mark.parametrize("layout", list(_STAGE_LAYOUTS))
+def test_staging_program_takes_vectors_only(layout, monkeypatch):
+    """The guard against handing ``data`` through the jit boundary (XLA
+    copies what crosses it: +2.7 GB a chunk measured when a matrix
+    did): no operand of the staging program is larger than one
+    per-scenario vector block, S x max(n, m) elements, so neither the
+    (n, n) factor nor the (m, n) / packed constraint matrix is among
+    them."""
+    from mpisppy_tpu.core import ph as ph_mod
+    from mpisppy_tpu.parallel.mesh import ShardedScenarioOps
+    ph = _stage_engine(layout, False)
+    ph.options["subproblem_pipeline"] = 1
+    calls = []
+    if layout == "sharded":
+        real = ShardedScenarioOps.map_chunks
+
+        def spy(self, key, fn, tree, lc, *rep):
+            calls.append((jax.tree.leaves(tree) + list(rep), None))
+            return real(self, key, fn, tree, lc, *rep)
+
+        monkeypatch.setattr(ShardedScenarioOps, "map_chunks", spy)
+    else:
+        real = ph_mod._ph_stage_chunks
+
+        def spy(*args, **kw):
+            calls.append((jax.tree.leaves(args), jax.make_jaxpr(
+                functools.partial(real, **kw))(*args)))
+            return real(*args, **kw)
+
+        monkeypatch.setattr(ph_mod, "_ph_stage_chunks", spy)
+    ph.solve_loop(w_on=True, prox_on=True, update=False)
+    (leaves, jaxpr), = calls
+    S, n, m = ph.batch.S, ph.batch.n, ph.batch.m
+    sizes = [int(np.prod(a.shape)) for a in leaves]
+    if jaxpr is not None:
+        assert sizes == [int(np.prod(v.shape)) for v in jaxpr.in_avals]
+    # at this size the bound does tell a vector block from a matrix
+    assert S * max(n, m) < min(n * n, n * m)
+    assert max(sizes) <= S * max(n, m), sizes
