@@ -27,6 +27,7 @@ TPU redesign — one jitted step per PH iteration over the whole batch:
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import time as _time
 from functools import partial
@@ -40,7 +41,7 @@ from .. import global_toc, log as _log_setup, obs  # noqa: F401  (log import
 #   propagates to)
 from ..obs import resource as _obs_resource
 from ..ir.batch import ScenarioBatch
-from ..ops.qp_solver import (QPData, QPState, qp_setup, qp_solve,
+from ..ops.qp_solver import (LInv, QPData, QPState, qp_setup, qp_solve,
                              qp_solve_mixed, qp_solve_segmented,
                              qp_cold_state, qp_dual_objective,
                              qp_reset_rho, stacked_residuals)
@@ -60,7 +61,8 @@ def _new_phase_entry():
     reset together."""
     return {"acc": {"assemble": 0.0, "solve": 0.0, "gate": 0.0,
                     "reduce": 0.0},
-            "admm": {"bulk": 0, "tail": 0, "refactors": 0},
+            "admm": {"bulk": 0, "tail": 0, "refactors": 0,
+                     "linv_builds": 0},
             "collective": {"combines": 0, "bytes": 0},
             "calls": 0, "gate_syncs": 0, "assemble_programs": 0,
             "devices": 1, "mode": "host"}
@@ -368,14 +370,31 @@ def _solver_call(factors, d, q, qp_state, *, prox_on, precision,
                               adaptive_rho=adaptive_rho, donate=donate)
 
 
-def _book_admm_iters(admm, states, fused):
+def _linv_wraps(plan, st):
+    """How many explicit inverses the fused solve's ENTRY builds for the
+    state ``st`` under ``plan``: 1 for a cold state's bare factor
+    (``fused_mixed_solve`` wraps it into a ``qp_solver.LInv`` eagerly),
+    0 for a state that already carries one; None where the plan keeps
+    no explicit inverse (``_book_admm_iters`` then books none)."""
+    if plan is None or not plan.l_inv:
+        return None
+    return int(not isinstance(st.L, LInv))
+
+
+def _book_admm_iters(admm, states, fused, linv_wraps=None):
     """Book the ADMM iterations of the solves that produced ``states``
     into ``admm`` (the "admm" dict of a mode's ``_phase_times`` entry):
     ``bulk`` = the low-precision phase's (``QPState.iters_lo``),
     ``tail`` = the rest, ``refactors`` = their in-loop rho
     refactorizations (``QPState.refactors``: how often the factor was
     prepared anew) — the work of exactly the solves the solve lap
-    times, with or without a telemetry session. No new device wait:
+    times, with or without a telemetry session. ``linv_builds``, where
+    the plan keeps an explicit inverse (``linv_wraps`` is not None:
+    ``_linv_wraps`` of the call's first state): the eager wraps plus
+    the refactorizations, each of which leaves the inverse to be built
+    anew (the handoff builds ONE however often the bulk refactored, so
+    the count is exact while a bulk refactors at most once, and an
+    upper bound beyond). No new device wait:
     fused plans' callers sit AFTER the phase-honesty block they pay
     anyway (scalar copies, not stalls), and the segmented drivers hand
     back HOST scalars (they read their counts segment by segment), for
@@ -386,6 +405,8 @@ def _book_admm_iters(admm, states, fused):
     admm["bulk"] += bulk
     admm["tail"] += total - bulk
     admm["refactors"] += refs
+    if linv_wraps is not None:
+        admm["linv_builds"] += linv_wraps + refs
     if obs.enabled():
         obs.counter_add("kernel.bulk_iters", bulk)
         obs.counter_add("kernel.tail_iters", total - bulk)
@@ -427,6 +448,7 @@ def _ph_step(qp_state, factors, data, c, c0, P0, prob, xbar_w, memberships,
         # cost while "solve" absorbs the device wait (segment iteration
         # readbacks block).
         lap("solve")
+    wraps = _linv_wraps(kernel, qp_state)
     qp_state, x, yA, yB = _solver_call(
         factors, d, q, qp_state, prox_on=prox_on, precision=precision,
         sub_max_iter=sub_max_iter, sub_eps=sub_eps,
@@ -446,7 +468,7 @@ def _ph_step(qp_state, factors, data, c, c0, P0, prob, xbar_w, memberships,
             jax.block_until_ready(qp_state.pri_rel)
         # the solve's ADMM iterations beside its seconds (``admm``
         # comes with ``lap``: the same ``_phase_times`` entry)
-        _book_admm_iters(admm, [qp_state], fused)
+        _book_admm_iters(admm, [qp_state], fused, wraps)
         lap("reduce")
     wmask = None if wscale is None else wscale > 0
     if combine_fn is None:
@@ -618,23 +640,9 @@ class PHBase(SPBase):
         self.converger_cls = converger
         self.converger = None
 
-        S, K = batch.S, batch.K
-        t = self.dtype
-        # per-(scenario, slot) rho like the reference's per-variable rho Param
-        if rho_setter is not None:
-            rho0 = np.broadcast_to(np.asarray(rho_setter(batch), dtype=np.float64), (K,))
-        else:
-            rho0 = np.full(K, self.rho_default)
-        self.rho = jnp.asarray(np.broadcast_to(rho0, (S, K)), t)
-        self.W = jnp.zeros((S, K), t)
-        self.xbar = jnp.zeros((S, K), t)
-        self.xsqbar = jnp.zeros((S, K), t)
-        if mesh is not None:
-            from ..parallel.mesh import scenario_sharding
-            sh = scenario_sharding(mesh, 2)
-            self.rho, self.W, self.xbar, self.xsqbar = (
-                jax.device_put(a, sh) for a in (self.rho, self.W, self.xbar,
-                                                self.xsqbar))
+        self._rho_moved = False  # invalidate_factors() since the last
+        #                          run began (reset_run reads it)
+        self._init_run_state()
         # variable-probability W scaling (see _ph_assemble): vprob/p,
         # with zero-probability scenarios mapped to 0 (their subproblems
         # carry no objective weight; an eps-floor division would overflow
@@ -643,10 +651,6 @@ class PHBase(SPBase):
             self.prob[:, None] > 0, self.vprob
             / jnp.where(self.prob[:, None] > 0, self.prob[:, None], 1.0),
             0.0)
-        self.x = None            # (S, n) latest subproblem solutions
-        self.conv = None
-        self._iter = 0
-        self.best_bound = -float("inf")  # outer (lower, for min) bound
         # wheel forensics (ops/forensics.py, doc/forensics.md):
         # device-resident attribution carry + the latest unpacked
         # sample (plain host dict: signal-safe reads). Sampled every
@@ -658,8 +662,6 @@ class PHBase(SPBase):
 
         self._factors = {}       # prox_on -> QPFactors
         self._qp_states = {}     # prox_on -> QPState (L/rho are per-mode)
-        self._fixed_mask = jnp.zeros((S, K), bool)   # fixer/xhat support
-        self._fixed_vals = jnp.zeros((S, K), t)
         # chunks whose reset-rho recovery retry didn't help, and
         # (chunk, row) scenarios the hospital failed to improve, per
         # mode key (see _solve_loop_chunked passes 2/2b). Blacklists are
@@ -695,6 +697,10 @@ class PHBase(SPBase):
         # rebuild cold instead of warm-starting from them
         self._chunk_dirty = set()
         self._phase_times = {}
+        # whole PH runs (run_span) and their resets (reset_run), booked
+        # beside the phases' seconds and reset with them
+        self._run_times = {"count": 0, "seconds": 0.0,
+                           "reset_seconds": 0.0}
         # 0/False were the documented "disable spreading" spellings of
         # the retired option — nothing changed for those configs, so
         # only values that used to alter behavior warn
@@ -707,6 +713,120 @@ class PHBase(SPBase):
                 "round-robin chunk spreading (doc/sharding.md) — pass "
                 "mesh=make_mesh(n); the option is ignored",
                 DeprecationWarning, stacklevel=2)
+
+    # ------------- a run's state, and its reset -------------
+    def _init_run_state(self):
+        """The state a PH run starts from: rho as constructed (the rho
+        setter's, else ``defaultPHrho``), W = x̄ = x̄² = 0, no x, no conv,
+        iteration 0, no bound, nothing fixed."""
+        batch = self.batch
+        S, K = batch.S, batch.K
+        t = self.dtype
+        # per-(scenario, slot) rho like the reference's per-variable rho Param
+        if self.rho_setter is not None:
+            # lint: ok[SYNC001] a rho setter returns HOST data (per-variable rho from the batch's costs); once per run, not per solve
+            rho0 = np.broadcast_to(np.asarray(self.rho_setter(batch),
+                                              dtype=np.float64), (K,))
+        else:
+            rho0 = np.full(K, self.rho_default)
+        self.rho = jnp.asarray(np.broadcast_to(rho0, (S, K)), t)
+        self.W = jnp.zeros((S, K), t)
+        self.xbar = jnp.zeros((S, K), t)
+        self.xsqbar = jnp.zeros((S, K), t)
+        if self.mesh is not None:
+            from ..parallel.mesh import scenario_sharding
+            sh = scenario_sharding(self.mesh, 2)
+            self.rho, self.W, self.xbar, self.xsqbar = (
+                jax.device_put(a, sh) for a in (self.rho, self.W, self.xbar,
+                                                self.xsqbar))
+        self.x = None            # (S, n) latest subproblem solutions
+        self.conv = None
+        self._iter = 0
+        self.best_bound = -float("inf")  # outer (lower, for min) bound
+        self._fixed_mask = jnp.zeros((S, K), bool)   # fixer/xhat support
+        self._fixed_vals = jnp.zeros((S, K), t)
+
+    def reset_run(self):
+        """Re-arm a warm engine for a new PH run from a cold W: the
+        run's state back to ``_init_run_state``, and every per-run
+        artifact dropped (warm-start QP states, recovery blacklists,
+        donation bookkeeping, pool states, an active shrink plan, the
+        extensions' ``reset()``, the warm-start attributes). What the
+        runs SHARE stays: the compiled programs, the factorizations
+        (functions of (A, P, rho): rebuilt only if a rho updater moved
+        rho since the last reset), the kernel plans. A run after
+        ``reset_run()`` repeats a fresh engine's iterates bit for bit
+        (tests/test_sslp_reference.py). ``serve.manager.install_batch`` calls
+        this after installing a tenant's vectors; a driver that runs
+        one instance again and again calls it between runs. Span
+        ``ph.run.reset``; the seconds land in
+        ``phase_timing()["runs"]``."""
+        with obs.span("ph.run.reset", cat="ph") as sp:
+            self._init_run_state()
+            if self._rho_moved:
+                # the prox factors were rebuilt at a rho this run does
+                # not start from
+                self.invalidate_factors()
+                self._rho_moved = False
+            # active-set compaction state is PER-RUN: the folded
+            # constants bake the previous run's rhs/cost values, so
+            # the plan (and its separately cached compacted factors)
+            # drops here — the next run's fixer re-accumulates and
+            # re-compacts against ITS data
+            self._shrink = None
+            self._shrink_factors.clear()
+            if getattr(self, "_shrink_skip_noted", None):
+                # the last run's noted skip targets must not mute this
+                # run's shrink.compaction_skipped bookings
+                self._shrink_skip_noted.clear()
+            if self._shrink_status is not None:
+                b = self.batch
+                self._shrink_status.update(
+                    {"fixed": 0, "free": b.K, "compactions": 0,
+                     "bucket": 0.0, "n_cols": int(b.n),
+                     "m_rows": int(b.m),
+                     # full-width estimate again — leaving the last
+                     # run's compacted figure would stamp wrong est-HBM
+                     # evidence on the next run's bucket-0 iterations
+                     "est_hbm_bytes_per_iter": self._shrink_est_hbm(
+                         int(b.n), int(b.m))})
+            # per-run EXTENSION state: the device fixer's streak
+            # counters / latched slot bounds and the rho updaters'
+            # prox-center history would otherwise leak the previous
+            # run's trajectory into the next (near-threshold streaks
+            # fixing after one iteration, bound parks pinning at stale
+            # bounds)
+            ext = self.extensions
+            for e in ([ext] if ext is not None else []) \
+                    + list(getattr(ext, "extensions", []) or []):
+                r = getattr(e, "reset", None)
+                if callable(r):
+                    r()
+            # warm-start states carry the previous run's iterates and
+            # scales, blacklists its pathology — drop them (cold states
+            # rebuild through the already-compiled jitted builders)
+            for cache in (self._qp_states, self._pool_states,
+                          self._pool_dirty, self._chunk_no_retry,
+                          self._hospital_no_retry, self._blacklist_calls,
+                          self._chunk_donatable, self._chunk_dirty):
+                cache.clear()
+            for attr in ("_warm_started", "_warm_started_xbar",
+                         "trivial_bound", "W_new"):
+                if hasattr(self, attr):
+                    delattr(self, attr)
+        self._run_times["reset_seconds"] += sp.seconds
+
+    @contextlib.contextmanager
+    def run_span(self):
+        """One whole PH run (span ``ph.run``): ``ph_main`` opens it
+        around iter-0, the iterations and the wrap-up; a driver that
+        steps ``solve_loop`` itself opens it around ``reset_run()`` and
+        its iterations. Counted and timed in ``phase_timing()["runs"]``
+        with no telemetry session; a run that raises books nothing."""
+        with obs.span("ph.run", cat="ph") as sp:
+            yield
+        self._run_times["count"] += 1
+        self._run_times["seconds"] += sp.seconds
 
     # ------------- observability plumbing -------------
     def _trace_note(self, etype, msg, **fields):
@@ -876,6 +996,7 @@ class PHBase(SPBase):
 
     def invalidate_factors(self):
         """Call after changing rho (rho setters / NormRhoUpdater)."""
+        self._rho_moved = True
         self._kernel_plans.clear()   # plans hold views of the factors
         # compacted factors carry the prox rho too (ops/shrink); the
         # prox-off entry survives a rho change like the full cache's
@@ -2144,8 +2265,11 @@ class PHBase(SPBase):
         # rather than inside kernel_solve, where the read would
         # serialize chunk k's solve with chunk k+1's dispatch. Retries
         # keep their own counter (ph.chunk_retries).
+        # (the chain flows ONE factor: only its first state can arrive
+        # without the explicit inverse)
         _book_admm_iters(ent["admm"], [rec[0] for rec in solved_chunks],
-                         plan.mode == "fused")
+                         plan.mode == "fused",
+                         _linv_wraps(plan, states[0]))
         ent["assemble_programs"] += asm_programs
         obs.counter_add("ph.assemble_programs", asm_programs)
         clock.lap("gate")
@@ -2502,6 +2626,7 @@ class PHBase(SPBase):
         are process-cumulative and deliberately survive this reset —
         invariant tests read them as pure before/after deltas."""
         self._phase_times.clear()
+        self._run_times.update(count=0, seconds=0.0, reset_seconds=0.0)
 
     def phase_timing(self, key=True):
         """Per-phase wall-clock anatomy of the solve loop for one
@@ -2559,6 +2684,10 @@ class PHBase(SPBase):
             # for keyword the facts a bytes-per-iteration model prices
             # (ops/kernels.est_hbm_bytes_per_iter)
             "solve_shape": ent.get("shape"),
+            # whole PH runs of the ENGINE (every mode's; ``run_span``)
+            # since the last reset: how many, their seconds, and the
+            # seconds of their ``reset_run()``s (totals, not per call)
+            "runs": dict(self._run_times),
         }
 
     def _solve_shape(self, factors, plan, rows_per_call):
@@ -3068,6 +3197,7 @@ class PHBase(SPBase):
                 w_on=bool(w_on), prox_on=bool(prox_on))
             d_c = data._replace(lb=bl_c, ub=bu_c)
             clock.lap("solve")
+            wraps = _linv_wraps(plan, qp_state)
             qp_state, x_c, yA, yB = _solver_call(
                 factors, d_c, q_c, qp_state, prox_on=bool(prox_on),
                 precision=self.sub_precision,
@@ -3087,7 +3217,7 @@ class PHBase(SPBase):
                 # lint: ok[SYNC001] phase honesty for fused plans, same site contract as _ph_step
                 jax.block_until_ready(qp_state.pri_rel)
             _book_admm_iters(ent["admm"], [qp_state],
-                             plan.mode == "fused")
+                             plan.mode == "fused", wraps)
             clock.lap("reduce")
             x = expand_solution(x_c, shrink.fixed_colvals,
                                 shrink.keep_cols, shrink.fixed_cols,
@@ -3690,6 +3820,10 @@ class PH(PHBase):
     """Synchronous PH driver (ref. mpisppy/opt/ph.py:26 ph_main)."""
 
     def ph_main(self, finalize=True):
+        with self.run_span():
+            return self._ph_main(finalize)
+
+    def _ph_main(self, finalize):
         self._ext("pre_iter0")
         # Iter 0: no W, no prox (ref. phbase.py:1364 Iter0). A warm start
         # (WXBarReader / load_state, or a checkpoint-bundle resume —

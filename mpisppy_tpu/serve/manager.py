@@ -71,9 +71,10 @@ def install_batch(engine, stacked):
     engine of the same bucket, preserving everything the bucket
     shares: the traced/jitted programs (module-level jit caches), the
     KKT factorizations (``_factors`` depend on (A, P, rho) — all
-    bucket identity), and the kernel plans. Resets the PH state
-    (W/x̄/x̄²), the warm-start QP states, and the recovery blacklists —
-    per-request artifacts that must not leak across tenants."""
+    bucket identity), and the kernel plans. The per-request reset
+    (the PH state W/x̄/x̄², the warm-start QP states, the recovery
+    blacklists, shrink and extension state) is the engine's own
+    ``PHBase.reset_run()``."""
     import jax.numpy as jnp
 
     from ..core.spbase import ship_stacked
@@ -122,18 +123,11 @@ def install_batch(engine, stacked):
         engine.c = c2
         engine.qp_data = engine.qp_data._replace(l=l2, u=u2, lb=lb2,
                                                  ub=ub2)
-    S, K = b.S, b.K
-    engine.rho = jnp.asarray(
-        np.broadcast_to(np.full(K, engine.rho_default), (S, K)), t)
-    engine.W = jnp.zeros((S, K), t)
-    engine.xbar = jnp.zeros((S, K), t)
-    engine.xsqbar = jnp.zeros((S, K), t)
-    engine.x = None
-    engine.conv = None
-    engine._iter = 0
-    engine.best_bound = -float("inf")
-    engine._fixed_mask = jnp.zeros((S, K), bool)
-    engine._fixed_vals = jnp.zeros((S, K), t)
+    # everything per-request goes with the engine's own per-run reset:
+    # artifacts that must not leak across tenants; factors and plans
+    # stay. It comes BEFORE the snapshots below: the prox diagonal is
+    # built from the rho a run starts from, which the reset restores
+    engine.reset_run()
     # the factor cache stores (factors, data) pairs and the solvers
     # read THE CACHED DATA — refresh each entry's data snapshot to the
     # new vectors while keeping the factors (equilibration + scaled
@@ -148,56 +142,6 @@ def install_batch(engine, stacked):
         prox_on = fkey[1] if isinstance(fkey, tuple) else fkey
         engine._factors[fkey] = (fac,
                                  engine._data_with_prox(bool(prox_on)))
-    # per-request caches: warm-start states carry the previous
-    # tenant's iterates/scales, blacklists its pathology — drop them
-    # (cold states rebuild through the already-compiled jitted
-    # builders); factors/plans stay
-    # active-set compaction state is PER-TENANT: the folded constants
-    # bake the previous request's rhs/cost values, so the plan (and
-    # its separately cached compacted factors) must drop with the
-    # install — the next tenant's fixer re-accumulates and re-compacts
-    # against ITS data. Bucket fingerprints include the shrink knobs,
-    # so shrink-on and shrink-off requests never share a lease.
-    if getattr(engine, "_shrink", None) is not None:
-        engine._shrink = None
-    if hasattr(engine, "_shrink_factors"):
-        engine._shrink_factors.clear()
-    if getattr(engine, "_shrink_skip_noted", None):
-        # tenant A's noted skip targets must not mute tenant B's
-        # shrink.compaction_skipped bookings
-        engine._shrink_skip_noted.clear()
-    if getattr(engine, "_shrink_status", None) is not None:
-        engine._shrink_status.update(
-            {"fixed": 0, "free": K, "compactions": 0, "bucket": 0.0,
-             "n_cols": int(b.n), "m_rows": int(b.m),
-             # full-width estimate again — leaving the previous
-             # tenant's compacted figure would stamp wrong est-HBM
-             # evidence on the next tenant's bucket-0 iterations
-             "est_hbm_bytes_per_iter": engine._shrink_est_hbm(
-                 int(b.n), int(b.m))})
-    # per-run EXTENSION state is per-tenant too: the device fixer's
-    # streak counters / latched slot bounds and the rho updaters'
-    # prox-center history would otherwise leak the previous tenant's
-    # trajectory into the next wheel (near-threshold streaks fixing
-    # after one iteration, bound parks pinning at stale bounds)
-    ext = getattr(engine, "extensions", None)
-    for e in ([ext] if ext is not None else []) \
-            + list(getattr(ext, "extensions", []) or []):
-        r = getattr(e, "reset", None)
-        if callable(r):
-            r()
-    engine._qp_states.clear()
-    engine._pool_states.clear()
-    engine._pool_dirty.clear()
-    engine._chunk_no_retry.clear()
-    engine._hospital_no_retry.clear()
-    engine._blacklist_calls.clear()
-    engine._chunk_donatable.clear()
-    engine._chunk_dirty.clear()
-    for attr in ("_warm_started", "_warm_started_xbar", "trivial_bound",
-                 "W_new"):
-        if hasattr(engine, attr):
-            delattr(engine, attr)
     return engine
 
 

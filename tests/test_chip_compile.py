@@ -263,3 +263,121 @@ def test_sharded_chunk_staging_is_local_over_four_chips(
     outs = jax.tree.leaves(compiled.output_shardings)
     assert len(outs) == 4 * 9           # l u lb ub q c c0 P0 W, a chunk
     assert all(s.spec == PartitionSpec(SCEN_AXIS) for s in outs)
+
+
+# ---------------- the un-chunked sslp solve (ISSUE 32) -----------------
+
+# benchmarks/configs/sslp_10_50_df32.json: SIPLIB's sslp_10_50, all of
+# its 2000 scenarios in ONE call of the fused df32 program
+_SSLP = dict(S=2000, n=520, m=61, rows=7)
+
+
+@pytest.fixture(scope="module")
+def sslp_calls():
+    """Two PH passes (iter-0, one hot) of the published sslp_10_50 on
+    the CPU at 7 rows, un-chunked, under the cell's recipe with a short
+    budget: every call core/ph makes of the fused df32 program and of
+    the eager explicit-inverse build."""
+    import mpisppy_tpu.core.ph as phmod
+    import mpisppy_tpu.ops.kernels.reference as ref
+    from mpisppy_tpu.ir.batch import build_batch
+    from mpisppy_tpu.models import sslp
+
+    calls = {"_fused_mixed_jit_donated": [], "make_l_inv": []}
+    mp = pytest.MonkeyPatch()
+    for name in calls:
+        fn = getattr(ref, name)
+
+        def wrapper(*a, _fn=fn, _name=name, **kw):
+            calls[_name].append((_fn, a, kw))
+            return _fn(*a, **kw)
+        mp.setattr(ref, name, wrapper)
+    try:
+        batch = build_batch(
+            sslp.scenario_creator, sslp.make_tree(_SSLP["rows"]),
+            creator_kwargs=dict(num_servers=10, num_clients=50,
+                                overflow=True, server_budget=10,
+                                capacity=188.0, demand_is_revenue=True),
+            vector_patch=sslp.scenario_vector_patch)
+        assert (batch.n, batch.m) == (_SSLP["n"], _SSLP["m"])
+        ph = phmod.PHBase(
+            batch, {"defaultPHrho": 1.0, "subproblem_precision": "df32",
+                    "subproblem_max_iter": 50, "subproblem_eps": 1e-5,
+                    "subproblem_eps_hot": 1e-4,
+                    "subproblem_eps_dua_hot": 1e-2,
+                    "subproblem_stall_rel": 1.5e-3,
+                    "subproblem_tail_iter": 100,
+                    "subproblem_polish_hot": False,
+                    "subproblem_hospital": False, "subproblem_chunk": 0},
+            dtype=jnp.float64)
+        ph.solve_loop(w_on=False, prox_on=False)
+        ph.W = ph.W_new
+        ph.solve_loop(w_on=True, prox_on=True)
+        ph.W = ph.W_new
+        ph.solve_loop(w_on=True, prox_on=True)
+        calls["plan"] = ph.phase_timing(True)["kernel"]
+    finally:
+        mp.undo()
+    return calls
+
+
+def _at_rows(tree, rows, S, sharding):
+    """The recorded operands as shapes on the described chip, their
+    scenario axis (leading, ``rows`` long) widened to ``S``."""
+    def leaf(a):
+        if not (hasattr(a, "shape") and hasattr(a, "dtype")):
+            return a
+        shape = tuple(a.shape)
+        if shape and shape[0] == rows:
+            shape = (S,) + shape[1:]
+        return jax.ShapeDtypeStruct(shape, a.dtype, sharding=sharding)
+    return jax.tree.map(leaf, tree)
+
+
+def test_unchunked_sslp_df32_solve_compiles_for_v5e(sslp_calls, one_chip,
+                                                    no_persistent_cache):
+    """(S, n, m) = (2000, 520, 61): ONE ``jit(_fused_mixed_impl)``
+    serves iter-0 and the hot passes (the same statics, the same
+    operand structure with the explicit inverse in the state: a second
+    signature would be a second compile inside a run), one eager
+    ``make_l_inv`` a mode's cold state, and the program the v5e
+    compiler accepts holds no float64 batched linear algebra (the
+    factor is the shared f32 one; the float64 is element-wise outer
+    arithmetic and the split matvecs' accumulation)."""
+    assert sslp_calls["plan"] == {"mode": "fused", "backend": "reference",
+                                  "l_inv": True, "block_dtype": "f32"}
+    solves = sslp_calls["_fused_mixed_jit_donated"]
+    assert len(solves) == 3
+    assert len(sslp_calls["make_l_inv"]) == 2      # iter-0's and hot's
+    rows, S = _SSLP["rows"], _SSLP["S"]
+    sigs = set()
+    for _fn, args, kw in solves:
+        # what jit keys an executable on: shapes and dtypes (a Python
+        # scalar, e.g. a tolerance, is a weak-typed operand whatever
+        # its value)
+        avals = jax.tree.map(
+            lambda a: (tuple(a.shape), str(a.dtype))
+            if hasattr(a, "shape") else type(a).__name__, args)
+        leaves, treedef = jax.tree.flatten(avals, is_leaf=lambda v:
+                                           isinstance(v, tuple))
+        sigs.add((str(treedef), tuple(map(str, leaves)),
+                  tuple(sorted(kw.items()))))
+    assert len(sigs) == 1, "iter-0 and hot passes share one executable"
+    fn, args, kw = solves[-1]
+    compiled = fn.lower(*_at_rows(args, rows, S, one_chip), **kw).compile()
+    mem = compiled.memory_analysis()
+    need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes + mem.generated_code_size_in_bytes)
+    assert need < 1e9         # ISSUE 32: well under 1 GB of the 16
+    hlo = compiled.as_text()
+    assert f"f64[{S},{_SSLP['n']}]" in hlo        # the real size
+    for op in ("cholesky", "triangular-solve"):
+        assert not [ln for ln in _hlo_lines(hlo, op) if "f64[" in ln], op
+    # no batched (per-scenario) factor of any dtype: the one factor is
+    # (n, n), shared by all 2000 rows
+    n = _SSLP["n"]
+    assert not re.search(rf"f(32|64)\[{S},{n},{n}\]", hlo)
+    assert not _hlo_lines(hlo, "all-reduce")
+    fn, args, kw = sslp_calls["make_l_inv"][0]
+    inv = fn.lower(*_at_rows(args, rows, S, one_chip), **kw).compile()
+    assert inv.memory_analysis().temp_size_in_bytes < 64e6

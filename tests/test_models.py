@@ -145,6 +145,63 @@ def test_sslp_feasibility_invariant():
         assert np.allclose(assign.sum(axis=0), h, atol=1e-4)
 
 
+SSLP_PUBLISHED = dict(overflow=True, server_budget=10, capacity=188.0,
+                      demand_is_revenue=True)
+
+
+def test_sslp_published_shape_at_10_50():
+    """SIPLIB's sslp_10_50 as published (Ntaimo & Sen 2005): 10 site
+    binaries, 500 assignment binaries, 10 overflow columns; 50
+    assignment rows, 10 capacity rows, the server-budget row. The
+    randomness is in the assignment rows' rhs alone, so build_batch
+    stores ONE shared matrix, through the vector patch and without
+    it."""
+    kw = dict(num_servers=10, num_clients=50, **SSLP_PUBLISHED)
+    fast = build_batch(sslp.scenario_creator, sslp.make_tree(12),
+                       creator_kwargs=kw,
+                       vector_patch=sslp.scenario_vector_patch)
+    assert (fast.n, fast.m, fast.K) == (520, 61, 10)
+    assert fast.shared_A and fast.A.shape == (61, 520)
+    assert int(np.asarray(fast.integer).sum()) == 510
+    assert list(fast.template.var_slices) == ["OpenServer", "Assign",
+                                              "Overflow"]
+    assert list(fast.template.con_slices) == [
+        "ClientAssignment", "ServerCapacity", "ServerBudget"]
+    full = build_batch(sslp.scenario_creator, sslp.make_tree(12),
+                       creator_kwargs=kw)
+    assert full.shared_A
+    np.testing.assert_array_equal(fast.A, full.A)
+    for fld in ("c", "c0", "P_diag", "l", "u", "lb", "ub", "c_stage",
+                "c0_stage", "prob"):
+        np.testing.assert_array_equal(getattr(fast, fld),
+                                      getattr(full, fld), err_msg=fld)
+    # rhs-only randomness: everything but the 50 assignment rows is one
+    # row repeated
+    rows = fast.template.con_slices["ClientAssignment"]
+    assert (rows.start, rows.stop) == (0, 50)
+    for fld in ("c", "lb", "ub"):
+        assert (getattr(fast, fld) == getattr(fast, fld)[0]).all()
+    assert (fast.l[:, 50:] == fast.l[0, 50:]).all()
+    assert (fast.u[:, 50:] == fast.u[0, 50:]).all()
+    assert len({tuple(r) for r in fast.l[:, :50]}) == 12
+    np.testing.assert_array_equal(fast.l[:, :50], fast.u[:, :50])
+
+
+def test_sslp_default_kwargs_keep_the_reduced_model():
+    """The 5 x 25 instances every earlier test builds: no overflow
+    column, no budget row, client demands d_j, capacity 2 sum(d) / m."""
+    b = build_batch(sslp.scenario_creator, sslp.make_tree(4))
+    assert (b.n, b.m, b.K) == (5 + 125, 25 + 5, 5)
+    assert list(b.template.var_slices) == ["OpenServer", "Assign"]
+    data = sslp.instance_data()
+    cap = b.A[25:, :]
+    np.testing.assert_array_equal(cap[:, :5], -data["u"] * np.eye(5))
+    np.testing.assert_array_equal(cap[0, 5:30], data["d"])
+    patched = build_batch(sslp.scenario_creator, sslp.make_tree(4),
+                          vector_patch=sslp.scenario_vector_patch)
+    np.testing.assert_array_equal(patched.l, b.l)
+
+
 def test_battery_flow_balance_at_opt():
     batch = build_batch(battery.scenario_creator, battery.make_tree(3),
                         creator_kwargs={"T": 12})

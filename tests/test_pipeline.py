@@ -187,11 +187,93 @@ def test_phase_timing_contract(layout, precision):
     priced = est_hbm_bytes_per_iter(**shape)
     assert priced["tail"] > priced["bulk"] > 0
     admm = pt["admm_iters_per_call"]
-    assert set(admm) == {"bulk", "tail", "refactors"}
+    assert set(admm) == {"bulk", "tail", "refactors", "linv_builds"}
     assert admm["bulk"] > 0 and admm["tail"] >= 0
+    assert admm["linv_builds"] == 0        # no LInv, nothing built
     assert set(pt["seconds_per_call"]) == {"assemble", "solve", "gate",
                                            "reduce"}
     assert (pt["collective"]["bytes"] > 0) == (ndev > 1)
+
+
+def _sslp_batch(S=6):
+    from mpisppy_tpu.models import sslp
+    return build_batch(
+        sslp.scenario_creator, sslp.make_tree(S),
+        creator_kwargs=dict(num_servers=3, num_clients=8, overflow=True,
+                            server_budget=3, capacity=60.0,
+                            demand_is_revenue=True),
+        vector_patch=sslp.scenario_vector_patch)
+
+
+_SSLP_DF32 = {**_DF32_OPTS, "defaultPHrho": 5.0, "subproblem_chunk": 0,
+              "subproblem_tail_iter": 30, "iter0_feas_tol": 1.0}
+
+
+@pytest.mark.parametrize("family", ["uc-chunked", "sslp-unchunked",
+                                    "sslp-chunked"])
+def test_runs_and_linv_builds_beside_the_seconds(family):
+    """``phase_timing()["runs"]`` and ``["admm_iters_per_call"]
+    ["linv_builds"]`` (ISSUE 32), with no telemetry session. A UC toy
+    in the chunked loop carries no explicit inverse (its rule says
+    off, as in the UC cells): 0 builds. An sslp toy has it ON by the same rule (30 tail
+    iterations x 2 applies x 6 rows >= n = 30), un-chunked and chunked:
+    the wrap of each mode's cold state is one build a run (a chunk
+    chain flows ONE factor, so only its first state arrives bare), and
+    every refactorization leaves the inverse to be built anew: the
+    count is wraps + ``refactors``. Runs are counted by ``run_span``
+    and their resets timed."""
+    assert not obs.enabled()
+    if family == "uc-chunked":
+        # a tail of 10 x 2 applies x 2 rows does not repay an inverse
+        mk = lambda: _uc_batch(4)
+        opts = {**_DF32_OPTS, "subproblem_tail_iter": 10}
+    else:
+        mk = _sslp_batch
+        opts = {**_SSLP_DF32, "subproblem_chunk":
+                3 if family == "sslp-chunked" else 0}
+    ph = PHBase(mk(), dict(opts), dtype=jnp.float64)
+    on = family != "uc-chunked"
+    firsts = []
+    for _ in range(2):
+        with ph.run_span():
+            ph.reset_run()
+            ph.solve_loop(w_on=False, prox_on=False)
+            ph.W = ph.W_new
+            before = ph.phase_timing(True)
+            ph.solve_loop(w_on=True, prox_on=True)
+            ph.W = ph.W_new
+            firsts.append(ph.phase_timing(True)["admm_iters_per_call"]
+                          ["linv_builds"] * ph.phase_timing(True)["calls"]
+                          - (before["admm_iters_per_call"]["linv_builds"]
+                             * before["calls"] if before else 0))
+            ph.solve_loop(w_on=True, prox_on=True)
+            ph.W = ph.W_new
+    pt = ph.phase_timing(True)
+    assert pt["kernel"]["l_inv"] is on
+    assert pt["calls"] == 4
+    if on:
+        # each run's first hot call wraps its cold state
+        assert all(f >= 1 for f in firsts), firsts
+        st = ph._qp_states[("chunks", True)][0] \
+            if family == "sslp-chunked" else ph._qp_states[True]
+        assert type(st.L).__name__ == "LInv"
+        total = pt["admm_iters_per_call"]["linv_builds"] * pt["calls"]
+        refs = pt["admm_iters_per_call"]["refactors"] * pt["calls"]
+        assert round(total) == 2 + round(refs)   # one wrap a run
+        assert ph.phase_timing(False)["admm_iters_per_call"][
+            "linv_builds"] >= 1
+    else:
+        assert firsts == [0, 0]
+        assert pt["admm_iters_per_call"]["linv_builds"] == 0
+    runs = pt["runs"]
+    assert runs["count"] == 2
+    assert 0 < runs["reset_seconds"] < runs["seconds"]
+    assert ph.phase_timing(False)["runs"] == runs    # the engine's, not a mode's
+    ph.reset_phase_timing()
+    with ph.run_span():
+        ph.solve_loop(w_on=False, prox_on=False)
+    assert ph.phase_timing(False)["runs"]["count"] == 1
+    assert ph.phase_timing(False)["runs"]["reset_seconds"] == 0.0
 
 
 def test_pipeline_recovery_matches_sequential_on_pathological_chunk():
