@@ -947,7 +947,8 @@ class PHBase(SPBase):
                     if base is not None:
                         fac = qp_setup_like(base, d_setup)
                     else:
-                        fac = qp_setup(d_setup, q_ref=self.c)
+                        fac = qp_setup(d_setup, q_ref=self.c,
+                                       rows_per_call=self._rows_per_call())
                         cache[bkey] = fac
                         # the raw split A and the scaled split cannot
                         # BOTH stay in HBM at the scale df32 exists for
@@ -959,7 +960,8 @@ class PHBase(SPBase):
                             fac.A_s, fac.D, fac.E)
             else:
                 # mesh df32 engines (or non-split) build their own
-                fac = qp_setup(d_setup, q_ref=self.c)
+                fac = qp_setup(d_setup, q_ref=self.c,
+                               rows_per_call=self._rows_per_call())
             if is_split and isinstance(fac.A_s, SplitMatrix) \
                     and isinstance(self.qp_data.A, SplitMatrix):
                 # swap this engine's raw split A for the scaled view
@@ -970,6 +972,19 @@ class PHBase(SPBase):
                 d = d._replace(A=view)
             self._factors[key] = (fac, d)
         return self._factors[key]
+
+    def _rows_per_call(self):
+        """Rows ONE device call of the hot loop solves on one device:
+        the per-device scenario count, or ``subproblem_chunk`` where
+        that microbatches it (solve_loop's own test). What the factors'
+        packed matvec form is held against (ops/packed.pack_profitable);
+        a streamed source's 2-row setup surrogate says nothing of it."""
+        sh = self._shard_ops
+        per_device = sh.shard_size if sh is not None else self.batch.S
+        chunk = int(self.options.get("subproblem_chunk", 0))
+        if not 0 < chunk < per_device:
+            return per_device
+        return self._local_chunk(chunk) if sh is not None else chunk
 
     def _kernel_plan(self, key, factors, s_chunk):
         """Cached ops/kernels plan for one mode's factors (resolved
@@ -3168,13 +3183,10 @@ class PHBase(SPBase):
         # per-device rows (see _solve_loop_chunked: the profitability
         # check amortizes the replicated L⁻¹ build against the LOCAL
         # shard's applies)
-        plan = self._kernel_plan(
-            skey, factors,
-            sh.shard_size if sh is not None else self.batch.S)
+        rows_per_call = self._rows_per_call()
+        plan = self._kernel_plan(skey, factors, rows_per_call)
         ent["kernel"] = plan.descriptor()
-        ent["shape"] = self._solve_shape(
-            factors, plan,
-            sh.shard_size if sh is not None else self.batch.S)
+        ent["shape"] = self._solve_shape(factors, plan, rows_per_call)
         sp_args = {"mode": _mode_str(skey)} if obs.enabled() else None
         clock = _PhaseClock(ent["acc"], sp_args)
 

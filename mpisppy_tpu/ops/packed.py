@@ -38,6 +38,26 @@ operand set is ~1.5% of the dense matrix's bytes (C=90 components of
 HBM streaming into ~0.2 ms of mostly-MXU work. Models without local
 structure simply fail the profitability test and keep the dense path.
 
+A structure that EXISTS is not yet one worth USING: the ratio test
+above is relative, and a matrix can pass it with nothing to save.
+SIPLIB's sslp_10_50 does: (61, 520), ten server rows global, 51
+one-row blocks of ten columns, 18% of dense, where dense is 254 KB.
+Packed, every A-pass of its 2000-row call gathered two (2000, 510)
+operands and placed an f64 (2000, 520) result through 51 one-row blocks
+to skip 0.2 MB of zeros: 1.6x SLOWER than the dense split product
+(0.230 against 0.140 ms an Ax + Aᵀy pair on a v5e; PERF.md §6, PR 33).
+So there is a second test, ``pack_profitable(m, n, packed_elems,
+rows)``: bytes (and MXU time) the packed form saves a pass against the
+vector bytes its gathers move for the rows ONE device call solves.
+``qp_solver._qp_setup_split`` applies it once per set of factors, the
+engine naming its rows per call (``PHBase._rows_per_call``). UC packs
+at any row count; sslp_10_50 is dense from four rows a call up. Left
+dense, ``A_s`` keeps the skeleton and no packed values, and the dense
+split Aᵀy still sums its leading pass by the skeleton's two row classes
+(``global_row_mask``): local and global contributions meet in f64 in
+the packed form, and that grouping, not the packing, is what holds the
+df32 tail's residual floor (doc/kernels.md §3c).
+
 Exactness: each nonzero lands in exactly one term (component blocks are
 bounding boxes over disjoint row/column sets; global rows are disjoint
 from local rows), so packed apply equals dense apply up to f32 summation
@@ -173,6 +193,80 @@ def analyze_structure(rows, cols, m, n, nnz_thresholds=None,
         return structure_from_lists(row_lists, col_lists,
                                     np.flatnonzero(g_mask), m, n)
     return None
+
+
+def packed_elems(structure: PackStructure) -> int:
+    """Matrix elements ONE packed pass streams (padded component blocks
+    plus the global rows): what ``analyze_structure`` held against
+    ``max_traffic_ratio`` of m * n, read back from the skeleton."""
+    C, mr = structure.l_rows.shape
+    nc = structure.l_cols.shape[1]
+    n = structure.col_src.shape[0]
+    return int(C * mr * nc + structure.g_rows.shape[0] * n)
+
+
+def global_row_mask(structure: PackStructure):
+    """(m,) bool: which rows the skeleton holds as GLOBAL rows (read
+    from ``row_src``: the slots after the C * mr local ones). The
+    packed matvecs sum local and global contributions apart; a
+    structured matrix left dense keeps that grouping by this mask
+    (qp_solver._ATy)."""
+    n_local = structure.l_rows.shape[0] * structure.l_rows.shape[1]
+    src = structure.row_src
+    return (src >= n_local) & (src < n_local + structure.g_rows.shape[0])
+
+
+# The rule's two constants, read on one v5e chip (PERF.md §6, PR 33:
+# packed against dense Ax + Aᵀy pairs, f32 and split, at sslp_10_50's
+# (61, 520), a 20-block UC-like (5,800, 2,944) and UC's (26,016, 13,056)
+# over 4 … 2,000 rows a call).
+#
+# _MXU_BYTES: what the three f32 dots of a split pass cost per matrix
+# element and row, as bytes of HBM stream: a dense pass's seconds grow
+# by 0.17 (UC) … 0.23 (middle shape) bytes' worth a row, so past ~40
+# rows a call the zeros cost MXU time, not bandwidth.
+_MXU_BYTES = 0.2
+# _PACK_MARGIN: gathered bytes are not streamed bytes. Dense won (2.2x,
+# both forms at launch latency) where the ratio below read 12.3 (sslp's
+# matrix at 4 rows) and everywhere under it; packed won 1.5x (split) /
+# 6.7x (f32) where it read 47 (the middle shape at 2,000 rows) and 7x
+# where it read 350 (UC at 64). The margin sits at the low end of that
+# gap: a matrix wrongly left dense pays for every zero on every pass
+# (7x at UC), one wrongly packed pays three sweeps (1.6x at sslp).
+_PACK_MARGIN = 14.0
+
+
+def pack_profitable(m, n, packed_elems, rows):
+    """Whether the packed matvec form pays for the call that runs it:
+    the second test a structured df32 matrix meets, after
+    ``analyze_structure``'s (is there a structure at all), made by
+    ``qp_solver._qp_setup_split`` from shapes alone, the way
+    ``kernels.l_inv_profitable`` decides the explicit inverse.
+
+    In bytes a split pass (hi and lo, f32): packing SAVES the
+    ``m * n - packed_elems`` elements it no longer touches, 8 bytes each
+    to stream plus ``_MXU_BYTES`` a row to multiply; it MOVES about
+    ``8 * (m + n)`` bytes a row that the dense form does not (two f32
+    operand gathers and one f64 placement through ``row_src`` /
+    ``col_src``; an Ax / Aᵀy pair averaged). Pack iff saved exceeds
+    ``_PACK_MARGIN`` times moved. ``rows`` is the rows ONE device call
+    solves (the chunk, per device on a mesh): the matrix is read once a
+    pass whatever the rows, the vectors are gathered row by row.
+
+    UC (m = 26,016, n = 13,056, 4.96 M packed elements of 340 M) at 64
+    rows: 7.0 GB against 20 MB, packed, and at any row count (its zeros
+    outweigh the gathers in MXU time alone). sslp_10_50 (m = 61,
+    n = 520, 5,710 of 31,720) at 2000 rows: 10.6 MB saved, nearly all of
+    it MXU time, against 9.3 MB moved: under the margin, dense. It
+    packs at three rows or fewer, where both forms cost ~10–20 µs a
+    pair on the chip and the verdict is worth nothing either way.
+    Monotone: more rows never turn dense into packed, a taller matrix
+    of the same width and packed share never turns packed into dense."""
+    dropped = int(m) * int(n) - int(packed_elems)
+    rows = max(int(rows), 1)
+    saved = dropped * (8.0 + _MXU_BYTES * rows)
+    moved = 8.0 * rows * (int(m) + int(n))
+    return saved > _PACK_MARGIN * moved
 
 
 def _padded(lists):

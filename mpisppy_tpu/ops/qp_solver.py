@@ -126,10 +126,14 @@ class SplitMatrix(NamedTuple):
 
     ``struct``/``pk_hi``/``pk_lo`` (optional): the structure-packed
     matvec form (see ops/packed.py). ``struct`` is the host-derived
-    index skeleton attached at ship time; setup gathers the SCALED
-    hi/lo into ``pk_hi``/``pk_lo``, after which every _Ax/_ATy pass
-    reads ~1.5% of the dense bytes (the r5 MFU fix — the round-4
-    dense kernel measured 3.8% MFU, its passes dominating HBM traffic).
+    index skeleton attached at ship time; where packing pays for the
+    rows a device call solves (``packed.pack_profitable``) setup gathers
+    the SCALED hi/lo into ``pk_hi``/``pk_lo``, after which every
+    _Ax/_ATy pass reads ~1.5% of the dense bytes (the r5 MFU fix — the
+    round-4 dense kernel measured 3.8% MFU, its passes dominating HBM
+    traffic); where it does not, the scaled pair keeps ``struct``
+    alone and the matvecs are the dense split products, Aᵀy's leading
+    pass summed by the skeleton's row classes (_ATy).
     The dense pair stays resident for the factorization matmul and
     support_touch."""
     hi: jax.Array
@@ -356,8 +360,24 @@ def _ATy(A, y):
             from .packed import pk_ATy_split
             return pk_ATy_split(A.pk_hi, A.pk_lo, yh, yl)
         f64 = jnp.float64
-        return ((yh @ A.hi).astype(f64) + (yh @ A.lo).astype(f64)
-                + (yl @ A.hi).astype(f64))
+        if A.struct is not None and A.struct.g_rows.shape[0]:
+            # a structured matrix left dense (packed.pack_profitable):
+            # the leading pass keeps the packed form's two partial
+            # sums, local rows and global rows, apart until f64. One
+            # f32 accumulation over ALL rows mixes the rho-weighted
+            # equality rows with the coupling rows, and that rounding
+            # is the tail's residual floor: twice the packed form's on
+            # sslp_10_50 (PERF.md §6, PR 33). A zeroed y entry adds an
+            # exact zero, so each dot sums its own class's rows only;
+            # the two correction passes are 1e-7 of the first and need
+            # no such care
+            from .packed import global_row_mask
+            g = global_row_mask(A.struct)
+            lead = (jnp.where(g, 0, yh) @ A.hi).astype(f64) \
+                + (jnp.where(g, yh, 0) @ A.hi).astype(f64)
+        else:
+            lead = (yh @ A.hi).astype(f64)
+        return lead + (yh @ A.lo).astype(f64) + (yl @ A.hi).astype(f64)
     if A.ndim == 2:
         return y @ A
     return jnp.einsum("smn,sm->sn", A, y)
@@ -860,7 +880,8 @@ def _scale_split_blocks(A: SplitMatrix, D, E, nblocks=8) -> SplitMatrix:
     return SplitMatrix(jnp.concatenate(his), jnp.concatenate(los))
 
 
-def _qp_setup_split(data: QPData, q_ref, rho_base, sigma, eq_boost):
+def _qp_setup_split(data: QPData, q_ref, rho_base, sigma, eq_boost,
+                    rows_per_call=None):
     """df32 setup: Ruiz on the f32 hi part (D/E/Eb are heuristic
     scalings — a 1e-7-relative view of |A| changes nothing), scaled
     split built blockwise, vector tail shared with the dense path. The
@@ -873,13 +894,22 @@ def _qp_setup_split(data: QPData, q_ref, rho_base, sigma, eq_boost):
     D, E, Eb = D32.astype(f64), E32.astype(f64), Eb32.astype(f64)
     A_s = _scale_split_blocks(A, D, E)
     if A.struct is not None:
-        # gather the SCALED hi/lo into the packed matvec form (same
-        # index skeleton for both — scaling preserves structure); from
-        # here every hot-loop A-pass is packed (see ops/packed.py)
-        from .packed import pack
-        A_s = A_s._replace(struct=A.struct,
-                           pk_hi=pack(A.struct, A_s.hi),
-                           pk_lo=pack(A.struct, A_s.lo))
+        from .packed import pack, pack_profitable, packed_elems
+        rows = data.l.shape[0] if rows_per_call is None else rows_per_call
+        # a structure EXISTS (analyze_structure found one at ship
+        # time); whether to USE it is decided here, once for every
+        # consumer of these factors, from the shapes of the call that
+        # will run the matvecs (ops/packed.pack_profitable). Left
+        # dense, A_s keeps the skeleton and no packed values: the
+        # matvecs are the dense split products, with Aᵀy's leading
+        # pass summed by the skeleton's row classes (_ATy)
+        A_s = A_s._replace(struct=A.struct)
+        if pack_profitable(*A.shape, packed_elems(A.struct), rows):
+            # gather the SCALED hi/lo into the packed matvec form (same
+            # index skeleton for both — scaling preserves structure);
+            # from here every hot-loop A-pass is packed
+            A_s = A_s._replace(pk_hi=pack(A.struct, A_s.hi),
+                               pk_lo=pack(A.struct, A_s.lo))
     P_s, cost_scale, rho_A, rho_b = _setup_vectors(
         data.P_diag, data.l, data.u, data.lb, data.ub, D, q_ref,
         rho_base, eq_boost, True)
@@ -889,13 +919,18 @@ def _qp_setup_split(data: QPData, q_ref, rho_base, sigma, eq_boost):
 
 
 def qp_setup(data: QPData, q_ref=None, rho_base=0.1, sigma=1e-6,
-             eq_boost=1e3):
+             eq_boost=1e3, rows_per_call=None):
     """Equilibrate and scale. Cheap relative to the solve; re-solves with a
     new q reuse everything. The equality-row rho boost pattern depends only
     on which rows/columns are pinned (l==u / lb==ub), so one setup serves
-    every PH iteration of a mode."""
+    every PH iteration of a mode. ``rows_per_call``: rows ONE device
+    call solves with these factors, where that is not ``data``'s own
+    row count (a chunked or sharded engine, a streamed source's 2-row
+    surrogate): what a structured df32 matrix's packed form is held
+    against (ops/packed.pack_profitable)."""
     if isinstance(data.A, SplitMatrix):
-        return _qp_setup_split(data, q_ref, rho_base, sigma, eq_boost)
+        return _qp_setup_split(data, q_ref, rho_base, sigma, eq_boost,
+                               rows_per_call)
     return _qp_setup_dense(data, q_ref, rho_base, sigma, eq_boost)
 
 

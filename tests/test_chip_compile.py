@@ -269,13 +269,15 @@ def test_sharded_chunk_staging_is_local_over_four_chips(
 
 # benchmarks/configs/sslp_10_50_df32.json: SIPLIB's sslp_10_50, all of
 # its 2000 scenarios in ONE call of the fused df32 program
-_SSLP = dict(S=2000, n=520, m=61, rows=7)
+# rows: enough that the packing rule answers at the rehearsal's row
+# count what it answers at 2000 (dense: ops/packed.pack_profitable)
+_SSLP = dict(S=2000, n=520, m=61, rows=24)
 
 
 @pytest.fixture(scope="module")
 def sslp_calls():
     """Two PH passes (iter-0, one hot) of the published sslp_10_50 on
-    the CPU at 7 rows, un-chunked, under the cell's recipe with a short
+    the CPU at 24 rows, un-chunked, under the cell's recipe with a short
     budget: every call core/ph makes of the fused df32 program and of
     the eager explicit-inverse build."""
     import mpisppy_tpu.core.ph as phmod
@@ -364,6 +366,15 @@ def test_unchunked_sslp_df32_solve_compiles_for_v5e(sslp_calls, one_chip,
                   tuple(sorted(kw.items()))))
     assert len(sigs) == 1, "iter-0 and hot passes share one executable"
     fn, args, kw = solves[-1]
+    # the form the cell's 2000 rows get: dense split matvecs, the bulk's
+    # operand the plain f32 hi (ISSUE 33: at this shape the packed form
+    # saves 0.2 MB a pass and gathers every vector through 51 blocks)
+    from mpisppy_tpu.ops.packed import pack_profitable
+    elems = 51 * 1 * 10 + 10 * _SSLP["n"]
+    assert not pack_profitable(_SSLP["m"], _SSLP["n"], elems, rows) \
+        and not pack_profitable(_SSLP["m"], _SSLP["n"], elems, S)
+    assert args[0].A_s.pk_hi is None and args[0].A_s.struct is not None
+    assert args[1] is args[0].A_s.hi
     compiled = fn.lower(*_at_rows(args, rows, S, one_chip), **kw).compile()
     mem = compiled.memory_analysis()
     need = (mem.argument_size_in_bytes + mem.output_size_in_bytes

@@ -411,3 +411,289 @@ def test_overlapping_skeleton_is_refused(rows, cols, g_rows, what):
 if __name__ == "__main__":
     import sys
     sys.exit(pytest.main([__file__, "-v"]))
+
+
+# ------------- pack only where packing pays (ISSUE 33) -------------
+#
+# ``analyze_structure`` says whether a structure EXISTS (a ratio of
+# elements); ``pack_profitable`` says whether the call that runs the
+# matvecs should USE it, from (m, n, packed elements, rows per device
+# call) alone. The shapes below are the benchmark's operands.
+
+# uc90x48_df32: 90 generator blocks of 286 x 144 and 96 global rows
+_UC = dict(m=26016, n=13056, elems=90 * 286 * 144 + 96 * 13056)
+# sslp_10_50_df32: 51 one-row blocks of 10 columns and 10 global rows
+_SSLP = dict(m=61, n=520, elems=51 * 1 * 10 + 10 * 520)
+
+
+# rows of the engine parity test: un-chunked they are ONE dense call,
+# over four devices four packed ones (the rule's answers, stated)
+_ENGINE_ROWS = 12
+
+
+def _sslp_10_50_A():
+    from mpisppy_tpu.models import sslp
+    sf = lower(sslp.scenario_creator(
+        "Scenario0", num_servers=10, num_clients=50, overflow=True,
+        server_budget=10, capacity=188.0, demand_is_revenue=True))
+    return np.asarray(sf.A, np.float64)
+
+
+def test_packed_elems_reads_the_analysers_count_back():
+    """The rule's third input is the quantity the analyser held against
+    its ratio, read from the skeleton: on the published sslp_10_50
+    matrix the ten server rows go global and the 51 others are one-row
+    blocks of ten columns."""
+    from mpisppy_tpu.ops.packed import packed_elems
+    A = _sslp_10_50_A()
+    assert A.shape == (_SSLP["m"], _SSLP["n"])
+    st = analyze_structure(*np.nonzero(A), *A.shape)
+    assert st is not None, "the ratio test alone packs this matrix"
+    assert (st.g_rows.shape, st.l_rows.shape, st.l_cols.shape) == \
+        ((10,), (51, 1), (51, 10))
+    assert packed_elems(st) == _SSLP["elems"]
+    A_uc = _uc_A()
+    st_uc = analyze_structure(*np.nonzero(A_uc), *A_uc.shape)
+    C, mr = st_uc.l_rows.shape
+    assert packed_elems(st_uc) == C * mr * st_uc.l_cols.shape[1] \
+        + st_uc.g_rows.shape[0] * A_uc.shape[1]
+
+
+@pytest.mark.parametrize("shape,rows,packed", [
+    # cell 1 and, per device, the mesh cell: 2.7 GB of zeros a pass
+    (_UC, 64, True),
+    # a chunk the chip cannot hold today; the rule must not flip there
+    (_UC, 1024, True),
+    # the sslp cell: 0.2 MB saved against three sweeps of (2000, 520)
+    (_SSLP, 2000, False),
+    (_SSLP, 64, False),
+    # the same matrix at a handful of rows (tier-1's sizes): packed up
+    # to three, dense from four (where the chip read dense 2.2x faster)
+    (_SSLP, 3, True),
+    (_SSLP, 4, False),
+], ids=["uc_64", "uc_1024", "sslp_2000", "sslp_64", "sslp_3", "sslp_4"])
+def test_rule_at_the_benchmarks_shapes(shape, rows, packed):
+    from mpisppy_tpu.ops.packed import pack_profitable
+    assert pack_profitable(shape["m"], shape["n"], shape["elems"],
+                           rows) is packed
+
+
+@pytest.mark.parametrize("n,ratio", [(520, 0.18), (520, 0.02),
+                                     (2944, 0.1), (13056, 0.015)])
+def test_rule_is_monotone_in_rows_and_in_m(n, ratio):
+    """More rows a call never turn a dense verdict into a packed one
+    (the gathers grow with the rows, the matrix does not), and a taller
+    matrix of the same width and packed share never turns a packed
+    verdict into a dense one (more zeros to skip, the same vectors)."""
+    from mpisppy_tpu.ops.packed import pack_profitable
+    ms = [8, 61, 300, 1500, 6000, 26016, 100000]
+    rows = [1, 2, 4, 8, 16, 64, 256, 1024, 2000, 8192, 65536]
+    table = np.array([[pack_profitable(m, n, int(ratio * m * n), r)
+                       for r in rows] for m in ms])
+    assert (np.diff(table.astype(int), axis=1) <= 0).all(), table
+    assert (np.diff(table.astype(int), axis=0) >= 0).all(), table
+    assert table.any() and not table.all()
+
+
+def test_setup_packs_by_the_rows_of_the_call():
+    """``qp_setup`` decides ONCE, for every consumer of the factors:
+    the same structured sslp matrix comes back packed for a two-row
+    call and dense (the skeleton stays, for ``_ATy``'s row classes; no
+    packed values) for a 2000-row one, whether the rows are the data's
+    own or the engine's ``rows_per_call``."""
+    from mpisppy_tpu.ops.qp_solver import QPData, qp_setup
+    A = _sslp_10_50_A()
+    m, n = A.shape
+    st = analyze_structure(*np.nonzero(A), m, n)
+    sp = split_f32(jnp.asarray(A))._replace(struct=st)
+
+    def data(S):
+        return QPData(jnp.full(n, 1e-3), sp, jnp.zeros((S, m)),
+                      jnp.ones((S, m)), jnp.zeros((S, n)),
+                      jnp.ones((S, n)))
+    few, many = qp_setup(data(2)), qp_setup(data(2000))
+    assert few.A_s.pk_hi is not None and few.A_s.struct is st
+    assert many.A_s.pk_hi is None and many.A_s.pk_lo is None \
+        and many.A_s.struct is st
+    np.testing.assert_array_equal(np.asarray(few.A_s.hi),
+                                  np.asarray(many.A_s.hi))
+    # a chunked or streamed engine's two-row surrogate of a 2000-row call
+    assert qp_setup(data(2), rows_per_call=2000).A_s.pk_hi is None
+    assert qp_setup(data(2000), rows_per_call=2).A_s.pk_hi is not None
+
+
+def test_dense_transpose_pass_keeps_the_packed_forms_row_classes():
+    """A structured matrix left dense sums Aᵀy's leading pass by the
+    skeleton's row classes (local rows, global rows), as the packed
+    form does by construction. With y weighted as ADMM weights it
+    (rho 100 on the equality rows, all local here; 0.1 on the ten
+    coupling rows, all global) the classed dense product is the packed
+    one to 1e-12 and ten times closer to numpy's f64 than one f32
+    accumulation over all 61 rows: that rounding is the df32 tail's
+    residual floor (a PH engine read it 2x higher on the plain form)."""
+    from mpisppy_tpu.ops.packed import global_row_mask
+    from mpisppy_tpu.ops.qp_solver import _ATy
+    A = _sslp_10_50_A()
+    m, n = A.shape
+    st = analyze_structure(*np.nonzero(A), m, n)
+    g = np.asarray(global_row_mask(st))
+    assert sorted(np.flatnonzero(g)) == sorted(np.asarray(st.g_rows))
+    sp = split_f32(jnp.asarray(A))
+    y = jnp.asarray(np.random.RandomState(0).randn(7, m)
+                    * np.where(g, 0.1, 100.0))
+    truth = np.asarray(y) @ A
+    packed = np.asarray(_ATy(sp._replace(
+        struct=st, pk_hi=pack(st, sp.hi), pk_lo=pack(st, sp.lo)), y))
+    classed = np.asarray(_ATy(sp._replace(struct=st), y))
+    plain = np.asarray(_ATy(sp, y))
+    scale = np.abs(truth).max()
+    assert np.abs(classed - packed).max() < 1e-12 * scale
+    err = {k: np.abs(v - truth).max()
+           for k, v in (("classed", classed), ("plain", plain))}
+    assert err["classed"] < 2e-8 * scale < 1e-7 * scale > err["plain"]
+    assert err["plain"] > 5 * err["classed"]
+    # a structure with no global rows has one class: the plain product
+    st0 = st._replace(g_rows=st.g_rows[:0])
+    np.testing.assert_array_equal(
+        np.asarray(_ATy(sp._replace(struct=st0), y)), plain)
+
+
+def test_dense_and_packed_split_solves_agree_row_for_row():
+    """The two representations, each reached by the shape of its call:
+    rows 0 and 1 of a 64-row solve (dense split matvecs) against the
+    same two rows solved alone (packed). Adaptation off and a fixed
+    budget: every row runs the same deterministic ADMM recursion on the
+    same factors, so the iterates differ by f32 summation order alone.
+    That is 3e-4 on x in [0, 1] here whichever form runs (a dense
+    two-row solve sits as far from the dense 64-row one: XLA:CPU blocks
+    a product by its row count), so the tolerance is 2e-3, ten times
+    ``test_packed_kernel_trajectory_matches_dense``'s."""
+    from mpisppy_tpu.ops.qp_solver import (QPData, qp_cold_state,
+                                           qp_setup, qp_solve)
+    A = _sslp_10_50_A()
+    m, n = A.shape
+    st = analyze_structure(*np.nonzero(A), m, n)
+    rng = np.random.RandomState(5)
+    S = 64
+    q = jnp.asarray(rng.rand(S, n) * 10.0 - 5.0)
+    # the published rows' kinds: equalities (client rows, h in {0, 1})
+    # and one-sided capacity rows
+    eq = rng.rand(m) < 0.8
+    l = np.where(eq, (rng.rand(S, m) < 0.5).astype(float), -1e3)
+    u = np.where(eq, l, 0.0)
+    outs = {}
+    for tag, rows in (("dense", S), ("packed", 2)):
+        sp = split_f32(jnp.asarray(A))._replace(struct=st)
+        data = QPData(jnp.ones(n), sp, jnp.asarray(l[:rows]),
+                      jnp.asarray(u[:rows]), jnp.zeros((rows, n)),
+                      jnp.ones((rows, n)))
+        # one cost scale for both: q_ref is the 64 rows' either way
+        fac = qp_setup(data, q_ref=q)
+        assert (fac.A_s.pk_hi is not None) == (tag == "packed")
+        state = qp_cold_state(fac, data)
+        state, x, yA, _yB = qp_solve(fac, data, q[:rows], state,
+                                     max_iter=200, adaptive_rho=False,
+                                     polish=False)
+        outs[tag] = (np.asarray(x)[:2], np.asarray(yA)[:2])
+    for a, b in zip(outs["packed"], outs["dense"]):
+        np.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-3)
+
+
+def test_sslp_engine_agrees_dense_against_packed():
+    """One sslp_10_50 engine, the same scenarios, both forms reached by
+    shape: un-chunked on one device the fused df32 call holds all the
+    rows (dense); row-sharded over four devices each device call holds
+    a quarter (packed). Same recipe, the explicit inverse on in both,
+    the cell's budget-capped 400 + 100 ADMM iterations a solve: x-bar,
+    conv and the objectives agree to what f32 summation order and the
+    mesh's reduction order leave after three hot iterations."""
+    from mpisppy_tpu.core.ph import PHBase
+    from mpisppy_tpu.ir.batch import build_batch
+    from mpisppy_tpu.models import sslp
+    from mpisppy_tpu.parallel.mesh import make_mesh
+
+    S = _ENGINE_ROWS
+    opts = {"defaultPHrho": 1.0, "subproblem_precision": "df32",
+            "subproblem_max_iter": 400, "subproblem_tail_iter": 100,
+            "subproblem_eps": 1e-5, "subproblem_eps_hot": 1e-4,
+            "subproblem_eps_dua_hot": 1e-2,
+            "subproblem_stall_rel": 1.5e-3,
+            "subproblem_polish_hot": False, "subproblem_hospital": False}
+    out = {}
+    for tag, mesh in (("dense", None), ("packed", make_mesh(4))):
+        batch = build_batch(
+            sslp.scenario_creator, sslp.make_tree(S),
+            creator_kwargs=dict(num_servers=10, num_clients=50,
+                                overflow=True, server_budget=10,
+                                capacity=188.0, demand_is_revenue=True),
+            vector_patch=sslp.scenario_vector_patch)
+        ph = PHBase(batch, dict(opts), dtype=jnp.float64, mesh=mesh)
+        obj0 = np.asarray(ph.solve_loop(w_on=False, prox_on=False))[:S]
+        ph.W = ph.W_new
+        for _ in range(3):
+            obj = np.asarray(ph.solve_loop(w_on=True, prox_on=True))[:S]
+            ph.W = ph.W_new
+        pt = ph.phase_timing(True)
+        assert pt["kernel"]["l_inv"] and pt["admm_iters_per_call"][
+            "bulk"] == 400 and pt["admm_iters_per_call"]["tail"] == 100
+        shape = pt["solve_shape"]
+        assert shape["s_chunk"] == (S if mesh is None else S // 4)
+        assert (shape["pk_pass_bytes"] is None) == (tag == "dense")
+        if tag == "packed":
+            assert shape["pk_pass_bytes"] == 8 * _SSLP["elems"]
+        out[tag] = (np.asarray(ph.xbar)[:S], float(ph.conv), obj0, obj)
+    d, p = out["dense"], out["packed"]
+    np.testing.assert_allclose(p[0], d[0], atol=5e-3)
+    assert abs(p[1] - d[1]) < 1e-3
+    np.testing.assert_allclose(p[2], d[2], rtol=2e-3)
+    np.testing.assert_allclose(p[3], d[3], rtol=2e-3)
+
+
+def test_uc_fused_program_is_the_same_text_without_the_rule(monkeypatch):
+    """At a UC-structured operand set the rule answers "packed" and
+    nothing else changes: the fused df32 program lowered from an engine
+    built with the rule in the path is, character for character, the
+    one lowered with the rule taken out (every structure packed, as
+    before ISSUE 33)."""
+    import mpisppy_tpu.ops.kernels.reference as ref
+    import mpisppy_tpu.ops.packed as packed_mod
+    from mpisppy_tpu.core.ph import PHBase
+    from mpisppy_tpu.ir.batch import build_batch
+
+    opts = {"defaultPHrho": 100.0, "subproblem_precision": "df32",
+            "subproblem_max_iter": 50, "subproblem_eps": 1e-5,
+            "subproblem_tail_iter": 25, "subproblem_hospital": False,
+            "subproblem_chunk": 2, "iter0_feas_tol": 1.0}
+    fn = ref._fused_mixed_jit_donated
+    asked = []
+    rule = packed_mod.pack_profitable
+
+    def asking(*a):
+        asked.append((a, rule(*a)))
+        return asked[-1][1]
+
+    def lowered(patched_rule):
+        monkeypatch.setattr(packed_mod, "pack_profitable", patched_rule)
+        calls = {}
+
+        def record(*a, **kw):
+            calls.setdefault("args", (a, kw))
+            return fn(*a, **kw)
+        monkeypatch.setattr(ref, "_fused_mixed_jit_donated", record)
+        batch = build_batch(
+            uc.scenario_creator, uc.make_tree(4),
+            creator_kwargs=dict(num_gens=6, num_hours=8,
+                                min_up_down=True, ramping=True,
+                                relax_integrality=True),
+            vector_patch=uc.scenario_vector_patch)
+        ph = PHBase(batch, dict(opts), dtype=jnp.float64)
+        ph.solve_loop(w_on=False, prox_on=False)
+        a, kw = calls["args"]
+        assert a[0].A_s.pk_hi is not None
+        return fn.lower(*a, **kw).as_text()
+
+    with_rule = lowered(asking)
+    assert asked and all(verdict for _a, verdict in asked)
+    (m, n, _elems, rows), _ = asked[0]
+    assert rows == opts["subproblem_chunk"] and m > n > 100
+    assert lowered(lambda *a: True) == with_rule
