@@ -124,6 +124,13 @@ def _aph_update(xn, W, y, z, rho, prob, xbar, ybar, nu, gamma, iter1: bool):
     return W_new, z_new, tau, phi, theta, conv, phis, pusq, pvsq, pwsq, pzsq
 
 
+def _new_aph_times():
+    """``APH.phase_timing()["aph"]``: the iterations since the last
+    reset, the host seconds of their two spans and their gate reads."""
+    return {"iterations": 0, "project_seconds": 0.0, "gate_seconds": 0.0,
+            "gate_syncs": 0}
+
+
 class APH(PHBase):
     """Asynchronous Projective Hedging engine (ref. mpisppy/opt/aph.py:54).
 
@@ -158,6 +165,7 @@ class APH(PHBase):
         self.tau = self.phi = 0.0
         self._phi_stats = None   # gate φ-histogram row (analyze/aph)
         self._aph_status = None  # per-iteration record block (rec["aph"])
+        self._aph_times = _new_aph_times()
 
     # ---- dispatch selection (ref. aph.py:592-640 _dispatch_list) ----
     def _dispatch_mask(self, it, frac):
@@ -248,47 +256,64 @@ class APH(PHBase):
         self._last_dispatch[mask] = self._iter
         self._dispatched = mask
 
-    def _aph_iteration(self, it, spcomm):
-        """One APH iteration (ref. aph.py:704-815 APH_iterk): projective
-        step, the stacked gate, termination tests, dispatch and solve.
-        Returns False when a termination test ended the run."""
+    def iterate(self, it, spcomm=None):
+        """One APH iteration, the engine's own step (ref.
+        aph.py:704-815 APH_iterk): projective step (span
+        ``aph.project``), the stacked gate (``aph.gate``), termination
+        tests, dispatch and solve. Returns False when a termination
+        test ended the run. ``APH_main`` calls it for it = 1, 2, ...
+        after iter-0 and ``Update_W``; a driver that steps the engine
+        itself does the same."""
+        self._iter = it
         nu, gamma = self.nu, self.gamma
         S, S_real = self.batch.S, self._S_orig
-        xn = self.nonants_of(self.x)
-        # Update_y on the previously dispatched set (ref. aph.py:157-186;
-        # y ≡ 0 at iter 1 — "iter 1 is iter 0 post-solves")
-        if it > 1:
-            W_y = self._W_lag if self.use_lag else self.W
-            z_y = self._z_lag if self.use_lag else self.z
-            y_new = W_y + self.rho * (xn - z_y)
-            self.y_aph = jnp.where(jnp.asarray(self._dispatched)[:, None],
-                                   y_new, self.y_aph)
-        # FirstReduce + projective step, fused
-        xbar = self.compute_xbar(xn)
-        xsqbar = self.compute_xbar(xn * xn)
-        ybar = self.compute_xbar(self.y_aph)
-        (self.W, self.z, tau, phi, theta, conv, phis,
-         pusq, pvsq, pwsq, pzsq) = _aph_update(
-            xn, self.W, self.y_aph, self.z, self.rho, self.prob,
-            xbar, ybar, nu, gamma, iter1=(it == 1))
-        self.xbar, self.xsqbar, self.ybar = xbar, xsqbar, ybar
-        self.phis = phis   # stays on device; the gate ships stats
-        # dispatch & solve (frac forced to 1 at iter 1 "to get a decent
-        # w for everyone", ref. aph.py:783-786). Selection runs on
-        # device and rides the SAME packed gate as the projective
-        # scalars: the iteration's entire host traffic is one row.
-        frac = 1.0 if it == 1 else self.dispatch_frac
-        scnt = max(1, int(np.ceil(S_real * frac)))
-        full = scnt >= S_real
-        if full:
-            gate = scalar_gate(tau, phi, theta, conv, phis,
-                               S_real=S_real)
-        else:
-            gate = dispatch_gate(tau, phi, theta, conv, phis,
-                                 jnp.asarray(self._last_dispatch),
-                                 scnt=scnt, S_real=S_real)
-        # lint: ok[SYNC001] THE stacked APH gate: one D2H per iteration carries scalars + phi stats + dispatch mask (aph.gate_syncs)
-        g = np.asarray(gate)
+        times = self._aph_times
+        with obs.span("aph.project", cat="aph") as sp:
+            if self.use_lag and it == 1:
+                self._W_lag, self._z_lag = self.W, self.z
+            xn = self.nonants_of(self.x)
+            # Update_y on the previously dispatched set (ref.
+            # aph.py:157-186; y ≡ 0 at iter 1 — "iter 1 is iter 0
+            # post-solves")
+            if it > 1:
+                W_y = self._W_lag if self.use_lag else self.W
+                z_y = self._z_lag if self.use_lag else self.z
+                y_new = W_y + self.rho * (xn - z_y)
+                self.y_aph = jnp.where(
+                    jnp.asarray(self._dispatched)[:, None], y_new,
+                    self.y_aph)
+            # FirstReduce + projective step, fused
+            xbar = self.compute_xbar(xn)
+            xsqbar = self.compute_xbar(xn * xn)
+            ybar = self.compute_xbar(self.y_aph)
+            (self.W, self.z, tau, phi, theta, conv, phis,
+             pusq, pvsq, pwsq, pzsq) = _aph_update(
+                xn, self.W, self.y_aph, self.z, self.rho, self.prob,
+                xbar, ybar, nu, gamma, iter1=(it == 1))
+            self.xbar, self.xsqbar, self.ybar = xbar, xsqbar, ybar
+            self.phis = phis   # stays on device; the gate ships stats
+            # dispatch & solve (frac forced to 1 at iter 1 "to get a
+            # decent w for everyone", ref. aph.py:783-786). Selection
+            # runs on device and rides the SAME packed gate as the
+            # projective scalars: the iteration's entire host traffic
+            # is one row.
+            frac = 1.0 if it == 1 else self.dispatch_frac
+            scnt = max(1, int(np.ceil(S_real * frac)))
+            full = scnt >= S_real
+            if full:
+                gate = scalar_gate(tau, phi, theta, conv, phis,
+                                   S_real=S_real)
+            else:
+                gate = dispatch_gate(tau, phi, theta, conv, phis,
+                                     jnp.asarray(self._last_dispatch),
+                                     scnt=scnt, S_real=S_real)
+        times["project_seconds"] += sp.seconds
+        with obs.span("aph.gate", cat="aph") as sp:
+            # lint: ok[SYNC001] THE stacked APH gate: one D2H per iteration carries scalars + phi stats + dispatch mask (aph.gate_syncs)
+            g = np.asarray(gate)
+        times["gate_seconds"] += sp.seconds
+        times["gate_syncs"] += 1
+        times["iterations"] += 1
         obs.counter_add("aph.gate_syncs")
         (self.tau, self.phi, self.theta, self.conv,
          phi_min, phi_max, phi_neg) = g[:GATE_HEAD].tolist()
@@ -368,19 +393,15 @@ class APH(PHBase):
             self.converger = self.converger_cls(self)
         global_toc(f"APH iter 0: trivial bound = {self.trivial_bound:.4f}",
                    self.verbose)
-        if self.use_lag:
-            self._W_lag = self.W
-            self._z_lag = self.z
 
         for it in range(1, self.max_iterations + 1):
-            self._iter = it
             rec_on = obs.enabled()
             if rec_on:
                 pt0 = self._phase_totals()
                 ctr0 = obs.counters_snapshot()
             sp_args = {"iter": it} if rec_on else None
             with obs.span("ph.iteration", cat="ph", args=sp_args) as sp_it:
-                go_on = self._aph_iteration(it, spcomm)
+                go_on = self.iterate(it, spcomm)
             if not go_on:
                 break
             if rec_on:
@@ -397,6 +418,22 @@ class APH(PHBase):
     def post_loops(self):
         self._ext("post_everything")
         return self.conv, self.Eobjective_value(), self.trivial_bound
+
+    # ---- the APH seconds beside the solve loop's (no session) ----
+    def reset_phase_timing(self):
+        super().reset_phase_timing()
+        self._aph_times = _new_aph_times()
+
+    def phase_timing(self, key=True):
+        """``PHBase.phase_timing`` plus ``"aph"``: the engine's
+        iterations since the last reset, the host seconds of their
+        ``aph.project`` spans (the projective step's launches), of
+        their ``aph.gate`` spans (the ONE transfer, which waits for the
+        step on the device) and the gate reads (totals)."""
+        out = super().phase_timing(key)
+        if out is not None:
+            out["aph"] = dict(self._aph_times)
+        return out
 
     def _hub_nonants(self):
         return self.nonants_of(self.x)

@@ -64,6 +64,11 @@ def _new_phase_entry():
             "admm": {"bulk": 0, "tail": 0, "refactors": 0,
                      "linv_builds": 0},
             "collective": {"combines": 0, "bytes": 0},
+            # dispatch-masked passes of this mode (APH φ-dispatch):
+            # counted at the launch sites, timed by their two spans
+            "dispatch": {"passes": 0, "chunks": 0, "solved": 0,
+                         "skipped": 0, "gather_seconds": 0.0,
+                         "scatter_seconds": 0.0, "bucket_compiles": 0},
             "calls": 0, "gate_syncs": 0, "assemble_programs": 0,
             "devices": 1, "mode": "host"}
 
@@ -1976,14 +1981,14 @@ class PHBase(SPBase):
                 slices = [(jnp.asarray(ids_pad[i * chunk:(i + 1) * chunk]),
                            min(chunk, scnt - i * chunk))
                           for i in range(n_dchunks)]
-                dispatch_ops.register_bucket({
+                new_bucket = dispatch_ops.register_bucket({
                     "n_chunks": n_dchunks, "chunk": chunk,
                     "S": self.batch.S, "mode": _mode_str(key),
                     "shrink": None if shrink is None else shrink.bucket,
                     "stream": stream is not None})
+                skipped = max(self._S_orig - scnt, 0)
                 obs.counter_add("dispatch.solved_scenarios", scnt)
-                obs.counter_add("dispatch.skipped_scenarios",
-                                max(self._S_orig - scnt, 0))
+                obs.counter_add("dispatch.skipped_scenarios", skipped)
             per = self._per_scen_operands(data, shrink, c0fold,
                                           stream is not None)
 
@@ -2074,17 +2079,23 @@ class PHBase(SPBase):
                 self._chunk_donatable.discard(key)
         if dispatch is not None:
             from ..ops.dispatch import gather_rows
-            states = [dstore._replace(
-                x=gather_rows(dstore.x, idx),
-                yA=gather_rows(dstore.yA, idx),
-                yB=gather_rows(dstore.yB, idx),
-                zA=gather_rows(dstore.zA, idx),
-                zB=gather_rows(dstore.zB, idx),
-                pri_res=gather_rows(dstore.pri_res, idx),
-                dua_res=gather_rows(dstore.dua_res, idx),
-                pri_rel=gather_rows(dstore.pri_rel, idx),
-                dua_rel=gather_rows(dstore.dua_rel, idx))
-                for idx, _ in slices]
+            # the warm states' way in is assembly, as the mesh's
+            # restage is: a span of its own, booked with the pass's
+            # assemble seconds below
+            with obs.span("ph.dispatch.gather", cat="ph",
+                          args=sp_args) as sp:
+                states = [dstore._replace(
+                    x=gather_rows(dstore.x, idx),
+                    yA=gather_rows(dstore.yA, idx),
+                    yB=gather_rows(dstore.yB, idx),
+                    zA=gather_rows(dstore.zA, idx),
+                    zB=gather_rows(dstore.zB, idx),
+                    pri_res=gather_rows(dstore.pri_res, idx),
+                    dua_res=gather_rows(dstore.dua_res, idx),
+                    pri_rel=gather_rows(dstore.pri_rel, idx),
+                    dua_rel=gather_rows(dstore.dua_rel, idx))
+                    for idx, _ in slices]
+            restage_s = sp.seconds
         polish_chunk = int(self.options.get("subproblem_polish_chunk", 0))
         from ..ops.qp_solver import SplitMatrix
         split_mode = isinstance(factors.A_s, SplitMatrix)
@@ -2123,6 +2134,14 @@ class PHBase(SPBase):
         ent["mode"] = "sharded" if sharded else "host"
         ent["kernel"] = plan.descriptor()
         ent["shape"] = self._solve_shape(factors, plan, rows_per_call)
+        if dispatch is not None:
+            dent = ent["dispatch"]
+            dent["passes"] += 1
+            dent["chunks"] += n_dchunks
+            dent["solved"] += scnt
+            dent["skipped"] += skipped
+            dent["bucket_compiles"] += int(new_bucket)
+            dent["gather_seconds"] += restage_s
         gate_syncs = 0
         # device programs the assemble phase launches for pass 1: ONE
         # on a staged pass (the mesh launched it above), one
@@ -2565,31 +2584,38 @@ class PHBase(SPBase):
             # SCALED post-solve states (warm-start semantics); the
             # engine-facing x/yA/yB take the unscaled solutions.
             from ..ops.dispatch import scatter_rows
-            ids_dev = jnp.asarray(ids_pad)
-            cat = {k: jnp.concatenate(v) for k, v in parts.items()}
-            srows = {f: jnp.concatenate([getattr(s, f) for s in states])
-                     for f in ("x", "yA", "yB", "zA", "zB", "pri_res",
-                               "dua_res", "pri_rel", "dua_rel")}
-            last = states[-1]
-            new_store = dstore._replace(
-                L=last.L, rho_scale=last.rho_scale, iters=last.iters,
-                **{f: scatter_rows(getattr(dstore, f), ids_dev, srows[f])
-                   for f in srows})
-            self._qp_states[("dispatch", key)] = new_store
-            # the full-width store doubles as this mode's QPState for
-            # the read-only consumers (residual_summary, feasibility
-            # checks, warm-start transplants)
-            self._qp_states[key] = new_store
-            self.x = scatter_rows(self.x, ids_dev, cat["x"])
-            self.yA = scatter_rows(self.yA, ids_dev, cat["yA"])
-            self.yB = scatter_rows(self.yB, ids_dev, cat["yB"])
-            self._last_base_obj = scatter_rows(
-                jnp.asarray(self._last_base_obj), ids_dev, cat["base"])
-            self._last_solved_obj = scatter_rows(
-                jnp.asarray(self._last_solved_obj), ids_dev,
-                cat["solved"])
-            self._last_dual_obj = scatter_rows(
-                jnp.asarray(self._last_dual_obj), ids_dev, cat["dual"])
+            with obs.span("ph.dispatch.scatter", cat="ph",
+                          args=sp_args) as sp:
+                ids_dev = jnp.asarray(ids_pad)
+                cat = {k: jnp.concatenate(v) for k, v in parts.items()}
+                srows = {f: jnp.concatenate([getattr(s, f)
+                                             for s in states])
+                         for f in ("x", "yA", "yB", "zA", "zB", "pri_res",
+                                   "dua_res", "pri_rel", "dua_rel")}
+                last = states[-1]
+                new_store = dstore._replace(
+                    L=last.L, rho_scale=last.rho_scale, iters=last.iters,
+                    **{f: scatter_rows(getattr(dstore, f), ids_dev,
+                                       srows[f])
+                       for f in srows})
+                self._qp_states[("dispatch", key)] = new_store
+                # the full-width store doubles as this mode's QPState
+                # for the read-only consumers (residual_summary,
+                # feasibility checks, warm-start transplants)
+                self._qp_states[key] = new_store
+                self.x = scatter_rows(self.x, ids_dev, cat["x"])
+                self.yA = scatter_rows(self.yA, ids_dev, cat["yA"])
+                self.yB = scatter_rows(self.yB, ids_dev, cat["yB"])
+                self._last_base_obj = scatter_rows(
+                    jnp.asarray(self._last_base_obj), ids_dev,
+                    cat["base"])
+                self._last_solved_obj = scatter_rows(
+                    jnp.asarray(self._last_solved_obj), ids_dev,
+                    cat["solved"])
+                self._last_dual_obj = scatter_rows(
+                    jnp.asarray(self._last_dual_obj), ids_dev,
+                    cat["dual"])
+            ent["dispatch"]["scatter_seconds"] += sp.seconds
             clock.lap()
             self._ext("post_solve")
             return self._last_solved_obj
@@ -2695,6 +2721,15 @@ class PHBase(SPBase):
             # zeros on one device
             "collective": {k: v / n
                            for k, v in ent["collective"].items()},
+            # this mode's dispatch-masked passes since the last reset
+            # (totals: a mode's calls mix full and partial passes):
+            # how many, their chunk solves, the scenarios they solved
+            # and skipped, the host seconds of the warm states' way in
+            # (``ph.dispatch.gather``, part of the assemble seconds)
+            # and of the scatter-back (``ph.dispatch.scatter``, part of
+            # the reduce seconds), and the bucket registry's first
+            # sightings
+            "dispatch": dict(ent["dispatch"]),
             # what one solve call of the last pass streams: keyword
             # for keyword the facts a bytes-per-iteration model prices
             # (ops/kernels.est_hbm_bytes_per_iter)

@@ -108,10 +108,10 @@ GATE_HEAD = 7   # scalar head width of both gate spellings
 
 
 # dispatch-layout row ops: one gather per chunk (constant shapes — one
-# compile per mode) and one padded-width scatter per pass (shape keyed
-# by the bucket registry below). ``rows`` may repeat trailing ids (the
-# chunk-pad convention); duplicates carry bit-identical values, so the
-# scatter outcome is deterministic despite XLA's unordered scatter.
+# compile per mode) and one padded-width scatter-back per pass (shape
+# keyed by the bucket registry below). ``idx`` may repeat trailing ids
+# (the chunk-pad convention); duplicates carry bit-identical values, so
+# the outcome does not depend on which of them lands.
 
 @jax.jit
 def gather_rows(full, idx):
@@ -120,7 +120,19 @@ def gather_rows(full, idx):
 
 @jax.jit
 def scatter_rows(full, idx, rows):
-    return full.at[idx].set(rows)
+    """``full`` with ``rows`` at ``idx``, PLACED by a gather through
+    the inverse index and a row select, never a wide scatter: at UC
+    width the v5e compiler refuses ``full.at[idx].set(rows)`` on the
+    emulated-float64 (256, 26,016) store (the scatter's row window asks
+    for 20.7 MB of scoped VMEM against a limit of 16). Only the (S,)
+    inverse index and hit mask are scattered; every row of ``full`` is
+    rewritten, 53 MB at that width."""
+    S = full.shape[0]
+    pos = jnp.zeros(S, jnp.int32).at[idx].set(
+        jnp.arange(idx.shape[0], dtype=jnp.int32))
+    hit = jnp.zeros(S, bool).at[idx].set(True)
+    return jnp.where(hit.reshape((S,) + (1,) * (full.ndim - 1)),
+                     rows[pos], full)
 
 
 # serve-cache-style shape-bucket registry (module-level, process-global
@@ -142,15 +154,17 @@ def bucket_registry():
     return dict(_BUCKET_REGISTRY)
 
 
-def register_bucket(fields: dict) -> str:
+def register_bucket(fields: dict) -> bool:
     """Book one dispatch-shape bucket use: first sighting of a
     fingerprint is a compile (new scatter-back shapes reach XLA),
-    repeats are cache hits. Returns the fingerprint."""
+    repeats are cache hits. Returns whether this was the first
+    sighting."""
     fp = bucket_fingerprint(fields)
-    if fp in _BUCKET_REGISTRY:
-        _BUCKET_REGISTRY[fp]["hits"] += 1
-        obs.counter_add("dispatch.bucket.cache_hit")
-    else:
+    new = fp not in _BUCKET_REGISTRY
+    if new:
         _BUCKET_REGISTRY[fp] = {"fields": dict(fields), "hits": 0}
         obs.counter_add("dispatch.bucket.compile")
-    return fp
+    else:
+        _BUCKET_REGISTRY[fp]["hits"] += 1
+        obs.counter_add("dispatch.bucket.cache_hit")
+    return new

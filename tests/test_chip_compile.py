@@ -392,3 +392,48 @@ def test_unchunked_sslp_df32_solve_compiles_for_v5e(sslp_calls, one_chip,
     fn, args, kw = sslp_calls["make_l_inv"][0]
     inv = fn.lower(*_at_rows(args, rows, S, one_chip), **kw).compile()
     assert inv.memory_analysis().temp_size_in_bytes < 64e6
+
+
+# ---------------- the APH cell's own programs (ISSUE 34) ---------------
+
+def test_aph_step_and_dispatch_programs_compile_for_v5e(
+        one_chip, no_persistent_cache):
+    """``uc_s256_aph_hot``'s programs beside the chunk solve, at the
+    cell's widths and in float64 (x64 is on: the outer arithmetic is):
+    the projective step, the stacked gate whose selection SORTS 256
+    float64 φ (the v5e compiler takes the float64 key apart into a
+    (hi, lo) pair of f32 and sorts on both), the staging program at ONE
+    chunk of 64 ids, and the row store's gather and scatter."""
+    from mpisppy_tpu.core.aph import _aph_update
+    from mpisppy_tpu.core.ph import _ph_stage_chunks
+    from mpisppy_tpu.ops.dispatch import (dispatch_gate, gather_rows,
+                                          scatter_rows)
+    S, K, m, chunk = _UC["S"], _UC["K"], _UC["m"], _UC["chunk"]
+    f8 = lambda *sh: jax.ShapeDtypeStruct(sh, jnp.float64,
+                                          sharding=one_chip)
+    i4 = lambda *sh: jax.ShapeDtypeStruct(sh, jnp.int32, sharding=one_chip)
+    stamps = jax.ShapeDtypeStruct((S,), jnp.int64, sharding=one_chip)
+    gate = dispatch_gate.lower(f8(), f8(), f8(), f8(), f8(S), stamps,
+                               scnt=chunk, S_real=S).compile()
+    sorts = _hlo_lines(gate.as_text(), "sort")
+    assert len(sorts) == 3
+    assert [ln for ln in sorts if ln.count(f"f32[{S}]") >= 2], sorts
+    assert not [ln for ln in sorts if "f64[" in ln], sorts
+    step = _aph_update.lower(*(f8(S, K),) * 5, f8(S), f8(S, K), f8(S, K),
+                             1.0, 1.0, iter1=False).compile()
+    assert step.memory_analysis().temp_size_in_bytes < 0.2e9
+    per = _stage_operands(S, lambda nd: one_chip)
+    stage = _ph_stage_chunks.lower(per, i4(K), i4(1, chunk), w_on=True,
+                                   prox_on=True).compile()
+    mem = stage.memory_analysis()
+    assert mem.output_size_in_bytes + mem.temp_size_in_bytes < 0.2e9
+    gather_rows.lower(f8(S, m), i4(chunk)).compile()
+    # the scatter-back of the widest store field (zA / yA): as
+    # ``full.at[idx].set(rows)`` the compiler refused it (20.7 MB of
+    # scoped VMEM for the row window, limit 16)
+    ids = jax.ShapeDtypeStruct((chunk,), jnp.int64, sharding=one_chip)
+    back = scatter_rows.lower(f8(S, m), ids, f8(chunk, m)).compile()
+    wide = [ln for ln in _hlo_lines(back.as_text(), "scatter")
+            if f"[{S},{m}]" in ln]
+    assert not wide, wide
+    scatter_rows.lower(f8(S), ids, f8(chunk)).compile()
