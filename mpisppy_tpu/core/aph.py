@@ -76,6 +76,7 @@ import numpy as np
 from .. import global_toc, obs
 from ..ops.dispatch import GATE_HEAD, dispatch_gate, scalar_gate
 from .ph import PHBase
+from .spbase import compute_xbar
 
 
 def aph_theta_step(u, ybar, W, z, xbar, tau, phi, nu, gamma, iter1: bool):
@@ -124,11 +125,63 @@ def _aph_update(xn, W, y, z, rho, prob, xbar, ybar, nu, gamma, iter1: bool):
     return W_new, z_new, tau, phi, theta, conv, phis, pusq, pvsq, pwsq, pzsq
 
 
+@partial(jax.jit, static_argnames=("gate", "xbar_fn", "slot_slices", "iter1",
+                                   "full", "scnt", "S_real"))
+def _aph_step(x, W, z, y, lag, rho, prob, nidx, weights, tree, mask, stamps,
+              it, nu, gamma, *, gate, xbar_fn, slot_slices, iter1: bool,
+              full: bool, scnt: int, S_real: int):
+    """ONE program for everything an iteration does on the device before
+    its solve (42 eager launches at UC width and two uploads before
+    this, with an idle device behind each): the nonant gather, Update_y
+    on the previously dispatched set (``mask``; y ≡ 0 at iter 1), the
+    three FirstReduce means, ``_aph_update`` and the stacked gate row.
+    One data flow from (x, W, z, y, last pass's mask, the stamps) to
+    (W, z, x̄, x̄², ȳ, y, φ_s, the gate row) and to the twins the NEXT
+    call takes back: this pass's mask and its stamps.
+
+    ``lag``: None, or the lagged (W, z) the y-update reads under
+    ``aph_use_lag``. ``it``: a traced scalar, so no compile per
+    iteration (as ν and γ are, which ``_aph_update`` took so).
+    ``gate``: this module's ``scalar_gate`` when ``full`` (every real
+    row dispatches), else its ``dispatch_gate``, looked up by the
+    CALLER at every call: a static operand, so that a gate put in their
+    place (benchmarks/tests does) retraces. ``xbar_fn``: None for the
+    dense membership means (``tree`` = the memberships), else the
+    mesh's collective (``tree`` = its node indices;
+    parallel/mesh.ShardedScenarioOps.xbar_traced)."""
+    xn = x[..., nidx]
+    if not iter1:
+        # Update_y (ref. aph.py:157-186)
+        W_y, z_y = (W, z) if lag is None else lag
+        y = jnp.where(mask[:, None], W_y + rho * (xn - z_y), y)
+
+    def mean(v):
+        if xbar_fn is None:
+            return compute_xbar(tree, slot_slices, weights, v)
+        return xbar_fn(v, weights, *tree)
+
+    xbar, xsqbar, ybar = mean(xn), mean(xn * xn), mean(y)
+    W, z, tau, phi, theta, conv, phis = _aph_update(
+        xn, W, y, z, rho, prob, xbar, ybar, nu, gamma, iter1=iter1)[:7]
+    if full:
+        row = gate(tau, phi, theta, conv, phis, S_real=S_real)
+        mask = jnp.arange(phis.shape[0]) < S_real
+    else:
+        row = gate(tau, phi, theta, conv, phis, stamps, scnt=scnt,
+                   S_real=S_real)
+        mask = row[GATE_HEAD:] != 0
+    stamps = jnp.where(mask, jnp.asarray(it, stamps.dtype), stamps)
+    return W, z, xbar, xsqbar, ybar, y, phis, row, mask, stamps
+
+
 def _new_aph_times():
     """``APH.phase_timing()["aph"]``: the iterations since the last
-    reset, the host seconds of their two spans and their gate reads."""
+    reset, the host seconds of their two spans, their gate reads, the
+    device programs their ``aph.project`` spans launched (``_aph_step``:
+    one an iteration) and how often the step's device twins had to be
+    seeded from the host (``APH._device_twins``)."""
     return {"iterations": 0, "project_seconds": 0.0, "gate_seconds": 0.0,
-            "gate_syncs": 0}
+            "gate_syncs": 0, "project_programs": 0, "twin_seeds": 0}
 
 
 class APH(PHBase):
@@ -161,6 +214,9 @@ class APH(PHBase):
         self.phis = np.zeros(S)
         self._last_dispatch = np.zeros(S, np.int64)
         self._dispatched = np.ones(S, bool)   # iter 0 solves everyone
+        # (mask, stamps, their device twins): the two host arrays above
+        # as the step program last left them (see _device_twins)
+        self._twins = None
         self.theta = 0.0
         self.tau = self.phi = 0.0
         self._phi_stats = None   # gate φ-histogram row (analyze/aph)
@@ -233,7 +289,11 @@ class APH(PHBase):
                             dispatch=didx)
         finally:
             self.xbar, self.W = saved_xbar, saved_W
-        m = jnp.asarray(mask)[:, None]
+        if didx is None or self.use_lag:
+            # the step program's own mask where this pass dispatches it
+            tw = self._twins
+            m = (tw[2] if tw is not None and tw[0] is mask
+                 else jnp.asarray(mask))[:, None]
         if didx is None:
             # masked acceptance: all S solved, dispatched rows accepted
             obs.counter_add("dispatch.solved_scenarios", self._S_orig)
@@ -256,6 +316,22 @@ class APH(PHBase):
         self._last_dispatch[mask] = self._iter
         self._dispatched = mask
 
+    def _device_twins(self):
+        """Last pass's mask and the stamps ON THE DEVICE, for the step
+        program: what the previous step returned, as long as the host's
+        ``_dispatched`` / ``_last_dispatch`` still hold the values that
+        flowed beside them (``_twins`` keeps those). Anything else (a
+        fresh engine, ``install_aph_state``, a caller's assignment or
+        in-place edit, a pass whose mask the host overrode) seeds them
+        anew: two small uploads, booked as ``twin_seeds``."""
+        tw = self._twins
+        if tw is not None and np.array_equal(tw[0], self._dispatched) \
+                and np.array_equal(tw[1], self._last_dispatch):
+            return tw[2], tw[3]
+        self._aph_times["twin_seeds"] += 1
+        return (jnp.asarray(self._dispatched, bool),
+                jnp.asarray(self._last_dispatch))
+
     def iterate(self, it, spcomm=None):
         """One APH iteration, the engine's own step (ref.
         aph.py:704-815 APH_iterk): projective step (span
@@ -268,45 +344,35 @@ class APH(PHBase):
         nu, gamma = self.nu, self.gamma
         S, S_real = self.batch.S, self._S_orig
         times = self._aph_times
+        # dispatch & solve (frac forced to 1 at iter 1 "to get a decent w
+        # for everyone", ref. aph.py:783-786). Selection runs on device
+        # and rides the SAME packed gate as the projective scalars: the
+        # iteration's entire host traffic is one row.
+        frac = 1.0 if it == 1 else self.dispatch_frac
+        scnt = max(1, int(np.ceil(S_real * frac)))
+        full = scnt >= S_real
         with obs.span("aph.project", cat="aph") as sp:
             if self.use_lag and it == 1:
                 self._W_lag, self._z_lag = self.W, self.z
-            xn = self.nonants_of(self.x)
-            # Update_y on the previously dispatched set (ref.
-            # aph.py:157-186; y ≡ 0 at iter 1 — "iter 1 is iter 0
-            # post-solves")
-            if it > 1:
-                W_y = self._W_lag if self.use_lag else self.W
-                z_y = self._z_lag if self.use_lag else self.z
-                y_new = W_y + self.rho * (xn - z_y)
-                self.y_aph = jnp.where(
-                    jnp.asarray(self._dispatched)[:, None], y_new,
-                    self.y_aph)
-            # FirstReduce + projective step, fused
-            xbar = self.compute_xbar(xn)
-            xsqbar = self.compute_xbar(xn * xn)
-            ybar = self.compute_xbar(self.y_aph)
-            (self.W, self.z, tau, phi, theta, conv, phis,
-             pusq, pvsq, pwsq, pzsq) = _aph_update(
-                xn, self.W, self.y_aph, self.z, self.rho, self.prob,
-                xbar, ybar, nu, gamma, iter1=(it == 1))
-            self.xbar, self.xsqbar, self.ybar = xbar, xsqbar, ybar
-            self.phis = phis   # stays on device; the gate ships stats
-            # dispatch & solve (frac forced to 1 at iter 1 "to get a
-            # decent w for everyone", ref. aph.py:783-786). Selection
-            # runs on device and rides the SAME packed gate as the
-            # projective scalars: the iteration's entire host traffic
-            # is one row.
-            frac = 1.0 if it == 1 else self.dispatch_frac
-            scnt = max(1, int(np.ceil(S_real * frac)))
-            full = scnt >= S_real
-            if full:
-                gate = scalar_gate(tau, phi, theta, conv, phis,
-                                   S_real=S_real)
+            ops = self._shard_ops
+            if ops is None:
+                xbar_fn, tree = None, tuple(self.memberships)
             else:
-                gate = dispatch_gate(tau, phi, theta, conv, phis,
-                                     jnp.asarray(self._last_dispatch),
-                                     scnt=scnt, S_real=S_real)
+                xbar_fn, tree = ops.xbar_traced(self.xbar_weights.ndim,
+                                                self.dtype, calls=3)
+            mask_dev, stamps_dev = self._device_twins()
+            # Update_y + FirstReduce + projective step + gate, fused;
+            # phis stays on device, the gate ships its stats
+            (self.W, self.z, self.xbar, self.xsqbar, self.ybar,
+             self.y_aph, self.phis, gate, mask_dev, stamps_dev) = _aph_step(
+                self.x, self.W, self.z, self.y_aph,
+                (self._W_lag, self._z_lag) if self.use_lag else None,
+                self.rho, self.prob, self.nonant_idx, self.xbar_weights,
+                tree, mask_dev, stamps_dev, it, nu, gamma,
+                gate=scalar_gate if full else dispatch_gate,
+                xbar_fn=xbar_fn, slot_slices=self.slot_bounds,
+                iter1=(it == 1), full=full, scnt=scnt, S_real=S_real)
+            times["project_programs"] += 1
         times["project_seconds"] += sp.seconds
         with obs.span("aph.gate", cat="aph") as sp:
             # lint: ok[SYNC001] THE stacked APH gate: one D2H per iteration carries scalars + phi stats + dispatch mask (aph.gate_syncs)
@@ -357,10 +423,19 @@ class APH(PHBase):
             full = True
             mask = np.zeros(S, bool)
             mask[:S_real] = True
+            mask_dev = None     # the step's twins are not this pass's
         self._aph_shrink_bucket = cur_bucket
         didx = None
         if not full and self._dispatch_capable():
             didx = np.flatnonzero(mask)
+        if mask_dev is None:
+            self._twins = None
+        else:
+            # the step's mask and stamps beside the host values they
+            # equal once _aph_solve has written this pass's
+            stamps = self._last_dispatch.copy()
+            stamps[mask] = it
+            self._twins = (mask, stamps, mask_dev, stamps_dev)
         self._aph_solve(mask, didx=didx)
         self._aph_status = {
             "frac": frac, "scnt": scnt, "S_real": S_real,

@@ -68,7 +68,8 @@ def _new_phase_entry():
             # counted at the launch sites, timed by their two spans
             "dispatch": {"passes": 0, "chunks": 0, "solved": 0,
                          "skipped": 0, "gather_seconds": 0.0,
-                         "scatter_seconds": 0.0, "bucket_compiles": 0},
+                         "scatter_seconds": 0.0, "bucket_compiles": 0,
+                         "gather_programs": 0, "scatter_programs": 0},
             "calls": 0, "gate_syncs": 0, "assemble_programs": 0,
             "devices": 1, "mode": "host"}
 
@@ -157,6 +158,10 @@ def _ph_assemble(data, c, W, xbar, rho, idx, fixed_mask, fixed_vals,
 
 # per-scenario operands the assembly consumes and no later pass reads
 _STAGE_CONSUMED = ("xbar", "rho", "fm", "fv", "ws")
+# the QPState fields a dispatch pass's row store keeps per scenario: what
+# its gather takes out for a chunk and its placement puts back
+_STORE_ROWS = ("x", "yA", "yB", "zA", "zB", "pri_res", "dua_res",
+               "pri_rel", "dua_rel")
 
 
 def _stage_chunk(rows, idx, *, w_on, prox_on):
@@ -1741,9 +1746,7 @@ class PHBase(SPBase):
                      for s, r in zip(chunk_states, trims)])
 
             st = chunk_states[-1]._replace(
-                **{f: catf(f) for f in ("x", "yA", "yB", "zA", "zB",
-                                        "pri_res", "dua_res",
-                                        "pri_rel", "dua_rel")})
+                **{f: catf(f) for f in _STORE_ROWS})
         else:
             if stream is not None:
                 # one chunk-shaped block for the cold template (direct
@@ -1761,9 +1764,7 @@ class PHBase(SPBase):
                 return jnp.zeros((S,) + a.shape[1:], a.dtype)
 
             st = st0._replace(
-                **{f: zf(getattr(st0, f))
-                   for f in ("x", "yA", "yB", "zA", "zB", "pri_res",
-                             "dua_res", "pri_rel", "dua_rel")})
+                **{f: zf(getattr(st0, f)) for f in _STORE_ROWS})
         self._qp_states[dk] = st
         return st
 
@@ -1978,7 +1979,14 @@ class PHBase(SPBase):
                 pad_n = n_dchunks * chunk - scnt
                 ids_pad = np.concatenate(
                     [didx, np.full(pad_n, didx[-1])]) if pad_n else didx
-                slices = [(jnp.asarray(ids_pad[i * chunk:(i + 1) * chunk]),
+                # the pass's ONE id upload: the operand of the staging
+                # program, of the store's gather and of the placement
+                ids_stack = jnp.asarray(ids_pad.reshape(n_dchunks, chunk))
+                # a staged pass reads its rows by chunk number and keeps
+                # the host's ids; the per-chunk paths (a streamed
+                # source, the sequential opt-out) index by device ids
+                slices = [(ids_pad[i * chunk:(i + 1) * chunk] if stage_all
+                           else ids_stack[i],
                            min(chunk, scnt - i * chunk))
                           for i in range(n_dchunks)]
                 new_bucket = dispatch_ops.register_bucket({
@@ -2078,23 +2086,17 @@ class PHBase(SPBase):
                 # privatize them
                 self._chunk_donatable.discard(key)
         if dispatch is not None:
-            from ..ops.dispatch import gather_rows
+            from ..ops.dispatch import gather_chunks
             # the warm states' way in is assembly, as the mesh's
             # restage is: a span of its own, booked with the pass's
-            # assemble seconds below
+            # assemble seconds below. ONE program gathers every chunk's
+            # rows of every row field.
             with obs.span("ph.dispatch.gather", cat="ph",
                           args=sp_args) as sp:
-                states = [dstore._replace(
-                    x=gather_rows(dstore.x, idx),
-                    yA=gather_rows(dstore.yA, idx),
-                    yB=gather_rows(dstore.yB, idx),
-                    zA=gather_rows(dstore.zA, idx),
-                    zB=gather_rows(dstore.zB, idx),
-                    pri_res=gather_rows(dstore.pri_res, idx),
-                    dua_res=gather_rows(dstore.dua_res, idx),
-                    pri_rel=gather_rows(dstore.pri_rel, idx),
-                    dua_rel=gather_rows(dstore.dua_rel, idx))
-                    for idx, _ in slices]
+                states = [dstore._replace(**dict(zip(_STORE_ROWS, rows_c)))
+                          for rows_c in gather_chunks(
+                              tuple(getattr(dstore, f)
+                                    for f in _STORE_ROWS), ids_stack)]
             restage_s = sp.seconds
         polish_chunk = int(self.options.get("subproblem_polish_chunk", 0))
         from ..ops.qp_solver import SplitMatrix
@@ -2142,6 +2144,7 @@ class PHBase(SPBase):
             dent["skipped"] += skipped
             dent["bucket_compiles"] += int(new_bucket)
             dent["gather_seconds"] += restage_s
+            dent["gather_programs"] += 1
         gate_syncs = 0
         # device programs the assemble phase launches for pass 1: ONE
         # on a staged pass (the mesh launched it above), one
@@ -2207,10 +2210,11 @@ class PHBase(SPBase):
         if stream is not None:
             stream.begin_pass()
         elif stage_all and not sharded:
-            # the ids are an operand: one H2D on a dispatch pass, whose
-            # set changes every iteration; cached on the device else
-            ids_stack = self._chunk_ids(chunk) if dispatch is None \
-                else jnp.asarray(ids_pad.reshape(n_dchunks, chunk))
+            # the ids are an operand: cached on the device for the
+            # full pass; a dispatch pass, whose set changes every
+            # iteration, made its one upload above
+            if dispatch is None:
+                ids_stack = self._chunk_ids(chunk)
             staged = _ph_stage_chunks(per, idx_asm, ids_stack, **stage_kw)
             asm_programs += 1
         clock.lap("solve")
@@ -2551,18 +2555,14 @@ class PHBase(SPBase):
                 xn, base, solved, dual = _ph_chunk_objs(
                     x, yA, yB, d_h, q_h, c_c, c0_c, P0_c,
                     self.nonant_idx, W_c, w_on=bool(w_on))
-            if dispatch is not None:
-                # keep the pad rows: the scatter-back writes the PADDED
-                # width (duplicate ids carry identical values, so the
-                # unordered scatter is still deterministic) — trimming
-                # would make the scatter shape vary per scnt instead of
-                # per chunk-count bucket
-                real = x.shape[0]
-            for k, v in (("x", x[:real]), ("yA", yA[:real]),
-                         ("yB", yB[:real]), ("xn", xn[:real]),
-                         ("base", base[:real]), ("solved", solved[:real]),
-                         ("dual", dual[:real])):
-                parts[k].append(v)
+            # a dispatch pass keeps the pad rows: the placement writes
+            # the PADDED width (duplicate ids carry identical values, so
+            # which of them lands does not matter); trimming would make
+            # its shape vary per scnt instead of per chunk-count bucket
+            for k, v in (("x", x), ("yA", yA), ("yB", yB), ("xn", xn),
+                         ("base", base), ("solved", solved),
+                         ("dual", dual)):
+                parts[k].append(v if dispatch is not None else v[:real])
         if split_mode and prev_st is not None:
             # UNIFY after the pass: every chunk state adopts the flow's
             # final (rho_scale, factor) so exactly ONE (n, n) factor
@@ -2583,38 +2583,33 @@ class PHBase(SPBase):
             # staleness contract, doc/aph.md). Store rows take the
             # SCALED post-solve states (warm-start semantics); the
             # engine-facing x/yA/yB take the unscaled solutions.
-            from ..ops.dispatch import scatter_rows
+            # ONE program places all fifteen fields.
+            from ..ops.dispatch import place_chunks
             with obs.span("ph.dispatch.scatter", cat="ph",
                           args=sp_args) as sp:
-                ids_dev = jnp.asarray(ids_pad)
-                cat = {k: jnp.concatenate(v) for k, v in parts.items()}
-                srows = {f: jnp.concatenate([getattr(s, f)
-                                             for s in states])
-                         for f in ("x", "yA", "yB", "zA", "zB", "pri_res",
-                                   "dua_res", "pri_rel", "dua_rel")}
+                placed = place_chunks(
+                    tuple(getattr(dstore, f) for f in _STORE_ROWS)
+                    + (self.x, self.yA, self.yB, self._last_base_obj,
+                       self._last_solved_obj, self._last_dual_obj),
+                    ids_stack,
+                    tuple(tuple(getattr(st, f) for st in states)
+                          for f in _STORE_ROWS)
+                    + tuple(tuple(parts[k]) for k in (
+                        "x", "yA", "yB", "base", "solved", "dual")))
                 last = states[-1]
+                n_rows = len(_STORE_ROWS)
                 new_store = dstore._replace(
                     L=last.L, rho_scale=last.rho_scale, iters=last.iters,
-                    **{f: scatter_rows(getattr(dstore, f), ids_dev,
-                                       srows[f])
-                       for f in srows})
+                    **dict(zip(_STORE_ROWS, placed[:n_rows])))
                 self._qp_states[("dispatch", key)] = new_store
                 # the full-width store doubles as this mode's QPState
                 # for the read-only consumers (residual_summary,
                 # feasibility checks, warm-start transplants)
                 self._qp_states[key] = new_store
-                self.x = scatter_rows(self.x, ids_dev, cat["x"])
-                self.yA = scatter_rows(self.yA, ids_dev, cat["yA"])
-                self.yB = scatter_rows(self.yB, ids_dev, cat["yB"])
-                self._last_base_obj = scatter_rows(
-                    jnp.asarray(self._last_base_obj), ids_dev,
-                    cat["base"])
-                self._last_solved_obj = scatter_rows(
-                    jnp.asarray(self._last_solved_obj), ids_dev,
-                    cat["solved"])
-                self._last_dual_obj = scatter_rows(
-                    jnp.asarray(self._last_dual_obj), ids_dev,
-                    cat["dual"])
+                (self.x, self.yA, self.yB, self._last_base_obj,
+                 self._last_solved_obj, self._last_dual_obj) = \
+                    placed[n_rows:]
+            ent["dispatch"]["scatter_programs"] += 1
             ent["dispatch"]["scatter_seconds"] += sp.seconds
             clock.lap()
             self._ext("post_solve")
@@ -2727,8 +2722,9 @@ class PHBase(SPBase):
             # and skipped, the host seconds of the warm states' way in
             # (``ph.dispatch.gather``, part of the assemble seconds)
             # and of the scatter-back (``ph.dispatch.scatter``, part of
-            # the reduce seconds), and the bucket registry's first
-            # sightings
+            # the reduce seconds), the device programs launched at
+            # those two sites (one each a pass), and the bucket
+            # registry's first sightings
             "dispatch": dict(ent["dispatch"]),
             # what one solve call of the last pass streams: keyword
             # for keyword the facts a bytes-per-iteration model prices

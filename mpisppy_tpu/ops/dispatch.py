@@ -36,6 +36,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from .. import obs
 from ..ckpt.bundle import config_fingerprint
@@ -107,15 +108,44 @@ def scalar_gate(tau, phi, theta, conv, phis, S_real: int):
 GATE_HEAD = 7   # scalar head width of both gate spellings
 
 
-# dispatch-layout row ops: one gather per chunk (constant shapes — one
-# compile per mode) and one padded-width scatter-back per pass (shape
-# keyed by the bucket registry below). ``idx`` may repeat trailing ids
-# (the chunk-pad convention); duplicates carry bit-identical values, so
-# the outcome does not depend on which of them lands.
+# dispatch-layout row ops. A dispatch pass launches ONE program each
+# way around its chunk solves (constant shapes per chunk COUNT, keyed by
+# the bucket registry below): ``gather_chunks`` on the way in,
+# ``place_chunks`` on the way back. ``gather_rows`` / ``scatter_rows``
+# are the same expressions for one field, the spelling the many-field
+# programs are checked against. Ids may repeat trailing ids (the
+# chunk-pad convention); duplicates carry bit-identical values, so the
+# outcome does not depend on which of them lands.
 
 @jax.jit
 def gather_rows(full, idx):
     return full[idx]
+
+
+@jax.jit
+def gather_chunks(fields, ids):
+    """Every chunk's rows of every field in one program: ``fields`` a
+    tuple of (S, ·) arrays, ``ids`` the chunks' scenario ids stacked
+    (n_chunks, chunk), an OPERAND. Returns a tuple over chunks of tuples
+    over fields, which Python unpacks with no further launch."""
+    return tuple(tuple(f[ids[ci]] for f in fields)
+                 for ci in range(ids.shape[0]))
+
+
+def _inverse_index(S, idx):
+    """Where row s of an (S, ·) array comes from in the placed rows (0
+    where it does not), and whether it does: the only scatters of a
+    placement, each (S,) wide."""
+    pos = jnp.zeros(S, jnp.int32).at[idx].set(
+        jnp.arange(idx.shape[0], dtype=jnp.int32))
+    hit = jnp.zeros(S, bool).at[idx].set(True)
+    return pos, hit
+
+
+def _place(full, rows, pos, hit):
+    S = full.shape[0]
+    return jnp.where(hit.reshape((S,) + (1,) * (full.ndim - 1)),
+                     rows[pos], full)
 
 
 @jax.jit
@@ -127,12 +157,38 @@ def scatter_rows(full, idx, rows):
     for 20.7 MB of scoped VMEM against a limit of 16). Only the (S,)
     inverse index and hit mask are scattered; every row of ``full`` is
     rewritten, 53 MB at that width."""
-    S = full.shape[0]
-    pos = jnp.zeros(S, jnp.int32).at[idx].set(
-        jnp.arange(idx.shape[0], dtype=jnp.int32))
-    hit = jnp.zeros(S, bool).at[idx].set(True)
-    return jnp.where(hit.reshape((S,) + (1,) * (full.ndim - 1)),
-                     rows[pos], full)
+    return _place(full, rows, *_inverse_index(full.shape[0], idx))
+
+
+@jax.jit
+def place_chunks(fulls, ids, rows):
+    """A dispatch pass's whole way back in one program: ``fulls`` a
+    tuple of (S, ·) arrays, ``rows`` per field the tuple of its solved
+    chunks' rows (concatenated here), ``ids`` as ``gather_chunks``
+    takes them. The inverse index and the hit mask are built ONCE and
+    every field is placed as ``scatter_rows`` places one. Nothing is
+    donated: the engine's callers may hold any of ``fulls``
+    (benchmarks/drivers/aph_hot keeps x, yA, yB and each pass's
+    pri_rel), and the store's flowed factor never enters.
+
+    The fields go ONE AFTER ANOTHER, widest first, each tied to the one
+    before by an ``optimization_barrier``: left free, the v5e compiler
+    interleaves all fifteen, their float64 halves outgrow the on-chip
+    memory a single field's fit in, and the program takes 4.5 ms at UC
+    width where fifteen programs took 2.0 between them; in this order
+    it takes 3.0 (chip microbenchmark, PR 35; the rest is the halves'
+    recombination, which the compiler keeps for the program's end)."""
+    pos, hit = _inverse_index(fulls[0].shape[0], ids.reshape(-1))
+    out = [None] * len(fulls)
+    prev = None
+    for k in sorted(range(len(fulls)), key=lambda k: -fulls[k].size):
+        full, parts = fulls[k], rows[k]
+        if prev is not None:
+            (full, parts), out[prev] = lax.optimization_barrier(
+                ((full, parts), out[prev]))
+        out[k] = _place(full, jnp.concatenate(parts), pos, hit)
+        prev = k
+    return tuple(out)
 
 
 # serve-cache-style shape-bucket registry (module-level, process-global

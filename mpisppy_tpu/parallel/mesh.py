@@ -227,6 +227,16 @@ class ShardedScenarioOps:
         fn = self._combine_fn(int(weights.ndim), False, full=False)
         return fn(xn, weights, *self.node_idx)
 
+    def xbar_traced(self, w_ndim, dtype, calls):
+        """``xbar`` for a caller that traces it inside a program of its
+        own (core/aph._aph_step): ``(fn, node_idx)`` with
+        ``fn(xn, weights, *node_idx)`` the same collective, booked here
+        for the ``calls`` means one launch of that program runs."""
+        for _ in range(calls):
+            self._book_collective(dtype, full=False)
+        return (self._combine_fn(int(w_ndim), False, full=False),
+                tuple(self.node_idx))
+
     def combine(self, xn, prob, weights, W, rho, wmask):
         """Collective _ph_combine: (xbar, xsqbar, W_new, conv)."""
         self._book_collective(xn.dtype, full=True)

@@ -18,6 +18,7 @@ for a described chip cannot be read back without one.
 
 import os
 import re
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -398,12 +399,15 @@ def test_unchunked_sslp_df32_solve_compiles_for_v5e(sslp_calls, one_chip,
 
 def test_aph_step_and_dispatch_programs_compile_for_v5e(
         one_chip, no_persistent_cache):
-    """``uc_s256_aph_hot``'s programs beside the chunk solve, at the
-    cell's widths and in float64 (x64 is on: the outer arithmetic is):
-    the projective step, the stacked gate whose selection SORTS 256
-    float64 φ (the v5e compiler takes the float64 key apart into a
-    (hi, lo) pair of f32 and sorts on both), the staging program at ONE
-    chunk of 64 ids, and the row store's gather and scatter."""
+    """The pieces of ``uc_s256_aph_hot``'s pass beside the chunk solve,
+    each as a program of its own (as the cell ran them until ISSUE 35,
+    and as the tests still compare the one-program forms below with),
+    at the cell's widths and in float64 (x64 is on: the outer
+    arithmetic is): the projective update, the stacked gate whose
+    selection SORTS 256 float64 φ (the v5e compiler takes the float64
+    key apart into a (hi, lo) pair of f32 and sorts on both), the
+    staging program at ONE chunk of 64 ids, and one field's gather and
+    placement."""
     from mpisppy_tpu.core.aph import _aph_update
     from mpisppy_tpu.core.ph import _ph_stage_chunks
     from mpisppy_tpu.ops.dispatch import (dispatch_gate, gather_rows,
@@ -437,3 +441,45 @@ def test_aph_step_and_dispatch_programs_compile_for_v5e(
             if f"[{S},{m}]" in ln]
     assert not wide, wide
     scatter_rows.lower(f8(S), ids, f8(chunk)).compile()
+
+
+def test_one_program_each_way_compiles_for_v5e(one_chip,
+                                               no_persistent_cache):
+    """ISSUE 35's three programs at ``uc_s256_aph_hot``'s widths: the
+    step (gather, y-update, three means, ``_aph_update``, the sorting
+    gate and the next stamps in one), the store's gather at ONE chunk
+    of 64 ids, and the placement of all fifteen fields, which must stay
+    under the scoped-VMEM limit that refused the wide scatter of the
+    (256, 26,016) float64 store in PR 34: every scatter it holds is
+    (S,) wide."""
+    from mpisppy_tpu.core.aph import _aph_step
+    from mpisppy_tpu.ops.dispatch import (dispatch_gate, gather_chunks,
+                                          place_chunks)
+    S, n, m, K, chunk = (_UC[k] for k in ("S", "n", "m", "K", "chunk"))
+    sds = lambda dt, *sh: jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
+    f8 = partial(sds, jnp.float64)
+    i4 = partial(sds, jnp.int32)
+    step = _aph_step.lower(
+        f8(S, n), f8(S, K), f8(S, K), f8(S, K), None, f8(S, K), f8(S),
+        i4(K), f8(S), (f8(S, 1),), sds(jnp.bool_, S), sds(jnp.int64, S),
+        7, 1.0, 1.0, gate=dispatch_gate, xbar_fn=None,
+        slot_slices=((0, K),), iter1=False, full=False, scnt=chunk,
+        S_real=S).compile()
+    mem = step.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.3e9
+    assert len(_hlo_lines(step.as_text(), "sort")) == 3
+    ids = sds(jnp.int64, 1, chunk)
+    store = (f8(S, n), f8(S, m), f8(S, n), f8(S, m), f8(S, n),
+             f8(S), f8(S), f8(S), f8(S))
+    gather_chunks.lower(store, ids).compile()
+    fulls = store + (f8(S, n), f8(S, m), f8(S, n), f8(S), f8(S), f8(S))
+    rows = tuple((f8(chunk, *f.shape[1:]),) for f in fulls)
+    back = place_chunks.lower(fulls, ids, rows).compile()
+    scatters = _hlo_lines(back.as_text(), "scatter")
+    assert not [ln for ln in scatters if f"[{S},{m}]" in ln
+                or f"[{S},{n}]" in ln], scatters
+    mem = back.memory_analysis()
+    # all fifteen results at once (the store's 0.19 GB, the engine's
+    # 0.11 GB) and less than that again in temporaries
+    assert mem.output_size_in_bytes < 0.35e9
+    assert mem.temp_size_in_bytes < 0.2e9

@@ -27,7 +27,9 @@ from mpisppy_tpu.ir.batch import build_batch
 from mpisppy_tpu.models import farmer
 from mpisppy_tpu.ops import dispatch as dispatch_ops
 from mpisppy_tpu.ops.dispatch import (GATE_HEAD, dispatch_gate,
-                                      dispatch_select, scalar_gate)
+                                      dispatch_select, gather_chunks,
+                                      gather_rows, place_chunks,
+                                      scalar_gate, scatter_rows)
 from mpisppy_tpu.parallel.mesh import make_mesh
 
 EF3 = -108390.0
@@ -115,6 +117,52 @@ def test_gate_packing_layout():
     assert ((g[GATE_HEAD:] != 0) == want).all()
     s = np.asarray(scalar_gate(1.5, -0.25, 0.75, 2.0, phis, S_real=4))
     assert s.tolist() == g[:GATE_HEAD].tolist()
+
+
+# ---------------- one program each way (ISSUE 35) ----------------
+
+@pytest.mark.parametrize("n_chunks", [1, 3])
+def test_one_program_each_way_is_the_row_ops_bit_for_bit(n_chunks):
+    """``gather_chunks`` is nine ``gather_rows`` a chunk and
+    ``place_chunks`` fifteen ``scatter_rows`` (the inverse index built
+    once instead of fifteen times), bit for bit, with a padded last
+    chunk (its trailing id repeated, the repeats carrying identical
+    rows); rows the ids do not name come through untouched."""
+    rng = np.random.default_rng(35 + n_chunks)
+    S, chunk, n, m = 13, 4, 7, 9
+    real = n_chunks * chunk - 2
+    ids_real = np.sort(rng.choice(S, real, replace=False))
+    ids = np.concatenate([ids_real, np.full(2, ids_real[-1])])
+    ids_stack = jnp.asarray(ids.reshape(n_chunks, chunk))
+    widths = [(n,), (m,), (n,), (m,), (n,), (), (), (), ()]   # the store
+    store = tuple(jnp.asarray(rng.standard_normal((S,) + w))
+                  for w in widths)
+    chunks = gather_chunks(store, ids_stack)
+    assert len(chunks) == n_chunks and all(len(c) == 9 for c in chunks)
+    for ci, got in enumerate(chunks):
+        for f, g in zip(store, got):
+            np.testing.assert_array_equal(
+                np.asarray(g), np.asarray(gather_rows(f, ids_stack[ci])))
+    # the way back: the store's nine and the engine's six, (S, ·) each
+    fulls = store + tuple(jnp.asarray(rng.standard_normal((S,) + w))
+                          for w in [(n,), (m,), (n,), (), (), ()])
+    rows = []
+    for full in fulls:
+        solved = rng.standard_normal((n_chunks * chunk,) + full.shape[1:])
+        solved[real:] = solved[real - 1]        # pads repeat the last row
+        rows.append(tuple(jnp.asarray(solved[c * chunk:(c + 1) * chunk])
+                          for c in range(n_chunks)))
+    placed = place_chunks(fulls, ids_stack, tuple(rows))
+    assert len(placed) == 15
+    untouched = np.setdiff1d(np.arange(S), ids_real)
+    assert untouched.size
+    for full, parts, got in zip(fulls, rows, placed):
+        want = scatter_rows(full, jnp.asarray(ids), jnp.concatenate(parts))
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        np.testing.assert_array_equal(np.asarray(got)[untouched],
+                                      np.asarray(full)[untouched])
+        np.testing.assert_array_equal(np.asarray(got)[ids_real],
+                                      np.concatenate(parts)[:real])
 
 
 # ---------------- the dispatch-masked chunked loop ----------------
