@@ -44,7 +44,8 @@ from ..ir.batch import ScenarioBatch
 from ..ops.qp_solver import (LInv, QPData, QPState, qp_setup, qp_solve,
                              qp_solve_mixed, qp_solve_segmented,
                              qp_cold_state, qp_dual_objective,
-                             qp_reset_rho, stacked_residuals)
+                             qp_reset_rho, stacked_residuals, packed_exit,
+                             EXIT_ROWS)
 from .spbase import SPBase, compute_xbar
 
 _log = logging.getLogger("mpisppy_tpu.ph")
@@ -63,6 +64,15 @@ def _new_phase_entry():
                     "reduce": 0.0},
             "admm": {"bulk": 0, "tail": 0, "refactors": 0,
                      "linv_builds": 0},
+            # how the same solves ENDED (``_book_exits``)
+            "exits": {"solves": 0, "bulk_hist": {}, "tail_hist": {},
+                      "bulk_capped": 0, "tail_capped": 0, "rows_read": 0,
+                      "rows_over": 0, "rows_over_pri_only": 0,
+                      "rows_over_dua_only": 0, "rows_over_both": 0,
+                      "worst_pri": 0.0, "worst_dua": 0.0,
+                      "rows_over_uncapped": 0, "rows_over_gate": 0,
+                      "capped_calls": 0, "capped_solve_seconds": 0.0,
+                      "per_call": [], "last": None, "tally": None},
             "collective": {"combines": 0, "bytes": 0},
             # dispatch-masked passes of this mode (APH φ-dispatch):
             # counted at the launch sites, timed by their two spans
@@ -311,6 +321,26 @@ def _hot_eps(prox_on, sub_eps, sub_eps_hot):
     return sub_eps_hot if (prox_on and sub_eps_hot is not None) else sub_eps
 
 
+def _exit_tests(*, prox_on, precision, sub_max_iter, sub_eps, sub_eps_hot,
+                sub_eps_dua_hot, tail_iter, **_):
+    """What ends one ``_solver_call``'s ADMM loops, from the call's own
+    keyword values: ``(e_pri, e_dua, bulk budget, tail budget)``. The
+    tolerances are the ones the solvers take as ``eps_abs = eps_rel``
+    (primal) and ``eps_abs_dua = eps_rel_dua``; a mixed / df32 solve
+    gives its f32 bulk ``sub_max_iter`` iterations and its accurate
+    tail ``tail_iter``, any other precision has no bulk phase and its
+    one loop (booked as tail: ``QPState.iters_lo`` is 0) has
+    ``sub_max_iter``. ``_solver_call`` derives its tolerances HERE, so
+    the exit booking (``_book_exits``) tests rows against what the loop
+    tested them against."""
+    e_pri = _hot_eps(prox_on, sub_eps, sub_eps_hot)
+    e_dua = sub_eps_dua_hot if (prox_on and sub_eps_dua_hot is not None) \
+        else sub_eps
+    if precision in ("mixed", "df32"):
+        return e_pri, e_dua, int(sub_max_iter), int(tail_iter)
+    return e_pri, e_dua, 0, int(sub_max_iter)
+
+
 def _solver_call(factors, d, q, qp_state, *, prox_on, precision,
                  sub_max_iter, sub_eps, sub_eps_hot, sub_eps_dua_hot,
                  tail_iter, stall_rel, segment, polish_hot, polish_chunk,
@@ -343,9 +373,10 @@ def _solver_call(factors, d, q, qp_state, *, prox_on, precision,
     — a pool's infeasible members contaminate the shared scalar and
     the feasible candidates mis-converge (measured 13% objective
     inflation on the UC fixture; doc/incumbents.md)."""
-    e_pri = _hot_eps(prox_on, sub_eps, sub_eps_hot)
-    e_dua = sub_eps_dua_hot if (prox_on and sub_eps_dua_hot is not None) \
-        else sub_eps
+    e_pri, e_dua, _, _ = _exit_tests(
+        prox_on=prox_on, precision=precision, sub_max_iter=sub_max_iter,
+        sub_eps=sub_eps, sub_eps_hot=sub_eps_hot,
+        sub_eps_dua_hot=sub_eps_dua_hot, tail_iter=tail_iter)
     do_polish = polish_hot or not prox_on
     if kernel is not None and kernel.mode == "fused":
         from ..ops import kernels as _kernels
@@ -391,7 +422,7 @@ def _linv_wraps(plan, st):
     return int(not isinstance(st.L, LInv))
 
 
-def _book_admm_iters(admm, states, fused, linv_wraps=None):
+def _book_admm_iters(admm, states, fused, linv_wraps=None, packed=None):
     """Book the ADMM iterations of the solves that produced ``states``
     into ``admm`` (the "admm" dict of a mode's ``_phase_times`` entry):
     ``bulk`` = the low-precision phase's (``QPState.iters_lo``),
@@ -408,10 +439,26 @@ def _book_admm_iters(admm, states, fused, linv_wraps=None):
     fused plans' callers sit AFTER the phase-honesty block they pay
     anyway (scalar copies, not stalls), and the segmented drivers hand
     back HOST scalars (they read their counts segment by segment), for
-    which ``device_get`` is the identity."""
-    its = jax.device_get([(st.iters, st.iters_lo, st.refactors)
-                          for st in states])
-    total, bulk, refs = (sum(int(v) for v in col) for col in zip(*its))
+    which ``device_get`` is the identity.
+
+    Returns ``(its, res)`` for ``_book_exits``: the per-solve
+    ``(total, bulk)`` counts, kept apart, and, with ``packed`` (the
+    un-chunked fused body, which has no gate to read them at: each
+    state's ``qp_solver.packed_exit`` vector, already waited for), the
+    states' ``EXIT_ROWS`` as one (4, solves, width) host array: counts
+    and rows then come in ONE transfer a state where the counts alone
+    were three; None otherwise."""
+    if packed is not None:
+        got = jax.device_get(packed)
+        res = np.stack([g[3:].reshape(len(EXIT_ROWS), -1) for g in got],
+                       axis=1)
+    else:
+        got = jax.device_get([(st.iters, st.iters_lo, st.refactors)
+                              for st in states])
+        res = None
+    its = [(int(g[0]), int(g[1])) for g in got]
+    total, bulk = (sum(col) for col in zip(*its))
+    refs = sum(int(g[2]) for g in got)
     admm["bulk"] += bulk
     admm["tail"] += total - bulk
     admm["refactors"] += refs
@@ -423,6 +470,144 @@ def _book_admm_iters(admm, states, fused, linv_wraps=None):
         obs.counter_add("kernel.factor_prepares", refs)
         if fused:
             obs.counter_add("kernel.fused_iters", total)
+    return its, res
+
+
+_PER_CALL_KEPT = 256    # calls whose exit record ``exits["per_call"]`` keeps
+
+
+def _rows_over(res, e_pri, e_dua):
+    """The loop's per-row exit test (``qp_solver._solve_impl``:
+    ``conv_ok = (pri <= eps_abs + eps_rel * pri_sc) & (dua <=
+    eps_abs_dua + eps_rel_dua * dua_sc)``, ``eps_abs = eps_rel``)
+    recomputed in host float64 from a returned state's ``EXIT_ROWS``
+    (``res``: pri_rel, pri_res, dua_res, dua_rel, any common shape).
+    The scales are recovered as residual / relative residual (a zero
+    residual passes under any scale). Returns ``(over_pri, over_dua,
+    pri_res / its tolerance, dua_res / its tolerance)``; a NaN row is
+    over by both tests."""
+    pri_rel, pri, dua, dua_rel = res
+    with np.errstate(divide="ignore", invalid="ignore"):
+        pri_tol = e_pri + e_pri * np.where(pri_rel > 0, pri / pri_rel, 0.0)
+        dua_tol = e_dua + e_dua * np.where(dua_rel > 0, dua / dua_rel, 0.0)
+        return (~(pri <= pri_tol), ~(dua <= dua_tol),
+                pri / pri_tol, dua / dua_tol)
+
+
+def _book_exits(ex, its, tests, solve_s, res=None, ids=None, live=None):
+    """Book how the chunk solves of ONE ``solve_loop`` call ended into
+    ``ex`` (the "exits" dict of the mode's ``_phase_times`` entry,
+    reset with the seconds; ``_exits_view`` documents every field).
+    ``its``: ``_book_admm_iters``' per-solve (total, bulk)
+    counts; ``tests``: ``_exit_tests`` of the keyword values
+    ``_solver_call`` was handed; ``solve_s``: the ``ph.solve`` span's
+    seconds of this call.
+
+    A phase is CAPPED when it ran its whole budget. At a tail-capped
+    exit the loop left through ``it < max_iter``, not through
+    ``done = all(conv_ok | stalled)``, so some row still failed
+    ``conv_ok``: with ``res`` ((4, solves, width) host array of the
+    returned states' ``EXIT_ROWS``) the rows are classified by the
+    loop's OWN test on the loop's own last residuals (``_rows_over``;
+    with no polish, the returned residuals are computed on the final
+    ADMM iterates). ``ids`` / ``live`` ((solves,
+    width)): each row's GLOBAL scenario id, and whether it counts
+    (chunk pads and zero-probability mesh pads do not). At an UNCAPPED
+    exit the rows still over are the ones the stall rule let go
+    (``rows_over_uncapped``). Host numpy over (solves, width):
+    microseconds. Returns the call's ``[tail iterations of each solve,
+    rows over at its capped exits]`` record."""
+    e_pri, e_dua, bulk_cap, tail_cap = tests
+    bulks = np.array([b for _, b in its])
+    tails = np.array([t - b for t, b in its])
+    capped = (tails >= tail_cap) if tail_cap > 0 else np.zeros(len(its), bool)
+    ex["solves"] += len(its)
+    for hist, vals in ((ex["bulk_hist"], bulks), (ex["tail_hist"], tails)):
+        for v in vals.tolist():
+            hist[v] = hist.get(v, 0) + 1
+    if bulk_cap > 0:
+        ex["bulk_capped"] += int((bulks >= bulk_cap).sum())
+    n_cap = int(capped.sum())
+    ex["tail_capped"] += n_cap
+    if n_cap:
+        ex["capped_calls"] += 1
+        ex["capped_solve_seconds"] += solve_s
+    n_over = 0
+    if res is not None:
+        over_p, over_d, by_pri, by_dua = _rows_over(res, e_pri, e_dua)
+        over_p, over_d = over_p & live, over_d & live
+        over = over_p | over_d
+        ex["rows_over_uncapped"] += int(over[~capped].sum())
+        if n_cap:
+            ex["rows_read"] += n_cap
+            op, od, ov = over_p[capped], over_d[capped], over[capped]
+            n_over = int(ov.sum())
+            ex["rows_over"] += n_over
+            ex["rows_over_pri_only"] += int((op & ~od).sum())
+            ex["rows_over_dua_only"] += int((od & ~op).sum())
+            ex["rows_over_both"] += int((op & od).sum())
+            for name, r in (("worst_pri", by_pri), ("worst_dua", by_dua)):
+                r = r[capped][live[capped] & np.isfinite(r[capped])]
+                if r.size:
+                    ex[name] = max(ex[name], float(r.max()))
+            ex["tally"] += np.bincount(ids[capped][ov],
+                                       minlength=ex["tally"].size)
+    rec = [tails.tolist(), n_over]
+    if len(ex["per_call"]) < _PER_CALL_KEPT:
+        ex["per_call"].append(rec)
+    if obs.enabled():
+        obs.counter_add("kernel.tail_capped", n_cap)
+        obs.counter_add("kernel.rows_over", n_over)
+    ex["last"] = rec
+    return rec
+
+
+def _exits_view(ex, top_n=8):
+    """``phase_timing()["exits"]``: the booked totals of a mode's
+    pass-1 chunk solves since the reset, as plain host values.
+
+    ``solves``; ``bulk_hist`` / ``tail_hist`` ({ADMM iterations of the
+    phase: solves}: multiples of ``check_every`` up to the budget, so
+    at most 16 / 4 keys under the UC recipe; their iterations sum to
+    ``admm_iters_per_call`` x calls exactly); ``bulk_capped`` /
+    ``tail_capped`` (solves whose phase ran its whole budget: see
+    ``_exit_tests``). At the tail-capped exits whose residual rows the
+    host had (``rows_read`` of them: all but the un-chunked
+    host-segmented path's): ``rows_over`` (rows that still failed the
+    loop's own ``conv_ok``), split ``rows_over_pri_only`` /
+    ``_dua_only`` / ``_both`` by the test they failed, and
+    ``worst_pri`` / ``worst_dua`` (largest finite residual / its
+    tolerance there); ``scenarios_over`` (distinct scenarios ever over
+    at a capped exit) and ``top`` (the ``top_n`` over at the most
+    capped exits, ``[[scenario, exits], ...]``, global ids).
+    ``rows_over_uncapped``: rows still over at exits that did NOT run
+    to the cap, i.e. let go by the stall rule. ``capped_calls`` /
+    ``capped_solve_seconds``: ``solve_loop`` calls with at least one
+    tail-capped solve, and the ``ph.solve`` span's seconds of exactly
+    those calls. ``rows_over_gate``: rows above the recovery gate
+    after passes 2 / 2b (what the ``ph.standing`` note narrates).
+    ``per_call``: the first 256 calls since the reset, each ``[tail
+    iterations of each chunk solve, rows over at its capped exits]``."""
+    out = {k: v for k, v in ex.items() if k not in ("tally", "last")}
+    out["bulk_hist"] = dict(sorted(ex["bulk_hist"].items()))
+    out["tail_hist"] = dict(sorted(ex["tail_hist"].items()))
+    out["per_call"] = list(ex["per_call"])
+    tally = ex["tally"]
+    hit = np.flatnonzero(tally) if tally is not None else ()
+    out["scenarios_over"] = len(hit)
+    # most exits first, the lower id first among equals
+    order = sorted(hit, key=lambda g: (-tally[g], g))[:top_n]
+    out["top"] = [[int(g), int(tally[g])] for g in order]
+    return out
+
+
+def _row_map(ids, reals, n_scen):
+    """``(ids, live)`` of a pass's chunk rows for ``_book_exits``:
+    ``ids`` (n_chunks, chunk) global scenario ids on the host, ``live``
+    whether a row counts: not a chunk pad (column >= the chunk's
+    ``real``), not a zero-probability mesh pad (id >= ``n_scen``)."""
+    cols = np.arange(ids.shape[1])[None, :]
+    return ids, (cols < np.asarray(reals)[:, None]) & (ids < n_scen)
 
 
 def _ph_step(qp_state, factors, data, c, c0, P0, prob, xbar_w, memberships,
@@ -431,7 +616,8 @@ def _ph_step(qp_state, factors, data, c, c0, P0, prob, xbar_w, memberships,
              polish_chunk, precision="native", tail_iter=1000,
              sub_eps_hot=None, sub_eps_dua_hot=None, stall_rel=0.0,
              segment=500, polish_hot=True, segment_lo=None, ir_sweeps=1,
-             lap=None, combine_fn=None, kernel=None, admm=None):
+             lap=None, combine_fn=None, kernel=None, admm=None,
+             exits=None):
     """The PH iteration: batched subproblem solve + Compute_Xbar +
     Update_W + convergence + objectives + certified dual bound, staged as
     THREE jitted programs (assemble / solve / reduce) rather than one
@@ -473,12 +659,21 @@ def _ph_step(qp_state, factors, data, c, c0, P0, prob, xbar_w, memberships,
             # (the segmented drivers' iteration readbacks did), so the
             # device wait would otherwise escape the lap anatomy
             # entirely — it lands at the caller's float(conv) sync,
-            # outside every phase
+            # outside every phase. What is waited for is the solve's
+            # packed exit vector: there is no gate to read the exit rows
+            # at, so they are packed with the counts by one small
+            # program enqueued BEHIND the solve (its launch costs the
+            # host nothing the device waits for) and come in the one
+            # read below (the host-segmented drivers' rows are not on
+            # the host and are not fetched)
+            packed = [packed_exit(qp_state)]
             # lint: ok[SYNC001] phase honesty: the fused wait must land inside the solve lap (see comment above)
-            jax.block_until_ready(qp_state.pri_rel)
+            jax.block_until_ready(packed)
         # the solve's ADMM iterations beside its seconds (``admm``
-        # comes with ``lap``: the same ``_phase_times`` entry)
-        _book_admm_iters(admm, [qp_state], fused, wraps)
+        # and ``exits`` come with ``lap``: the same ``_phase_times``
+        # entry)
+        its, res = _book_admm_iters(admm, [qp_state], fused, wraps,
+                                    packed if fused else None)
         lap("reduce")
     wmask = None if wscale is None else wscale > 0
     if combine_fn is None:
@@ -494,6 +689,11 @@ def _ph_step(qp_state, factors, data, c, c0, P0, prob, xbar_w, memberships,
             x, yA, yB, d, q, c, c0, P0, idx, W, w_on=w_on)
         xbar_new, xsqbar_new, W_new, conv = combine_fn(
             xn, prob, xbar_w, W, rho, wmask)
+    if lap is not None:
+        # how the solve ended, booked once the solve span's seconds are
+        # closed and the reduce is enqueued: the host's numpy runs
+        # beside the device's reduce, not in front of it
+        exits(its, res)
     return qp_state, x, yA, yB, xn, xbar_new, xsqbar_new, W_new, \
         conv, base_obj, solved_obj, dual_obj
 
@@ -1630,6 +1830,42 @@ class PHBase(SPBase):
                 [idx for idx, _ in slices])
         return self._chunk_idx_cache[key]
 
+    def _chunk_row_map(self, slices=None, layout=None):
+        """``_row_map`` of a FULL pass's layout, cached beside the
+        chunk index it is read from (same invalidation): the chunked
+        loop's ``slices`` under their ``layout`` key (host chunks or a
+        mesh's strided chunks, and their size), or, with none, the
+        un-chunked body's one "chunk" of all S rows."""
+        if not hasattr(self, "_chunk_idx_cache"):
+            self._chunk_idx_cache = {}
+        key = ("rowmap", layout, self.batch.S)
+        if key not in self._chunk_idx_cache:
+            if slices is None:
+                ids, reals = np.arange(self.batch.S)[None, :], [self.batch.S]
+            else:
+                # lint: ok[SYNC001] layout read once per chunk layout (cached above), never per iteration
+                ids = np.stack([np.asarray(idx) for idx, _ in slices])
+                reals = [real for _, real in slices]
+            self._chunk_idx_cache[key] = _row_map(ids, reals, self._S_orig)
+        return self._chunk_idx_cache[key]
+
+    def _book_call_exits(self, ent, solve0, tests, its, res=None,
+                         ids=None, live=None):
+        """``_book_exits`` of one ``solve_loop`` call into ``ent``
+        (``tests``: ``_exit_tests`` of the keyword values the call
+        hands ``_solver_call``): the call's solve seconds are what
+        ``ent``'s solve accumulator gained since ``solve0``, and the
+        per-scenario tally behind
+        ``scenarios_over`` / ``top`` is (S,) on the host, held by the
+        entry (so it resets with the seconds)."""
+        ex = ent["exits"]
+        if ex["tally"] is None:
+            ex["tally"] = np.zeros(self._S_orig, np.int64)
+        if res is not None and ids is None:
+            ids, live = self._chunk_row_map()
+        return _book_exits(ex, its, tests, ent["acc"]["solve"] - solve0,
+                           res, ids, live)
+
     def _ensure_chunk_states(self, key, factors, data, slices,
                              lc=None, cold_data=None):
         """Per-chunk QPStates (each owns its L / rho_scale trajectory —
@@ -2146,6 +2382,7 @@ class PHBase(SPBase):
             dent["gather_seconds"] += restage_s
             dent["gather_programs"] += 1
         gate_syncs = 0
+        solve0 = ent["acc"]["solve"]
         # device programs the assemble phase launches for pass 1: ONE
         # on a staged pass (the mesh launched it above), one
         # _ph_assemble per chunk on the per-chunk paths
@@ -2305,9 +2542,9 @@ class PHBase(SPBase):
         # keep their own counter (ph.chunk_retries).
         # (the chain flows ONE factor: only its first state can arrive
         # without the explicit inverse)
-        _book_admm_iters(ent["admm"], [rec[0] for rec in solved_chunks],
-                         plan.mode == "fused",
-                         _linv_wraps(plan, states[0]))
+        its, _ = _book_admm_iters(
+            ent["admm"], [rec[0] for rec in solved_chunks],
+            plan.mode == "fused", _linv_wraps(plan, states[0]))
         ent["assemble_programs"] += asm_programs
         obs.counter_add("ph.assemble_programs", asm_programs)
         clock.lap("gate")
@@ -2327,20 +2564,40 @@ class PHBase(SPBase):
         # the opt-out keeps the historical one-blocking-sync-per-chunk
         # reads. Retries update their row from values they already
         # synced, so the matrix stays current through passes 2/2b.
+        # The same read carries the other residual rows the exit
+        # booking classifies (EXIT_ROWS, pri_rel first: 4 fields x 8 B
+        # a row), so how the pass-1 solves ended costs no sync.
         if pipeline:
             # np.array (not asarray): retry/hospital row writebacks need
             # a writable host matrix, and jax exports read-only views
             # lint: ok[SYNC001] THE stacked-residual gate: ONE D2H per iteration for the whole chunk chain (ph.gate_syncs)
-            pri_host = np.array(stacked_residuals(
-                [rec[0] for rec in solved_chunks]))
+            res_host = np.array(stacked_residuals(
+                [rec[0] for rec in solved_chunks], EXIT_ROWS)).reshape(
+                    len(EXIT_ROWS), len(solved_chunks), -1)
             gate_syncs += 1
         else:
-            # lint: ok[SYNC001] sequential opt-out: the documented one-blocking-sync-per-chunk path (gate_syncs books each)
-            pri_host = np.stack([np.asarray(rec[0].pri_rel)
-                                 for rec in solved_chunks])
+            # sequential opt-out: the documented one-blocking-read-per-
+            # chunk path (gate_syncs books each)
+            res_host = np.stack([
+                np.stack(jax.device_get([getattr(rec[0], f)
+                                         for f in EXIT_ROWS]))
+                for rec in solved_chunks], axis=1)
             gate_syncs += len(solved_chunks)
+        pri_host = res_host[0]
         if obs.enabled():
-            obs.counter_add("xfer.d2h_bytes", pri_host.nbytes)
+            obs.counter_add("xfer.d2h_bytes", res_host.nbytes)
+        # every chunk row's global scenario id, and whether it counts
+        # (chunk pads and zero-probability mesh pads do not): a
+        # dispatch pass names the scenarios it solved
+        if dispatch is None:
+            row_ids, row_live = self._chunk_row_map(
+                slices, ("sharded", lc) if sharded else ("host", chunk))
+        else:
+            row_ids, row_live = _row_map(
+                ids_pad.reshape(n_dchunks, chunk),
+                [real for _, real in slices], self._S_orig)
+        self._book_call_exits(ent, solve0, _exit_tests(**kw), its,
+                              res_host, row_ids, row_live)
         # blacklist RE-ADMISSION (VERDICT r3 #6): PH moves q every
         # iteration, so a row declared incurable under one (W, x̄) may be
         # easy under a later one; permanent blacklists would freeze its
@@ -2467,31 +2724,26 @@ class PHBase(SPBase):
         # above the gate after recovery + hospital enter x̄/W with their
         # loose solutions this iteration — that must be visible in the
         # trace, not only the hospital's treatment log. pri_host was
-        # kept current through passes 2/2b, so this is free host math
-        # (done only when something consumes the note: screen, logger,
+        # kept current through passes 2/2b, so this is one more mask
+        # over the exit booking's row map, counted with it; the note
+        # is narrated only when something consumes it (screen, logger,
         # or the telemetry event stream).
-        if self._trace_consumers_active():
-            standing = []
-            for ci, (idx_c, real) in enumerate(slices):
-                pr = pri_host[ci][:real]
-                for r in np.flatnonzero(~(pr <= thr)):
-                    # lint: ok[SYNC001] trace-note path: runs only when a trace consumer is active (guard above)
-                    g = int(np.asarray(idx_c)[r])
-                    if g >= self._S_orig:
-                        continue   # zero-probability mesh pad rows
-                    standing.append((g, float(pr[r])))   # lint: ok[SYNC001] host numpy slice of the gate read
-            if standing:
-                g_w, pr_w = max(standing, key=lambda t: t[1])
-                when = (f"re-admission in {readmit - calls % readmit} "
-                        "solves" if readmit else "re-admission disabled")
-                obs.counter_add("ph.standing_rows", len(standing))
-                self._trace_note(
-                    "ph.standing",
-                    f"standing: {len(standing)} scenario row(s) above "
-                    f"pri_rel gate {thr:.0e} enter xbar/W loose "
-                    f"(worst s{g_w}:{pr_w:.0e}; {when})",
-                    rows=len(standing), gate=thr, worst_scenario=g_w,
-                    worst_pri_rel=pr_w)
+        standing = ~(pri_host <= thr) & row_live
+        n_standing = int(standing.sum())
+        ent["exits"]["rows_over_gate"] += n_standing
+        if n_standing and self._trace_consumers_active():
+            worst = np.argmax(np.where(standing, pri_host, -np.inf))
+            g_w, pr_w = int(row_ids.flat[worst]), float(pri_host.flat[worst])   # lint: ok[SYNC001] host numpy of the gate read
+            when = (f"re-admission in {readmit - calls % readmit} "
+                    "solves" if readmit else "re-admission disabled")
+            obs.counter_add("ph.standing_rows", n_standing)
+            self._trace_note(
+                "ph.standing",
+                f"standing: {n_standing} scenario row(s) above "
+                f"pri_rel gate {thr:.0e} enter xbar/W loose "
+                f"(worst s{g_w}:{pr_w:.0e}; {when})",
+                rows=n_standing, gate=thr, worst_scenario=g_w,
+                worst_pri_rel=pr_w)
         ent["gate_syncs"] += gate_syncs
         obs.counter_add("ph.gate_syncs", gate_syncs)
         clock.lap("reduce")
@@ -2711,6 +2963,9 @@ class PHBase(SPBase):
             # the factor was prepared anew (qp_solver.PreparedFactor).
             "admm_iters_per_call": {k: v / n
                                     for k, v in ent["admm"].items()},
+            # how those same solves ENDED, totals since the reset
+            # (``_exits_view``)
+            "exits": _exits_view(ent["exits"]),
             # a sharded engine's consensus reduces per call and the
             # bytes their psums move between the chips (_mesh_combine);
             # zeros on one device
@@ -2923,6 +3178,12 @@ class PHBase(SPBase):
         now = self._phase_totals()
         rec["phase_seconds"] = {k: now[k] - phase_before.get(k, 0.0)
                                 for k in now}
+        last = (self._phase_times.get(True) or {}).get("exits", {}).get(
+            "last")
+        if last is not None:
+            # how the hot mode's last call's chunk solves ended (the
+            # record ``phase_timing()["exits"]["per_call"]`` keeps)
+            rec["exits"] = {"tail_iters": last[0], "rows_over": last[1]}
         ctr = obs.counters_snapshot()
         rec["counter_deltas"] = {
             k: ctr.get(k, 0) - counters_before.get(k, 0)
@@ -3220,6 +3481,21 @@ class PHBase(SPBase):
         ent["shape"] = self._solve_shape(factors, plan, rows_per_call)
         sp_args = {"mode": _mode_str(skey)} if obs.enabled() else None
         clock = _PhaseClock(ent["acc"], sp_args)
+        # the ONE set of keyword values both spellings of the body hand
+        # ``_solver_call``, and what its loops' exits are booked against
+        kw = dict(prox_on=bool(prox_on), precision=self.sub_precision,
+                  sub_max_iter=self.sub_max_iter, sub_eps=self.sub_eps,
+                  sub_eps_hot=self.sub_eps_hot,
+                  sub_eps_dua_hot=self.sub_eps_dua_hot,
+                  tail_iter=self.sub_tail_iter,
+                  stall_rel=self.sub_stall_rel, segment=self.sub_segment,
+                  polish_hot=self.sub_polish_hot,
+                  polish_chunk=int(self.options.get(
+                      "subproblem_polish_chunk", 0)),
+                  segment_lo=self.sub_segment_lo,
+                  ir_sweeps=self.sub_ir_sweeps, kernel=plan)
+        book_exits = partial(self._book_call_exits, ent,
+                             ent["acc"]["solve"], _exit_tests(**kw))
 
         combine_fn = partial(self._mesh_combine, ent) \
             if sh is not None else None
@@ -3242,25 +3518,16 @@ class PHBase(SPBase):
             clock.lap("solve")
             wraps = _linv_wraps(plan, qp_state)
             qp_state, x_c, yA, yB = _solver_call(
-                factors, d_c, q_c, qp_state, prox_on=bool(prox_on),
-                precision=self.sub_precision,
-                sub_max_iter=self.sub_max_iter, sub_eps=self.sub_eps,
-                sub_eps_hot=self.sub_eps_hot,
-                sub_eps_dua_hot=self.sub_eps_dua_hot,
-                tail_iter=self.sub_tail_iter,
-                stall_rel=self.sub_stall_rel, segment=self.sub_segment,
-                polish_hot=self.sub_polish_hot,
-                polish_chunk=int(self.options.get(
-                    "subproblem_polish_chunk", 0)),
-                segment_lo=self.sub_segment_lo,
-                ir_sweeps=self.sub_ir_sweeps, kernel=plan)
-            if plan.mode == "fused":
+                factors, d_c, q_c, qp_state, **kw)
+            fused = plan.mode == "fused"
+            if fused:
                 # phase honesty (see _ph_step): the fused wait must
                 # land inside the solve lap
+                packed = [packed_exit(qp_state)]
                 # lint: ok[SYNC001] phase honesty for fused plans, same site contract as _ph_step
-                jax.block_until_ready(qp_state.pri_rel)
-            _book_admm_iters(ent["admm"], [qp_state],
-                             plan.mode == "fused", wraps)
+                jax.block_until_ready(packed)
+            its, res = _book_admm_iters(ent["admm"], [qp_state], fused,
+                                        wraps, packed if fused else None)
             clock.lap("reduce")
             x = expand_solution(x_c, shrink.fixed_colvals,
                                 shrink.keep_cols, shrink.fixed_cols,
@@ -3281,6 +3548,7 @@ class PHBase(SPBase):
                 xbar_new, xsqbar_new, W_new, conv = combine_fn(
                     xn, self.prob, self.xbar_weights, self.W, self.rho,
                     wmask)
+            book_exits(its, res)    # beside the device's reduce
             clock.lap()
             self._qp_states[skey] = qp_state
             self.x, self.yA, self.yB = x, yA, yB
@@ -3308,19 +3576,9 @@ class PHBase(SPBase):
             self.prob, self.xbar_weights, tuple(self.memberships),
             self.nonant_idx, self.W, self.xbar, self.rho,
             self._fixed_mask, self._fixed_vals, self._w_scale,
-            w_on=bool(w_on), prox_on=bool(prox_on),
-            slot_slices=self.slot_bounds,
-            sub_max_iter=self.sub_max_iter, sub_eps=self.sub_eps,
-            polish_chunk=int(self.options.get("subproblem_polish_chunk",
-                                              0)),
-            precision=self.sub_precision, tail_iter=self.sub_tail_iter,
-            sub_eps_hot=self.sub_eps_hot,
-            sub_eps_dua_hot=self.sub_eps_dua_hot,
-            stall_rel=self.sub_stall_rel, segment=self.sub_segment,
-            polish_hot=self.sub_polish_hot,
-            segment_lo=self.sub_segment_lo,
-            ir_sweeps=self.sub_ir_sweeps, lap=clock.lap,
-            combine_fn=combine_fn, kernel=plan, admm=ent["admm"])
+            w_on=bool(w_on), slot_slices=self.slot_bounds, lap=clock.lap,
+            combine_fn=combine_fn, admm=ent["admm"], exits=book_exits,
+            **kw)
         clock.lap()
         self._qp_states[skey] = qp_state
         self.x, self.yA, self.yB = x, yA, yB

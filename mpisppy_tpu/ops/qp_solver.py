@@ -1693,6 +1693,13 @@ _solve_lo_jit_donated = compile_serialized(
             donate_argnames=("st_lo",)), _LO_STATICS)
 
 
+@jax.jit
+def _stack_rows(rows):
+    # ONE program: eager ``jnp.stack`` launches an expand_dims per row
+    # before its concatenate (~0.2 ms of host each on the chip)
+    return jnp.stack(rows)
+
+
 def stacked_residuals(states, field="pri_rel"):
     """One device-side stack of per-chunk residual vectors ->
     (n_chunks, chunk). The chunked PH quality gates read EVERY chunk's
@@ -1702,9 +1709,40 @@ def stacked_residuals(states, field="pri_rel"):
     (``np.asarray(stacked_residuals(...))``) per PH iteration. Sharded
     chunk states all carry the same mesh placement (colocate passes
     through); the stack compiles to a sharded (n_chunks, chunk) array
-    and the host read gathers it in one transfer."""
+    and the host read gathers it in one transfer.
+
+    ``field`` may be a TUPLE of field names: the same ONE stack program
+    and ONE transfer then carry every named row, field-major, as
+    (len(field) * n_chunks, chunk) — the host reshapes for free, where
+    a device reshape would be a second launch. The PH gate reads
+    ``EXIT_ROWS`` this way: the residuals the loop's exit test saw."""
     from ..parallel.mesh import colocate
-    return jnp.stack(colocate([getattr(s, field) for s in states]))
+    fields = (field,) if isinstance(field, str) else field
+    return _stack_rows(tuple(colocate([getattr(s, f) for f in fields
+                                       for s in states])))
+
+
+# the per-row residuals of a returned state, in the order the PH
+# engine's exit booking reads them (core/ph._book_exits): ``pri_rel``
+# first, so that row block IS the recovery gate's matrix
+EXIT_ROWS = ("pri_rel", "pri_res", "dua_res", "dua_rel")
+
+
+@jax.jit
+def _pack_exit(counts, rows):
+    return jnp.concatenate(
+        [jnp.stack(counts).astype(rows[0].dtype), *rows])
+
+
+def packed_exit(state):
+    """A returned state's three scalar counts (``iters``, ``iters_lo``,
+    ``refactors``: exact in any float dtype at the budgets a solve can
+    have) and its ``EXIT_ROWS``, as ONE (3 + 4 S,) device vector: one
+    launch, so that the un-chunked PH body, which has no gate to read
+    the rows at, reads counts and rows in ONE transfer where the counts
+    alone were three."""
+    return _pack_exit((state.iters, state.iters_lo, state.refactors),
+                      tuple(getattr(state, f) for f in EXIT_ROWS))
 
 
 def _unscaled_residuals(A_s, P_s, g, D, E, Eb, csx, q_s, x, yA, yB, zA, zB):

@@ -483,3 +483,28 @@ def test_one_program_each_way_compiles_for_v5e(one_chip,
     # 0.11 GB) and less than that again in temporaries
     assert mem.output_size_in_bytes < 0.35e9
     assert mem.temp_size_in_bytes < 0.2e9
+
+
+def test_the_gates_four_field_stack_compiles_for_v5e(topo, one_chip,
+                                                     no_persistent_cache):
+    """The chunked loop's ONE gate read stacks four residual rows of
+    every chunk state since ISSUE 37 (``qp_solver.EXIT_ROWS``), where it
+    stacked ``pri_rel`` alone: at the UC cells' shapes, float64, that is
+    sixteen (64,) rows on one chip and sixteen row-sharded (256,) rows
+    over the 2x2 mesh, whose stack stays sharded (no collective: the
+    host's read gathers it)."""
+    from jax.sharding import Mesh
+    from mpisppy_tpu.ops.qp_solver import EXIT_ROWS
+    from mpisppy_tpu.parallel.mesh import SCEN_AXIS
+    chunk, n_chunks = _UC["chunk"], _UC["S"] // _UC["chunk"]
+    stack = jax.jit(lambda *rows: jnp.stack(rows))
+    rows = [jax.ShapeDtypeStruct((chunk,), jnp.float64, sharding=one_chip)
+            ] * (len(EXIT_ROWS) * n_chunks)
+    stack.lower(*rows).compile()
+    mesh = Mesh(np.asarray(topo.devices[:4]), (SCEN_AXIS,))
+    sharded = NamedSharding(mesh, PartitionSpec(SCEN_AXIS))
+    rows = [jax.ShapeDtypeStruct((4 * chunk,), jnp.float64,
+                                 sharding=sharded)] * len(rows)
+    hlo = stack.lower(*rows).compile().as_text()
+    assert not _hlo_lines(hlo, "all-gather") \
+        and not _hlo_lines(hlo, "all-reduce")

@@ -195,6 +195,13 @@ JAX_FREE_DEFAULT = (
 # qualname and everything nested inside it.
 SYNC_ALLOW_DEFAULT = {
     "mpisppy_tpu/core/ph.py": {
+        "_book_exits":
+            "host numpy over rows the gate read (or _book_admm_iters' "
+            "device_get) already brought to the host: no device value "
+            "enters it",
+        "_row_map":
+            "host numpy over host chunk ids, once per layout or per "
+            "dispatch pass",
         "PHBase.residual_summary":
             "gate-time diagnostics: reads residuals AFTER the stacked "
             "gate synced them",
