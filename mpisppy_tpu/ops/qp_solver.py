@@ -341,7 +341,7 @@ def _Ax(A, x):
                 + (xl @ A.hi.T).astype(f64))
     if A.ndim == 2:
         return x @ A.T
-    return jnp.einsum("smn,sn->sm", A, x)
+    return _batched_matvec(A, x)
 
 
 @jax.named_scope("qp.ATy")
@@ -380,9 +380,74 @@ def _ATy(A, y):
         return lead + (yh @ A.lo).astype(f64) + (yl @ A.hi).astype(f64)
     if A.ndim == 2:
         return y @ A
-    return jnp.einsum("smn,sm->sn", A, y)
+    return _batched_rmatvec(A, y)
 
 
+# ---- batched float64 products: per-scenario matrices in native f64 ----
+# The TPU has no float64 dot. Its compiler emulates a BATCHED f64
+# ``dot_general`` as nested ``while`` loops over eight f32 limbs (~44
+# trips of slice / update / select ops a product), which at a served
+# farmer stack ((24, 7, 12)) was four fifths of the chip's time (PERF.md
+# §6, PR 38). The same product written as a broadcast multiply and a sum
+# over the contracted axis is ONE fusion of the soft-float element-wise
+# f64 the rest of the ADMM body already runs on, IEEE f64 throughout.
+# The rule is "always": on the chip the reduction was 40-50x faster at
+# (24, 7, 12) and still 50x at (24, 700, 1200), where it streams the
+# matrix at 650 GB/s of the 819 the HBM gives; the emulated dot reads at
+# least those bytes, so no larger shape turns the order (sweep table and
+# HLO census: doc/kernels.md §3d).
+
+def _matvec_dot(M, v):
+    return jnp.einsum("sij,sj->si", M, v)
+
+
+def _matvec_reduce(M, v):
+    return jnp.sum(M * v[:, None, :], axis=-1)
+
+
+def _rmatvec_dot(M, v):
+    return jnp.einsum("sij,si->sj", M, v)
+
+
+def _rmatvec_reduce(M, v):
+    # sums over the matrix's own row axis: no transposed copy of M is
+    # carried beside it (on the chip faster than, or within 1% of, the
+    # transposed copy summed over its minor axis at every swept shape)
+    return jnp.sum(M * v[:, :, None], axis=1)
+
+
+def f64_product_form(M) -> str | None:
+    """``"reduce"`` / ``"dot"``: how THIS process's backend runs the
+    batched products of a per-scenario float64 matrix ``M`` (anything
+    with ``ndim`` 3 and that dtype); None for everything else (shared
+    matrices, plain or in a split / packed / scaled container, are 2-D;
+    the mixed bulk's batch is f32), whose products are not these."""
+    if M.ndim != 3 or M.dtype != jnp.float64:
+        return None
+    return "reduce" if jax.default_backend() == "tpu" else "dot"
+
+
+def _batched_product(M, v, reduce_form, dot_form):
+    form = f64_product_form(M)
+    if form is None:                 # an f32 batch (the mixed bulk): MXU
+        return dot_form(M, v)
+    # trace-time count of the products lowered each way on this backend
+    obs.counter_add(f"kernel.f64_products_{form}")
+    # chosen at lowering time, per platform (as _chol_solve does for a
+    # PreparedFactor): CPU and GPU keep the library dot, and a program
+    # compiled HERE for a described TPU takes the TPU form
+    return jax.lax.platform_dependent(M, v, tpu=reduce_form,
+                                      default=dot_form)
+
+
+def _batched_matvec(M, v):
+    """Rows of M v: M (S, r, c), v (S, c) -> (S, r)."""
+    return _batched_product(M, v, _matvec_reduce, _matvec_dot)
+
+
+def _batched_rmatvec(M, v):
+    """Rows of Mᵀ v: M (S, r, c), v (S, r) -> (S, c)."""
+    return _batched_product(M, v, _rmatvec_reduce, _rmatvec_dot)
 
 
 def _ruiz_equilibrate(P_diag, A, iters=15):
@@ -795,7 +860,7 @@ def _chol_solve(F, b):
     if F.dtype == jnp.float64:
         if F.ndim == 2:
             return b @ F
-        return jnp.einsum("sij,sj->si", F, b)
+        return _batched_matvec(F, b)
     if F.ndim == 2:
         return _pair_solve(F, b.astype(F.dtype)).astype(b.dtype)
     return _tri_solve(F, b.astype(F.dtype)).astype(b.dtype)

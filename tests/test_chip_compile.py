@@ -348,7 +348,8 @@ def test_unchunked_sslp_df32_solve_compiles_for_v5e(sslp_calls, one_chip,
     factor is the shared f32 one; the float64 is element-wise outer
     arithmetic and the split matvecs' accumulation)."""
     assert sslp_calls["plan"] == {"mode": "fused", "backend": "reference",
-                                  "l_inv": True, "block_dtype": "f32"}
+                                  "l_inv": True, "block_dtype": "f32",
+                                  "f64_products": None}
     solves = sslp_calls["_fused_mixed_jit_donated"]
     assert len(solves) == 3
     assert len(sslp_calls["make_l_inv"]) == 2      # iter-0's and hot's
@@ -508,3 +509,89 @@ def test_the_gates_four_field_stack_compiles_for_v5e(topo, one_chip,
     hlo = stack.lower(*rows).compile().as_text()
     assert not _hlo_lines(hlo, "all-gather") \
         and not _hlo_lines(hlo, "all-reduce")
+
+
+# ---------------- the stacked native-f64 solve (ISSUE 38) --------------
+
+@pytest.fixture(scope="module")
+def stacked_farmer_segment():
+    """The served cell's segment program as the chip's plan runs it
+    (``_needs_host_factor``: ``polish=False``, ``adaptive_rho=False``,
+    segments of 500) at a full stack's operands, recorded from a CPU
+    pass of eight stacked three-scenario farmers: A_s (24, 7, 12)
+    float64, the factor the explicit (24, 12, 12) float64 inverse."""
+    import mpisppy_tpu.ops.qp_solver as qps
+    from stacked_farmer import record_stacked_farmer_calls
+    calls, _plan = record_stacked_farmer_calls()
+    args, kw = calls[-1]
+    assert args[0].A_s.shape == (24, 7, 12) \
+        and args[0].A_s.dtype == np.float64
+    assert args[3].L.shape == (24, 12, 12) and args[3].L.dtype == np.float64
+    kw = {k: v for k, v in kw.items() if k != "_segmented_caller"}
+    kw.update(max_iter=500, polish=False, adaptive_rho=False)
+    fn = jax.jit(qps._solve_impl, static_argnames=qps._SOLVE_STATICS)
+    return fn, args, kw
+
+
+_PRODUCT_SCOPES = ("qp.Ax", "qp.ATy", "qp.kkt_solve")
+
+
+def _product_loops(hlo):
+    """The ``while`` instructions whose ``op_name`` lies under one of
+    the three product scopes: the compiler's emulation of a batched
+    float64 ``dot_general`` (eight f32 limbs, nested loops)."""
+    return [ln for ln in _hlo_lines(hlo, "while")
+            if any(s + "/" in ln for s in _PRODUCT_SCOPES)]
+
+
+def _widened(tree, S, scale, sharding):
+    """The recorded (24, 7, 12) operands as shapes on the described
+    chip: the scenario axis at ``S`` rows, m and n times ``scale``."""
+    dims = {24: S, 7: 7 * scale, 12: 12 * scale}
+
+    def leaf(a):
+        if not (hasattr(a, "shape") and hasattr(a, "dtype")):
+            return a
+        return jax.ShapeDtypeStruct(tuple(dims[d] for d in a.shape),
+                                    a.dtype, sharding=sharding)
+    return jax.tree.map(leaf, tree)
+
+
+# (S, scale): the served stack, a solo wheel, and the largest shape the
+# chip sweep timed ((24, 700, 1200): ``crops_multiplier`` 100)
+@pytest.mark.parametrize("S,scale", [(24, 1), (3, 1), (24, 100)])
+def test_stacked_f64_segment_has_no_emulated_dot_loops_on_v5e(
+        stacked_farmer_segment, one_chip, no_persistent_cache, S, scale):
+    """The rule answers "reduce" for every per-scenario float64 matrix
+    (doc/kernels.md §3d: the sweep found no shape where the emulated
+    dot wins), and the segment program the v5e compiler makes of it
+    holds the solve's own two loops and nothing of the dot emulation:
+    no ``while`` under ``qp.Ax`` / ``qp.ATy`` / ``qp.kkt_solve``, no
+    ``dynamic-update-slice`` (at (24, 7, 12): 34 loops and 74
+    update-slices before ISSUE 38, 14 of the loops in the ADMM scan
+    body). One answer, held by a compile at each pinned shape."""
+    fn, args, kw = stacked_farmer_segment
+    hlo = fn.lower(*_widened(args, S, scale, one_chip), **kw).compile() \
+        .as_text()
+    assert f"f64[{S},{7 * scale},{12 * scale}]" in hlo
+    assert not _product_loops(hlo)
+    assert len(_hlo_lines(hlo, "while")) == 2
+    assert not _hlo_lines(hlo, "dynamic-update-slice")
+
+
+def test_the_emulated_dot_is_a_loop_nest_on_v5e(one_chip,
+                                                no_persistent_cache):
+    """What the reduction replaced, so that a compiler that learns to
+    multiply float64 batches shows up here: one batched float64
+    ``einsum`` at the stacked inverse's shape compiles to ``while``
+    loops over f32 limbs with ``dynamic-update-slice`` in them, the
+    reduction of the same product to neither."""
+    from mpisppy_tpu.ops.qp_solver import _matvec_dot, _matvec_reduce
+    F = jax.ShapeDtypeStruct((24, 12, 12), jnp.float64, sharding=one_chip)
+    b = jax.ShapeDtypeStruct((24, 12), jnp.float64, sharding=one_chip)
+    dot = jax.jit(_matvec_dot).lower(F, b).compile().as_text()
+    assert _hlo_lines(dot, "while") \
+        and _hlo_lines(dot, "dynamic-update-slice")
+    red = jax.jit(_matvec_reduce).lower(F, b).compile().as_text()
+    assert not _hlo_lines(red, "while") \
+        and not _hlo_lines(red, "dynamic-update-slice")

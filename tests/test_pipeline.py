@@ -171,7 +171,8 @@ def test_phase_timing_contract(layout, precision):
               opts, iters=2, mesh=make_mesh(ndev) if ndev > 1 else None)
     pt = ph.phase_timing(True)
     assert pt["kernel"] == {"mode": "fused", "backend": "reference",
-                            "l_inv": False, "block_dtype": "f32"}
+                            "l_inv": False, "block_dtype": "f32",
+                            "f64_products": None}
     assert (pt["mode"], pt["devices"]) == (
         "sharded" if ndev > 1 else "host", ndev)
     shape = pt["solve_shape"]
