@@ -17,7 +17,13 @@ script starts no child that imports jax):
     and recipe are never cut; the counts are (S, CHUNK, MAX_HOT_ITERS
     and the x̂ spoke's candidate pool below): the hub iterates until
     the first incumbent has landed, so the bound sandwich is checked
-    on every run.
+    on every run. The three engines share the chip's memory and its
+    one device queue under the wheel's arbiter (doc/cylinders.md): the
+    leg's line carries ``wheel_timing`` (turns, device seconds and
+    queue-wait seconds by cylinder, the exchange's seconds and bytes),
+    so the spokes are timed beside the hub. The cell ``uc_s256_wheel``
+    (``benchmarks/drivers/wheel_hot.py``) is this path at the
+    deployment's rows.
  2. the serving layer, started in-process the way ``serve_main`` does:
     one farmer request, then the same shape with a ``patch`` — the
     second must be a warm-cache hit with zero new XLA compiles.
@@ -218,8 +224,9 @@ def uc_wheel_leg():
         # CLI has no precision flag
         hub_options=dict(recipe),
         # the x̂ spoke is the device candidate-pool spoke with the
-        # smallest pool: one vote threshold + the two slam rows = 3
-        # candidates x S scenarios per round (a count, like S)
+        # smallest pool: one vote threshold + the builder's two slam
+        # and two bound rows = 5 candidates x S scenarios per round (a
+        # count, like S)
         spokes=[SpokeConfig("lagrangian", dict(recipe)),
                 SpokeConfig("dive",
                             dict(recipe, xhat_pin_vars=["u"],
@@ -298,6 +305,8 @@ def uc_wheel_leg():
          xla_compiles=compiles1[0] - compiles0[0],
          xla_compile_seconds=round(compiles1[1] - compiles0[1], 1),
          peak_hbm_bytes=_peak_hbm(),
+         wheel_timing={k: v for k, v in wheel.hub.wheel_timing().items()
+                       if k != "spokes"},
          peaks={"flops": pk[0], "hbm_gbps": pk[1], "source": pk[2],
                 "device_kind": pk[3]})
     # where the cold run's seconds went, from the event stream's clock

@@ -116,6 +116,19 @@ class _PhaseClock:
         if nxt is not None:
             self._open(nxt)
 
+    @contextlib.contextmanager
+    def outside(self, waiting):
+        """Enter ``waiting`` (a wait that is not this pass's own: its
+        turn in the wheel's arbiter) with the open phase CLOSED, and
+        open it again once inside: the phase's seconds and spans stay
+        this engine's work (a call then holds several spans of the
+        phase, as the sequential opt-out's do)."""
+        phase = self._phase
+        self.lap()
+        with waiting:
+            self._open(phase)
+            yield
+
 
 def _mode_str(key):
     """Human mode tag for telemetry span args: the solve-mode key of
@@ -617,7 +630,7 @@ def _ph_step(qp_state, factors, data, c, c0, P0, prob, xbar_w, memberships,
              sub_eps_hot=None, sub_eps_dua_hot=None, stall_rel=0.0,
              segment=500, polish_hot=True, segment_lo=None, ir_sweeps=1,
              lap=None, combine_fn=None, kernel=None, admm=None,
-             exits=None):
+             exits=None, turn=None):
     """The PH iteration: batched subproblem solve + Compute_Xbar +
     Update_W + convergence + objectives + certified dual bound, staged as
     THREE jitted programs (assemble / solve / reduce) rather than one
@@ -645,13 +658,21 @@ def _ph_step(qp_state, factors, data, c, c0, P0, prob, xbar_w, memberships,
         # readbacks block).
         lap("solve")
     wraps = _linv_wraps(kernel, qp_state)
-    qp_state, x, yA, yB = _solver_call(
-        factors, d, q, qp_state, prox_on=prox_on, precision=precision,
-        sub_max_iter=sub_max_iter, sub_eps=sub_eps,
-        sub_eps_hot=sub_eps_hot, sub_eps_dua_hot=sub_eps_dua_hot,
-        tail_iter=tail_iter, stall_rel=stall_rel, segment=segment,
-        polish_hot=polish_hot, polish_chunk=polish_chunk,
-        segment_lo=segment_lo, ir_sweeps=ir_sweeps, kernel=kernel)
+    # ``turn``: an engine of an in-process wheel takes the solve as ONE
+    # turn of the wheel's arbiter (``PHBase._wheel_turn``): the solve
+    # lands in the solve lap, the wait for the turn in no lap (it is the
+    # arbiter's to book), the reduce outside the turn
+    with contextlib.nullcontext() if turn is None else turn():
+        qp_state, x, yA, yB = _solver_call(
+            factors, d, q, qp_state, prox_on=prox_on, precision=precision,
+            sub_max_iter=sub_max_iter, sub_eps=sub_eps,
+            sub_eps_hot=sub_eps_hot, sub_eps_dua_hot=sub_eps_dua_hot,
+            tail_iter=tail_iter, stall_rel=stall_rel, segment=segment,
+            polish_hot=polish_hot, polish_chunk=polish_chunk,
+            segment_lo=segment_lo, ir_sweeps=ir_sweeps, kernel=kernel)
+        if turn is not None:
+            # lint: ok[SYNC001] the wheel's admission grain: the turn ends when the device is free again
+            jax.block_until_ready(qp_state.pri_rel)
     if lap is not None:
         fused = kernel is not None and kernel.mode == "fused"
         if fused:
@@ -895,11 +916,19 @@ class PHBase(SPBase):
         # donating one chunk's would delete its siblings') and the
         # per-phase wall-clock/sync accounting the bench and tests read
         self._chunk_donatable = set()
+        # the admission port of an in-process wheel's arbiter
+        # (utils/runtime.WheelArbiter; set by spin_the_wheel when the
+        # wheel has spokes). None everywhere else: a hub-only engine, a
+        # mesh, the serve path and APH's dispatch never take a turn
+        self._wheel_port = None
+        self._freed_modes = set()
         # batched incumbent-pool evaluation (ops/incumbent): per-
         # (pool, chunk) warm-start states + the donation crash window,
         # exactly the chunked loop's pattern (see evaluate_incumbent_pool)
         self._pool_states = {}
         self._pool_dirty = set()
+        # the last batched screen's per-row objectives (row p * S + s)
+        self._pool_obj_rows = None
         # modes whose donating pass is in flight: set before pass 1
         # consumes the warm-start buffers, cleared once pass 3 stores
         # their successors — a crash in between leaves the cached
@@ -1703,6 +1732,96 @@ class PHBase(SPBase):
             xn.dtype.itemsize)
         return out
 
+    # ---- three engines in one HBM (doc/cylinders.md §residency) ----
+    def residency(self):
+        """What this engine keeps on the device that matters at UC
+        width, and who else holds it: ``shared`` names the immutable
+        operands that are ONE buffer for every engine of the wheel
+        (through ``batch._dev_cache``, under its lock: the scaled split
+        matrix with its packed form, and the per-scenario data blocks
+        ``l u lb ub c``), ``private`` the solve modes whose warm states
+        hold an (n, n) factor of their own (a factor follows its
+        engine's own rho: sharing one would change results), ``freed``
+        the modes this engine has dropped (``_free_mode``)."""
+        cache = getattr(self.batch, "_dev_cache", None) or {}
+        view = cache.get(("A", str(self.dtype), True))
+        shared = []
+        if view is not None and getattr(self.qp_data.A, "A_s", None) \
+                is view.A_s:
+            shared.append("A_s")
+        shared += [f for f in ("l", "u", "lb", "ub")
+                   if cache.get((f, str(self.dtype)))
+                   is getattr(self.qp_data, f)]
+        private = sorted(
+            {_mode_str(k[1]) for k, v in self._qp_states.items()
+             if isinstance(k, tuple) and k and k[0] == "chunks" and v}
+            | {_mode_str(k) for k, v in self._qp_states.items()
+               if isinstance(v, QPState)}
+            | ({"pool"} if self._pool_states else set()))
+        return {"shared": shared, "private": private,
+                "freed": sorted(self._freed_modes)}
+
+    def _free_mode(self, key):
+        """Drop a solve mode's warm states, and with them its (n, n)
+        factor (0.68 GB at n = 13,056) and its rows: what an engine of
+        a wheel can no longer use (the hub's iter-0 mode once hot
+        iterations run). Safe at any time: a later pass of the mode, or
+        ``reset_run()``, rebuilds cold states through the compiled
+        builders."""
+        if self._qp_states.pop(("chunks", key), None) is not None \
+                or key in self._qp_states:
+            self._freed_modes.add(_mode_str(key))
+        self._qp_states.pop(key, None)
+        self._qp_states.pop(("dispatch", key), None)
+        self._chunk_donatable.discard(key)
+        self._chunk_dirty.discard(key)
+
+    def _take_flowed_factor(self, states, on):
+        """At the start of a DONATING df32 pass of an engine of a
+        wheel: the one (n, n) factor every chunk state of the mode
+        shares between passes, taken OUT of ``states`` (they keep a
+        placeholder until the pass's unify re-attaches the flow's last
+        factor). The fused program never donates its factor (the flow
+        shares it), so every chunk solve hands back a fresh one, and
+        the inherited factor would stay alive in the not-yet-solved
+        chunks' states for the whole pass: two factors an engine, 0.68
+        GB each at n = 13,056, where three engines share one HBM (the
+        pool's pass is a round of ~50 s). Taken out, it is freed as
+        soon as the first chunk's solve has used it. A donating pass
+        has marked its states dirty until their successors are stored,
+        so a pass that dies mid-flight rebuilds cold and never meets a
+        stripped state. Returns the factor for the first chunk's solve;
+        None (and nothing changes) outside a wheel or on a pass that
+        does not donate."""
+        if not on or self._wheel_port is None:
+            return None
+        L0 = states[0].L
+        bare = jnp.zeros((), jnp.float32)
+        for ci in range(len(states)):
+            states[ci] = states[ci]._replace(L=bare)
+        return L0
+
+    def _wheel_turn(self, rows, clock=None):
+        """One device solve of ``rows`` scenario rows as ONE turn of the
+        wheel's arbiter (doc/cylinders.md): admitted in the wheel's
+        fixed order, held until the caller leaves the block, which it
+        does once the solve's outputs are ready (``_wheel_ready``).
+        The wait for the turn is booked by the arbiter
+        (``wheel.queue_wait``), not by ``clock``'s open phase. Outside
+        an in-process wheel with spokes this is a no-op."""
+        port = self._wheel_port
+        if port is None:
+            return contextlib.nullcontext()
+        return port(rows) if clock is None else clock.outside(port(rows))
+
+    def _wheel_ready(self, out):
+        """Inside a turn: wait for the solve just enqueued, so that the
+        turn ends when the device is free again (an engine with no port
+        never blocks here: its chunk solves stay pipelined)."""
+        if self._wheel_port is not None:
+            # lint: ok[SYNC001] the wheel's admission grain: one chunk solve in flight (utils/runtime.WheelArbiter)
+            jax.block_until_ready(out)
+
     def _cold_state(self, factors, d):
         """``qp_cold_state`` with the placement every later solve hands
         back. On a mesh the solve programs return their per-row fields
@@ -2462,6 +2581,8 @@ class PHBase(SPBase):
         # computed strictly on accepted solutions.)
         solved_chunks = [None] * len(slices)
         prev_st = None
+        wraps0 = _linv_wraps(plan, states[0])
+        flow0 = self._take_flowed_factor(states, split_mode and donate)
         for ci in range(len(slices)):
             if stream is not None:
                 # streamed staging: the prefetch thread has chunk ci
@@ -2497,15 +2618,19 @@ class PHBase(SPBase):
                 # iterates warm-start across scale changes.
                 st_in = st_in._replace(L=prev_st.L,
                                        rho_scale=prev_st.rho_scale)
+            elif flow0 is not None:
+                st_in, flow0 = st_in._replace(L=flow0), None
             # sharded: ONE SPMD chunk solve over all devices (lc
             # scenarios each, psum-reduced termination tests inside
             # the jit); host-chunked: the single-device program
             ck_args = None if sp_args is None else {
                 "chunk": ci, "mode": sp_args["mode"],
                 "devices": ent["devices"]}
-            with obs.span("ph.solve.chunk", cat="ph", args=ck_args):
+            with self._wheel_turn(slices[ci][1], clock), \
+                    obs.span("ph.solve.chunk", cat="ph", args=ck_args):
                 st, x, yA, yB = _solver_call(factors, d_c, q_c, st_in,
                                              donate=donate, **kw)
+                self._wheel_ready(st.pri_rel)
             prev_st = st
             if split_mode:
                 # record a STRIPPED state: keeping each chunk's L
@@ -2544,7 +2669,7 @@ class PHBase(SPBase):
         # without the explicit inverse)
         its, _ = _book_admm_iters(
             ent["admm"], [rec[0] for rec in solved_chunks],
-            plan.mode == "fused", _linv_wraps(plan, states[0]))
+            plan.mode == "fused", wraps0)
         ent["assemble_programs"] += asm_programs
         obs.counter_add("ph.assemble_programs", asm_programs)
         clock.lap("gate")
@@ -2662,8 +2787,10 @@ class PHBase(SPBase):
             kw_r = dict(kw, precision="native", kernel=None,
                         sub_max_iter=max(kw["sub_max_iter"]
                                          + 4 * kw["tail_iter"], 1500))
-            st2, x2, yA2, yB2 = _solver_call(fac_c, d_r, q_r,
-                                             st_r, **kw_r)
+            with self._wheel_turn(slices[ci][1], clock):
+                st2, x2, yA2, yB2 = _solver_call(fac_c, d_r, q_r,
+                                                 st_r, **kw_r)
+                self._wheel_ready(st2.pri_rel)
             pri2 = np.asarray(st2.pri_rel)   # lint: ok[SYNC001] exceptional-path retry sync, booked as its own gate_sync
             gate_syncs += 1
             if obs.enabled():
@@ -2934,6 +3061,9 @@ class PHBase(SPBase):
         per_call = {p: ent["acc"][p] / n for p in
                     ("assemble", "solve", "gate", "reduce")}
         total = sum(per_call.values())
+        kernel = ent.get("kernel")
+        if kernel is not None and self._wheel_port is not None:
+            kernel = dict(kernel, residency=self.residency())
         return {
             "calls": n,
             "seconds_per_call": per_call,
@@ -2953,7 +3083,10 @@ class PHBase(SPBase):
             # backend, l_inv, block_dtype} — ops/kernels.KernelPlan
             # .descriptor(), doc/kernels.md); None on engines predating
             # a kernel-plan build
-            "kernel": ent.get("kernel"),
+            # (an engine of an in-process wheel adds what it shares
+            # with the other cylinders' engines and what is its own:
+            # ``residency()``)
+            "kernel": kernel,
             # the ADMM work of the SAME solve passes the solve seconds
             # cover (pass-1 solves; reset with them): f32 bulk and
             # refinement-tail iterations per solve_loop call, summed
@@ -3517,8 +3650,10 @@ class PHBase(SPBase):
             d_c = data._replace(lb=bl_c, ub=bu_c)
             clock.lap("solve")
             wraps = _linv_wraps(plan, qp_state)
-            qp_state, x_c, yA, yB = _solver_call(
-                factors, d_c, q_c, qp_state, **kw)
+            with self._wheel_turn(self._S_orig, clock):
+                qp_state, x_c, yA, yB = _solver_call(
+                    factors, d_c, q_c, qp_state, **kw)
+                self._wheel_ready(qp_state.pri_rel)
             fused = plan.mode == "fused"
             if fused:
                 # phase honesty (see _ph_step): the fused wait must
@@ -3578,7 +3713,9 @@ class PHBase(SPBase):
             self._fixed_mask, self._fixed_vals, self._w_scale,
             w_on=bool(w_on), slot_slices=self.slot_bounds, lap=clock.lap,
             combine_fn=combine_fn, admm=ent["admm"], exits=book_exits,
-            **kw)
+            # an un-chunked engine's whole batch is its one chunk solve
+            turn=None if self._wheel_port is None
+            else partial(self._wheel_turn, self._S_orig, clock), **kw)
         clock.lap()
         self._qp_states[skey] = qp_state
         self.x, self.yA, self.yB = x, yA, yB
@@ -3791,6 +3928,7 @@ class PHBase(SPBase):
                         "subproblem_polish_chunk", 0)))
                 if not bool(jnp.all(feasible)):
                     return None
+                self._incumbent_rows = obj
                 return float(self.Eobjective(obj))
             self.solve_loop(w_on=False, prox_on=False, update=False,
                             fixed=True)
@@ -3812,6 +3950,9 @@ class PHBase(SPBase):
                 self._qp_states.pop(("fixed", False), None)
                 self._qp_states.pop(("chunks", ("fixed", False)), None)
                 return None
+            # the per-scenario values behind the expectation returned
+            # (the x̂ spokes keep the published candidate's)
+            self._incumbent_rows = self._last_base_obj
             return self.Eobjective_value()
         finally:
             (self._fixed_mask, self._fixed_vals, self.x, self.yA, self.yB,
@@ -4046,6 +4187,7 @@ class PHBase(SPBase):
             obs.counter_add("qp.donated_passes")
         split_mode = isinstance(factors.A_s, SplitMatrix)
         prev_st = None
+        flow0 = self._take_flowed_factor(states, split_mode and donate)
         outs = []
         for ci, (sidx, pidx, _) in enumerate(slices):
             lb_c, ub_c, l_c, u_c, q_c, c0_c = _pool_assemble(
@@ -4059,8 +4201,12 @@ class PHBase(SPBase):
                 # alive, not one per chunk)
                 st_in = st_in._replace(L=prev_st.L,
                                        rho_scale=prev_st.rho_scale)
-            st, x, _, _ = _solver_call(factors, d_c, q_c, st_in,
-                                       donate=donate, **kw)
+            elif flow0 is not None:
+                st_in, flow0 = st_in._replace(L=flow0), None
+            with self._wheel_turn(slices[ci][2]):
+                st, x, _, _ = _solver_call(factors, d_c, q_c, st_in,
+                                           donate=donate, **kw)
+                self._wheel_ready(st.pri_rel)
             prev_st = st
             if split_mode:
                 st = st._replace(L=jnp.zeros((), jnp.float32))
@@ -4075,6 +4221,9 @@ class PHBase(SPBase):
         # privately owned buffers — the next round may donate them
         self._pool_dirty.discard(ck)
         obj_rows = jnp.concatenate([o for o, _, _ in outs])[:rows]
+        # the screen's per-row objectives, row r = p * S + s, beside
+        # the verdict (the pool spoke keeps a round's in ``last_screen``)
+        self._pool_obj_rows = obj_rows
         pri_res = jnp.concatenate([r for _, r, _ in outs])[:rows]
         pri_rel = jnp.concatenate([r for _, _, r in outs])[:rows]
         live = jnp.asarray(np.arange(S) < self._S_orig)
@@ -4083,31 +4232,38 @@ class PHBase(SPBase):
         # THE one stacked D2H of the round (the chunked loop's fused-
         # gate discipline — doc/pipelining.md)
         obs.counter_add("incumbent.gate_syncs")
+        # the screen's chunk solves book like every other solve path's
+        # (``phase_timing(("pool", False))``: ADMM counts and how each
+        # solve ended), behind the verdict: scalar copies, no new wait
+        ent = self._phase_times.setdefault(("pool", False),
+                                           _new_phase_entry())
+        ent["calls"] += 1
+        ent["kernel"] = plan.descriptor()
+        its, _ = _book_admm_iters(ent["admm"], states,
+                                  plan.mode == "fused")
+        _book_exits(ent["exits"], its, _exit_tests(**kw), 0.0)
         if obs.enabled():
             obs.counter_add("xfer.d2h_bytes", v.nbytes)
-            if plan.mode == "fused":
-                # post-verdict scalar copies, not stalls (the verdict
-                # already synced every chunk's program)
-                obs.counter_add("kernel.fused_iters",
-                                sum(int(s.iters) for s in states))
         feas = v[1] > 0.5
-        if not feas.all():
-            # cold-reset the infeasible candidates' rows before the
-            # states are reused as next round's warm starts (see
-            # _pool_rows_zeroed); tail-chunk pad rows duplicate the
-            # LAST candidate's rows, so they inherit ITS verdict — a
-            # blanket keep would preserve diverged pad iterates when
-            # that candidate is infeasible
-            keep = np.repeat(feas, S)
-            keep = np.concatenate(
-                [keep, np.full(len(slices) * chunk - rows, feas[-1])])
-            for ci in range(len(states)):
-                kc = jnp.asarray(keep[ci * chunk:(ci + 1) * chunk])
-                st = states[ci]
-                x_z, yA_z, yB_z, zA_z, zB_z = _pool_rows_zeroed(
-                    st.x, st.yA, st.yB, st.zA, st.zB, kc)
-                states[ci] = st._replace(x=x_z, yA=yA_z, yB=yB_z,
-                                         zA=zA_z, zB=zB_z)
+        # cold-reset the infeasible candidates' rows before the
+        # states are reused as next round's warm starts (see
+        # _pool_rows_zeroed); tail-chunk pad rows duplicate the
+        # LAST candidate's rows, so they inherit ITS verdict — a
+        # blanket keep would preserve diverged pad iterates when
+        # that candidate is infeasible. Run EVERY round (an
+        # all-feasible round keeps every row): the program is then
+        # compiled by the first round, not by the first infeasible
+        # verdict minutes into a run
+        keep = np.repeat(feas, S)
+        keep = np.concatenate(
+            [keep, np.full(len(slices) * chunk - rows, feas[-1])])
+        for ci in range(len(states)):
+            kc = jnp.asarray(keep[ci * chunk:(ci + 1) * chunk])
+            st = states[ci]
+            x_z, yA_z, yB_z, zA_z, zB_z = _pool_rows_zeroed(
+                st.x, st.yA, st.yB, st.zA, st.zB, kc)
+            states[ci] = st._replace(x=x_z, yA=yA_z, yB=yB_z,
+                                     zA=zA_z, zB=zB_z)
         objs = np.where(feas, v[0], np.inf)
         return objs, feas
 
@@ -4189,6 +4345,11 @@ class PH(PHBase):
             with obs.span("ph.iteration", cat="ph", args=sp_args) as sp_it:
                 self.solve_loop(w_on=True, prox_on=True)
                 self.W = self.W_new
+            if it == 1 and self._wheel_port is not None:
+                # three engines share this HBM: the iter-0 mode's warm
+                # states and factor have served (the hot mode took its
+                # warm start from them above)
+                self._free_mode(False)
             if rec_on:
                 obs.histogram_observe("ph.iteration_seconds",
                                       sp_it.seconds)

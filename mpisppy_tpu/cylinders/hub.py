@@ -10,6 +10,7 @@ each `sync()` (ref. hub.py:417-428).
 
 from __future__ import annotations
 
+import collections
 import math
 import threading
 import time
@@ -25,6 +26,13 @@ class Hub(SPCommunicator):
     def __init__(self, spbase_object, spokes=None, options=None):
         super().__init__(spbase_object, options)
         self.spokes = list(spokes or [])
+        # the in-process wheel's arbiter (set by spin_the_wheel when the
+        # wheel has spokes; utils/runtime.WheelArbiter)
+        self.arbiter = None
+        # what the exchange cost and what the spokes' bounds were made
+        # from, totals since ``reset_wheel_timing`` (``wheel_timing``)
+        self._wheel_lock = threading.Lock()
+        self._reset_wheel_totals()
         # best bounds for a MIN problem: outer = lower, inner = upper/incumbent
         self.BestOuterBound = -math.inf
         self.BestInnerBound = math.inf
@@ -132,6 +140,91 @@ class Hub(SPCommunicator):
             resume_hub(self, resume_from,
                        fingerprint=self.options.get(
                            "checkpoint_fingerprint"))
+
+    # ---- the wheel's own anatomy (doc/cylinders.md, doc/observability.md) ----
+    KEEP = 4096     # newest stamps kept per list below
+
+    def _reset_wheel_totals(self):
+        self._sync_tot = {"syncs": 0, "seconds": 0.0, "read_s": 0.0,
+                          "put_s": 0.0, "receive_s": 0.0,
+                          "bytes_read": 0, "bytes_put": 0}
+        # per spoke, from its fresh publishes as the hub consumed them
+        self._wheel_flow = [
+            {"published": 0, "accepted": 0, "rejected": 0,
+             "accepted_at": collections.deque(maxlen=self.KEEP),
+             "lag_iters": collections.deque(maxlen=self.KEEP)}
+            for _ in self.spokes]
+
+    def reset_wheel_timing(self):
+        """Zero ``wheel_timing``'s totals: the arbiter's turns and
+        seconds, the exchange's, the hub's per-spoke ledger and every
+        in-process spoke's own (a driver's window opens here, as it
+        resets ``phase_timing``)."""
+        with self._wheel_lock:
+            self._reset_wheel_totals()
+        if self.arbiter is not None:
+            self.arbiter.reset()
+        for sp in self.spokes:
+            r = getattr(sp, "reset_wheel_totals", None)
+            if callable(r):
+                r()
+
+    def wheel_timing(self):
+        """Where an in-process wheel's time went since the last
+        ``reset_wheel_timing``, readable with no telemetry session:
+
+        - ``cylinders``: per cylinder (hub, spoke0, ...) the chunk
+          solves admitted (``turns``, one in flight at a time: the
+          arbiter's policy), the scenario rows they solved,
+          the seconds the device spent on them (``device_s``) and the
+          seconds the cylinder waited for its turn (``queue_wait_s``);
+          None for a hub-only wheel;
+        - ``sync``: the exchanges (``PHHub.sync``): how many, their
+          host seconds split read-back / put / receive, and the bytes
+          read back from the device and put into the spokes' windows;
+        - ``spokes``: per spoke the fresh publishes the hub consumed
+          (``published``) and settled ``accepted`` / ``rejected``, the
+          ``perf_counter`` stamps of the accepted ones, the hub syncs
+          between the payload a bound was made from and the sync that
+          consumed it (``lag_iters``, one per publish), and the
+          spoke's own totals (``Spoke.wheel_totals``: payloads read,
+          bounds made, rounds)."""
+        with self._wheel_lock:
+            sync = dict(self._sync_tot)
+            spokes = {}
+            for i, f in enumerate(self._wheel_flow):
+                sp = self.spokes[i]
+                own = getattr(sp, "wheel_totals", None)
+                spokes[f"spoke{i}"] = {
+                    "spoke": type(sp).__name__,
+                    "char": getattr(sp, "converger_spoke_char", "?"),
+                    "published": f["published"],
+                    "accepted": f["accepted"], "rejected": f["rejected"],
+                    "accepted_at": list(f["accepted_at"]),
+                    "lag_iters": list(f["lag_iters"]),
+                    "own": own() if callable(own) else None}
+        return {"cylinders": None if self.arbiter is None
+                else self.arbiter.totals(),
+                "sync": sync, "spokes": spokes}
+
+    def _book_wheel_publish(self, i, accepted):
+        """One fresh publish of spoke ``i`` into the wheel ledger: its
+        verdict, when it was accepted, and how many hub syncs lie
+        between the payload it was made from and now (the hub window's
+        write-id counts the syncs; an in-process spoke notes the id its
+        bound came from beside the publish seq, ``Spoke.spoke_to_hub``)."""
+        sp = self.spokes[i]
+        src = getattr(sp, "_publish_source", None)
+        now = sp.hub_window.read_id()         # KILL once terminated
+        with self._wheel_lock:
+            f = self._wheel_flow[i]
+            f["published"] += 1
+            f["accepted" if accepted else "rejected"] += 1
+            if accepted:
+                f["accepted_at"].append(time.perf_counter())
+            if src is not None and 0 < src[1] <= now \
+                    and src[0] == self._spoke_flow[i]["last_seq"]:
+                f["lag_iters"].append(int(now - src[1]))
 
     @staticmethod
     def _new_flow():
@@ -322,6 +415,7 @@ class Hub(SPCommunicator):
         if not verdicts or i is None or i >= len(self._spoke_flow):
             return
         accepted = any(v == "accepted" for v in verdicts)
+        self._book_wheel_publish(i, accepted)
         with self._flow_lock:
             flow = self._spoke_flow[i]
             if accepted:
@@ -830,11 +924,30 @@ class PHHub(Hub):
             self.spokes[i].hub_window.put(X)
 
     def sync(self):
-        """Called from inside the PH iteration (ref. phbase.py:1522)."""
-        W, X = self._hub_arrays()
-        self.send_ws(X, W=W)
-        self.send_nonants(X)
-        self.receive_bounds()
+        """Called from inside the PH iteration (ref. phbase.py:1522).
+        Spans ``hub.sync`` > ``.read`` (the two read-backs of W and the
+        nonants from the device) / ``.put`` / ``.receive``; the seconds
+        and bytes land in ``wheel_timing()["sync"]``."""
+        with obs.span("hub.sync", cat="wheel") as sp:
+            with obs.span("hub.sync.read", cat="wheel") as sp_r:
+                W, X = self._hub_arrays()
+            with obs.span("hub.sync.put", cat="wheel") as sp_p:
+                self.send_ws(X, W=W)
+                self.send_nonants(X)
+            with obs.span("hub.sync.receive", cat="wheel") as sp_v:
+                self.receive_bounds()
+        put = sum((W.nbytes if has_w else 0) + (X.nbytes if has_x else 0)
+                  for has_w, has_x in (s.hub_read_layout()
+                                       for s in self.spokes))
+        with self._wheel_lock:
+            t = self._sync_tot
+            t["syncs"] += 1
+            t["seconds"] += sp.seconds
+            t["read_s"] += sp_r.seconds
+            t["put_s"] += sp_p.seconds
+            t["receive_s"] += sp_v.seconds
+            t["bytes_read"] += W.nbytes + X.nbytes
+            t["bytes_put"] += put
 
     def is_converged(self) -> bool:
         # at iter 1 seed the outer bound with PH's trivial bound

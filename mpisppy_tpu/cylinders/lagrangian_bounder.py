@@ -18,6 +18,8 @@ import time
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
+from ..utils.runtime import wheel_host_section
 from .spoke import OuterBoundWSpoke, OuterBoundNonantSpoke
 
 _UNSET = object()
@@ -167,6 +169,23 @@ class LagrangianOuterBound(OuterBoundWSpoke):
         # parked by install_spoke_state; lagrangian_prep bounds at it
         # instead of the W=0 cold prep
         self._resume_W = None
+        # what the last DEVICE bound was made of (the benchmark's
+        # reference check reads it, tests too): the projected W, the
+        # per-scenario certified values whose expectation was
+        # published, the hub write-id of the payload
+        self.last_bound = None
+        self._certified_rows = None
+
+    def reset_wheel_totals(self):
+        super().reset_wheel_totals()
+        self._certify_tot = {"calls": 0, "seconds": 0.0}
+
+    def wheel_totals(self):
+        return dict(super().wheel_totals(), certify=dict(self._certify_tot))
+
+    def _note_bound(self, W, value, rows):
+        self.last_bound = {"W": W, "value": float(value), "rows": rows,
+                           "source": self._last_hub_id}
 
     # ---- durable warm state (mpisppy_tpu.ckpt) ----
     def spoke_state(self):
@@ -287,9 +306,18 @@ class LagrangianOuterBound(OuterBoundWSpoke):
             # device — one repair suffices).
             yA = np.asarray(jnp.asarray(self.opt.yA, jnp.float32),
                             np.float64)
-            b, _ = self._certifier.bound(
-                yA, None if W is None else np.asarray(W, np.float64))
-            return b if np.isfinite(b) else None
+            # host float64 work: the wheel's other cylinders do not
+            # wait for it (utils/runtime.wheel_host_section)
+            with wheel_host_section(self.opt), \
+                    obs.span("lagrangian.certify", cat="wheel") as sp:
+                b, rows = self._certifier.bound(
+                    yA, None if W is None else np.asarray(W, np.float64))
+            self._certify_tot["calls"] += 1
+            self._certify_tot["seconds"] += sp.seconds
+            if np.isfinite(b):
+                self._certified_rows = rows
+                return b
+            return None
         except Exception as e:
             # evaluation failure may be TRANSIENT (host memory spike at
             # uc1024 scale): log, fall back to the device certificate
@@ -317,7 +345,11 @@ class LagrangianOuterBound(OuterBoundWSpoke):
             opt.solve_loop(w_on=True, prox_on=False, update=False)
         dev = opt.Ebound()
         cert = self._host_certified(W)
-        return dev if cert is None else cert
+        if cert is None:
+            self._note_bound(W, dev, opt._last_dual_obj)
+            return dev
+        self._note_bound(W, cert, self._certified_rows)
+        return cert
 
     def _ensure_tightener(self):
         if self._tightener is None:
@@ -396,7 +428,9 @@ class LagrangianOuterBound(OuterBoundWSpoke):
         """LP-relaxation bound at W: exact host LP oracle when enabled,
         else the certified device bound."""
         if self._exact:
-            b = self._oracle_bound(np.asarray(W), time_limit=self._lp_tl)
+            with wheel_host_section(self.opt):      # host LPs
+                b = self._oracle_bound(np.asarray(W),
+                                       time_limit=self._lp_tl)
             if b is not None:
                 return b
             if self.killed():
@@ -404,15 +438,20 @@ class LagrangianOuterBound(OuterBoundWSpoke):
             # oracle failure: fall through to the device bound
         self.opt.W = jnp.asarray(W, self.opt.dtype)
         self.opt.solve_loop(w_on=True, prox_on=False, update=False)
-        return self.opt.Ebound()
+        b = self.opt.Ebound()
+        self._note_bound(W, b, self.opt._last_dual_obj)
+        return b
 
     def _mip_refresh(self, W):
         """MIP-tight L(W): expensive (B&B per scenario), so it runs on
         the newest W at the configured cadence and aborts on kill."""
         self._last_mip_at = time.monotonic()
-        b = self._oracle_bound(np.asarray(W), milp=True,
-                               time_limit=self._mip_tl,
-                               mip_gap=self._mip_gap)
+        # blocking for the SPOKE (a branch-and-bound per scenario), not
+        # for the wheel: its place in the arbiter's cycle is given up
+        with wheel_host_section(self.opt):
+            b = self._oracle_bound(np.asarray(W), milp=True,
+                                   time_limit=self._mip_tl,
+                                   mip_gap=self._mip_gap)
         self._last_mip_ok = b is not None
         return b
 

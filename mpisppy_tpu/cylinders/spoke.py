@@ -15,6 +15,7 @@ import time
 import numpy as np
 
 from .. import obs
+from ..utils.runtime import wheel_pass
 from . import SPOKE_SLEEP_TIME
 from .spcommunicator import (LINEAGE_SLOTS, SPCommunicator, Window,
                              wire_payload)
@@ -64,6 +65,11 @@ class Spoke(SPCommunicator):
         # publish (same seq, same stamps — only the write-id advances)
         self._publish_seq = 0
         self._last_wire = None
+        # (publish seq, hub write-id of the payload it was made from):
+        # the hub of an in-process wheel reads it beside the lineage
+        # seq to count the syncs a bound lags by (Hub.wheel_timing)
+        self._publish_source = None
+        self.reset_wheel_totals()
         # ---- durable warm state (mpisppy_tpu.ckpt, doc/fault_
         # tolerance.md): with "checkpoint_dir" set, this spoke keeps a
         # tiny atomic state file fresh (best bound, incumbent, duals,
@@ -84,6 +90,17 @@ class Spoke(SPCommunicator):
         # in the ctor chain
         self._resume_state_path = self.options.get("resume_state")
 
+    # ---- this spoke's part of Hub.wheel_timing ----
+    def reset_wheel_totals(self):
+        self._wheel_tot = {"reads": 0, "read_s": 0.0, "read_bytes": 0,
+                           "bounds": 0, "bound_s": 0.0}
+
+    def wheel_totals(self):
+        """Payloads read from the hub and bounds published since the
+        last reset, with their host seconds; subclasses add theirs
+        (the pool spoke its rounds)."""
+        return dict(self._wheel_tot)
+
     # -- wire protocol (ref. spoke.py:59-99) --
     def spoke_to_hub(self, values, t_compute=None):
         """Publish one payload with its lineage stamp. ``t_compute`` is
@@ -91,6 +108,7 @@ class Spoke(SPCommunicator):
         compute and publish coincide for every current spoke — the slot
         exists so a spoke that batches results can stamp honestly)."""
         self._publish_seq += 1
+        self._publish_source = (float(self._publish_seq), self._last_hub_id)
         self._last_wire = wire_payload(values, self._publish_seq,
                                        t_compute=t_compute)
         self._last_put = time.monotonic()
@@ -99,13 +117,23 @@ class Spoke(SPCommunicator):
     def spoke_from_hub(self):
         """Return (fresh, values). Fresh iff the hub's write-id advanced.
         Peek the id first so stale polls don't copy the whole payload."""
+        # looking for a payload ends the pass the last one started; a
+        # fresh one starts the next (the wheel's arbiter keeps this
+        # cylinder's place in its cycle for the length of a pass)
+        wheel_pass(self.opt, False)
         wid = self.hub_window.read_id()
         if wid == Window.KILL or wid <= self._last_hub_id:
             return False, None
-        values, wid = self.hub_window.read()
+        with obs.span("spoke.read", cat="wheel") as sp:
+            values, wid = self.hub_window.read()
         if wid == Window.KILL:
             return False, None
         self._last_hub_id = wid
+        t = self._wheel_tot
+        t["reads"] += 1
+        t["read_s"] += sp.seconds
+        t["read_bytes"] += values.nbytes
+        wheel_pass(self.opt, True)
         return True, values
 
     def got_kill_signal(self) -> bool:
@@ -118,6 +146,10 @@ class Spoke(SPCommunicator):
         if now - self._last_kill_check < self._sleep_time:
             time.sleep(self._sleep_time)
         self._last_kill_check = time.monotonic()
+        # between iterations of its loop a spoke is in no pass (see
+        # spoke_from_hub): a spoke that stops looking for payloads must
+        # not keep the wheel's other cylinders waiting for its place
+        wheel_pass(self.opt, False)
         self._heartbeat()
         self.maybe_write_spoke_state()
         return self.killed()
@@ -317,6 +349,15 @@ class _BoundSpoke(Spoke):
                                             np.nan))
 
     def update_bound(self, value: float):
+        with obs.span("spoke.bound", cat="wheel",
+                      args={"spoke": type(self).__name__,
+                            "source": self._last_hub_id}
+                      if obs.enabled() else None) as sp:
+            self._publish_bound(value)
+        self._wheel_tot["bounds"] += 1
+        self._wheel_tot["bound_s"] += sp.seconds
+
+    def _publish_bound(self, value: float):
         t_compute = time.time()      # lineage compute stamp (wall clock)
         prev_t = self._trace[-1][0] if self._trace else None
         self.bound = float(value)

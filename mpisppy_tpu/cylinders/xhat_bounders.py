@@ -19,11 +19,13 @@ variants chiefly differ in candidate *order*, exactly as upstream.
 
 from __future__ import annotations
 
+import collections
 import time
 
 import numpy as np
 
 from .. import obs
+from ..utils.runtime import wheel_host_section
 from .spoke import InnerBoundNonantSpoke
 
 
@@ -186,12 +188,16 @@ class _XhatInnerBound(InnerBoundNonantSpoke):
                 self._oracle_pool = OraclePool(
                     self.opt.batch,
                     n_workers=self.options.get("xhat_oracle_workers"))
-            return "ok", self._oracle_pool.incumbent_value(
-                self.opt.round_nonants(xhat), self.opt.batch.prob,
-                milp=self._eval_milp, pin_mask=self._pin_mask,
-                time_limit=float(self.options.get(
-                    "xhat_oracle_time_limit", 60.0)),
-                kill_check=self.killed)
+            xr = self.opt.round_nonants(xhat)
+            # the oracle's LPs / MILPs run on the host: the wheel's
+            # other cylinders do not wait for them
+            with wheel_host_section(self.opt):
+                return "ok", self._oracle_pool.incumbent_value(
+                    xr, self.opt.batch.prob,
+                    milp=self._eval_milp, pin_mask=self._pin_mask,
+                    time_limit=float(self.options.get(
+                        "xhat_oracle_time_limit", 60.0)),
+                    kill_check=self.killed)
         except Exception as e:
             from .. import global_toc
             global_toc(f"{type(self).__name__}: exact incumbent eval "
@@ -319,13 +325,15 @@ class _XhatInnerBound(InnerBoundNonantSpoke):
                     n_workers=self.options.get(
                         "xhat_oracle_workers",
                         min(limit, os.cpu_count() or 1)))
-            res = self._oracle_pool.scenario_values(
-                milp=True,
-                time_limit=float(self.options.get(
-                    "xhat_oracle_time_limit", 10.0)),
-                mip_gap=float(self.options.get("xhat_oracle_gap", 1e-4)),
-                scenarios=range(limit), kill_check=self.killed,
-                return_x=True)
+            with wheel_host_section(self.opt):      # host MILPs
+                res = self._oracle_pool.scenario_values(
+                    milp=True,
+                    time_limit=float(self.options.get(
+                        "xhat_oracle_time_limit", 10.0)),
+                    mip_gap=float(self.options.get("xhat_oracle_gap",
+                                                   1e-4)),
+                    scenarios=range(limit), kill_check=self.killed,
+                    return_x=True)
         except Exception as e:
             from .. import global_toc
             global_toc(f"{type(self).__name__}: oracle candidates "
@@ -459,9 +467,31 @@ class DiveInnerBound(_XhatInnerBound):
                 else max(100.0 * float(getattr(self.opt, "sub_eps", 1e-8)),
                          1e-6)
         self._publish_feas_tol = float(tol)
+        # the pool SCREEN's tolerance is the user's ``xhat_feas_tol``
+        # where one is set. Its DEFAULT (1e-4) is under a df32 engine's
+        # ~1e-3 residual floor: a cold df32 fixed-nonant solve ends by
+        # its own criteria with a few rows at 2.6e-4 (7 of 256 UC rows,
+        # the max-commitment anchor among them: my chip run, PR 39);
+        # screened at 1e-4 those candidates are reset cold every round
+        # and the pool never admits one. So a df32 engine's default is
+        # its floor. The publish gate above still decides what is
+        # published.
+        self._screen_kw = {}        # the engine's own (its option)
+        if "xhat_feas_tol" not in o and getattr(
+                self.opt, "sub_precision", "native") == "df32":
+            self._screen_kw["feas_tol"] = 1e-3
         self._rounds = 0
         self._dry = 0
         self._last_X_key = None
+        # the published incumbent's per-scenario values (the rows
+        # whose expectation is the bound), for whoever checks them
+        self.best_xhat_rows = None
+        # what the last completed round SCREENED, for whoever checks it
+        # (the benchmark's reference check, tests): the (P, K) pool,
+        # its verdict, the per-row objectives (row p * S + s) where the
+        # engine's batched screen ran, the hub write-id of the payload
+        # and the ``perf_counter`` stamp of the screen's end
+        self.last_screen = None
         # dive slots: BINARY nonant slots inside the pinned set — the
         # slots a candidate decides. Derived integer nonants (UC
         # startups) stay out via xhat_pin_vars exactly like every other
@@ -505,7 +535,30 @@ class DiveInnerBound(_XhatInnerBound):
             _, X = self.unpack_hub(values)
             self.try_pool(np.asarray(X, dtype=np.float64))
 
+    def reset_wheel_totals(self):
+        super().reset_wheel_totals()
+        self._round_tot = {"rounds": 0, "seconds": 0.0, "pool_s": 0.0,
+                           "verify_s": 0.0, "verifications": 0,
+                           "round_s": collections.deque(maxlen=1024)}
+
+    def wheel_totals(self):
+        return dict(super().wheel_totals(),
+                    rounds=dict(self._round_tot,
+                                round_s=list(self._round_tot["round_s"])))
+
     def try_pool(self, X):
+        """One round: span ``incumbent.round`` > ``.pool`` (the batched
+        screen) / ``.verify`` (the winner's single-candidate
+        re-evaluation); seconds in ``wheel_totals()["rounds"]``."""
+        with obs.span("incumbent.round", cat="wheel") as sp:
+            ran = self._try_pool(X)
+        if ran:
+            t = self._round_tot
+            t["rounds"] += 1
+            t["seconds"] += sp.seconds
+            t["round_s"].append(sp.seconds)
+
+    def _try_pool(self, X):
         from ..ops import incumbent as _inc
         key = X.tobytes()
         reused = key == self._last_X_key
@@ -522,11 +575,19 @@ class DiveInnerBound(_XhatInnerBound):
             n_random=self._n_random, ball=self._ball, seed=self._seed,
             round_index=self._rounds, random_only=reused)
         if pool is None:       # unchanged block, nothing left to vary
-            return
+            return False
         self._rounds += 1
         obs.counter_add("incumbent.rounds")
-        objs, feas = self.opt.evaluate_incumbent_pool(
-            pool, pin_mask=self._pin_mask)
+        with obs.span("incumbent.round.pool", cat="wheel") as sp_p:
+            self.opt._pool_obj_rows = None
+            objs, feas = self.opt.evaluate_incumbent_pool(
+                pool, pin_mask=self._pin_mask, **self._screen_kw)
+        self._round_tot["pool_s"] += sp_p.seconds
+        self.last_screen = {"pool": np.asarray(pool), "objs": objs,
+                            "feas": feas,
+                            "rows": self.opt._pool_obj_rows,
+                            "source": self._last_hub_id,
+                            "at": time.perf_counter()}
         # no killed() gate here: the evaluation is already paid, the
         # publish below is one window put (the kill signal rides the
         # OTHER window), and dropping a computed incumbent on the way
@@ -553,9 +614,14 @@ class DiveInnerBound(_XhatInnerBound):
                 # warm-started full-batch solve makes the published
                 # value evaluator-grade (the same number every other x̂
                 # spoke would publish for this candidate).
-                best_val = self.opt.calculate_incumbent(
-                    cand, feas_tol=self._publish_feas_tol,
-                    pin_mask=self._pin_mask)
+                with obs.span("incumbent.round.verify",
+                              cat="wheel") as sp_v:
+                    best_val = self.opt.calculate_incumbent(
+                        cand, feas_tol=self._publish_feas_tol,
+                        pin_mask=self._pin_mask)
+                self._round_tot["verify_s"] += sp_v.seconds
+                self._round_tot["verifications"] += 1
+                rows = getattr(self.opt, "_incumbent_rows", None)
                 if self.options.get("xhat_exact_eval", False) \
                         and self._incumbent_mode != "device" \
                         and best_val is not None:
@@ -566,6 +632,7 @@ class DiveInnerBound(_XhatInnerBound):
                 if best_val is not None and (self.bound is None
                                              or best_val < self.bound):
                     self.best_xhat = cand
+                    self.best_xhat_rows = rows
                     self.update_bound(best_val)
                     improved = True
                     obs.counter_add("incumbent.improvements")
@@ -588,6 +655,7 @@ class DiveInnerBound(_XhatInnerBound):
             if status == "ok" and exact is not None \
                     and (self.bound is None or exact < self.bound):
                 self.update_bound(exact)
+        return True
 
 
 class XhatLooperInnerBound(_XhatInnerBound):

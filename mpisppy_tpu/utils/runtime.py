@@ -7,9 +7,11 @@ shared when every process configures the same directory).
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import os
 import threading
+import time
 
 # the cache path is part of a cache entry's key, so the default must be
 # a FIXED place every process of this checkout resolves identically:
@@ -116,6 +118,178 @@ def compile_serialized(jitted, static_argnames=()):
     call.__name__ = getattr(jitted, "__name__", "jitted")
     call.__wrapped__ = jitted
     return call
+
+
+# ---- the wheel's ONE owner of the device queue (doc/cylinders.md) ----
+# The cylinders of an in-process wheel are host threads that share one
+# device and its one execution queue. Left to themselves they enqueue
+# whole passes (a hub pass is 4 chunk solves at S = 256 / chunk 64, a
+# Lagrangian pass 4, a pool round 20 plus a 4-chunk verification), and
+# the hub's O(1) reads (the gate, ``float(conv)``, the exchange's
+# read-backs) wait behind whatever another thread queued: hub
+# iterations of 12.8 - 25 s with 1 - 4 s in the gate alone (my chip
+# run, PR 39, S = 256). The policy, stated once: chunk solves of ALL
+# cylinders are admitted at the grain of ONE chunk solve, ONE in flight
+# at a time, in a FIXED cyclic order (hub, spoke 0, spoke 1, ...). A
+# cylinder that is IN A PASS (the hub for as long as it iterates; a
+# spoke from the hub payload it read to its next look for one) keeps
+# its place in the cycle through the SHORT host work between its
+# solves: the others wait for it, so which solve follows which does not
+# depend on thread timing. A cylinder between passes, with nothing to
+# solve, is passed over and loses nothing, and so is a spoke inside a
+# host-only section of its pass (``wheel_host_section``: a host
+# oracle's LPs and MILPs, the float64 certification): the wheel never
+# waits for host work that may take minutes. So a read of the hub's
+# waits for at most one foreign chunk solve, no cylinder starves
+# (upstream gives each cylinder its own ranks: equal turns are the
+# faithful mapping), and the same run interleaves the same way.
+
+
+class WheelArbiter:
+    """Admission of chunk solves for the cylinders of ONE in-process
+    wheel (``utils/sputils.spin_the_wheel`` builds it when the wheel
+    has spokes and hands every engine its ``port``). An engine outside
+    such a wheel has no port and never comes here: the hub-only
+    engine, a mesh, the serve path and APH's dispatch are untouched.
+
+    A turn is held from admission until the caller leaves the ``with``
+    block, which it does once its solve's outputs are READY (the
+    engine blocks on them inside the turn): "in flight" means on the
+    device's queue, not merely enqueued by the host."""
+
+    LOG = 4096      # admitted turns kept for ``turn_log`` (tests, traces)
+
+    def __init__(self, names):
+        self.names = tuple(names)
+        self._cv = threading.Condition()
+        self._waiting = set()       # asked for a turn
+        self._in_pass = set()       # keep their place between solves
+        self._busy = False          # a turn is held: its solve is in flight
+        self._last = len(self.names) - 1      # so cylinder 0 goes first
+        self._log = collections.deque(maxlen=self.LOG)
+        self.reset()
+
+    def reset(self):
+        """Zero the totals (the seconds reset with ``phase_timing``'s)."""
+        with self._cv:
+            self._tot = {n: {"turns": 0, "rows": 0, "queue_wait_s": 0.0,
+                             "device_s": 0.0}
+                         for n in self.names}
+
+    def port(self, name):
+        """One cylinder's way in: ``with port(rows): <enqueue one chunk
+        solve and block on it>``, and ``port.begin_pass()`` /
+        ``port.end_pass()`` around the work one hub payload (or, for
+        the hub, one run) starts."""
+        return _WheelPort(self, self.names.index(name))
+
+    def _next(self):
+        n = len(self.names)
+        for k in range(1, n + 1):
+            i = (self._last + k) % n
+            if i in self._waiting or i in self._in_pass:
+                return i
+        return None
+
+    def _set_in_pass(self, i, on):
+        with self._cv:
+            if on:
+                self._in_pass.add(i)
+            else:
+                self._in_pass.discard(i)
+                self._cv.notify_all()       # whoever waited for it
+
+    @contextlib.contextmanager
+    def _turn(self, i, rows):
+        from .. import obs
+
+        name = self.names[i]
+        with obs.span("wheel.queue_wait", cat="wheel",
+                      args={"cylinder": name}) as sp_w:
+            with self._cv:
+                self._waiting.add(i)
+                self._cv.notify_all()       # it may be the awaited one
+                while self._busy or self._next() != i:
+                    self._cv.wait()
+                self._waiting.discard(i)
+                self._busy = True
+                self._last = i
+        t_adm = time.perf_counter()
+        try:
+            with obs.span("wheel.turn", cat="wheel",
+                          args={"cylinder": name, "rows": rows}):
+                yield
+        finally:
+            t_done = time.perf_counter()
+            with self._cv:
+                self._busy = False
+                tot = self._tot[name]
+                tot["turns"] += 1
+                tot["rows"] += rows
+                tot["queue_wait_s"] += sp_w.seconds
+                tot["device_s"] += t_done - t_adm
+                self._log.append((name, rows, t_adm, t_done))
+                self._cv.notify_all()
+
+    def totals(self):
+        """{cylinder: {turns, rows, queue_wait_s, device_s}} since the
+        last ``reset``."""
+        with self._cv:
+            return {n: dict(t) for n, t in self._tot.items()}
+
+    def turn_log(self):
+        """The last ``LOG`` admitted turns, oldest first:
+        (cylinder, rows, admitted at, done at) on ``perf_counter``."""
+        with self._cv:
+            return list(self._log)
+
+
+def wheel_pass(opt, begin):
+    """Begin or end a pass of the cylinder whose engine ``opt`` is (a
+    cylinder in a pass keeps its place in the arbiter's cycle); nothing
+    for an engine outside an in-process wheel."""
+    port = getattr(opt, "_wheel_port", None)
+    if port is not None:
+        port.begin_pass() if begin else port.end_pass()
+
+
+@contextlib.contextmanager
+def wheel_host_section(opt):
+    """A host-only stretch inside a spoke's pass (a host oracle's LPs
+    or MILPs, a subprocess, the float64 certification): the cylinder
+    gives up its place in the arbiter's cycle for its length, so no
+    other cylinder's turn waits for host work, and takes it back
+    after. Nothing outside a pass or outside an in-process wheel."""
+    port = getattr(opt, "_wheel_port", None)
+    held = port is not None and port.in_pass()
+    if held:
+        port.end_pass()
+    try:
+        yield
+    finally:
+        if held:
+            port.begin_pass()
+
+
+class _WheelPort:
+    """What one engine holds of its wheel's arbiter."""
+
+    __slots__ = ("_arb", "_i")
+
+    def __init__(self, arb, i):
+        self._arb, self._i = arb, i
+
+    def __call__(self, rows):
+        return self._arb._turn(self._i, int(rows))
+
+    def in_pass(self):
+        return self._i in self._arb._in_pass
+
+    def begin_pass(self):
+        self._arb._set_in_pass(self._i, True)
+
+    def end_pass(self):
+        self._arb._set_in_pass(self._i, False)
 
 
 _SPAWN_ENV_LOCK = threading.Lock()

@@ -20,6 +20,7 @@ import threading
 import time
 
 from .. import global_toc
+from .runtime import WheelArbiter, wheel_pass
 
 
 def nonant_slot_names(batch):
@@ -142,6 +143,18 @@ def spin_the_wheel(hub_dict, list_of_spoke_dicts=(), spin_timeout=None,
 
     hub = hub_dict["hub_class"](hub_opt, spokes=spokes,
                                 **hub_dict.get("hub_kwargs", {}))
+    if spokes:
+        # ONE owner of the device queue for the cylinders of this
+        # in-process wheel (utils/runtime.WheelArbiter: chunk solves
+        # admitted one at a time, in the fixed order hub, spoke 0,
+        # spoke 1, ... among the cylinders that have one ready). A
+        # hub-only wheel and a sharded hub engine get no port.
+        hub.arbiter = WheelArbiter(
+            ["hub"] + [f"spoke{i}" for i in range(len(spokes))])
+        for name, opt in [("hub", hub_opt)] + [
+                (f"spoke{i}", sp.opt) for i, sp in enumerate(spokes)]:
+            if getattr(opt, "_shard_ops", None) is None:
+                opt._wheel_port = hub.arbiter.port(name)
     hub.make_windows()
     hub.setup_hub()
     if register_hub is not None:
@@ -160,6 +173,10 @@ def spin_the_wheel(hub_dict, list_of_spoke_dicts=(), spin_timeout=None,
             sp.main()
         except BaseException as e:  # surface spoke crashes to the caller
             spoke_errors[i] = e
+        finally:
+            # however a cylinder ends, it gives up its place in the
+            # arbiter's cycle (utils/runtime.WheelArbiter)
+            wheel_pass(sp.opt, False)
 
     threads = [threading.Thread(target=_run_spoke, args=(i, sp),
                                 name=f"spoke{i}", daemon=True)
@@ -185,6 +202,7 @@ def spin_the_wheel(hub_dict, list_of_spoke_dicts=(), spin_timeout=None,
         except ValueError:
             prev_sigterm = None         # not the main thread
     try:
+        wheel_pass(hub_opt, True)       # in a pass for as long as it iterates
         hub.main()                      # ref. sputils.py:115 spcomm.main()
     except BaseException:
         # exceptional exit skips hub_finalize — release the status
@@ -193,6 +211,7 @@ def spin_the_wheel(hub_dict, list_of_spoke_dicts=(), spin_timeout=None,
         hub.shutdown_live()
         raise
     finally:
+        wheel_pass(hub_opt, False)
         if prev_sigterm is not None:
             import signal as _signal
             _signal.signal(_signal.SIGTERM, prev_sigterm)
