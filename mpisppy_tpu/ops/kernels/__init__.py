@@ -34,7 +34,8 @@ from dataclasses import dataclass
 
 from ...utils.config import FUSED_IR_SWEEPS
 from ..qp_solver import (PackedMatrix, SplitMatrix, _needs_host_factor,
-                         _trace_seg, f64_product_form, qp_solve)
+                         _trace_seg, f64_polish_form, f64_product_form,
+                         qp_solve)
 from .reference import fused_mixed_solve, l_inv_profitable
 
 
@@ -62,6 +63,7 @@ class KernelPlan:
     l_inv: bool = False
     A_lo: object = None          # bulk-phase A_s operand (mixed/df32)
     f64_products: str | None = None   # qp_solver.f64_product_form(A_s)
+    f64_polish: str | None = None     # qp_solver.f64_polish_form(A_s)
 
     def descriptor(self) -> dict:
         """The bench/telemetry kernel block. ``backend`` and
@@ -70,10 +72,15 @@ class KernelPlan:
         ``est_hbm_bytes_per_iter`` keep the keys). ``f64_products`` is
         how this backend runs the batched float64 products of a
         per-scenario matrix (``"reduce"`` / ``"dot"``,
-        doc/kernels.md §3d), None where the factors have none."""
+        doc/kernels.md §3d), None where the factors have none;
+        ``f64_polish`` how it runs the factor-and-substitute side of a
+        float64 polish over these factors (``"unrolled"`` /
+        ``"library"``, §3e), None where no float64 polish can run (a
+        split matrix never polishes)."""
         return {"mode": self.mode, "backend": "reference",
                 "l_inv": bool(self.l_inv), "block_dtype": "f32",
-                "f64_products": self.f64_products}
+                "f64_products": self.f64_products,
+                "f64_polish": self.f64_polish}
 
 
 def prepare(factors, *, mode="auto", l_inv="auto", precision="native",
@@ -87,14 +94,15 @@ def prepare(factors, *, mode="auto", l_inv="auto", precision="native",
     config error the engine raises before any trace; ``auto`` falls
     back to segmented here, so exotic sweep counts keep working through
     the host-segmented drivers."""
-    form = f64_product_form(factors.A_s)
+    forms = dict(f64_products=f64_product_form(factors.A_s),
+                 f64_polish=f64_polish_form(factors.A_s))
     if int(ir_sweeps) not in FUSED_IR_SWEEPS:
         if mode == "fused":
             raise ValueError(
                 f"kernel mode 'fused' supports ir_sweeps in "
                 f"[{FUSED_IR_SWEEPS.start}, {FUSED_IR_SWEEPS.stop - 1}]"
                 f"; got {ir_sweeps} (use 'segmented')")
-        return KernelPlan(mode="segmented", f64_products=form)
+        return KernelPlan(mode="segmented", **forms)
     if mode == "fused" and _needs_host_factor(factors):
         # explicit fused cannot serve these factors: the tail handoff
         # and in-loop rho adaptation would call _factorize in-trace on
@@ -107,7 +115,7 @@ def prepare(factors, *, mode="auto", l_inv="auto", precision="native",
             "(untrusted f64 device linalg on this backend); use "
             "'segmented', or 'auto' which falls back automatically")
     if resolve_mode(mode, factors) == "segmented":
-        return KernelPlan(mode="segmented", f64_products=form)
+        return KernelPlan(mode="segmented", **forms)
     split = isinstance(factors.A_s, SplitMatrix)
     use_linv = False
     if split:
@@ -129,8 +137,7 @@ def prepare(factors, *, mode="auto", l_inv="auto", precision="native",
             # non-split mixed: the bulk casts the dense operand
             # in-trace, exactly as qp_solve_mixed does eagerly
             A_lo = factors.A_s
-    return KernelPlan(mode="fused", l_inv=use_linv, A_lo=A_lo,
-                      f64_products=form)
+    return KernelPlan(mode="fused", l_inv=use_linv, A_lo=A_lo, **forms)
 
 
 def kernel_solve(plan: KernelPlan, factors, data, q, state, *,

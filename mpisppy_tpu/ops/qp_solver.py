@@ -639,14 +639,150 @@ def factorize_dispatch(factors: QPFactors, rho_scale):
 
 
 def _tri_solve(L, b):
-    """Solve M x = b given a true Cholesky factor L; b (S, n). Used by the
-    POLISH only (its rho_big penalty systems are too ill-conditioned for
-    an explicit inverse); the main loop applies _chol_solve's inverse."""
+    """Solve M x = b given a true BATCHED Cholesky factor L (S, n, n);
+    b (S, n): the ``lax.linalg.triangular_solve`` pair. Two users: the
+    POLISH's library form (its rho_big penalty systems, cond ~
+    rho_big/sigma, are too ill-conditioned for an explicit M⁻¹; on the
+    TPU at small n the polish takes ``_linv_pair_solve`` instead, see
+    ``f64_polish_form``) and ``_chol_solve`` on a per-scenario f32
+    factor. The native-f64 main loop applies _chol_solve's inverse."""
     y = jax.lax.linalg.triangular_solve(L, b[..., None], left_side=True,
                                         lower=True, transpose_a=False)
     x = jax.lax.linalg.triangular_solve(L, y, left_side=True,
                                         lower=True, transpose_a=True)
     return x[..., 0]
+
+
+# ---- the polish's small batched float64 linalg (ISSUE 40) ----
+# The TPU has no float64 linalg either: its compiler expands a batched
+# f64 ``cholesky`` / ``triangular_solve`` into ``while`` loops over rows
+# with ``dynamic-update-slice``s, and the ``smi,sm,smj->sij`` product
+# into the limb loop nest of the products above. On a served farmer
+# stack ((24, 12, 12): 3 factorizations and 288 triangular solves a
+# polish call) that was 68 ms a call, twelve calls a wheel (PERF.md §6,
+# PR 40). The SAME arithmetic written as recurrences UNROLLED over the
+# static n, on whole (S, n) / (S, n, n) slabs, is element-wise float64,
+# which the compiler runs as soft-float inside ordinary fusions: no
+# loop, no update-slice. The substitutions run ONCE per factor, on the
+# identity (``_unrolled_linv``), and every solve of the polish's scans
+# is then two reduce-form products with L⁻¹ (``_linv_pair_solve``):
+# L⁻ᵀ(L⁻¹ b) carries sqrt(cond) a factor where an explicit Mp⁻¹ would
+# carry cond, and al_step's refinement sweeps stay (doc/kernels.md §3e:
+# the chip sweep's seconds and residuals by form, n and S).
+
+# Largest n the polish unrolls at. The unrolled program grows with n
+# (~14 fusions a Cholesky column, ~4 a row of L⁻¹, three factors a
+# polish program) and the TPU compiler's seconds grow faster: a polish
+# program at S = 24 compiles for a v5e in 28 s at n = 12 and at n = 16
+# (the library form: 23 and 30), 73 s at n = 24 (19) and 163 s at
+# n = 48 (21): COMPILE seconds turn first, between 16 and 24, long
+# before device seconds could (doc/kernels.md §3e). Above it the
+# library calls stay.
+_POLISH_UNROLL_MAX_N = 16
+
+
+def _gram_reduce(A, r):
+    # Aᵀ diag(r) A as a multiply and a sum over the row axis (the form
+    # of _rmatvec_reduce, one rank up)
+    return jnp.sum(A[:, :, :, None] * (r[:, :, None] * A)[:, :, None, :],
+                   axis=1)
+
+
+def _unrolled_cholesky(M):
+    """Lower Cholesky factor of M (S, n, n), n static: the left-looking
+    column recurrence, one reduce, one sqrt and one divide a column on
+    (S, n) slabs, the factor filled in by a select on the column index
+    (no stack, so nothing the compiler turns into an update-slice).
+    Reads M's lower triangle. A non-positive-definite matrix gives NaN
+    from its first bad pivot's column on (sqrt of a negative), as the
+    library does: the polish's NaN candidates must lose."""
+    n = M.shape[-1]
+    idx = jnp.arange(n)
+    L = jnp.zeros_like(M)
+    for j in range(n):
+        c = M[:, :, j]
+        if j:
+            c = c - jnp.sum(L * L[:, j, None, :], axis=-1)
+        col = jnp.where(idx >= j, c / jnp.sqrt(c[:, j])[:, None], 0.0)
+        L = jnp.where(idx == j, col[:, :, None], L)
+    return L
+
+
+def _unrolled_linv(L):
+    """L⁻¹ of a batched lower factor (S, n, n) by the forward
+    substitution on the identity, unrolled over the static n: row j is
+    (e_j − Σ_{k<j} L[j, k] X[k, :]) / L[j, j], one reduce and one divide
+    a row on (S, n) slabs."""
+    n = L.shape[-1]
+    idx = jnp.arange(n)
+    eye = jnp.eye(n, dtype=L.dtype)
+    X = jnp.zeros_like(L)
+    for j in range(n):
+        s = eye[j][None, :]
+        if j:
+            s = s - jnp.sum(L[:, j, :, None] * X, axis=1)
+        X = jnp.where(idx[:, None] == j,
+                      (s / L[:, j, j, None])[:, None, :], X)
+    return X
+
+
+def _linv_pair_solve(Linv, b):
+    """x = L⁻ᵀ (L⁻¹ b) from the explicit L⁻¹ (S, n, n): two reduce-form
+    products (the second over L⁻¹'s own row axis, no transposed copy)."""
+    return _rmatvec_reduce(Linv, _matvec_reduce(Linv, b))
+
+
+def _penalty_factor_library(A_b, rpA, diag):
+    Mp = jnp.einsum("smi,sm,smj->sij", A_b, rpA, A_b)
+    Mp = Mp + jax.vmap(jnp.diag)(diag)
+    return jnp.linalg.cholesky(Mp)
+
+
+def _penalty_factor_unrolled(A_b, rpA, diag):
+    eye = jnp.eye(A_b.shape[-1], dtype=diag.dtype)
+    Mp = _gram_reduce(A_b, rpA) + diag[:, :, None] * eye
+    return _unrolled_linv(_unrolled_cholesky(Mp))
+
+
+def _polish_unrollable(A_s) -> bool:
+    """Static: whether the TPU lowering of a polish over these factors
+    takes the unrolled forms (per-scenario float64 matrices no wider
+    than ``_POLISH_UNROLL_MAX_N``)."""
+    return (A_s.ndim == 3 and A_s.dtype == jnp.float64
+            and A_s.shape[-1] <= _POLISH_UNROLL_MAX_N)
+
+
+def f64_polish_form(A_s) -> str | None:
+    """``"unrolled"`` / ``"library"``: how THIS process's backend runs
+    the factor-and-substitute side of a float64 polish over the scaled
+    matrix ``A_s``; None where there is none (a SplitMatrix never
+    polishes, an f32 polish is not float64 linalg). "unrolled" only on
+    the TPU, for per-scenario (3-D) matrices with n <=
+    ``_POLISH_UNROLL_MAX_N``; a shared 2-D matrix, a wider n, and every
+    other backend keep ``jnp.linalg.cholesky`` and the
+    ``triangular_solve`` pair."""
+    if isinstance(A_s, SplitMatrix) or A_s.dtype != jnp.float64:
+        return None
+    if _polish_unrollable(A_s) and jax.default_backend() == "tpu":
+        return "unrolled"
+    return "library"
+
+
+def _polish_linalg(A_s):
+    """``(factorize, solve)`` of the polish's penalty systems over
+    ``A_s``: ``F = factorize(A_b, rpA, diag)`` and ``x = solve(F, b)``.
+    Where the TPU would unroll, the pair is chosen at lowering time
+    per platform, as the batched products are (CPU and GPU keep the
+    library calls, and a program compiled HERE for a described TPU
+    takes the TPU form); F is then L on one platform and L⁻¹ on the
+    other, which only the pair's own solve ever reads."""
+    if not _polish_unrollable(A_s):
+        return _penalty_factor_library, _tri_solve
+    return (lambda A_b, rpA, diag: jax.lax.platform_dependent(
+                A_b, rpA, diag, tpu=_penalty_factor_unrolled,
+                default=_penalty_factor_library),
+            lambda F, b: jax.lax.platform_dependent(
+                F, b, tpu=_linv_pair_solve, default=_tri_solve))
 
 
 # Block size of the prepared substitution: the one XLA's triangular-
@@ -1879,18 +2015,29 @@ def _polish_select(A_s, P_s, g, D, E, Eb, cs, csx, sigma, data, q, q_s,
                                 0.0))
         return a_l | a_u, b
 
+    # how the factor-and-substitute side lowers: the library calls, or
+    # on the TPU at small n the unrolled recurrences (f64_polish_form)
+    form = f64_polish_form(A_s)
+    factorize, solve = _polish_linalg(A_s)
+
     def penalty_factor(actA, actB):
         rpA = jnp.where(actA, rho_big, 0.0)
         rpB = jnp.where(actB, rho_big, 0.0)
-        Mp = jnp.einsum("smi,sm,smj->sij", A_b, rpA, A_b)
-        Mp = Mp + jax.vmap(jnp.diag)(Pdiag_b + sigma + g * g * rpB)
-        Lp = jnp.linalg.cholesky(Mp)
+        if form is not None:
+            # trace-time count of the factorizations lowered each way
+            obs.counter_add(f"kernel.f64_polish_{form}")
+        with jax.named_scope("qp.polish_factor"):
+            Fp = factorize(A_b, rpA, Pdiag_b + sigma + g * g * rpB)
+
+        @jax.named_scope("qp.polish_solve")
+        def solve_Mp(b):
+            return solve(Fp, b)
 
         def apply_Mp(v):
             return Pdiag_b * v + sigma * v \
                 + _ATy(A_b, rpA * _Ax(A_b, v)) + g * g * rpB * v
 
-        return rpA, rpB, Lp, apply_Mp
+        return rpA, rpB, solve_Mp, apply_Mp
 
     def polish_round(actA, bA, actB, bB, x0):
         """Proximal augmented-Lagrangian solve on the guessed active set.
@@ -1903,15 +2050,15 @@ def _polish_select(A_s, P_s, g, D, E, Eb, cs, csx, sigma, data, q, q_s,
         regularization bias at the fixed point. Duals start from ZERO:
         stalled ADMM duals carry huge drift components along degenerate
         dual rays."""
-        rpA, rpB, Lp, apply_Mp = penalty_factor(actA, actB)
+        rpA, rpB, solve_Mp, apply_Mp = penalty_factor(actA, actB)
 
         def al_step(carry, _):
             x_prev, yA_p, yB_p = carry
             rhs = sigma * x_prev - q_s + _ATy(A_b, rpA * bA - yA_p) \
                 + g * (rpB * bB - yB_p)
-            x_p = _tri_solve(Lp, rhs)
-            x_p = x_p + _tri_solve(Lp, rhs - apply_Mp(x_p))
-            x_p = x_p + _tri_solve(Lp, rhs - apply_Mp(x_p))
+            x_p = solve_Mp(rhs)
+            x_p = x_p + solve_Mp(rhs - apply_Mp(x_p))
+            x_p = x_p + solve_Mp(rhs - apply_Mp(x_p))
             yA_p = yA_p + rpA * (_Ax(A_b, x_p) - bA)
             yB_p = yB_p + rpB * (g * x_p - bB)
             return (x_p, yA_p, yB_p), None
@@ -1927,7 +2074,7 @@ def _polish_select(A_s, P_s, g, D, E, Eb, cs, csx, sigma, data, q, q_s,
         >= 0, lower-active <= 0, equalities free): wrong-sign junk along
         degenerate dual rays cannot persist, at the cost of slower
         convergence. Used as a safe dual CANDIDATE."""
-        rpA, rpB, Lp, apply_Mp = penalty_factor(alA | auA, alB | auB)
+        rpA, rpB, solve_Mp, apply_Mp = penalty_factor(alA | auA, alB | auB)
 
         def clampy(y, al, au, eq):
             y = jnp.where(au & ~eq, jnp.maximum(y, 0.0), y)
@@ -1938,8 +2085,8 @@ def _polish_select(A_s, P_s, g, D, E, Eb, cs, csx, sigma, data, q, q_s,
             x_prev, yA_p, yB_p = carry
             rhs = sigma * x_prev - q_s + _ATy(A_b, rpA * bA - yA_p) \
                 + g * (rpB * bB - yB_p)
-            x_p = _tri_solve(Lp, rhs)
-            x_p = x_p + _tri_solve(Lp, rhs - apply_Mp(x_p))
+            x_p = solve_Mp(rhs)
+            x_p = x_p + solve_Mp(rhs - apply_Mp(x_p))
             yA_p = clampy(yA_p + rpA * (_Ax(A_b, x_p) - bA), alA, auA, eqA)
             yB_p = clampy(yB_p + rpB * (g * x_p - bB), alB, auB, eqB)
             return (x_p, yA_p, yB_p), None

@@ -349,7 +349,8 @@ def test_unchunked_sslp_df32_solve_compiles_for_v5e(sslp_calls, one_chip,
     arithmetic and the split matvecs' accumulation)."""
     assert sslp_calls["plan"] == {"mode": "fused", "backend": "reference",
                                   "l_inv": True, "block_dtype": "f32",
-                                  "f64_products": None}
+                                  "f64_products": None,
+                                  "f64_polish": None}
     solves = sslp_calls["_fused_mixed_jit_donated"]
     assert len(solves) == 3
     assert len(sslp_calls["make_l_inv"]) == 2      # iter-0's and hot's
@@ -595,3 +596,59 @@ def test_the_emulated_dot_is_a_loop_nest_on_v5e(one_chip,
     red = jax.jit(_matvec_reduce).lower(F, b).compile().as_text()
     assert not _hlo_lines(red, "while") \
         and not _hlo_lines(red, "dynamic-update-slice")
+
+
+# ---------------- the polish of the stacked native-f64 solve (ISSUE 40) -
+
+def _polish_loops(hlo):
+    """The ``while`` instructions under ``qp.polish``, and those of
+    them that are the compiler's expansion of a batched float64
+    ``cholesky`` / ``triangular_solve`` / Gram ``dot_general``."""
+    loops = [ln for ln in _hlo_lines(hlo, "while") if "qp.polish/" in ln]
+    return loops, [ln for ln in loops
+                   if any(k in ln for k in ("cholesky", "triangular_solve",
+                                            "dot_general"))]
+
+
+# (S, scale): the served stack and a solo wheel, at n = 12
+@pytest.mark.parametrize("S,scale", [(24, 1), (3, 1)])
+def test_stacked_f64_polish_has_only_its_three_scans_on_v5e(
+        stacked_farmer_segment, one_chip, no_persistent_cache, S, scale):
+    """The polish program the chip's segmented driver launches last
+    (``max_iter=0``, ``polish=True``) at n = 12, where the rule answers
+    "unrolled" (doc/kernels.md §3e): the v5e compiler's program holds
+    the polish's own three scans as ``while``s under ``qp.polish`` and
+    nothing of the library expansions: no loop of a ``cholesky``, a
+    ``triangular_solve`` or the Gram ``dot_general``, no
+    ``dynamic-update-slice`` (at (24, 7, 12): 115 loops and 262
+    update-slices under ``qp.polish`` before ISSUE 40)."""
+    fn, args, kw = stacked_farmer_segment
+    kw = dict(kw, max_iter=0, polish=True)
+    hlo = fn.lower(*_widened(args, S, scale, one_chip), **kw).compile() \
+        .as_text()
+    assert f"f64[{S},{7 * scale},{12 * scale}]" in hlo
+    loops, expansions = _polish_loops(hlo)
+    assert len(loops) == 3 and not expansions
+    # with the solve's own two (never entered at max_iter 0)
+    assert len(_hlo_lines(hlo, "while")) == 5
+    assert not _hlo_lines(hlo, "dynamic-update-slice")
+
+
+def test_the_polish_keeps_the_library_calls_above_the_width_on_v5e(
+        stacked_farmer_segment, one_chip, no_persistent_cache):
+    """Above ``_POLISH_UNROLL_MAX_N`` (here n = 24: the unrolled
+    program's compile seconds turn between 16 and 24) the library path
+    is still what is lowered: the compiler's loops of the batched
+    float64 ``cholesky`` and ``triangular_solve`` are there. So a
+    compiler that learns float64 linalg, or a width that moves, shows
+    up here."""
+    from mpisppy_tpu.ops.qp_solver import _POLISH_UNROLL_MAX_N
+    fn, args, kw = stacked_farmer_segment
+    kw = dict(kw, max_iter=0, polish=True)
+    assert 12 * 2 > _POLISH_UNROLL_MAX_N
+    hlo = fn.lower(*_widened(args, 3, 2, one_chip), **kw).compile() \
+        .as_text()
+    loops, expansions = _polish_loops(hlo)
+    assert len(loops) > 3
+    assert any("cholesky" in ln for ln in expansions)
+    assert any("triangular_solve" in ln for ln in expansions)

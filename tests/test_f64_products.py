@@ -132,7 +132,11 @@ def test_stacked_farmer_solve_on_the_reduce_forms(stacked_farmer_calls,
     check_every = kw.get("check_every", 25)
 
     def solve():
-        fn = jax.jit(qps._solve_impl, static_argnames=qps._SOLVE_STATICS)
+        # a fresh function object each time: jax caches a trace by the
+        # function it wraps, and the switches are looked up while tracing
+        def impl(factors, data, q, state, **k):
+            return qps._solve_impl(factors, data, q, state, **k)
+        fn = jax.jit(impl, static_argnames=qps._SOLVE_STATICS)
         st, x, _yA, _yB = fn(*args, **kw)
         data, q = args[1], args[2]
         return int(st.iters), np.asarray(

@@ -522,7 +522,11 @@ def test_prepare_resolution(monkeypatch, mode, kind, ir_sweeps):
         # a per-scenario float64 matrix's batched products: the
         # library dot on this backend (tests/test_f64_products.py)
         "f64_products": "dot" if kind == "per-scenario-f64-host"
-        else None}
+        else None,
+        # a float64 polish over these factors: the library calls on
+        # this backend (tests/test_f64_polish.py); a split matrix
+        # never polishes
+        "f64_polish": None if kind == "split-df32" else "library"}
     if want == "S":
         assert plan.A_lo is None
     elif kind == "split-df32":
