@@ -63,7 +63,7 @@ def _new_phase_entry():
     return {"acc": {"assemble": 0.0, "solve": 0.0, "gate": 0.0,
                     "reduce": 0.0},
             "admm": {"bulk": 0, "tail": 0, "refactors": 0,
-                     "linv_builds": 0},
+                     "linv_builds": 0, "linv_applies": 0},
             # how the same solves ENDED (``_book_exits``)
             "exits": {"solves": 0, "bulk_hist": {}, "tail_hist": {},
                       "bulk_capped": 0, "tail_capped": 0, "rows_read": 0,
@@ -435,7 +435,8 @@ def _linv_wraps(plan, st):
     return int(not isinstance(st.L, LInv))
 
 
-def _book_admm_iters(admm, states, fused, linv_wraps=None, packed=None):
+def _book_admm_iters(admm, states, fused, linv_wraps=None, packed=None,
+                     ir_sweeps=1):
     """Book the ADMM iterations of the solves that produced ``states``
     into ``admm`` (the "admm" dict of a mode's ``_phase_times`` entry):
     ``bulk`` = the low-precision phase's (``QPState.iters_lo``),
@@ -448,7 +449,12 @@ def _book_admm_iters(admm, states, fused, linv_wraps=None, packed=None):
     the refactorizations, each of which leaves the inverse to be built
     anew (the handoff builds ONE however often the bulk refactored, so
     the count is exact while a bulk refactors at most once, and an
-    upper bound beyond). No new device wait:
+    upper bound beyond); and ``linv_applies``, the L⁻¹ products the
+    same solves ran: every tail iteration's x-update solves
+    ``1 + ir_sweeps`` times (the seed and the refinement sweeps) and an
+    ``LInv`` solve is two products; 0 where the plan keeps no inverse
+    (the x-update then substitutes), so the pair says which form the
+    timed solves ran. No new device wait:
     fused plans' callers sit AFTER the phase-honesty block they pay
     anyway (scalar copies, not stalls), and the segmented drivers hand
     back HOST scalars (they read their counts segment by segment), for
@@ -475,9 +481,13 @@ def _book_admm_iters(admm, states, fused, linv_wraps=None, packed=None):
     admm["bulk"] += bulk
     admm["tail"] += total - bulk
     admm["refactors"] += refs
+    applies = 0
     if linv_wraps is not None:
         admm["linv_builds"] += linv_wraps + refs
+        applies = (total - bulk) * (1 + int(ir_sweeps)) * 2
+        admm["linv_applies"] += applies
     if obs.enabled():
+        obs.counter_add("kernel.l_inv_applies", applies)
         obs.counter_add("kernel.bulk_iters", bulk)
         obs.counter_add("kernel.tail_iters", total - bulk)
         obs.counter_add("kernel.factor_prepares", refs)
@@ -694,7 +704,7 @@ def _ph_step(qp_state, factors, data, c, c0, P0, prob, xbar_w, memberships,
         # and ``exits`` come with ``lap``: the same ``_phase_times``
         # entry)
         its, res = _book_admm_iters(admm, [qp_state], fused, wraps,
-                                    packed if fused else None)
+                                    packed if fused else None, ir_sweeps)
         lap("reduce")
     wmask = None if wscale is None else wscale > 0
     if combine_fn is None:
@@ -2490,6 +2500,7 @@ class PHBase(SPBase):
         ent["devices"] = ops.n_devices if sharded else 1
         ent["mode"] = "sharded" if sharded else "host"
         ent["kernel"] = plan.descriptor()
+        ent["linv_build"] = plan.linv_build
         ent["shape"] = self._solve_shape(factors, plan, rows_per_call)
         if dispatch is not None:
             dent = ent["dispatch"]
@@ -2669,7 +2680,7 @@ class PHBase(SPBase):
         # without the explicit inverse)
         its, _ = _book_admm_iters(
             ent["admm"], [rec[0] for rec in solved_chunks],
-            plan.mode == "fused", wraps0)
+            plan.mode == "fused", wraps0, ir_sweeps=self.sub_ir_sweeps)
         ent["assemble_programs"] += asm_programs
         obs.counter_add("ph.assemble_programs", asm_programs)
         clock.lap("gate")
@@ -3118,6 +3129,12 @@ class PHBase(SPBase):
             # for keyword the facts a bytes-per-iteration model prices
             # (ops/kernels.est_hbm_bytes_per_iter)
             "solve_shape": ent.get("shape"),
+            # the eager explicit-inverse builds of this mode's plan
+            # (span ``qp.l_inv_build``): {builds, seconds, n, panels},
+            # totals kept by the PLAN, so a cold state's build during
+            # set-up is still told after ``reset_phase_timing``; empty
+            # where none ran (ops/kernels.KernelPlan.linv_build)
+            "linv_build": dict(ent.get("linv_build") or {}),
             # whole PH runs of the ENGINE (every mode's; ``run_span``)
             # since the last reset: how many, their seconds, and the
             # seconds of their ``reset_run()``s (totals, not per call)
@@ -3611,6 +3628,7 @@ class PHBase(SPBase):
         rows_per_call = self._rows_per_call()
         plan = self._kernel_plan(skey, factors, rows_per_call)
         ent["kernel"] = plan.descriptor()
+        ent["linv_build"] = plan.linv_build
         ent["shape"] = self._solve_shape(factors, plan, rows_per_call)
         sp_args = {"mode": _mode_str(skey)} if obs.enabled() else None
         clock = _PhaseClock(ent["acc"], sp_args)
@@ -3662,7 +3680,8 @@ class PHBase(SPBase):
                 # lint: ok[SYNC001] phase honesty for fused plans, same site contract as _ph_step
                 jax.block_until_ready(packed)
             its, res = _book_admm_iters(ent["admm"], [qp_state], fused,
-                                        wraps, packed if fused else None)
+                                        wraps, packed if fused else None,
+                                        self.sub_ir_sweeps)
             clock.lap("reduce")
             x = expand_solution(x_c, shrink.fixed_colvals,
                                 shrink.keep_cols, shrink.fixed_cols,
@@ -4239,6 +4258,7 @@ class PHBase(SPBase):
                                            _new_phase_entry())
         ent["calls"] += 1
         ent["kernel"] = plan.descriptor()
+        ent["linv_build"] = plan.linv_build
         its, _ = _book_admm_iters(ent["admm"], states,
                                   plan.mode == "fused")
         _book_exits(ent["exits"], its, _exit_tests(**kw), 0.0)

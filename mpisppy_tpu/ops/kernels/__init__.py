@@ -17,7 +17,9 @@ engine options; anatomy in doc/kernels.md):
 
 Inside the fused program rides one doc/roofline.md §5 trade: explicit
 L⁻¹ matmuls for the df32 tail's triangular solves (behind
-``l_inv_profitable``).
+``l_inv_profitable``: on where the build amortizes AND an apply beats
+the prepared substitution's — sslp's n = 520; off at UC width, where
+the chip measured the substitution faster at 64 and at 128 rows).
 Recovery solves (chunk retries, the scenario hospital) ALWAYS take
 the segmented path in native precision — the existing quality-gate
 machinery doubles as the fused path's full-precision fallback.
@@ -30,7 +32,7 @@ catalogued in doc/observability.md.
 from __future__ import annotations
 
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 
 from ...utils.config import FUSED_IR_SWEEPS
 from ..qp_solver import (PackedMatrix, SplitMatrix, _needs_host_factor,
@@ -64,6 +66,11 @@ class KernelPlan:
     A_lo: object = None          # bulk-phase A_s operand (mixed/df32)
     f64_products: str | None = None   # qp_solver.f64_product_form(A_s)
     f64_polish: str | None = None     # qp_solver.f64_polish_form(A_s)
+    # the eager explicit-inverse builds of this plan's solves (span
+    # ``qp.l_inv_build``): {builds, seconds, n, panels}, totals; empty
+    # until one ran. The plan outlives ``reset_phase_timing``, so a
+    # build of set-up is still told after a window
+    linv_build: dict = field(default_factory=dict)
 
     def descriptor(self) -> dict:
         """The bench/telemetry kernel block. ``backend`` and
@@ -125,7 +132,8 @@ def prepare(factors, *, mode="auto", l_inv="auto", precision="native",
         elif l_inv == "auto":
             # budget = TAIL only: the f32 bulk phase never applies the
             # explicit inverse (un-refined solves hand L.tri to the
-            # componentwise-stable back-substitution — see LInv)
+            # componentwise-stable back-substitution — see LInv); and
+            # at this (n, rows) an apply must beat the prepared one
             use_linv = l_inv_profitable(n, s_chunk, tail_iter, ir_sweeps)
     A_lo = None
     if precision in ("mixed", "df32"):
@@ -168,7 +176,8 @@ def kernel_solve(plan: KernelPlan, factors, data, q, state, *,
             polish=polish, polish_iters=polish_iters,
             polish_chunk=polish_chunk, stall_rel=stall_rel,
             ir_sweeps=ir_sweeps, l_inv=plan.l_inv,
-            adaptive_rho=adaptive_rho, donate=donate)
+            adaptive_rho=adaptive_rho, donate=donate,
+            build_log=plan.linv_build)
         tag = "fused-mixed"
     else:
         st, x, yA, yB = qp_solve(
@@ -200,10 +209,11 @@ def est_hbm_bytes_per_iter(*, n, m, s_chunk, pk_pass_bytes=None,
     re-run can confirm the predicted drop:
 
       factor applies : 2 triangle passes x (1 seed + ir_sweeps IR
-                       solves) x n² x 4 B — identical bytes for
-                       triangular solves and L⁻¹ matmuls (the trade
-                       converts latency, not traffic; l_inv=False only
-                       flags that the latency win is off);
+                       solves) x n² x 4 B — a FULL square a pass:
+                       exact for an L⁻¹ matmul, twice what a prepared
+                       substitution reads (PERF.md §7 row 10; the
+                       signature keeps ``l_inv``, the price ignores
+                       it);
       A passes       : (2 + 2·ir_sweeps) packed split passes (1 rhs Aᵀy
                        + ir_sweeps x (Ax + Aᵀy) + 1 zAx) over the
                        hi+lo packed operand bytes (dense m·n·8 when
@@ -226,3 +236,13 @@ def est_hbm_bytes_per_iter(*, n, m, s_chunk, pk_pass_bytes=None,
     bulk = int(2 * n * n * factor_bytes + 2 * bulk_a_pass
                + 6 * (m + n) * s_chunk * 4)
     return {"tail": int(tail_factor + tail_a + tail_vec), "bulk": bulk}
+
+
+def est_l_inv_build_bytes(*, n, factor_bytes=4):
+    """HBM bytes the explicit inverse's build cannot do without
+    (``qp_solver._make_l_inv``): the factor's computed half read once
+    and the inverse's half written once, n² · ``factor_bytes`` in all.
+    A floor, not a traffic count: the panel build re-reads the panel's
+    rows so far at every block row. benchmarks/linv_bytes_model.py is
+    the benchmark's own copy (held equal by benchmarks/tests)."""
+    return int(n) * int(n) * int(factor_bytes)

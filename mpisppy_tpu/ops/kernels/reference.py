@@ -26,9 +26,10 @@ doc/kernels.md).
 
 One roofline trade lives here (doc/roofline.md §5 headroom item 1),
 ``l_inv``: the df32 tail's two triangular solves become two MXU
-matmuls of the same bytes by carrying the EXPLICIT L⁻¹
-(qp_solver.LInv) in the solver state, behind ``l_inv_profitable``
-(the n-RHS inverse build must amortize over the iteration budget).
+matmuls by carrying the EXPLICIT L⁻¹ (qp_solver.LInv) in the solver
+state, behind ``l_inv_profitable`` (the inverse's build must amortize
+over the iteration budget, and its apply must beat the prepared
+substitution's at this width and row count).
 
 A solve that goes wrong is caught by the SAME df32 gate machinery that
 already guards the segmented path: the chunked PH loop's quality gate
@@ -46,31 +47,79 @@ import numpy as np
 
 from ... import obs
 from ...utils.runtime import compile_serialized
-from ..qp_solver import (LInv, PackedMatrix, QPData, QPState, SplitMatrix,
-                         _cast_floats, _factorize, _make_l_inv,
+from ..qp_solver import (_TRI_BLOCK, LInv, PackedMatrix, QPData, QPState,
+                         SplitMatrix, _cast_floats, _factorize, _make_l_inv,
                          _prepare_factor, _raw_factor, _solve_impl,
-                         make_l_inv)
+                         l_inv_panels, make_l_inv)
 
 __all__ = ["fused_mixed_solve", "l_inv_profitable"]
 
 
 # ---------------- roofline trade guard ----------------
 
+# Seconds of ONE M⁻¹ apply (all rows of a device call) in either form,
+# fitted to four chip readings on the UC factor (TPU v5e, n = 13,056,
+# REPS applies chained in one loop: PERF.md §6, PR 41's Step 0):
+#
+#   rows   prepared substitution   two products with L⁻¹
+#    64        1.733 ms                 1.923 ms
+#   128        2.214 ms                 2.849 ms
+#
+# The substitution is 2·⌈n/_TRI_BLOCK⌉ sequential block steps plus its
+# triangular products (2·rows·n² flops); the inverse is two FULL
+# (rows, n) × (n, n) products at HIGHEST (4·rows·n² flops: 93% of the
+# chip's 197e12 / 6 at 128 rows) and never less than reading both
+# squares (87% of 819 GB/s at 64 rows).
+_PREP_STEP_S = 6.1e-6      # (1.733 ms − its products) / 204 steps
+_PREP_FLOPS = 4.5e13       # 0.481 ms more for 64 more rows
+_LINV_FLOPS = 3.06e13      # 2.849 ms at 128 rows
+_LINV_BYTES_S = 7.09e11    # 1.923 ms at 64 rows
+
+
+def prepared_apply_s(n, rows):
+    """Modelled seconds of one prepared M⁻¹ apply (see the table)."""
+    return 2 * -(-n // _TRI_BLOCK) * _PREP_STEP_S \
+        + 2 * rows * n * n / _PREP_FLOPS
+
+
+def l_inv_apply_s(n, rows):
+    """Modelled seconds of one explicit-inverse M⁻¹ apply."""
+    return max(2 * n * n * 4 / _LINV_BYTES_S, 4 * rows * n * n / _LINV_FLOPS)
+
+
 def l_inv_profitable(n, s_chunk, tail_iter, ir_sweeps=1):
-    """Whether the explicit L⁻¹ build amortizes. The inverse
-    back-substitutes n RHS columns ONCE; only the TAIL applies it
-    (``s_chunk`` columns ``(1 + ir_sweeps)`` times per iteration — the
-    f32 bulk hands ``LInv.tri`` to the plain back-substitution, see
-    qp_solver.LInv), so the break-even test is one tail's column
-    solves >= the build's n. That is deliberately the margin, not a
-    multiple: the df32 chunk chain flows ONE factor across every chunk
-    and every warm-started PH iteration until rho refactorizes, so
-    each solve past the first applies the same inverse for free —
-    break-even within one solve makes the chain pure win. A short
-    exploratory solve (small s_chunk·tail) still must not pay an
-    (n, n) inversion it never recoups."""
-    applies = int(tail_iter) * (1 + int(ir_sweeps)) * max(int(s_chunk), 1)
-    return applies >= int(n)
+    """Whether the tail carries the explicit L⁻¹. Two tests, both from
+    what the program can observe (n, rows per device call, the tail's
+    budget):
+
+    The build must amortize. The inverse back-substitutes n RHS
+    columns ONCE; only the TAIL applies it (``s_chunk`` columns
+    ``(1 + ir_sweeps)`` times per iteration — the f32 bulk hands
+    ``LInv.tri`` to the plain back-substitution, see qp_solver.LInv),
+    so the break-even test is one tail's column solves >= the build's
+    n. That is deliberately the margin, not a multiple: the df32 chunk
+    chain flows ONE factor across every chunk and every warm-started
+    PH iteration until rho refactorizes, so each solve past the first
+    applies the same inverse for free. A short exploratory solve
+    (small s_chunk·tail) must not pay an (n, n) inversion it never
+    recoups.
+
+    And an apply must beat the PREPARED substitution's, which is what
+    the inverse stands in for since qp_solver.PreparedFactor:
+    ``l_inv_apply_s < prepared_apply_s``. The inverse removes the
+    substitution's sequential block steps and pays twice its flops and
+    bytes, so it wins where the steps dominate (narrow factors, few
+    rows: 71 µs against 85 at sslp's (520, 2000)) and loses at UC
+    width: measured there, a df32 tail iteration is 5.36 ms prepared
+    against 5.82 with L⁻¹ at 64 rows and 7.08 against 8.91 at 128, the
+    steady hot PH iteration at 128 rows 0.518 s against 0.62–0.85, with
+    2.3 GB less HBM (PERF.md §6, PR 41). The constants are the v5e's;
+    no other backend has a reading, and the trade is the same in kind
+    there."""
+    rows = max(int(s_chunk), 1)
+    applies = int(tail_iter) * (1 + int(ir_sweeps)) * rows
+    return applies >= int(n) \
+        and l_inv_apply_s(int(n), rows) < prepared_apply_s(int(n), rows)
 
 
 # ---------------- the fused mixed/df32 program ----------------
@@ -203,17 +252,32 @@ def fused_mixed_solve(factors, A_lo, data, q, state, *, bulk_iter,
                       tail_iter, check_every, eps_abs, eps_rel,
                       eps_abs_dua, eps_rel_dua, polish, polish_iters,
                       polish_chunk, stall_rel, ir_sweeps, l_inv,
-                      adaptive_rho=True, donate=False):
+                      adaptive_rho=True, donate=False, build_log=None):
     """One fused mixed/df32 solve call (see _fused_mixed_impl).
     ``l_inv`` states arriving with a 2-D f32 Cholesky factor (prepared
     or bare) are wrapped to LInv EAGERLY so the jit sees one pytree
     structure for the whole chunk chain (a mid-chain structure flip
-    would recompile the UC-sized program)."""
+    would recompile the UC-sized program). That build is the span
+    ``qp.l_inv_build``; it waits for the inverse (a cold state's first
+    solve, which nothing overlaps), so its seconds are the build's, and
+    ``build_log`` (the plan's ``KernelPlan.linv_build``) adds them
+    up."""
     if l_inv and not isinstance(state.L, LInv):
         L = _raw_factor(state.L)
         if getattr(L, "ndim", 0) == 2 and L.dtype == jnp.float32:
             obs.counter_add("kernel.l_inv_factorizations")
-            state = state._replace(L=make_l_inv(L))
+            n = int(L.shape[-1])
+            shape = {"n": n, "panels": l_inv_panels(n)}
+            with obs.span("qp.l_inv_build", cat="qp", args=shape) as sp:
+                F = make_l_inv(L)
+                # lint: ok[SYNC001] a cold state's one eager build, ahead of its first solve: the span's seconds are the build's only if it waits
+                jax.block_until_ready(F.inv)
+            state = state._replace(L=F)
+            if build_log is not None:
+                build_log.update(shape,
+                                 builds=build_log.get("builds", 0) + 1,
+                                 seconds=build_log.get("seconds", 0.0)
+                                 + sp.seconds)
     iterates = (state.x, state.yA, state.yB, state.zA, state.zB)
     if not donate:
         iterates = tuple(jnp.copy(a) for a in iterates)

@@ -899,10 +899,17 @@ def _pair_solve(L, b):
 class LInv(NamedTuple):
     """EXPLICIT inverse of a (shared, 2-D) Cholesky factor, carried in
     QPState.L alongside the factor itself: the x-update's M⁻¹ apply
-    becomes TWO MXU MATMULS of exactly the factor's bytes
-    (x = L⁻ᵀ(L⁻¹b) — roofline headroom item 1, doc/roofline.md §5)
-    instead of two sequential back-substitutions, which on TPU are
-    latency-bound at chunk batch sizes.
+    becomes TWO MXU MATMULS (x = L⁻ᵀ(L⁻¹b) — roofline headroom item 1,
+    doc/roofline.md §5) instead of two sequential back-substitutions.
+    What that buys is the substitution's 2·⌈n/128⌉ sequential block
+    steps (~6 µs each on a v5e since PreparedFactor took the per-call
+    preparations away); what it costs is twice the substitution's
+    flops and bytes (full squares for triangles). So it pays on narrow
+    factors (sslp's n = 520: ten steps against two thin products) and
+    LOSES at UC width: n = 13,056 on the chip, one apply 1.92 ms
+    against the prepared solve's 1.73 at 64 rows and 2.85 against 2.21
+    at 128 (PERF.md §6, PR 41); ops/kernels' ``l_inv_profitable`` holds
+    that comparison.
 
     Distinct from _factorize's f64 explicit M⁻¹: inverting M composes
     κ(M)·eps error (measured NaN blowups in f32 — see _factorize), but
@@ -917,10 +924,10 @@ class LInv(NamedTuple):
     un-refined L⁻¹ bulk shifts the degenerate-UC plateau objective by
     ~0.5%, outside the packed path's calibrated band). Residency is
     two f32 (n, n) buffers — the same bytes as the one f64 factor the
-    non-split path carries; per-iteration HBM traffic is unchanged
-    (the trade converts solve latency, not bytes). Built by the
-    ops/kernels layer behind a profitability check (the n-RHS inverse
-    build must amortize over the iteration budget); every _chol_solve
+    non-split path carries. Built by the ops/kernels layer behind a
+    profitability check (the build must amortize over the iteration
+    budget and the apply must beat the prepared substitution's), in
+    column panels beyond ``_LINV_PANEL`` columns; every _chol_solve
     consumer dispatches on the container, so a state carrying L or
     L⁻¹ flows through the same solver code."""
     inv: jax.Array          # (n, n) = L⁻¹ (NOT M⁻¹), factor dtype
@@ -939,16 +946,88 @@ class LInv(NamedTuple):
         return self.inv.shape
 
 
+# Column-panel width of the explicit inverse's build: a whole number of
+# _TRI_BLOCK blocks (17; UC's n = 13,056 is six panels exactly). Up to
+# this width the inverse is ONE n-RHS triangular solve; beyond it that
+# solve's expansion asks the v5e compiler for 32.65 GB at n = 13,056
+# (chip run, PR 25), so the inverse is built panel by panel.
+_LINV_PANEL = 17 * _TRI_BLOCK
+
+
+def l_inv_panels(n) -> int:
+    """Column panels ``_make_l_inv`` builds an (n, n) inverse in."""
+    return -(-int(n) // _LINV_PANEL)
+
+
+def _l_inv_by_panels(L, dinv):
+    """L⁻¹ of the lower-triangular (n, n) ``L`` by forward substitution
+    on the identity, one column panel of ``_LINV_PANEL`` at a time: in
+    the panel that starts at column c0 the rows above c0 are zero, and
+    below them block row i is X_i = D_i⁻¹ (I_i − L[i, c0:] X) with X the
+    panel's rows so far (those not yet reached are still zero, so the
+    product needs no mask). ``dinv``: the inverted diagonal blocks
+    (PreparedFactor.dinv). A ``fori_loop`` over the block rows of each
+    panel, a static loop over the panels (each has its own height): the
+    working set is one (n − c0, panel) slab beside the output, and only
+    blocks of the lower triangle are ever written. Products at
+    _TRI_PRECISION, like the substitution the inverse stands in for."""
+    n = L.shape[-1]
+    bs = dinv.shape[-1]
+    nb = dinv.shape[0]
+    npad = nb * bs
+    if npad != n:
+        # a short last block: dinv carries an identity there, so the
+        # padded inverse is [[L⁻¹, 0], [0, I]]
+        L = jnp.pad(L, ((0, npad - n), (0, npad - n)))
+    X = jnp.zeros((npad, npad), L.dtype)
+    for c0 in range(0, npad, _LINV_PANEL):
+        w = min(_LINV_PANEL, npad - c0)
+        h = npad - c0
+        cols = c0 + jnp.arange(w)[None, :]
+
+        def block_row(i, Xp):       # traced at once, in this pass
+            r0 = i * bs
+            Li = jax.lax.dynamic_slice(L, (r0, c0), (bs, h))
+            eye_i = (r0 + jnp.arange(bs)[:, None] == cols).astype(L.dtype)
+            R = eye_i - jnp.matmul(Li, Xp, precision=_TRI_PRECISION)
+            Xi = jnp.matmul(dinv[i], R, precision=_TRI_PRECISION)
+            return jax.lax.dynamic_update_slice(Xp, Xi, (r0 - c0, 0))
+
+        Xp = jax.lax.fori_loop(c0 // bs, nb, block_row,
+                               jnp.zeros((h, w), L.dtype))
+        X = jax.lax.dynamic_update_slice(X, Xp, (c0, c0))
+    return X[:n, :n] if npad != n else X
+
+
 def _make_l_inv(L) -> LInv:
-    """Traceable L -> (L⁻¹, L) (one n-RHS triangular solve,
-    MXU-blocked); a PreparedFactor contributes its raw factor."""
-    L = _raw_factor(L)
-    eye = jnp.eye(L.shape[-1], dtype=L.dtype)
-    return LInv(jax.lax.linalg.triangular_solve(
-        L, eye, left_side=True, lower=True), L)
+    """Traceable L -> (L⁻¹, L). Up to ``_LINV_PANEL`` columns ONE n-RHS
+    triangular solve against the identity (MXU-blocked); wider, the
+    same substitution in column panels (``_l_inv_by_panels``), which
+    the compiler can hold at UC width. A PreparedFactor contributes its
+    raw factor and, to the panel build, its inverted diagonal blocks (a
+    bare factor's are inverted here)."""
+    tri = _raw_factor(L)
+    if tri.shape[-1] <= _LINV_PANEL:
+        eye = jnp.eye(tri.shape[-1], dtype=tri.dtype)
+        return LInv(jax.lax.linalg.triangular_solve(
+            tri, eye, left_side=True, lower=True), tri)
+    dinv = L.dinv if isinstance(L, PreparedFactor) \
+        else _prepare_factor(tri).dinv
+    return LInv(_l_inv_by_panels(tri, dinv), tri)
 
 
-make_l_inv = compile_serialized(jax.jit(_make_l_inv))
+_l_inv_jit = compile_serialized(jax.jit(lambda L: _make_l_inv(L).inv))
+
+
+def make_l_inv(L) -> LInv:
+    """Eager L -> LInv. Only the inverse comes out of the jit: a jit
+    that passes a matrix through to its output makes XLA COPY it (see
+    _setup_vectors), 0.68 GB at UC width; ``tri`` is the caller's own
+    buffer."""
+    return LInv(_l_inv_jit(L), _raw_factor(L))
+
+
+make_l_inv.lower = _l_inv_jit.lower
 
 
 def _refactor_like(factors, rho_scale, like):

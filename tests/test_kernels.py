@@ -360,16 +360,39 @@ def test_l_inv_matmul_vs_triangular_solve_parity():
 
 
 def test_l_inv_profitability_check():
-    """The n-RHS inverse build must break even within one solve's TAIL
-    (the bulk never applies it): chunked production budgets engage,
-    short exploratory solves must not."""
-    # the uc1024 production shape (tail 100, 128-scenario chunks)
-    assert kernels.l_inv_profitable(n=13056, s_chunk=128,
-                                    tail_iter=100, ir_sweeps=1)
-    assert kernels.l_inv_profitable(n=13056, s_chunk=128,
-                                    tail_iter=500, ir_sweeps=1)
+    """The inverse's build must break even within one solve's TAIL (the
+    bulk never applies it), and an apply must beat the prepared
+    substitution's at that width and row count (measured on the v5e,
+    PERF.md §6, PR 41): narrow factors and production budgets engage;
+    short exploratory solves must not; and UC width must not at any
+    row count: there the chip read the substitution faster at 64 rows
+    (1.73 against 1.92 ms an apply) and at 128 (2.21 against 2.85)."""
+    from mpisppy_tpu.ops.kernels.reference import (l_inv_apply_s,
+                                                   prepared_apply_s)
+    # the uc1024 production shape (tail 100, 128-scenario chunks): the
+    # build would amortize (25,600 applies >= n), the apply loses
+    assert not kernels.l_inv_profitable(n=13056, s_chunk=128,
+                                        tail_iter=100, ir_sweeps=1)
+    assert not kernels.l_inv_profitable(n=13056, s_chunk=128,
+                                        tail_iter=500, ir_sweeps=1)
     assert not kernels.l_inv_profitable(n=13056, s_chunk=1,
                                         tail_iter=100, ir_sweeps=1)
+    # the benchmark's cells: (13056, 64) off, sslp's (520, 2000) ON
+    assert not kernels.l_inv_profitable(n=13056, s_chunk=64,
+                                        tail_iter=100, ir_sweeps=1)
+    assert kernels.l_inv_profitable(n=520, s_chunk=2000, tail_iter=100,
+                                    ir_sweeps=1)
+    # a mid width, where the substitution's steps still dominate
+    assert kernels.l_inv_profitable(n=2944, s_chunk=64, tail_iter=100,
+                                    ir_sweeps=1)
+    assert not kernels.l_inv_profitable(n=2944, s_chunk=1, tail_iter=100,
+                                        ir_sweeps=1)     # never repaid
+    # the model IS the four readings it was fitted to, to 1%
+    for rows, prep_ms, inv_ms in ((64, 1.733, 1.923), (128, 2.214, 2.849)):
+        assert prepared_apply_s(13056, rows) * 1e3 == pytest.approx(
+            prep_ms, rel=0.01)
+        assert l_inv_apply_s(13056, rows) * 1e3 == pytest.approx(
+            inv_ms, rel=0.01)
 
 
 def test_host_factor_path_matches_device_path(monkeypatch):

@@ -193,7 +193,8 @@ def test_phase_timing_contract(layout, precision):
     priced = est_hbm_bytes_per_iter(**shape)
     assert priced["tail"] > priced["bulk"] > 0
     admm = pt["admm_iters_per_call"]
-    assert set(admm) == {"bulk", "tail", "refactors", "linv_builds"}
+    assert set(admm) == {"bulk", "tail", "refactors", "linv_builds",
+                         "linv_applies"}
     assert admm["bulk"] > 0 and admm["tail"] >= 0
     assert admm["linv_builds"] == 0        # no LInv, nothing built
     assert set(pt["seconds_per_call"]) == {"assemble", "solve", "gate",

@@ -349,7 +349,8 @@ def test_refactors_counted_beside_the_iterations(mode):
     ph.solve_loop(w_on=False, prox_on=False)
     sts = ph._qp_states[("chunks", False)]
     admm = ph.phase_timing(False)["admm_iters_per_call"]
-    assert set(admm) == {"bulk", "tail", "refactors", "linv_builds"}
+    assert set(admm) == {"bulk", "tail", "refactors", "linv_builds",
+                         "linv_applies"}
     assert admm["refactors"] == sum(int(s.refactors) for s in sts)
     # a cold UC solve adapts rho at least once, far less often than it
     # iterates: the hoisted preparation is paid per refactorization
