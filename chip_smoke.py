@@ -72,6 +72,9 @@ MESH_XBAR_MEAN_ATOL, MESH_CONV_RTOL = 0.1, 0.5
 # |M·M⁻¹ − I|max a trusted f64 device inverse must meet at every probed
 # width (the ADMM runs at 1e-4..1e-6)
 F64_RESID_BOUND = 1e-8
+# the same residual for an inverse rebuilt INSIDE a solve program when
+# rho moves (the served stack's, doc/kernels.md §3f): the host path's
+F64_REFACTOR_BOUND = 1e-9
 V5E_ROW = (197e12, 819.0)
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -197,6 +200,53 @@ def f64_linalg_probe(num_gens, num_hours, batch=4):
           or out["batched_device_resid"] <= F64_RESID_BOUND,
           f"the rule trusts a batched f64 inverse that misses "
           f"{F64_RESID_BOUND}: {out}")
+    return out
+
+
+def f64_refactor_probe(rho_scale, stack=8):
+    """What ops/qp_solver.f64_refactor_form asserts for the served
+    stack, measured on THIS device through the program's own routine:
+    |M·M⁻¹ − I|max of ``_factorize``'s explicit inverse of a full
+    wheel's per-scenario float64 KKTs (``stack`` three-scenario farmers
+    with cost patches, stacked as serve/batch stacks them: (24, 12, 12))
+    at ``rho_scale``, beside numpy on the same matrices. Where the rule
+    keeps the refactorization on the device ("unrolled" on the TPU,
+    "library" on a backend with trusted f64 linalg) it must meet the
+    host path's bar at every rho the adaptation's clip allows."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mpisppy_tpu.core.spbase import SPBase
+    from mpisppy_tpu.ops.qp_solver import (_factorize, _kkt_host,
+                                           f64_refactor_form, qp_setup)
+    from mpisppy_tpu.serve import batch as sbatch
+    from mpisppy_tpu.utils.vanilla import build_batch_for
+
+    payload = {"model": "farmer", "num_scens": 3}
+    base = build_batch_for(sbatch.base_runconfig(payload))
+    rng = np.random.default_rng(20260927)
+    stacked, _ = sbatch.stack_instances([
+        sbatch.apply_patch(base, {"c": {"DevotedAcreage": [
+            float(b * rng.uniform(0.9, 1.1)) for b in (150., 230., 260.)]}})
+        for _ in range(stack)])
+    sp = SPBase(stacked, {}, dtype=jnp.float64)
+    fac = qp_setup(sp.qp_data, q_ref=sp.c)
+    S, _m, n = fac.A_s.shape
+    rs = np.full((S,), float(rho_scale))
+    inv = np.asarray(jax.jit(_factorize)(fac, jnp.asarray(rs)))
+    M = _kkt_host(fac, rs)
+    out = {"case": "farmer_stack", "shape": [S, n, n],
+           "rho_scale": float(rho_scale),
+           "cond": float(np.linalg.cond(M).max()),
+           "form_by_rule": f64_refactor_form(fac.A_s),
+           "batched_device_resid": float(np.abs(M @ inv - np.eye(n)).max()),
+           "numpy_resid": float(np.abs(M @ np.linalg.inv(M)
+                                       - np.eye(n)).max())}
+    check(out["form_by_rule"] == "host"
+          or out["batched_device_resid"] <= F64_REFACTOR_BOUND,
+          f"the rule keeps a float64 refactorization on the device that "
+          f"misses {F64_REFACTOR_BOUND}: {out}")
     return out
 
 
@@ -564,6 +614,8 @@ def main(argv=None):
         if args.chips == 1:
             for gens, hours in ((3, 12), (10, 24)):
                 emit("f64_linalg", **f64_linalg_probe(gens, hours))
+            for rho_scale in (1.0, 1e6):
+                emit("f64_linalg", **f64_refactor_probe(rho_scale))
             uc_wheel_leg()
             serve_leg()
         else:

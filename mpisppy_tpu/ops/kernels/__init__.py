@@ -37,7 +37,7 @@ from dataclasses import dataclass, field
 from ...utils.config import FUSED_IR_SWEEPS
 from ..qp_solver import (PackedMatrix, SplitMatrix, _needs_host_factor,
                          _trace_seg, f64_polish_form, f64_product_form,
-                         qp_solve)
+                         f64_refactor_form, qp_solve)
 from .reference import fused_mixed_solve, l_inv_profitable
 
 
@@ -46,7 +46,11 @@ def resolve_mode(mode: str, factors) -> str:
     adaptation must run on the HOST (non-shared f64 factors on a
     backend with untrusted f64 device linalg — qp_solver
     ._needs_host_factor): the fused program cannot call back out for
-    the host-exact refactorization mid-loop. Program length is no
+    the host-exact refactorization mid-loop. A per-scenario float64
+    stack narrow enough for the TPU lowering to invert by unrolled
+    recurrences (qp_solver.f64_refactor_form "unrolled": n <= 16)
+    needs no host, so it fuses: loop, in-program rho adaptation and
+    polish are ONE launch (PR 42). Program length is no
     criterion: one program of 36,794 f64 matmul iterations ran 59.7 s
     to completion on the attached v5e (CHANGES.md PR 24)."""
     if mode == "fused":
@@ -66,6 +70,7 @@ class KernelPlan:
     A_lo: object = None          # bulk-phase A_s operand (mixed/df32)
     f64_products: str | None = None   # qp_solver.f64_product_form(A_s)
     f64_polish: str | None = None     # qp_solver.f64_polish_form(A_s)
+    f64_refactor: str | None = None   # qp_solver.f64_refactor_form(A_s)
     # the eager explicit-inverse builds of this plan's solves (span
     # ``qp.l_inv_build``): {builds, seconds, n, panels}, totals; empty
     # until one ran. The plan outlives ``reset_phase_timing``, so a
@@ -83,11 +88,16 @@ class KernelPlan:
         ``f64_polish`` how it runs the factor-and-substitute side of a
         float64 polish over these factors (``"unrolled"`` /
         ``"library"``, §3e), None where no float64 polish can run (a
-        split matrix never polishes)."""
+        split matrix never polishes); ``f64_refactor`` where and how
+        the explicit float64 KKT inverse of these factors is rebuilt
+        when rho moves (``"unrolled"`` / ``"library"``: inside the
+        solve program; ``"host"``: numpy, between device calls, §3f),
+        None where the factor is no float64 inverse."""
         return {"mode": self.mode, "backend": "reference",
                 "l_inv": bool(self.l_inv), "block_dtype": "f32",
                 "f64_products": self.f64_products,
-                "f64_polish": self.f64_polish}
+                "f64_polish": self.f64_polish,
+                "f64_refactor": self.f64_refactor}
 
 
 def prepare(factors, *, mode="auto", l_inv="auto", precision="native",
@@ -102,7 +112,8 @@ def prepare(factors, *, mode="auto", l_inv="auto", precision="native",
     back to segmented here, so exotic sweep counts keep working through
     the host-segmented drivers."""
     forms = dict(f64_products=f64_product_form(factors.A_s),
-                 f64_polish=f64_polish_form(factors.A_s))
+                 f64_polish=f64_polish_form(factors.A_s),
+                 f64_refactor=f64_refactor_form(factors.A_s))
     if int(ir_sweeps) not in FUSED_IR_SWEEPS:
         if mode == "fused":
             raise ValueError(

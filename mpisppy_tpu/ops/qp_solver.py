@@ -503,7 +503,10 @@ def _factorize(factors: QPFactors, rho_scale):
     PREPARED (PreparedFactor: the factor plus its inverted diagonal
     blocks, doc/kernels.md §prepared factor); batched f32 factors stay
     raw. The ill-conditioned penalty systems in the POLISH always use
-    honest Cholesky solves."""
+    honest Cholesky solves. The per-scenario (3-D) float64 inverse is
+    the library pair (``_kkt_inverse_library``) or, in the TPU lowering
+    at n <= ``_POLISH_UNROLL_MAX_N``, the unrolled recurrences
+    (``_kkt_inverse_unrolled``, ``f64_refactor_form``)."""
     A_s, P_s = factors.A_s, factors.P_s
     g = factors.Eb * factors.D
     n = A_s.shape[-1]
@@ -530,16 +533,14 @@ def _factorize(factors: QPFactors, rho_scale):
                                                lower=True, transpose_a=True)
     rA = factors.rho_A * rho_scale[:, None]
     rB = factors.rho_b * rho_scale[:, None]
-    M = (A_s * rA[:, :, None]).swapaxes(1, 2) @ A_s
-    M = M + jnp.eye(n, dtype=A_s.dtype) * factors.sigma
-    M = M + jax.vmap(jnp.diag)(P_s + g * g * rB)
-    L = jnp.linalg.cholesky(M)
+    diag = P_s + g * g * rB
     if not invert:
-        return L
-    eye = jnp.broadcast_to(jnp.eye(n, dtype=A_s.dtype), M.shape)
-    w = jax.lax.linalg.triangular_solve(L, eye, left_side=True, lower=True)
-    return jax.lax.linalg.triangular_solve(L, w, left_side=True,
-                                           lower=True, transpose_a=True)
+        return _kkt_factor_library(A_s, rA, factors.sigma, diag)
+    # trace-time count of the per-scenario float64 refactorizations
+    # lowered each way on this backend (f64_refactor_form)
+    obs.counter_add(f"kernel.f64_refactor_{f64_refactor_form(A_s)}")
+    with jax.named_scope("qp.refactor"):
+        return _kkt_inverse(A_s)(A_s, rA, factors.sigma, diag)
 
 
 def _factorize_split(factors: QPFactors, rho_scale):
@@ -587,7 +588,9 @@ def _device_f64_linalg_trusted():
     dependent, so no width is safe. An inverse that wrong turns the
     ADMM x-update into an expanding map (iterates to 1e33 within 100
     iterations, then NaN), so on TPU — and on any backend nobody has
-    measured — non-shared f64 factors take the host-exact path below.
+    measured — non-shared f64 factors take the host-exact path below,
+    unless they are narrow enough for the TPU lowering not to call the
+    library at all (f64_refactor_form, the one reader of this rule).
     CPU and GPU have native f64 linalg."""
     return jax.default_backend() in ("cpu", "gpu", "cuda", "rocm")
 
@@ -598,17 +601,18 @@ def _needs_host_factor(factors) -> bool:
     host inverse cannot be recomputed inside the device loop). The
     SHARED f64 branch always keeps the device path: one unbatched
     factor on the hub's hot path, measured at 4.9e-12 / 7.0e-12 /
-    7.9e-12 for n = 132 / 768 / 1488 on the attached v5e (ibid.)."""
-    return factors.A_s.ndim == 3 and factors.A_s.dtype == jnp.float64 \
-        and not _device_f64_linalg_trusted()
+    7.9e-12 for n = 132 / 768 / 1488 on the attached v5e (ibid.).
+    Neither do the small per-scenario stacks the TPU lowering inverts
+    by unrolled element-wise recurrences (``f64_refactor_form``
+    "unrolled": the library linalg the distrust is about never runs)."""
+    return f64_refactor_form(factors.A_s) == "host"
 
 
-def _factorize_host(factors: QPFactors, rho_scale, rows=None):
-    """numpy twin of _factorize's non-shared f64 explicit-inverse branch
-    (see _device_f64_linalg_trusted for why it exists). Eager-only.
-    ``rows``: optional index array — invert only those scenarios' KKTs
-    and return a (len(rows), n, n) block for the caller to scatter.
-    Returns a HOST array; the caller ships it."""
+def _kkt_host(factors: QPFactors, rho_scale, rows=None):
+    """The per-scenario KKT matrices M = diag(P_s) + sigma I +
+    A_sᵀ diag(ρ_A) A_s + diag(g²ρ_b) themselves, in numpy float64
+    (``rows``: only those scenarios'): what _factorize_host inverts, and
+    what a device inverse is held against (chip_smoke, tests)."""
     sel = (lambda a: a if rows is None else a[rows])
     A_s = sel(np.asarray(factors.A_s))
     P_s = sel(np.asarray(factors.P_s))
@@ -621,7 +625,16 @@ def _factorize_host(factors: QPFactors, rho_scale, rows=None):
     diag = P_s + g * g * rB
     idx = np.arange(A_s.shape[-1])
     M[:, idx, idx] += diag
-    return np.linalg.inv(M)
+    return M
+
+
+def _factorize_host(factors: QPFactors, rho_scale, rows=None):
+    """numpy twin of _factorize's non-shared f64 explicit-inverse branch
+    (see _device_f64_linalg_trusted for why it exists). Eager-only.
+    ``rows``: optional index array — invert only those scenarios' KKTs
+    and return a (len(rows), n, n) block for the caller to scatter.
+    Returns a HOST array; the caller ships it."""
+    return np.linalg.inv(_kkt_host(factors, rho_scale, rows))
 
 
 _factorize_jit = compile_serialized(jax.jit(_factorize))
@@ -783,6 +796,77 @@ def _polish_linalg(A_s):
                 default=_penalty_factor_library),
             lambda F, b: jax.lax.platform_dependent(
                 F, b, tpu=_linv_pair_solve, default=_tri_solve))
+
+
+# ---- the per-scenario float64 KKT inverse (ISSUE 42) ----
+# _factorize's non-shared f64 branch. The library pair below is what
+# every backend with native f64 linalg runs. The TPU's expansion of it
+# is not trusted (_device_f64_linalg_trusted), so there the inverse was
+# numpy's (_factorize_host) and rho could move only between device
+# calls: a served farmer wheel of 8 spent 0.68 of its 0.71 s in ~57
+# host round trips around 0.08 s of device work (PERF.md §6, PR 42).
+# At the widths the polish unrolls, the SAME recurrences give the
+# explicit inverse from element-wise float64 alone: M by _gram_reduce,
+# L by _unrolled_cholesky, L⁻¹ by _unrolled_linv, M⁻¹ = L⁻ᵀ L⁻¹ by one
+# more reduce-form product. On the served stack's own KKTs ((24, 12,
+# 12), cond <= 6.3 after equilibration at every rho the clip allows)
+# the chip reads |M·M⁻¹ − I|max <= 6e-14 (doc/kernels.md §3f), so the
+# refactorization stays inside the solve program and a solve is one
+# launch (kernels.resolve_mode).
+
+def _kkt_factor_library(A_s, rA, sigma, diag):
+    n = A_s.shape[-1]
+    M = (A_s * rA[:, :, None]).swapaxes(1, 2) @ A_s
+    M = M + jnp.eye(n, dtype=A_s.dtype) * sigma
+    M = M + jax.vmap(jnp.diag)(diag)
+    return jnp.linalg.cholesky(M)
+
+
+def _kkt_inverse_library(A_s, rA, sigma, diag):
+    L = _kkt_factor_library(A_s, rA, sigma, diag)
+    eye = jnp.broadcast_to(jnp.eye(L.shape[-1], dtype=L.dtype), L.shape)
+    w = jax.lax.linalg.triangular_solve(L, eye, left_side=True, lower=True)
+    return jax.lax.linalg.triangular_solve(L, w, left_side=True,
+                                           lower=True, transpose_a=True)
+
+
+def _kkt_inverse_unrolled(A_s, rA, sigma, diag):
+    Linv = _penalty_factor_unrolled(A_s, rA, diag + sigma)
+    # L⁻ᵀ L⁻¹ as a multiply and a sum over L⁻¹'s own row axis
+    return jnp.sum(Linv[:, :, :, None] * Linv[:, :, None, :], axis=1)
+
+
+def f64_refactor_form(A_s) -> str | None:
+    """``"unrolled"`` / ``"host"`` / ``"library"``: where and how THIS
+    process's backend builds the explicit KKT inverse of float64
+    factors over the scaled matrix ``A_s``; None where the factor is no
+    float64 inverse (a SplitMatrix, an f32 matrix). A shared 2-D matrix
+    always takes the device library (one unbatched factor, trusted on
+    every backend measured). A per-scenario (3-D) one takes it where
+    the batched f64 linalg is trusted (_device_f64_linalg_trusted);
+    elsewhere "unrolled" on the TPU at n <= ``_POLISH_UNROLL_MAX_N``
+    (the polish's shape test: compile seconds set the width, there as
+    here) and "host" (numpy between device calls, _factorize_host) for
+    everything wider or on a backend nobody has measured."""
+    if isinstance(A_s, SplitMatrix) or A_s.dtype != jnp.float64:
+        return None
+    if A_s.ndim == 2 or _device_f64_linalg_trusted():
+        return "library"
+    if _polish_unrollable(A_s) and jax.default_backend() == "tpu":
+        return "unrolled"
+    return "host"
+
+
+def _kkt_inverse(A_s):
+    """``inverse(A_s, rA, sigma, diag)`` of _factorize's per-scenario
+    float64 branch; like _polish_linalg, the TPU form is chosen at
+    lowering time per platform (CPU and GPU keep the library calls bit
+    for bit, and a program compiled HERE for a described TPU takes the
+    TPU form)."""
+    if not _polish_unrollable(A_s):
+        return _kkt_inverse_library
+    return lambda *ops: jax.lax.platform_dependent(
+        *ops, tpu=_kkt_inverse_unrolled, default=_kkt_inverse_library)
 
 
 # Block size of the prepared substitution: the one XLA's triangular-

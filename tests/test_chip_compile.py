@@ -350,7 +350,8 @@ def test_unchunked_sslp_df32_solve_compiles_for_v5e(sslp_calls, one_chip,
     assert sslp_calls["plan"] == {"mode": "fused", "backend": "reference",
                                   "l_inv": True, "block_dtype": "f32",
                                   "f64_products": None,
-                                  "f64_polish": None}
+                                  "f64_polish": None,
+                                  "f64_refactor": None}
     solves = sslp_calls["_fused_mixed_jit_donated"]
     assert len(solves) == 3
     assert len(sslp_calls["make_l_inv"]) == 2      # iter-0's and hot's
@@ -652,6 +653,54 @@ def test_the_polish_keeps_the_library_calls_above_the_width_on_v5e(
     assert len(loops) > 3
     assert any("cholesky" in ln for ln in expansions)
     assert any("triangular_solve" in ln for ln in expansions)
+
+
+# ---------------- the in-program refactorization (ISSUE 42) ------------
+
+def _refactor_loops(hlo):
+    """The ``while`` instructions under ``qp.refactor`` (the rebuild of
+    the explicit float64 inverse inside ``qp.rho_adapt``): the
+    compiler's expansions of the batched float64 ``cholesky`` /
+    ``triangular_solve`` pair and of the product in front of them."""
+    return [ln for ln in _hlo_lines(hlo, "while") if "qp.refactor/" in ln]
+
+
+# (S, scale): the served stack and a solo wheel, at n = 12
+@pytest.mark.parametrize("S,scale", [(24, 1), (3, 1)])
+def test_stacked_f64_loop_adapts_rho_without_library_linalg_on_v5e(
+        stacked_farmer_segment, one_chip, no_persistent_cache, S, scale):
+    """The solve's loop as the chip's plan runs it since ISSUE 42
+    (``adaptive_rho=True``: the rule keeps the refactorization of a
+    per-scenario float64 stack with n <= 16 inside the program,
+    doc/kernels.md §3f): the v5e compiler's program still holds the
+    solve's own two loops and nothing else: no loop of a ``cholesky``,
+    a ``triangular_solve`` or a batched ``dot_general`` under
+    ``qp.refactor``, no ``dynamic-update-slice``."""
+    fn, args, kw = stacked_farmer_segment
+    kw = dict(kw, adaptive_rho=True)
+    hlo = fn.lower(*_widened(args, S, scale, one_chip), **kw).compile() \
+        .as_text()
+    assert f"f64[{S},{7 * scale},{12 * scale}]" in hlo
+    assert "qp.refactor" in hlo
+    assert not _refactor_loops(hlo) and not _product_loops(hlo)
+    assert len(_hlo_lines(hlo, "while")) == 2
+    assert not _hlo_lines(hlo, "dynamic-update-slice")
+
+
+def test_the_refactorization_keeps_the_library_pair_above_the_width_on_v5e(
+        stacked_farmer_segment, one_chip, no_persistent_cache):
+    """Above ``_POLISH_UNROLL_MAX_N`` (n = 24) ``_factorize`` lowers the
+    library pair, and the compiler's loops of it are there: what the
+    rule keeps away from the TPU by sending such factors to the host
+    (``_needs_host_factor``; this program is never launched there)."""
+    from mpisppy_tpu.ops.qp_solver import _POLISH_UNROLL_MAX_N
+    fn, args, kw = stacked_farmer_segment
+    assert 12 * 2 > _POLISH_UNROLL_MAX_N
+    hlo = fn.lower(*_widened(args, 3, 2, one_chip),
+                   **dict(kw, adaptive_rho=True)).compile().as_text()
+    loops = _refactor_loops(hlo)
+    assert any("cholesky" in ln for ln in loops)
+    assert any("triangular_solve" in ln for ln in loops)
 
 
 # ---------------- the explicit inverse at UC width (ISSUE 41) ----------

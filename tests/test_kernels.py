@@ -549,7 +549,13 @@ def test_prepare_resolution(monkeypatch, mode, kind, ir_sweeps):
         # a float64 polish over these factors: the library calls on
         # this backend (tests/test_f64_polish.py); a split matrix
         # never polishes
-        "f64_polish": None if kind == "split-df32" else "library"}
+        "f64_polish": None if kind == "split-df32" else "library",
+        # the explicit float64 inverse's rebuild: numpy between device
+        # calls where the batched linalg is not trusted and the TPU's
+        # unrolled form does not apply (tests/test_f64_refactor.py)
+        "f64_refactor": {"split-df32": None,
+                         "per-scenario-f64-host": "host"}.get(kind,
+                                                              "library")}
     if want == "S":
         assert plan.A_lo is None
     elif kind == "split-df32":

@@ -177,6 +177,11 @@ def test_phase_timing_contract(layout, precision):
                             # mixed recipe's shared float64 matrix
                             # would through the library calls
                             "f64_polish": None if precision == "df32"
+                            else "library",
+                            # nor is its factor a float64 inverse; the
+                            # mixed recipe's shared one is the device
+                            # library's on every backend
+                            "f64_refactor": None if precision == "df32"
                             else "library"}
     assert (pt["mode"], pt["devices"]) == (
         "sharded" if ndev > 1 else "host", ndev)

@@ -254,6 +254,9 @@ SYNC_ALLOW_DEFAULT = {
         "_trace_seg":
             "MPISPPY_TPU_SOLVE_TRACE stamp forces a sync by documented "
             "design (doc/observability.md), never default-on",
+        "_kkt_host":
+            "the host factor path is host-side by design "
+            "(qp.host_rho_refactors, doc/tpu_numerics.md)",
         "_factorize_host":
             "the host factor path is host-side by design "
             "(qp.host_rho_refactors, doc/tpu_numerics.md)",
