@@ -857,6 +857,26 @@ def f64_refactor_form(A_s) -> str | None:
     return "host"
 
 
+def f64_loop_form(A_s) -> str | None:
+    """``"resident"`` / ``"conditional"``: the shape of the ADMM loop
+    that adapts rho INSIDE the solve program over float64 factors of
+    the scaled matrix ``A_s`` (_solve_impl; doc/kernels.md §3g); None
+    where no program does (the factor is no float64 inverse, or its
+    rebuild is the host's: ``f64_refactor_form`` None / "host").
+    "resident": a per-scenario stack at n <= ``_POLISH_UNROLL_MAX_N``,
+    whose rebuild is cheap enough to run unconditionally once a
+    four-check period, BETWEEN two inner loops that hold the inverse as
+    a loop-invariant operand, so that no loop carrying it holds a
+    ``conditional``. "conditional": the rebuild under a ``lax.cond`` in
+    the loop's one body (a shared 2-D inverse, a wider stack). Read
+    from the shape alone: every backend traces the same structure, and
+    the platform only chooses HOW the inverse is built
+    (``f64_refactor_form``)."""
+    if f64_refactor_form(A_s) in (None, "host"):
+        return None
+    return "resident" if _polish_unrollable(A_s) else "conditional"
+
+
 def _kkt_inverse(A_s):
     """``inverse(A_s, rA, sigma, diag)`` of _factorize's per-scenario
     float64 branch; like _polish_linalg, the TPU form is chosen at
@@ -1539,13 +1559,19 @@ def _solve_impl(factors: QPFactors, data: QPData, q, state: QPState,
         return _unscaled_residuals(A_s, P_s, g, D, E, Eb, csx, q_s,
                                    x, yA, yB, zA, zB)
 
-    def cond(carry):
-        it, done = carry[7], carry[8]
+    def live(it, done):
         return jnp.logical_and(it < max_iter, jnp.logical_not(done))
 
-    def body(carry):
-        (x, yA, yB, zA, zB, L, rho_scale, it, _, best_pri, best_dua,
-         stall_ct, nref) = carry
+    def check(vals, L, refactor):
+        """One residual check of the loop: ``check_every`` iterations on
+        the factor ``L``, the residuals, the exit tests and the rho
+        adaptation's decision. ``refactor(need, rho_scale, L)`` gives
+        the factor the loop's carry holds next. Returns the carry's
+        values (``L`` apart), that factor, and ``need`` / ``adapt_now``
+        (whether rho moved; whether this was a period's fourth check:
+        None where rho is frozen)."""
+        (x, yA, yB, zA, zB, rho_scale, it, _, best_pri, best_dua,
+         stall_ct, nref) = vals
         rA, rB = rho_of(rho_scale)
         x, yA, yB, zA, zB = admm_chunk(x, yA, yB, zA, zB, L, rA, rB)
         with jax.named_scope("qp.check"):
@@ -1562,6 +1588,7 @@ def _solve_impl(factors: QPFactors, data: QPData, q, state: QPState,
             best_pri = jnp.minimum(best_pri, pri)
             best_dua = jnp.minimum(best_dua, dua)
         rho_changed = jnp.zeros_like(conv_ok)   # per-scenario where possible
+        need = adapt_now = None
         # ``adaptive_rho``: a python bool (a jit static — False leaves
         # the adaptation out of the program) or a TRACED flag (the fused
         # df32 program: one executable serves the hot loop and the
@@ -1601,9 +1628,7 @@ def _solve_impl(factors: QPFactors, data: QPData, q, state: QPState,
                     # refactorize must not postpone another's plateau exit
                     # (ADVICE r2)
                     rho_changed = mask
-                L = jax.lax.cond(need,
-                                 lambda: _refactor_like(factors, rho_scale, L),
-                                 lambda: L)
+                L = refactor(need, rho_scale, L)
                 nref = nref + need.astype(nref.dtype)
         if stall_rel:
             # a rho refactorize resets the window (the residual jump is
@@ -1613,15 +1638,70 @@ def _solve_impl(factors: QPFactors, data: QPData, q, state: QPState,
         else:
             stalled = jnp.zeros_like(conv_ok)
         done = jnp.all(conv_ok | stalled)
-        return (x, yA, yB, zA, zB, L, rho_scale, it + check_every, done,
-                best_pri, best_dua, stall_ct, nref)
+        return ((x, yA, yB, zA, zB, rho_scale, it + check_every, done,
+                 best_pri, best_dua, stall_ct, nref), L, need, adapt_now)
 
+    # the loop's carry is (x, yA, yB, zA, zB, L, rho_scale, it, done,
+    # best_pri, best_dua, stall_ct, nref); ``check`` takes L apart
+    def apart(carry):
+        return carry[:5] + carry[6:], carry[5]
+
+    def whole(vals, L):
+        return vals[:5] + (L,) + vals[5:]
+
+    def cond(carry):
+        return live(carry[7], carry[8])
+
+    def body(carry):
+        vals, L, _, _ = check(
+            *apart(carry),
+            lambda need, rho_scale, L: jax.lax.cond(
+                need, lambda: _refactor_like(factors, rho_scale, L),
+                lambda: L))
+        return whole(vals, L)
+
+    def period_body(carry):
+        """The loop's body where the factor is a small per-scenario
+        float64 inverse (``f64_loop_form`` "resident", doc/kernels.md
+        §3g): one PERIOD of the adaptation a turn. The inner loop runs
+        up to four checks on a factor that is its loop-invariant
+        operand and leaves on ``done``, on ``max_iter`` or after the
+        period's fourth check, the only one at which rho can move;
+        then ONE unconditional rebuild from the ``rho_scale`` it left,
+        kept where rho moved. Check for check the computation of
+        ``body``, with no ``conditional`` in a loop that carries the
+        factor: behind one the v5e compiler leaves the loop's 3-D
+        operands in HBM."""
+        vals, L = apart(carry)
+
+        def in_period(c):
+            vals, _, period_end = c
+            it, done = vals[6:8]
+            return jnp.logical_and(live(it, done),
+                                   jnp.logical_not(period_end))
+
+        def one_check(c):
+            vals, _, need, adapt_now = check(
+                c[0], L, lambda need, rho_scale, L: L)
+            return vals, need, adapt_now
+
+        vals, need, _ = jax.lax.while_loop(
+            in_period, one_check, (vals, jnp.array(False), jnp.array(False)))
+        rho_scale = vals[5]
+        with jax.named_scope("qp.rho_adapt"):
+            L = jnp.where(need, _refactor_like(factors, rho_scale, L), L)
+        return whole(vals, L)
+
+    loop_form = None if adaptive_rho is False else f64_loop_form(A_s)
+    if loop_form:
+        # trace-time count of the float64 loops traced each way
+        obs.counter_add(f"kernel.f64_loop_{loop_form}")
     S_ = data.l.shape[0]
     inf0 = jnp.full((S_,), jnp.inf, dt)
     ct0 = jnp.zeros((S_,), jnp.int32)
     x, yA, yB, zA, zB, L, rho_scale, it, _, _, _, _, nref = \
         jax.lax.while_loop(
-            cond, body,
+            cond, period_body if loop_form == "resident" else body,
             (state.x, state.yA, state.yB, state.zA, state.zB, state.L,
              state.rho_scale, jnp.zeros((), jnp.int32), jnp.array(False),
              inf0, inf0, ct0, jnp.zeros((), jnp.int32)))

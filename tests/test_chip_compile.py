@@ -351,7 +351,8 @@ def test_unchunked_sslp_df32_solve_compiles_for_v5e(sslp_calls, one_chip,
                                   "l_inv": True, "block_dtype": "f32",
                                   "f64_products": None,
                                   "f64_polish": None,
-                                  "f64_refactor": None}
+                                  "f64_refactor": None,
+                                  "f64_loop": None}
     solves = sslp_calls["_fused_mixed_jit_donated"]
     assert len(solves) == 3
     assert len(sslp_calls["make_l_inv"]) == 2      # iter-0's and hot's
@@ -665,6 +666,24 @@ def _refactor_loops(hlo):
     return [ln for ln in _hlo_lines(hlo, "while") if "qp.refactor/" in ln]
 
 
+def _loops_carrying_halves(hlo, S):
+    """For every ``while`` of the compiled program whose body reads an
+    f32[S,7,12] / f32[S,12,12] array out of its carry (the two f32
+    halves of the float64 matrix and of the explicit inverse): how many
+    such reads the body holds, and how many of them the compiler placed
+    in ``S(1)`` (VMEM), as ``(reads, resident)`` pairs."""
+    out = []
+    for body in re.findall(r"\bwhile\(.*?body=%?([\w.\-]+)", hlo):
+        text = re.search(r"\n%?" + re.escape(body) + r" \(.*?\n\}", hlo,
+                         re.S).group(0)
+        reads = [ln for ln in text.splitlines()
+                 if "get-tuple-element(" in ln
+                 and re.search(rf"f32\[{S},(7|12),12\]", ln)]
+        if reads:
+            out.append((len(reads), sum("S(1)" in ln for ln in reads)))
+    return out
+
+
 # (S, scale): the served stack and a solo wheel, at n = 12
 @pytest.mark.parametrize("S,scale", [(24, 1), (3, 1)])
 def test_stacked_f64_loop_adapts_rho_without_library_linalg_on_v5e(
@@ -672,10 +691,13 @@ def test_stacked_f64_loop_adapts_rho_without_library_linalg_on_v5e(
     """The solve's loop as the chip's plan runs it since ISSUE 42
     (``adaptive_rho=True``: the rule keeps the refactorization of a
     per-scenario float64 stack with n <= 16 inside the program,
-    doc/kernels.md §3f): the v5e compiler's program still holds the
-    solve's own two loops and nothing else: no loop of a ``cholesky``,
-    a ``triangular_solve`` or a batched ``dot_general`` under
-    ``qp.refactor``, no ``dynamic-update-slice``."""
+    doc/kernels.md §3f), in the shape it has since ISSUE 43 (§3g): the
+    v5e compiler's program holds the solve's own three loops (the
+    periods, the checks of a period, the ADMM scan) and nothing else:
+    no loop of a ``cholesky``, a ``triangular_solve`` or a batched
+    ``dot_general`` under ``qp.refactor``, no ``dynamic-update-slice``;
+    no ``conditional``, and every loop that carries the f32 halves of
+    the matrix and of the inverse carries all four in VMEM."""
     fn, args, kw = stacked_farmer_segment
     kw = dict(kw, adaptive_rho=True)
     hlo = fn.lower(*_widened(args, S, scale, one_chip), **kw).compile() \
@@ -683,8 +705,41 @@ def test_stacked_f64_loop_adapts_rho_without_library_linalg_on_v5e(
     assert f"f64[{S},{7 * scale},{12 * scale}]" in hlo
     assert "qp.refactor" in hlo
     assert not _refactor_loops(hlo) and not _product_loops(hlo)
-    assert len(_hlo_lines(hlo, "while")) == 2
+    assert len(_hlo_lines(hlo, "while")) == 3
     assert not _hlo_lines(hlo, "dynamic-update-slice")
+    assert not _hlo_lines(hlo, "conditional")
+    carrying = _loops_carrying_halves(hlo, S)
+    assert len(carrying) == 3
+    assert all(reads >= 4 and resident == reads
+               for reads, resident in carrying), carrying
+
+
+@pytest.mark.parametrize("S,scale", [(24, 1), (3, 1)])
+def test_a_conditional_in_the_loop_keeps_its_matrices_in_hbm_on_v5e(
+        stacked_farmer_segment, one_chip, no_persistent_cache, monkeypatch,
+        S, scale):
+    """What the two-level loop replaced, so that a compiler which learns
+    to keep operands resident across a ``conditional`` shows up here:
+    the same solve with the rebuild under a ``lax.cond`` in the loop's
+    one body (the shape every other factor form keeps, traced here by
+    answering for one; the rebuild itself stays the unrolled one)
+    compiles to a ``conditional``, and not one of the four halves is in
+    VMEM in either loop."""
+    import mpisppy_tpu.ops.qp_solver as qps
+    _fn, args, kw = stacked_farmer_segment
+    monkeypatch.setattr(qps, "f64_loop_form", lambda A_s: "conditional")
+
+    def impl(factors, data, q, state, **k):         # a trace of its own
+        return qps._solve_impl(factors, data, q, state, **k)
+    fn = jax.jit(impl, static_argnames=qps._SOLVE_STATICS)
+    hlo = fn.lower(*_widened(args, S, scale, one_chip),
+                   **dict(kw, adaptive_rho=True)).compile().as_text()
+    assert not _refactor_loops(hlo) and not _product_loops(hlo)
+    assert len(_hlo_lines(hlo, "while")) == 2
+    assert len(_hlo_lines(hlo, "conditional")) == 1
+    carrying = _loops_carrying_halves(hlo, S)
+    assert len(carrying) == 2
+    assert all(resident == 0 for _reads, resident in carrying), carrying
 
 
 def test_the_refactorization_keeps_the_library_pair_above_the_width_on_v5e(

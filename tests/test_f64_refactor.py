@@ -105,38 +105,45 @@ def test_the_switch_keeps_the_library_pair_off_the_tpu(stack_factors,
 # ---------------- the rule ---------------------------------------------
 
 # (backend, ndim, dtype, n) -> (f64_refactor_form, _needs_host_factor,
-# resolve_mode("auto")). "metal" stands for a backend nobody measured.
+# resolve_mode("auto"), f64_loop_form). "metal" stands for a backend
+# nobody measured. The loop's shape is read from ndim, dtype and n
+# alone; the backend only takes the answer away where it sends the
+# rebuild to the host, so that no program holds one.
 _F8, _F4 = "float64", "float32"
+_RES, _CND = "resident", "conditional"
 _RULE = [
-    ("tpu", 3, _F8, 12, "unrolled", False, "fused"),
-    ("tpu", 3, _F8, 16, "unrolled", False, "fused"),
-    ("tpu", 3, _F8, 17, "host", True, "segmented"),
-    ("tpu", 3, _F8, 768, "host", True, "segmented"),
-    ("tpu", 2, _F8, 12, "library", False, "fused"),
-    ("tpu", 2, _F8, 768, "library", False, "fused"),
-    ("tpu", 3, _F4, 12, None, False, "fused"),
-    ("tpu", 3, _F4, 768, None, False, "fused"),
-    ("tpu", 2, _F4, 16, None, False, "fused"),
-    ("cpu", 3, _F8, 12, "library", False, "fused"),
-    ("cpu", 3, _F8, 16, "library", False, "fused"),
-    ("cpu", 3, _F8, 17, "library", False, "fused"),
-    ("cpu", 3, _F8, 768, "library", False, "fused"),
-    ("cpu", 2, _F8, 768, "library", False, "fused"),
-    ("cpu", 3, _F4, 12, None, False, "fused"),
-    ("metal", 3, _F8, 12, "host", True, "segmented"),
-    ("metal", 2, _F8, 12, "library", False, "fused"),
+    ("tpu", 3, _F8, 12, "unrolled", False, "fused", _RES),
+    ("tpu", 3, _F8, 16, "unrolled", False, "fused", _RES),
+    ("tpu", 3, _F8, 17, "host", True, "segmented", None),
+    ("tpu", 3, _F8, 768, "host", True, "segmented", None),
+    ("tpu", 2, _F8, 12, "library", False, "fused", _CND),
+    ("tpu", 2, _F8, 768, "library", False, "fused", _CND),
+    ("tpu", 3, _F4, 12, None, False, "fused", None),
+    ("tpu", 3, _F4, 768, None, False, "fused", None),
+    ("tpu", 2, _F4, 16, None, False, "fused", None),
+    ("cpu", 3, _F8, 12, "library", False, "fused", _RES),
+    ("cpu", 3, _F8, 16, "library", False, "fused", _RES),
+    ("cpu", 3, _F8, 17, "library", False, "fused", _CND),
+    ("cpu", 3, _F8, 768, "library", False, "fused", _CND),
+    ("cpu", 2, _F8, 768, "library", False, "fused", _CND),
+    ("cpu", 3, _F4, 12, None, False, "fused", None),
+    ("metal", 3, _F8, 12, "host", True, "segmented", None),
+    ("metal", 2, _F8, 12, "library", False, "fused", _CND),
 ]
 
 
 @pytest.mark.parametrize(
-    "backend,ndim,dtype,n,form,host,mode", _RULE,
+    "backend,ndim,dtype,n,form,host,mode,loop", _RULE,
     ids=[f"{b}-{d}d-{t}-n{n}" for b, d, t, n, *_ in _RULE])
 def test_the_rule_by_backend_ndim_dtype_and_n(monkeypatch, backend, ndim,
-                                              dtype, n, form, host, mode):
+                                              dtype, n, form, host, mode,
+                                              loop):
     """ONE rule, read from shapes, dtype and platform alone: the host
     inverts only per-scenario float64 stacks that are wider than the
     polish's unroll width (or on a backend nobody measured); those and
-    only those solve in host-driven segments under ``auto``."""
+    only those solve in host-driven segments under ``auto``; and the
+    loop that adapts rho inside a program takes its shape from ndim,
+    dtype and n (``f64_loop_form``, what the plan's descriptor tells)."""
     assert _POLISH_UNROLL_MAX_N == 16
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     m = max(1, (7 * n) // 12)
@@ -148,6 +155,8 @@ def test_the_rule_by_backend_ndim_dtype_and_n(monkeypatch, backend, ndim,
     assert kernels.resolve_mode("auto", fac) == mode
     assert kernels.resolve_mode("segmented", fac) == "segmented"
     assert kernels.resolve_mode("fused", fac) == "fused"
+    assert qps.f64_loop_form(A_s) == loop
+    assert kernels.prepare(fac).descriptor()["f64_loop"] == loop
 
 
 def test_a_split_matrix_has_no_float64_inverse(monkeypatch):
@@ -155,8 +164,96 @@ def test_a_split_matrix_has_no_float64_inverse(monkeypatch):
     for backend in ("cpu", "tpu"):
         monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
         assert f64_refactor_form(split) is None
-    assert kernels.KernelPlan(mode="fused").descriptor()["f64_refactor"] \
-        is None
+    plan = kernels.KernelPlan(mode="fused").descriptor()
+    assert plan["f64_refactor"] is None and plan["f64_loop"] is None
+
+
+# ---------------- the loop's two shapes (ISSUE 43) ---------------------
+
+@pytest.fixture(scope="module")
+def stack_calls():
+    """The recorded solves of the full served stack: iter-0's (cold) and
+    the hot pass's, as ``(args, kwargs)`` of ``_solve_impl``."""
+    from stacked_farmer import record_stacked_farmer_calls
+    calls, plan = record_stacked_farmer_calls()
+    # what the engine's ``phase_timing()["kernel"]`` says of its loop
+    assert plan["f64_loop"] == "resident" and plan["mode"] == "fused"
+    return [(a, {k: v for k, v in kw.items() if k != "_segmented_caller"})
+            for a, kw in (calls[0], calls[-1])]
+
+
+def _eps(e):
+    return dict(eps_abs=e, eps_rel=e, eps_abs_dua=e, eps_rel_dua=e)
+
+
+# (recorded call, keywords over the recorded ones, the check of its
+# four-check period the solve must END on (None: not asked)). Budgets
+# that end mid-period (75, 250), at a period's fourth check with rho
+# moving there (400) and the recorded one; tolerances at which the cold
+# solve converges at a period's first, second and fourth check (its
+# residuals fall 1.2-1.5x a check there, the tolerance between two);
+# the stall window on (the cold solve then leaves through it at 1,300
+# iterations for 5,000, after twelve rho moves that each reset it);
+# and the whole program, polish included.
+_LOOP_CASES = [
+    (0, dict(max_iter=75), None), (0, dict(max_iter=250), None),
+    (0, dict(max_iter=400), 3), (0, {}, None),
+    (1, dict(max_iter=75), None), (1, dict(max_iter=250), None),
+    (1, dict(max_iter=400), 3), (1, {}, None),
+    (0, _eps(2.2e-4), 0), (0, _eps(4.7e-5), 1), (0, _eps(2.4e-5), 3),
+    (0, dict(stall_rel=1e-2), None), (1, dict(stall_rel=1e-2), None),
+    (1, dict(polish=True), None),
+]
+
+
+@pytest.mark.parametrize(
+    "call,over,ends_on", _LOOP_CASES,
+    ids=[f"call{c}-" + ("-".join(f"{k}={v}" for k, v in o.items()
+                                 if not k.endswith(("_rel", "_dua"))
+                                 or k == "stall_rel") or "recorded")
+         for c, o, _ in _LOOP_CASES])
+def test_the_two_level_loop_equals_the_conditional_loop(
+        stack_calls, monkeypatch, call, over, ends_on):
+    """The loop that rebuilds the inverse once a period between two
+    inner loops (``f64_loop_form`` "resident", what a per-scenario
+    float64 stack at n <= 16 traces on every backend) against the
+    one-level loop with the rebuild under a ``lax.cond`` (what every
+    other factor form keeps, traced here by answering for one): the
+    same checks, exits and rebuilds, so every output is equal bit for
+    bit."""
+    args, kw = stack_calls[call]
+    kw = {**kw, "adaptive_rho": True, "polish": False, **over}
+
+    def solve():
+        def impl(factors, data, q, state, **k):     # a trace of its own
+            return qps._solve_impl(factors, data, q, state, **k)
+        fn = jax.jit(impl, static_argnames=qps._SOLVE_STATICS)
+        loops = fn.lower(*args, **kw).as_text().count("stablehlo.while")
+        return fn(*args, **kw), loops
+
+    assert qps.f64_loop_form(args[0].A_s) == "resident"
+    two, loops_two = solve()
+    monkeypatch.setattr(qps, "f64_loop_form", lambda A_s: "conditional")
+    one, loops_one = solve()
+    # the loop, the period inside it (two-level only) and the ADMM scan
+    assert loops_two - loops_one == 1
+    st2, st1 = two[0], one[0]
+    # iters, refactors, L, rho_scale and the iterates among the fields
+    for name, a, b in [("x", two[1], one[1]), ("yA", two[2], one[2]),
+                       ("yB", two[3], one[3])] + [
+            (f, getattr(st2, f), getattr(st1, f)) for f in st2._fields]:
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=name)
+    iters, budget = int(st2.iters), kw["max_iter"]
+    if "max_iter" in over:
+        assert iters == budget
+    if ends_on is not None:
+        assert (iters // kw["check_every"] - 1) % 4 == ends_on
+        if "eps_abs" in over:
+            assert iters < budget and int(st2.refactors) > 0
+    if over.get("stall_rel") and call == 0:
+        # left through the stall window, with rho moves behind it
+        assert iters < budget and int(st2.refactors) > 0
 
 
 # ---------------- a served wheel on the unrolled refactorization -------
@@ -232,6 +329,8 @@ def test_served_wheel_on_the_unrolled_refactorization(tmp_path,
              for _ in range(3)]
     lib = _served(tmp_path, "library", costs)
     assert {p["f64_refactor"] for p in lib[3]} == {"library"}
+    # the loop's shape is read from the operand alone: the same on both
+    assert {p["f64_loop"] for p in lib[3]} == {"resident"}
 
     jax.clear_caches()      # the traces above hold the library pair
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -241,12 +340,15 @@ def test_served_wheel_on_the_unrolled_refactorization(tmp_path,
         unr = _served(tmp_path, "unrolled", costs)
         assert obs.counter_value("qp.host_rho_refactors") == 0
         assert obs.counter_value("kernel.f64_refactor_unrolled") > 0
+        assert obs.counter_value("kernel.f64_loop_resident") > 0
+        assert obs.counter_value("kernel.f64_loop_conditional") == 0
         assert obs.counter_value("kernel.factor_prepares") > 0
     finally:
         obs.shutdown()
         monkeypatch.undo()
         jax.clear_caches()  # nor may a later test inherit these traces
     assert unr[3] and all(p["f64_refactor"] == "unrolled"
+                          and p["f64_loop"] == "resident"
                           and p["mode"] == "fused" for p in unr[3])
 
     _LIMITS = _cell_limits()

@@ -555,7 +555,13 @@ def test_prepare_resolution(monkeypatch, mode, kind, ir_sweeps):
         # unrolled form does not apply (tests/test_f64_refactor.py)
         "f64_refactor": {"split-df32": None,
                          "per-scenario-f64-host": "host"}.get(kind,
-                                                              "library")}
+                                                              "library"),
+        # the loop that adapts rho inside the program: the rebuild
+        # under a ``lax.cond`` for the shared float64 inverses; none
+        # where no program rebuilds a float64 inverse
+        "f64_loop": {"split-df32": None,
+                     "per-scenario-f64-host": None}.get(kind,
+                                                        "conditional")}
     if want == "S":
         assert plan.A_lo is None
     elif kind == "split-df32":

@@ -182,7 +182,10 @@ def test_phase_timing_contract(layout, precision):
                             # mixed recipe's shared one is the device
                             # library's on every backend
                             "f64_refactor": None if precision == "df32"
-                            else "library"}
+                            else "library",
+                            # rebuilt under a ``lax.cond`` in the loop
+                            "f64_loop": None if precision == "df32"
+                            else "conditional"}
     assert (pt["mode"], pt["devices"]) == (
         "sharded" if ndev > 1 else "host", ndev)
     shape = pt["solve_shape"]
