@@ -8,9 +8,11 @@ user calls, on the attached TPU.
 One chip, two legs in ONE process (a chip belongs to one process; this
 script starts no child that imports jax):
 
- 1. the reference-scale stochastic unit-commitment wheel (bench.INSTANCE
-    — 90 gens x 48 h, n = 13,056, m = 26,016, integrality on) under the
-    df32 recipe (bench.DF32), entered through
+ 1. the reference-scale stochastic unit-commitment wheel (the
+    ``instance`` of ``benchmarks/configs/uc90x48_df32.json`` — 90 gens
+    x 48 h, n = 13,056, m = 26,016, integrality on) under its df32
+    ``recipe``: the deployment the benchmark's cells state, read from
+    the same file (``deployment()`` below), entered through
     ``mpisppy_tpu.__main__.run(RunConfig)``: PH hub + Lagrangian outer
     spoke + x̂ inner spoke as threads of one wheel, incumbents on the
     device, ``subproblem_chunk`` < S so the chunked loop iterates. Width
@@ -42,6 +44,7 @@ no JAX_PLATFORMS and forces no platform.
 """
 
 import argparse
+import functools
 import json
 import os
 import resource
@@ -62,7 +65,7 @@ MESH_S, MESH_CHUNK = 8, 1               # --chips 4: 2 scen/device, 2 chunks
 # engine's to rounding
 MESH_REDUCE_RTOL = 1e-9
 # sharded vs single device is a comparison of two SOLVER runs: both are
-# the bench recipe's budget-capped df32 solves of a degenerate LP
+# the df32 recipe's budget-capped df32 solves of a degenerate LP
 # relaxation (accepted at a 1e-2 pri_rel gate, doc/tpu_numerics.md), so
 # two correct runs land on different points of the optimal face and x̄
 # agrees in the mean, not slot by slot (rehearsed on 4 virtual CPU
@@ -103,6 +106,18 @@ def emit(stage, **fields):
 def check(cond, what):
     if not cond:
         raise AssertionError(what)
+
+
+@functools.cache
+def deployment():
+    """(instance, recipe, n, m) of the UC deployment, from the file the
+    benchmark's cells read: the bring-up check and the yardstick run
+    ONE stated deployment. Callers copy the dicts before adding keys."""
+    with open(os.path.join(HERE, "benchmarks", "configs",
+                           "uc90x48_df32.json"), encoding="utf-8") as f:
+        cfg = json.load(f)
+    return (cfg["instance"], cfg["recipe"],
+            cfg["shape"]["n"], cfg["shape"]["m"])
 
 
 def _events():
@@ -162,10 +177,8 @@ def f64_linalg_probe(num_gens, num_hours, batch=4):
                                            _device_f64_linalg_trusted,
                                            _factorize, qp_setup)
 
-    from bench import INSTANCE
-
     b = build_batch(uc.scenario_creator, uc.make_tree(batch),
-                    creator_kwargs=dict(INSTANCE, num_gens=num_gens,
+                    creator_kwargs=dict(deployment()[0], num_gens=num_gens,
                                         num_hours=num_hours),
                     vector_patch=uc.scenario_vector_patch)
     sp = SPBase(b, {}, dtype=jnp.float64)
@@ -258,17 +271,17 @@ def uc_wheel_leg():
     import numpy as np
 
     import mpisppy_tpu.utils.sputils as sputils
-    from bench import DF32, INSTANCE, M_PER_SCEN, N_PER_SCEN
     from mpisppy_tpu import obs
     from mpisppy_tpu.__main__ import run
     from mpisppy_tpu.obs import profile
     from mpisppy_tpu.utils.config import AlgoConfig, RunConfig, SpokeConfig
 
-    recipe = dict(DF32, subproblem_chunk=CHUNK, iter0_feas_tol=5e-3,
+    instance, df32, n_scen, m_scen = deployment()
+    recipe = dict(df32, subproblem_chunk=CHUNK, iter0_feas_tol=5e-3,
                   display_timing=False)
     cfg = RunConfig(
-        model="uc", num_scens=S, model_kwargs=dict(INSTANCE),
-        algo=AlgoConfig(default_rho=DF32["defaultPHrho"],
+        model="uc", num_scens=S, model_kwargs=dict(instance),
+        algo=AlgoConfig(default_rho=df32["defaultPHrho"],
                         max_iterations=MAX_HOT_ITERS, convthresh=-1.0),
         # the recipe rides hub_options / the spokes' own options: the
         # CLI has no precision flag
@@ -307,7 +320,7 @@ def uc_wheel_leg():
     wheel = seen["wheel"]
     ph = wheel.hub.opt
 
-    check(ph.batch.n == N_PER_SCEN and ph.batch.m == M_PER_SCEN,
+    check(ph.batch.n == n_scen and ph.batch.m == m_scen,
           f"width was cut: n={ph.batch.n} m={ph.batch.m}")
     check(int(ph._iter) >= 2,
           f"only {ph._iter} hot PH iterations completed")
@@ -453,26 +466,27 @@ def mesh_leg(n_chips):
     import jax.numpy as jnp
     import numpy as np
 
-    from bench import DF32, INSTANCE
     from mpisppy_tpu.core.ph import PHBase
     from mpisppy_tpu.ir.batch import build_batch
     from mpisppy_tpu.ir.tree import two_stage_tree
     from mpisppy_tpu.models import uc
     from mpisppy_tpu.parallel.mesh import make_mesh
 
+    instance, df32 = deployment()[:2]
+
     def build(order):
         t = time.perf_counter()
         tree = two_stage_tree([f"scen{i}" for i in order],
                               nonant_names=["u", "st"])
         b = build_batch(uc.scenario_creator, tree,
-                        creator_kwargs=dict(INSTANCE),
+                        creator_kwargs=dict(instance),
                         vector_patch=uc.scenario_vector_patch)
         emit("mesh_host_build", seconds=round(time.perf_counter() - t, 1),
              order=[int(i) for i in order], n=b.n, m=b.m)
         return b
 
     def two_steps(batch, mesh, chunk):
-        opts = dict(DF32, subproblem_chunk=chunk, iter0_feas_tol=5e-3,
+        opts = dict(df32, subproblem_chunk=chunk, iter0_feas_tol=5e-3,
                     display_timing=False)
         t = time.perf_counter()
         ph = PHBase(batch, opts, mesh=mesh, dtype=jnp.float64)

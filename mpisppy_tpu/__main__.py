@@ -53,7 +53,7 @@ def make_parser() -> argparse.ArgumentParser:
     p.add_argument("--subproblem-polish-chunk", type=int, default=0)
     p.add_argument("--subproblem-ir-sweeps", type=int, default=1,
                    help="df32 x-update iterative-refinement sweeps "
-                        "(doc/roofline.md §2; fused kernel mode "
+                        "(doc/drivers.md; fused kernel mode "
                         "supports 1-4)")
     p.add_argument("--subproblem-kernel-mode", choices=KERNEL_MODES,
                    default="auto",

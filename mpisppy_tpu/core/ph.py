@@ -950,18 +950,6 @@ class PHBase(SPBase):
         # beside the phases' seconds and reset with them
         self._run_times = {"count": 0, "seconds": 0.0,
                            "reset_seconds": 0.0}
-        # 0/False were the documented "disable spreading" spellings of
-        # the retired option — nothing changed for those configs, so
-        # only values that used to alter behavior warn
-        if opts.get("subproblem_spread_devices") not in (
-                None, "auto", 0, "0", False):
-            import warnings
-            warnings.warn(
-                "subproblem_spread_devices is retired: multi-device "
-                "runs shard the scenario axis over the mesh instead of "
-                "round-robin chunk spreading (doc/sharding.md) — pass "
-                "mesh=make_mesh(n); the option is ignored",
-                DeprecationWarning, stacklevel=2)
 
     # ------------- a run's state, and its reset -------------
     def _init_run_state(self):
@@ -2490,8 +2478,7 @@ class PHBase(SPBase):
         # aliases the dispatch store's single flowed factor, so the
         # first donated solve would delete the buffer chunk 2 needs
         donate = pipeline and key in self._chunk_donatable \
-            and dispatch is None \
-            and bool(int(self.options.get("subproblem_donate", 1)))
+            and dispatch is None
         if donate:
             self._chunk_dirty.add(key)   # cleared after pass 3 stores
             obs.counter_add("qp.donated_passes")
@@ -3217,7 +3204,8 @@ class PHBase(SPBase):
             if buckets:
                 shrink["first_bucket"] = float(buckets[0])
         _obs_diagnose.note_sample(fx, shrink=shrink)
-        # rebind, don't mutate: the bench signal handler reads this
+        # rebind, don't mutate: a reader on another thread or in a
+        # signal frame must see a whole record
         self._forensic_last = fx
         return fx
 
@@ -4199,8 +4187,7 @@ class PHBase(SPBase):
             states = [st0] * len(slices)
             self._pool_states[ck] = states
         donate = (not fresh) \
-            and bool(int(self.options.get("subproblem_pipeline", 1))) \
-            and bool(int(self.options.get("subproblem_donate", 1)))
+            and bool(int(self.options.get("subproblem_pipeline", 1)))
         if donate:
             self._pool_dirty.add(ck)
             obs.counter_add("qp.donated_passes")

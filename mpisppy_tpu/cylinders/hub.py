@@ -673,9 +673,8 @@ class Hub(SPCommunicator):
     # ---- wheel watchdog (doc/fault_tolerance.md) ----
     def fire_watchdog(self, source):
         """Deadline exceeded: terminate the wheel CLEANLY — kill signal
-        to every spoke, telemetry flushed, partial bounds evented (the
-        wheel-level analog of bench.py's SIGTERM flush). Once-guarded;
-        callable from the supervisor's timer thread."""
+        to every spoke, telemetry flushed, partial bounds evented.
+        Once-guarded; callable from the supervisor's timer thread."""
         with self._watchdog_lock:
             if self._watchdog_fired:
                 return
@@ -697,7 +696,7 @@ class Hub(SPCommunicator):
         if self.ckpt is not None:
             self.ckpt.maybe_capture(force=True, reason="watchdog")
         # nonblocking: the timer thread may interrupt a frame holding a
-        # sink lock (the same contract as bench's signal-handler flush)
+        # sink lock
         self._write_live_snapshot(force=True)
         obs.flush(nonblocking=True)
         self.send_terminate()
@@ -705,8 +704,7 @@ class Hub(SPCommunicator):
     def handle_preemption(self, source="sigterm"):
         """The preemption notice path (SIGTERM on a preemptible pod —
         utils/multiproc installs the handler when checkpointing is
-        armed, the wheel-level analog of bench.py's signal-safe
-        flush): force one final checkpoint bundle, flush telemetry
+        armed): force one final checkpoint bundle, flush telemetry
         nonblocking, signal the spokes, and mark the wheel terminated
         so the hub loop exits at its next check. Once-guarded; safe
         from a signal frame (main thread) interrupting the hub loop."""

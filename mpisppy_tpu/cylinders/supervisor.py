@@ -32,8 +32,7 @@ Four mechanisms (doc/fault_tolerance.md has the full semantics):
   continues without it.
 - **watchdog** — ``start_watchdog(deadline)`` arms a timer that fires
   :meth:`Hub.fire_watchdog` (terminate + telemetry flush + partial
-  bounds) if the wheel outlives its deadline, the wheel-level analog
-  of bench.py's SIGTERM flush.
+  bounds) if the wheel outlives its deadline.
 
 Every transition lands in telemetry: ``hub.spoke_down`` /
 ``hub.spoke_respawn`` / ``hub.spoke_quarantined`` events + same-named

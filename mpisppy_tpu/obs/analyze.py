@@ -10,10 +10,9 @@ re-running anything.
 
 ``analyze --compare A B`` diffs two runs' headline metrics with
 thresholded verdicts (exit code 3 on REGRESSION), which turns a pair
-of bench telemetry dirs into a CI-checkable artifact. Runs whose
+of telemetry dirs into a CI-checkable artifact. Runs whose
 ``run_header.schema`` versions differ are REFUSED (exit code 2)
-instead of mis-parsed — bench.py stamps the same ``schema_version``
-into its BENCH JSON rows for the same reason.
+instead of mis-parsed.
 
 Pure host-side JSON work: no jax import, safe to run anywhere.
 """
@@ -1610,9 +1609,9 @@ def compare(a: Run, b: Run, threshold=1.5,
     ``abs_floor`` (seconds) suppresses time-metric verdicts whose
     absolute delta is below it: micro-phases (sub-ms per call) ride
     scheduler noise, so a 3x ratio on 0.5 ms is jitter, not a
-    regression. Same-machine compares keep the tight 1 ms default;
-    cross-machine gates (tools/regression_gate.py) pass a looser
-    floor."""
+    regression. ``threshold`` at infinity leaves the count rows and
+    the clock-free verdict rows as the only ones that can fail
+    (tools/regression_gate.py: a CPU second decides nothing)."""
     if a.schema != b.schema:
         raise ValueError(
             f"schema mismatch: {a.path} is v{a.schema}, {b.path} is "

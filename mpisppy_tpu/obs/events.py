@@ -36,11 +36,11 @@ import threading
 import time
 from collections import deque
 
-# Telemetry artifact schema version, stamped into every run_header (and
-# by bench.py into its BENCH JSON rows). Consumers that join artifacts
-# across runs (``analyze --compare``) refuse mismatched versions instead
-# of mis-parsing. Bump when an event/trace/metrics field changes
-# meaning; absent = 1 (the PR-3 format).
+# Telemetry artifact schema version, stamped into every run_header.
+# Consumers that join artifacts across runs (``analyze --compare``)
+# refuse mismatched versions instead of mis-parsing. Bump when an
+# event/trace/metrics field changes meaning; absent = 1 (the PR-3
+# format).
 SCHEMA_VERSION = 2
 
 # rotation defaults (documented in doc/observability.md): cap one

@@ -21,7 +21,7 @@ import os
 # reports (lower-cased): (peak FLOP/s, peak HBM GB/s). One row per kind
 # a machine has actually reported: the attached v5e says ``TPU v5
 # lite`` (published bf16 peak and HBM bandwidth, Google Cloud TPU v5e
-# documentation; bench.py's V5E_PEAK_BF16 is the same number). Any
+# documentation; benchmarks/peaks.json carries the same row). Any
 # other accelerator is an ERROR, never a default — add its row when a
 # machine reports its name, or set BOTH MPISPPY_TPU_PEAK_FLOPS and
 # MPISPPY_TPU_PEAK_HBM_GBPS. The CPU row is a NOMINAL placeholder that

@@ -23,8 +23,7 @@ Three surfaces:
  - **Memory watermarks** (:func:`sample_memory`): per-device
    ``device.memory_stats()`` gauges (bytes in use + peak) where the
    backend supports it; a guarded no-op on backends that don't (CPU
-   returns None) — sampled once per PH iteration and at bench phase
-   boundaries.
+   returns None) — sampled once per PH iteration.
  - **Transfer byte helpers** (:func:`tree_nbytes`): the instrumented
    sites (core/ph.py gate reads,
    core/spbase.py batch shipping, ops/qp_solver.py host rho
@@ -153,12 +152,10 @@ def install():
 
 
 # ---- device memory watermarks ----
-def sample_memory(event=False):
+def sample_memory():
     """Sample ``memory_stats()`` of every device into gauges
     (``mem.<dev>.bytes_in_use`` + ``.peak_bytes_in_use``). Returns the
-    sampled {dev: stats} map ({} when unsupported/disabled). With
-    ``event=True`` also emits one ``resource.memory`` event carrying
-    the per-device byte counts (the per-iteration record path)."""
+    sampled {dev: stats} map ({} when unsupported/disabled)."""
     r = _active()
     if r is None:
         return {}
@@ -185,8 +182,6 @@ def sample_memory(event=False):
         if peak is not None:
             r.metrics.gauge_set(f"mem.{key}.peak_bytes_in_use", peak)
         out[key] = {"bytes_in_use": in_use, "peak_bytes_in_use": peak}
-    if event and out:
-        r.event("resource.memory", {"devices": out})
     return out
 
 

@@ -223,9 +223,8 @@ def kernel_solve(plan: KernelPlan, factors, data, q, state, *,
 def est_hbm_bytes_per_iter(*, n, m, s_chunk, pk_pass_bytes=None,
                            ir_sweeps=1, l_inv=True, block_dtype="f32",
                            factor_bytes=4, vec_bytes=8):
-    """doc/roofline.md traffic model of ONE fused df32 tail iteration
-    (per chunk), the number the bench's uc1024 row records so a driver
-    re-run can confirm the predicted drop:
+    """Traffic model of ONE fused df32 tail iteration (per chunk);
+    benchmarks/bytes_model.py is held equal to it (doc/roofline.md §6):
 
       factor applies : 2 triangle passes x (1 seed + ir_sweeps IR
                        solves) x n² x 4 B — a FULL square a pass:

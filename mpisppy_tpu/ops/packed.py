@@ -315,12 +315,9 @@ def structure_from_lists(row_lists, col_lists, g_rows, m, n):
 
 def pk_nbytes(pk: Packed) -> int:
     """Bytes of matrix operands one packed A-pass streams from HBM (the
-    value arrays; index vectors are noise). The observability companion
-    to the per-phase pipeline timing: bench.py records the hi+lo packed
-    operand footprint in its uc1024 JSON row next to MFU, making the
-    bandwidth-bound cost basis of the hot loop auditable (see
-    doc/roofline.md — dense-equivalent MFU understates a packed kernel
-    by the sparsity factor)."""
+    value arrays; index vectors are noise): the hi+lo sum is
+    ``phase_timing()["solve_shape"]["pk_pass_bytes"]``, the byte basis
+    of the benchmark's roofline share (benchmarks/bytes_model.py)."""
     return int(pk.g_vals.size * pk.g_vals.dtype.itemsize
                + pk.l_vals.size * pk.l_vals.dtype.itemsize)
 

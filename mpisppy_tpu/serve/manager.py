@@ -835,8 +835,8 @@ class ServeService:
             obs.counter_add("serve.requests.completed")
             if req.migrated_from:
                 # the receiver-side close of a handoff: the migrated-in
-                # request actually finished here — the gate's e2e
-                # signal (regression_gate migrate smoke)
+                # request actually finished here (tests/test_migrate.py
+                # reads it off the receiver's /metrics)
                 obs.counter_add("serve.migrate.completed")
         elif status == "failed":
             obs.counter_add("serve.requests.failed")

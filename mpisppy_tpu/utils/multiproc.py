@@ -379,10 +379,9 @@ def spin_the_wheel_processes(cfg: RunConfig, join_timeout=None, f32=False,
             install_hub_faults(hub, hub_fault_spec)
         # the preemption notice path (doc/fault_tolerance.md): with
         # checkpointing armed, SIGTERM forces one final bundle +
-        # nonblocking telemetry flush + clean terminate (bench.py's
-        # signal-safe flush pattern) instead of losing the whole
-        # optimization state. Handler restored on every exit path
-        # (outermost finally).
+        # nonblocking telemetry flush + clean terminate instead of
+        # losing the whole optimization state. Handler restored on
+        # every exit path (outermost finally).
         if cfg.checkpoint_dir:
             import signal as _signal
 

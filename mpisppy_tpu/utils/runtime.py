@@ -42,11 +42,11 @@ def enable_honest_f32():
 
 def setup_jax_runtime(f32: bool = False):
     """The ONE owner of the process-level JAX settings (precision
-    policy + persistent compile cache): the CLI, ``serve``, bench.py,
-    the test harness and every spawned spoke/shard call it. Where
-    ``JAX_COMPILATION_CACHE_DIR`` is set the cache directory is left
-    to JAX (which reads the variable); no other directory is ever set
-    in code."""
+    policy + persistent compile cache): the CLI, ``serve``, the
+    benchmark's harness, ``chip_smoke.py``, the test harness and every
+    spawned spoke/shard call it. Where ``JAX_COMPILATION_CACHE_DIR`` is
+    set the cache directory is left to JAX (which reads the variable);
+    no other directory is ever set in code."""
     import jax
 
     if not f32:

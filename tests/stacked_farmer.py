@@ -4,7 +4,7 @@
 iter-0 and one hot PH pass on the CPU while recording every call of
 the native-f64 solve: the operands ``core/ph`` hands
 ``qp_solver._solve_impl`` (shared by tests/test_f64_products.py and
-tests/test_chip_compile.py)."""
+tests/test_chip_compile_stacked_f64.py)."""
 
 import jax
 import numpy as np

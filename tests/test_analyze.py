@@ -44,10 +44,11 @@ def farmer_run_dir(tmp_path_factory):
     return str(tdir)
 
 
-def _tampered_copy(src, dst, factor=2.0, schema=None):
+def _tampered_copy(src, dst, factor=2.0, schema=None, counters=None):
     """Copy a telemetry dir, scaling every per-iteration/phase time by
     ``factor`` (the injected regression) and optionally rewriting the
-    header schema version."""
+    header schema version or (``counters``: a function of the recorded
+    counters) the final counter values."""
     import shutil
 
     shutil.copytree(src, dst)
@@ -69,6 +70,17 @@ def _tampered_copy(src, dst, factor=2.0, schema=None):
         if e.get("ph") == "X" and e.get("name", "").startswith("ph."):
             e["dur"] *= factor
     json.dump(tr, open(tr_path, "w"))
+    mx_path = os.path.join(dst, "metrics.json")
+    mx = json.load(open(mx_path))
+    for name, h in mx.get("histograms", {}).items():
+        if name.endswith("seconds"):
+            for k in ("sum", "min", "max", "last", "mean",
+                      "p50", "p95", "p99"):
+                if isinstance(h.get(k), (int, float)):
+                    h[k] *= factor
+    if counters is not None:
+        mx["counters"].update(counters(mx["counters"]))
+    json.dump(mx, open(mx_path, "w"))
     return dst
 
 
@@ -196,6 +208,36 @@ def test_compare_flags_injected_2x_regression(farmer_run_dir, tmp_path,
     rc = analyze.main(["--compare", bad, farmer_run_dir])
     assert rc == 0
     assert "improved" in capsys.readouterr().out
+
+
+@pytest.mark.parametrize("tamper, rc, row", [
+    # every time field tenfold: printed, never a verdict
+    (dict(factor=10.0), 0, None),
+    # the committed golden books NO gate sync; one a solve call is the
+    # regression the gate exists for
+    (dict(factor=1.0, counters=lambda c: {
+        "ph.gate_syncs": c["ph.solve_loop_calls"]}), 3,
+     "gate_syncs_per_solve_call"),
+    (dict(factor=1.0, counters=lambda c: {
+        "jax.compiles": 2 * c["jax.compiles"]}), 3,
+     "xla_compiles_total"),
+], ids=["tenfold_slower_passes", "gate_syncs_fail",
+        "doubled_compiles_fail"])
+def test_regression_gate_compares_counts_not_clocks(tamper, rc, row,
+                                                    tmp_path, capsys):
+    """tools/regression_gate.py's comparison stage, in-process, on
+    copies of the committed golden: a CPU clock cannot fail it, a
+    count can (exit 3)."""
+    from tools import regression_gate as rg
+    fresh = _tampered_copy(rg.GOLDEN, str(tmp_path / "fresh"), **tamper)
+    assert rg.compare_counts(rg.GOLDEN, fresh) == rc
+    out = capsys.readouterr().out
+    assert ("VERDICT: PASS" in out) == (rc == 0)
+    if row is None:
+        assert "REGRESSION" not in out
+        assert "ph_seconds_per_iteration" in out    # still printed
+    else:
+        assert f"VERDICT: REGRESSION ({row})" in out
 
 
 def test_compare_refuses_schema_mismatch(farmer_run_dir, tmp_path,

@@ -1,0 +1,265 @@
+"""Compile for the described v5e the stacked native-f64 programs of the
+served cell: the segment's products, the polish, the in-program
+refactorization and the loop that carries their matrices.
+
+The TPU compiler is installed here and compiles for a chip that is
+DESCRIBED (``v5e:2x2``), not attached: what it refuses here, the chip's
+compiler refuses there. Nothing runs, so these tests say nothing about
+results or times; a compile that passes is not a chip run. The shared
+fixtures and why they are fixtures: tests/chip_compile_helpers.py.
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chip_compile_helpers import _hlo_lines
+from chip_compile_helpers import (  # noqa: F401  (fixtures by name)
+    no_persistent_cache, one_chip, topo)
+
+
+# ---------------- the stacked native-f64 solve (ISSUE 38) --------------
+
+@pytest.fixture(scope="module")
+def stacked_farmer_segment():
+    """The served cell's segment program as the chip's plan runs it
+    (``_needs_host_factor``: ``polish=False``, ``adaptive_rho=False``,
+    segments of 500) at a full stack's operands, recorded from a CPU
+    pass of eight stacked three-scenario farmers: A_s (24, 7, 12)
+    float64, the factor the explicit (24, 12, 12) float64 inverse."""
+    import mpisppy_tpu.ops.qp_solver as qps
+    from stacked_farmer import record_stacked_farmer_calls
+    calls, _plan = record_stacked_farmer_calls()
+    args, kw = calls[-1]
+    assert args[0].A_s.shape == (24, 7, 12) \
+        and args[0].A_s.dtype == np.float64
+    assert args[3].L.shape == (24, 12, 12) and args[3].L.dtype == np.float64
+    kw = {k: v for k, v in kw.items() if k != "_segmented_caller"}
+    kw.update(max_iter=500, polish=False, adaptive_rho=False)
+    fn = jax.jit(qps._solve_impl, static_argnames=qps._SOLVE_STATICS)
+    return fn, args, kw
+
+
+_PRODUCT_SCOPES = ("qp.Ax", "qp.ATy", "qp.kkt_solve")
+
+
+def _product_loops(hlo):
+    """The ``while`` instructions whose ``op_name`` lies under one of
+    the three product scopes: the compiler's emulation of a batched
+    float64 ``dot_general`` (eight f32 limbs, nested loops)."""
+    return [ln for ln in _hlo_lines(hlo, "while")
+            if any(s + "/" in ln for s in _PRODUCT_SCOPES)]
+
+
+def _widened(tree, S, scale, sharding):
+    """The recorded (24, 7, 12) operands as shapes on the described
+    chip: the scenario axis at ``S`` rows, m and n times ``scale``."""
+    dims = {24: S, 7: 7 * scale, 12: 12 * scale}
+
+    def leaf(a):
+        if not (hasattr(a, "shape") and hasattr(a, "dtype")):
+            return a
+        return jax.ShapeDtypeStruct(tuple(dims[d] for d in a.shape),
+                                    a.dtype, sharding=sharding)
+    return jax.tree.map(leaf, tree)
+
+
+# (S, scale): the served stack, a solo wheel, and the largest shape the
+# chip sweep timed ((24, 700, 1200): ``crops_multiplier`` 100)
+@pytest.mark.parametrize("S,scale", [(24, 1), (3, 1), (24, 100)])
+def test_stacked_f64_segment_has_no_emulated_dot_loops_on_v5e(
+        stacked_farmer_segment, one_chip, no_persistent_cache, S, scale):
+    """The rule answers "reduce" for every per-scenario float64 matrix
+    (doc/kernels.md §3d: the sweep found no shape where the emulated
+    dot wins), and the segment program the v5e compiler makes of it
+    holds the solve's own two loops and nothing of the dot emulation:
+    no ``while`` under ``qp.Ax`` / ``qp.ATy`` / ``qp.kkt_solve``, no
+    ``dynamic-update-slice`` (at (24, 7, 12): 34 loops and 74
+    update-slices before ISSUE 38, 14 of the loops in the ADMM scan
+    body). One answer, held by a compile at each pinned shape."""
+    fn, args, kw = stacked_farmer_segment
+    hlo = fn.lower(*_widened(args, S, scale, one_chip), **kw).compile() \
+        .as_text()
+    assert f"f64[{S},{7 * scale},{12 * scale}]" in hlo
+    assert not _product_loops(hlo)
+    assert len(_hlo_lines(hlo, "while")) == 2
+    assert not _hlo_lines(hlo, "dynamic-update-slice")
+
+
+def test_the_emulated_dot_is_a_loop_nest_on_v5e(one_chip,
+                                                no_persistent_cache):
+    """What the reduction replaced, so that a compiler that learns to
+    multiply float64 batches shows up here: one batched float64
+    ``einsum`` at the stacked inverse's shape compiles to ``while``
+    loops over f32 limbs with ``dynamic-update-slice`` in them, the
+    reduction of the same product to neither."""
+    from mpisppy_tpu.ops.qp_solver import _matvec_dot, _matvec_reduce
+    F = jax.ShapeDtypeStruct((24, 12, 12), jnp.float64, sharding=one_chip)
+    b = jax.ShapeDtypeStruct((24, 12), jnp.float64, sharding=one_chip)
+    dot = jax.jit(_matvec_dot).lower(F, b).compile().as_text()
+    assert _hlo_lines(dot, "while") \
+        and _hlo_lines(dot, "dynamic-update-slice")
+    red = jax.jit(_matvec_reduce).lower(F, b).compile().as_text()
+    assert not _hlo_lines(red, "while") \
+        and not _hlo_lines(red, "dynamic-update-slice")
+
+
+# ---------------- the polish of the stacked native-f64 solve (ISSUE 40) -
+
+def _polish_loops(hlo):
+    """The ``while`` instructions under ``qp.polish``, and those of
+    them that are the compiler's expansion of a batched float64
+    ``cholesky`` / ``triangular_solve`` / Gram ``dot_general``."""
+    loops = [ln for ln in _hlo_lines(hlo, "while") if "qp.polish/" in ln]
+    return loops, [ln for ln in loops
+                   if any(k in ln for k in ("cholesky", "triangular_solve",
+                                            "dot_general"))]
+
+
+# (S, scale): the served stack and a solo wheel, at n = 12
+@pytest.mark.parametrize("S,scale", [(24, 1), (3, 1)])
+def test_stacked_f64_polish_has_only_its_three_scans_on_v5e(
+        stacked_farmer_segment, one_chip, no_persistent_cache, S, scale):
+    """The polish program the chip's segmented driver launches last
+    (``max_iter=0``, ``polish=True``) at n = 12, where the rule answers
+    "unrolled" (doc/kernels.md §3e): the v5e compiler's program holds
+    the polish's own three scans as ``while``s under ``qp.polish`` and
+    nothing of the library expansions: no loop of a ``cholesky``, a
+    ``triangular_solve`` or the Gram ``dot_general``, no
+    ``dynamic-update-slice`` (at (24, 7, 12): 115 loops and 262
+    update-slices under ``qp.polish`` before ISSUE 40)."""
+    fn, args, kw = stacked_farmer_segment
+    kw = dict(kw, max_iter=0, polish=True)
+    hlo = fn.lower(*_widened(args, S, scale, one_chip), **kw).compile() \
+        .as_text()
+    assert f"f64[{S},{7 * scale},{12 * scale}]" in hlo
+    loops, expansions = _polish_loops(hlo)
+    assert len(loops) == 3 and not expansions
+    # with the solve's own two (never entered at max_iter 0)
+    assert len(_hlo_lines(hlo, "while")) == 5
+    assert not _hlo_lines(hlo, "dynamic-update-slice")
+
+
+def test_the_polish_keeps_the_library_calls_above_the_width_on_v5e(
+        stacked_farmer_segment, one_chip, no_persistent_cache):
+    """Above ``_POLISH_UNROLL_MAX_N`` (here n = 24: the unrolled
+    program's compile seconds turn between 16 and 24) the library path
+    is still what is lowered: the compiler's loops of the batched
+    float64 ``cholesky`` and ``triangular_solve`` are there. So a
+    compiler that learns float64 linalg, or a width that moves, shows
+    up here."""
+    from mpisppy_tpu.ops.qp_solver import _POLISH_UNROLL_MAX_N
+    fn, args, kw = stacked_farmer_segment
+    kw = dict(kw, max_iter=0, polish=True)
+    assert 12 * 2 > _POLISH_UNROLL_MAX_N
+    hlo = fn.lower(*_widened(args, 3, 2, one_chip), **kw).compile() \
+        .as_text()
+    loops, expansions = _polish_loops(hlo)
+    assert len(loops) > 3
+    assert any("cholesky" in ln for ln in expansions)
+    assert any("triangular_solve" in ln for ln in expansions)
+
+
+# ---------------- the in-program refactorization (ISSUE 42) ------------
+
+def _refactor_loops(hlo):
+    """The ``while`` instructions under ``qp.refactor`` (the rebuild of
+    the explicit float64 inverse inside ``qp.rho_adapt``): the
+    compiler's expansions of the batched float64 ``cholesky`` /
+    ``triangular_solve`` pair and of the product in front of them."""
+    return [ln for ln in _hlo_lines(hlo, "while") if "qp.refactor/" in ln]
+
+
+def _loops_carrying_halves(hlo, S):
+    """For every ``while`` of the compiled program whose body reads an
+    f32[S,7,12] / f32[S,12,12] array out of its carry (the two f32
+    halves of the float64 matrix and of the explicit inverse): how many
+    such reads the body holds, and how many of them the compiler placed
+    in ``S(1)`` (VMEM), as ``(reads, resident)`` pairs."""
+    out = []
+    for body in re.findall(r"\bwhile\(.*?body=%?([\w.\-]+)", hlo):
+        text = re.search(r"\n%?" + re.escape(body) + r" \(.*?\n\}", hlo,
+                         re.S).group(0)
+        reads = [ln for ln in text.splitlines()
+                 if "get-tuple-element(" in ln
+                 and re.search(rf"f32\[{S},(7|12),12\]", ln)]
+        if reads:
+            out.append((len(reads), sum("S(1)" in ln for ln in reads)))
+    return out
+
+
+# (S, scale): the served stack and a solo wheel, at n = 12
+@pytest.mark.parametrize("S,scale", [(24, 1), (3, 1)])
+def test_stacked_f64_loop_adapts_rho_without_library_linalg_on_v5e(
+        stacked_farmer_segment, one_chip, no_persistent_cache, S, scale):
+    """The solve's loop as the chip's plan runs it since ISSUE 42
+    (``adaptive_rho=True``: the rule keeps the refactorization of a
+    per-scenario float64 stack with n <= 16 inside the program,
+    doc/kernels.md §3f), in the shape it has since ISSUE 43 (§3g): the
+    v5e compiler's program holds the solve's own three loops (the
+    periods, the checks of a period, the ADMM scan) and nothing else:
+    no loop of a ``cholesky``, a ``triangular_solve`` or a batched
+    ``dot_general`` under ``qp.refactor``, no ``dynamic-update-slice``;
+    no ``conditional``, and every loop that carries the f32 halves of
+    the matrix and of the inverse carries all four in VMEM."""
+    fn, args, kw = stacked_farmer_segment
+    kw = dict(kw, adaptive_rho=True)
+    hlo = fn.lower(*_widened(args, S, scale, one_chip), **kw).compile() \
+        .as_text()
+    assert f"f64[{S},{7 * scale},{12 * scale}]" in hlo
+    assert "qp.refactor" in hlo
+    assert not _refactor_loops(hlo) and not _product_loops(hlo)
+    assert len(_hlo_lines(hlo, "while")) == 3
+    assert not _hlo_lines(hlo, "dynamic-update-slice")
+    assert not _hlo_lines(hlo, "conditional")
+    carrying = _loops_carrying_halves(hlo, S)
+    assert len(carrying) == 3
+    assert all(reads >= 4 and resident == reads
+               for reads, resident in carrying), carrying
+
+
+@pytest.mark.parametrize("S,scale", [(24, 1), (3, 1)])
+def test_a_conditional_in_the_loop_keeps_its_matrices_in_hbm_on_v5e(
+        stacked_farmer_segment, one_chip, no_persistent_cache, monkeypatch,
+        S, scale):
+    """What the two-level loop replaced, so that a compiler which learns
+    to keep operands resident across a ``conditional`` shows up here:
+    the same solve with the rebuild under a ``lax.cond`` in the loop's
+    one body (the shape every other factor form keeps, traced here by
+    answering for one; the rebuild itself stays the unrolled one)
+    compiles to a ``conditional``, and not one of the four halves is in
+    VMEM in either loop."""
+    import mpisppy_tpu.ops.qp_solver as qps
+    _fn, args, kw = stacked_farmer_segment
+    monkeypatch.setattr(qps, "f64_loop_form", lambda A_s: "conditional")
+
+    def impl(factors, data, q, state, **k):         # a trace of its own
+        return qps._solve_impl(factors, data, q, state, **k)
+    fn = jax.jit(impl, static_argnames=qps._SOLVE_STATICS)
+    hlo = fn.lower(*_widened(args, S, scale, one_chip),
+                   **dict(kw, adaptive_rho=True)).compile().as_text()
+    assert not _refactor_loops(hlo) and not _product_loops(hlo)
+    assert len(_hlo_lines(hlo, "while")) == 2
+    assert len(_hlo_lines(hlo, "conditional")) == 1
+    carrying = _loops_carrying_halves(hlo, S)
+    assert len(carrying) == 2
+    assert all(resident == 0 for _reads, resident in carrying), carrying
+
+
+def test_the_refactorization_keeps_the_library_pair_above_the_width_on_v5e(
+        stacked_farmer_segment, one_chip, no_persistent_cache):
+    """Above ``_POLISH_UNROLL_MAX_N`` (n = 24) ``_factorize`` lowers the
+    library pair, and the compiler's loops of it are there: what the
+    rule keeps away from the TPU by sending such factors to the host
+    (``_needs_host_factor``; this program is never launched there)."""
+    from mpisppy_tpu.ops.qp_solver import _POLISH_UNROLL_MAX_N
+    fn, args, kw = stacked_farmer_segment
+    assert 12 * 2 > _POLISH_UNROLL_MAX_N
+    hlo = fn.lower(*_widened(args, 3, 2, one_chip),
+                   **dict(kw, adaptive_rho=True)).compile().as_text()
+    loops = _refactor_loops(hlo)
+    assert any("cholesky" in ln for ln in loops)
+    assert any("triangular_solve" in ln for ln in loops)

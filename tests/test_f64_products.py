@@ -7,8 +7,8 @@ contracted axis. The form is picked per platform at lowering time
 (``jax.lax.platform_dependent``), so here, on the CPU, the solver keeps
 the library dot; the reduce forms are called directly, and a whole
 solve is steered onto them by handing the solver the reduce helpers in
-place of the switch (tests/test_chip_compile.py holds what the TPU
-compiler makes of each form)."""
+place of the switch (tests/test_chip_compile_stacked_f64.py holds
+what the TPU compiler makes of each form)."""
 
 import jax
 import jax.numpy as jnp

@@ -471,6 +471,26 @@ def test_healthy_run_judges_healthy(tmp_path, capsys):
     assert doc["forensics"]["verdicts"] == []
 
 
+def test_rho_starved_wheel_judges_unhealthy_with_evidence(tmp_path):
+    """The false-negative side on a REAL wheel, end to end through the
+    artifacts: rho 1e-9 barely moves W, so the Lagrangian outer bound
+    freezes while a real gap remains; analyze must name a non-HEALTHY
+    verdict and carry its evidence (the diagnosis rules went blind if
+    it reads HEALTHY)."""
+    from mpisppy_tpu.__main__ import config_from_args, make_parser, run
+    d = str(tmp_path / "starved")
+    run(config_from_args(make_parser().parse_args(
+        ["farmer", "--num-scens", "3", "--max-iterations", "14",
+         "--convthresh", "-1", "--subproblem-max-iter", "1500",
+         "--with-lagrangian", "--with-xhatshuffle",
+         "--rel-gap", "1e-6", "--default-rho", "1e-9",
+         "--forensics-interval", "1", "--telemetry-dir", d])))
+    fo = analyze.forensics_summary(analyze.load_run(d))
+    assert fo is not None and fo["samples"] > 0
+    assert fo["verdict"] != "HEALTHY", fo
+    assert fo["verdicts"][0]["evidence"], fo["verdicts"][0]
+
+
 # ---------------- satellite 1: no bare NaN in --json ----------------
 
 def _nan_dir(tmp_path, name="nandir"):

@@ -858,15 +858,19 @@ def _spawn_fleet_member(state, port, peer_port, tdir):
     env = {**os.environ, "PYTHONPATH": REPO, "JAX_PLATFORMS": "cpu"}
     env.pop("MPISPPY_TPU_TELEMETRY_DIR", None)
     env.pop("MPISPPY_TPU_FAULT_PLAN", None)
-    return subprocess.Popen(
-        [sys.executable, "-m", "mpisppy_tpu", "serve",
-         "--port", str(port), "--state-dir", state,
-         "--peers", f"127.0.0.1:{peer_port}",
-         "--telemetry-dir", tdir,
-         "--batch-window", "0.05", "--checkpoint-interval", "0.2",
-         "--migrate-deadline", "30"],
-        cwd=REPO, env=env, stdout=subprocess.PIPE,
-        stderr=subprocess.STDOUT, text=True)
+    # output to a FILE: a pipe nobody drains blocks the server once a
+    # warm .jax_cache makes XLA:CPU loud (tests/test_serve.py)
+    with open(tdir + ".log", "ab") as log:
+        proc = subprocess.Popen(
+            [sys.executable, "-m", "mpisppy_tpu", "serve",
+             "--port", str(port), "--state-dir", state,
+             "--peers", f"127.0.0.1:{peer_port}",
+             "--telemetry-dir", tdir,
+             "--batch-window", "0.05", "--checkpoint-interval", "0.2",
+             "--migrate-deadline", "30"],
+            cwd=REPO, env=env, stdout=log, stderr=subprocess.STDOUT)
+    proc.log_path = tdir + ".log"
+    return proc
 
 
 def _get(url):
@@ -883,9 +887,9 @@ def _post(url, obj):
 
 
 def test_sigterm_escalates_to_migrate_then_exit(tmp_path):
-    """The regression-gate migration smoke, as a test: SIGTERM on the
-    donor of a 2-process fleet must complete the in-flight request on
-    the receiver with resumed_from_iter > 0 and exactly one
+    """The live-handoff contract end to end (doc/serving.md): SIGTERM
+    on the donor of a 2-process fleet must complete the in-flight
+    request on the receiver with resumed_from_iter > 0 and exactly one
     serve.migrate.completed on the receiver's /metrics."""
     ports = (_free_port(), _free_port())
     procs = []
@@ -921,7 +925,8 @@ def test_sigterm_escalates_to_migrate_then_exit(tmp_path):
             time.sleep(0.1)
         assert os.path.exists(latest), "donor never checkpointed"
         procs[0].send_signal(signal.SIGTERM)
-        assert procs[0].wait(timeout=120) == 0, procs[0].stdout.read()
+        assert procs[0].wait(timeout=120) == 0, \
+            open(procs[0].log_path, errors="replace").read()
         # the donor's durable record settled "migrated", not parked
         drec = json.load(open(os.path.join(
             str(tmp_path / "s0"), "requests", f"{rid}.json"),

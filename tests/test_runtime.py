@@ -79,6 +79,27 @@ def test_one_setter_of_the_cache_directory():
     assert hits == [os.path.join("mpisppy_tpu", "utils", "runtime.py")]
 
 
+def test_chip_smoke_reads_the_deployment_the_benchmark_states():
+    """The bring-up check and the cells run ONE stated deployment:
+    ``chip_smoke.deployment()`` is the configuration file's instance,
+    recipe and shape, read without jax, and no second definition (a
+    module ``bench``) can be imported from the checkout's root."""
+    code = (
+        "import sys, json, importlib.util\n"
+        "sys.modules['jax'] = None   # import attempts now raise\n"
+        "import chip_smoke\n"
+        "cfg = json.load(open('benchmarks/configs/uc90x48_df32.json'))\n"
+        "assert chip_smoke.deployment() == (cfg['instance'],\n"
+        "    cfg['recipe'], cfg['shape']['n'], cfg['shape']['m'])\n"
+        "assert importlib.util.find_spec('bench') is None\n"
+        "print('ONE', cfg['shape']['n'], cfg['shape']['m'])\n")
+    out = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                         env={**os.environ, "PYTHONPATH": REPO},
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.split() == ["ONE", "13056", "26016"]
+
+
 def test_spawn_environment_sets_and_restores(monkeypatch):
     monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     monkeypatch.delenv("TPU_VISIBLE_DEVICES", raising=False)

@@ -319,24 +319,35 @@ def test_streamed_per_iteration_bytes_flat(mem_obs):
     ph.close_stream()
 
 
-def test_streamed_telemetry_streaming_section(tmp_path):
-    """End to end through the artifacts: a streamed wheel's telemetry
-    renders analyze's streaming section with the flatness verdict."""
+@pytest.mark.parametrize("source", ["streamed", "synthesized"])
+def test_streamed_telemetry_streaming_section(tmp_path, source):
+    """End to end through the artifacts: a streamed or synthesized
+    wheel's telemetry renders analyze's streaming section with the
+    flatness verdict (a synthesized source stages on the device and
+    ships nothing)."""
     from mpisppy_tpu.obs.analyze import load_run, streaming_summary
     obs.configure(out_dir=str(tmp_path))
     try:
-        b_res, _, _ = farmer_pair(S=8)
+        b_res, b_syn, spec = farmer_pair(S=8)
         ph = PH(b_res, options=dict(FARMER_OPTS, PHIterLimit=4,
-                                    scenario_source="streamed"))
+                                    scenario_source="streamed")) \
+            if source == "streamed" else \
+            PH(b_syn, options=dict(FARMER_OPTS, PHIterLimit=4,
+                                   subproblem_chunk=4,
+                                   scenario_source="synthesized",
+                                   synth_spec=spec))
         ph.ph_main()
         ph.close_stream()
     finally:
         obs.shutdown()
     sm = streaming_summary(load_run(str(tmp_path)))
-    assert sm is not None and sm["source"] == "streamed"
-    assert sm["chunks_shipped"] > 0 and sm["bytes_shipped"] > 0
+    assert sm is not None and sm["source"] == source
     assert sm["device_put_flat_steady_state"] is True
-    assert sm["prefetch_occupancy"] is not None
+    if source == "streamed":
+        assert sm["chunks_shipped"] > 0 and sm["bytes_shipped"] > 0
+        assert sm["prefetch_occupancy"] is not None
+    else:
+        assert sm["synth_chunks"] > 0
 
 
 # ---------------- pipeline + shutdown ----------------
@@ -588,6 +599,16 @@ def test_streamed_compacted_bit_equal_resident_compacted(tmp_path):
     # the one-off restage booked out of band, NOT on bytes_shipped
     assert sum(d.get("stream.compacted_restage_bytes", 0)
                for d in deltas) == ss["compacted_restage_bytes"]
+    # analyze reads the same run the same way: one re-block, flat
+    # transfers after it, the warm transplant landed
+    from mpisppy_tpu.obs.analyze import (load_run, shrink_summary,
+                                         streaming_summary)
+    run = load_run(str(tmp_path))
+    sm, sh = streaming_summary(run), shrink_summary(run)
+    assert sh["compactions"] == 1 and sm["compacted_transitions"] == 1
+    assert sm["device_put_flat_steady_state"] is not False
+    assert sm["compacted_restage_bytes"] == ss["compacted_restage_bytes"]
+    assert sh["transplant_cold_fallbacks"] == 0
     ph1.close_stream()
     ph0.close_stream()
 

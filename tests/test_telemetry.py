@@ -406,7 +406,7 @@ def test_memory_sampling_guarded_on_cpu(telemetry):
     from mpisppy_tpu.obs import resource
 
     assert resource.sample_memory() == {}
-    assert resource.sample_memory(event=True) == {}    # and again
+    assert resource.sample_memory() == {}    # and again
 
 
 def test_transfer_byte_counters(telemetry):

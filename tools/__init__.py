@@ -1,3 +1,3 @@
-# in-repo developer tooling (jax-free): the perf regression gate and
-# the graft-lint static analysis suite. Package-shaped so
-# ``python -m tools.lint`` works from the repo root.
+# in-repo developer tooling (jax-free): the regression gate (lint +
+# exact counts) and the graft-lint static analysis suite.
+# Package-shaped so ``python -m tools.lint`` works from the repo root.
