@@ -1820,10 +1820,11 @@ class PHBase(SPBase):
             # lint: ok[SYNC001] the wheel's admission grain: one chunk solve in flight (utils/runtime.WheelArbiter)
             jax.block_until_ready(out)
 
-    def _cold_state(self, factors, d):
+    def _cold_state(self, factors, d, build_log=None):
         """``qp_cold_state`` with the placement every later solve hands
-        back. On a mesh the solve programs return their per-row fields
-        row-sharded over "scen"; a cold state's zeros come out of their
+        back (``build_log``: where the build of a per-scenario float64
+        inverse is added up, the mode's ``KernelPlan.f64_build``). On a
+        mesh the solve programs return their per-row fields row-sharded over "scen"; a cold state's zeros come out of their
         own jit REPLICATED, and a solve program called once with each
         is lowered and compiled twice (at UC width on a four-chip v5e
         host 111 s and 83 s, with ~11 GiB of host memory each: the
@@ -1833,7 +1834,7 @@ class PHBase(SPBase):
         the solves return them. One-device engines and row counts the
         mesh does not divide (the hospital's few rows) pass through
         untouched."""
-        st = qp_cold_state(factors, d)
+        st = qp_cold_state(factors, d, build_log)
         ops = self._shard_ops
         if ops is None or st.x.shape[0] % ops.n_devices:
             return st
@@ -1874,7 +1875,9 @@ class PHBase(SPBase):
             return st
         if key not in self._qp_states:
             factors, d = self._get_factors(prox_on, fixed)
-            st = self._cold_state(factors, d)
+            st = self._cold_state(
+                factors, d, self._kernel_plan(
+                    key, factors, self._rows_per_call()).f64_build)
             other = next((v for k, v in self._qp_states.items()
                           if k != key and k not in self._chunk_dirty
                           and isinstance(v, (QPState, _ChunkStateView))),
@@ -2488,6 +2491,7 @@ class PHBase(SPBase):
         ent["mode"] = "sharded" if sharded else "host"
         ent["kernel"] = plan.descriptor()
         ent["linv_build"] = plan.linv_build
+        ent["f64_build"] = plan.f64_build
         ent["shape"] = self._solve_shape(factors, plan, rows_per_call)
         if dispatch is not None:
             dent = ent["dispatch"]
@@ -3122,6 +3126,12 @@ class PHBase(SPBase):
             # set-up is still told after ``reset_phase_timing``; empty
             # where none ran (ops/kernels.KernelPlan.linv_build)
             "linv_build": dict(ent.get("linv_build") or {}),
+            # the eager builds of this mode's per-scenario float64
+            # inverse (span ``qp.f64_refactor_build``: its cold state):
+            # {builds, seconds, rows, n}, kept by the plan like
+            # ``linv_build``; empty where the factor is none
+            # (ops/kernels.KernelPlan.f64_build)
+            "f64_refactor_build": dict(ent.get("f64_build") or {}),
             # whole PH runs of the ENGINE (every mode's; ``run_span``)
             # since the last reset: how many, their seconds, and the
             # seconds of their ``reset_run()``s (totals, not per call)
@@ -3617,6 +3627,7 @@ class PHBase(SPBase):
         plan = self._kernel_plan(skey, factors, rows_per_call)
         ent["kernel"] = plan.descriptor()
         ent["linv_build"] = plan.linv_build
+        ent["f64_build"] = plan.f64_build
         ent["shape"] = self._solve_shape(factors, plan, rows_per_call)
         sp_args = {"mode": _mode_str(skey)} if obs.enabled() else None
         clock = _PhaseClock(ent["acc"], sp_args)
@@ -4246,6 +4257,7 @@ class PHBase(SPBase):
         ent["calls"] += 1
         ent["kernel"] = plan.descriptor()
         ent["linv_build"] = plan.linv_build
+        ent["f64_build"] = plan.f64_build
         its, _ = _book_admm_iters(ent["admm"], states,
                                   plan.mode == "fused")
         _book_exits(ent["exits"], its, _exit_tests(**kw), 0.0)

@@ -43,16 +43,19 @@ from .reference import fused_mixed_solve, l_inv_profitable
 
 def resolve_mode(mode: str, factors) -> str:
     """``auto`` resolution. A solve is fused-eligible unless its rho
-    adaptation must run on the HOST (non-shared f64 factors on a
-    backend with untrusted f64 device linalg — qp_solver
-    ._needs_host_factor): the fused program cannot call back out for
-    the host-exact refactorization mid-loop. A per-scenario float64
-    stack narrow enough for the TPU lowering to invert by unrolled
-    recurrences (qp_solver.f64_refactor_form "unrolled": n <= 16)
-    needs no host, so it fuses: loop, in-program rho adaptation and
-    polish are ONE launch (PR 42). Program length is no
-    criterion: one program of 36,794 f64 matmul iterations ran 59.7 s
-    to completion on the attached v5e (CHANGES.md PR 24)."""
+    adaptation must run on the HOST (non-shared f64 factors whose
+    explicit inverse this backend cannot build on the device —
+    qp_solver._needs_host_factor): the fused program cannot call back
+    out for the host-exact refactorization mid-loop. On the TPU a
+    per-scenario float64 stack is inverted inside the program by the
+    TPU's own forms (qp_solver.f64_refactor_form: "unrolled" at
+    n <= 16, PR 42; "blocked" above, PR 45), so it fuses: loop,
+    in-program rho adaptation and polish are ONE launch; only a stack
+    too large to rebuild on the device (the hospital's UC-width
+    batches) or a backend nobody measured keeps the host. Program
+    length is no criterion: one program of 36,794 f64 matmul
+    iterations ran 59.7 s to completion on the attached v5e
+    (CHANGES.md PR 24)."""
     if mode == "fused":
         return "fused"
     if mode == "segmented" or _needs_host_factor(factors):
@@ -77,6 +80,12 @@ class KernelPlan:
     # until one ran. The plan outlives ``reset_phase_timing``, so a
     # build of set-up is still told after a window
     linv_build: dict = field(default_factory=dict)
+    # the eager builds of this plan's per-scenario float64 inverse
+    # (span ``qp.f64_refactor_build``: a mode's cold state): {builds,
+    # seconds, rows, n}, totals kept the same way; empty where the
+    # factor is none (the in-program rebuilds of a solve are
+    # ``admm_iters_per_call["refactors"]``)
+    f64_build: dict = field(default_factory=dict)
 
     def descriptor(self) -> dict:
         """The bench/telemetry kernel block. ``backend`` and
@@ -88,11 +97,12 @@ class KernelPlan:
         doc/kernels.md §3d), None where the factors have none;
         ``f64_polish`` how it runs the factor-and-substitute side of a
         float64 polish over these factors (``"unrolled"`` /
-        ``"library"``, §3e), None where no float64 polish can run (a
-        split matrix never polishes); ``f64_refactor`` where and how
-        the explicit float64 KKT inverse of these factors is rebuilt
-        when rho moves (``"unrolled"`` / ``"library"``: inside the
-        solve program; ``"host"``: numpy, between device calls, §3f),
+        ``"blocked"`` / ``"library"``, §3e, §3h), None where no float64
+        polish can run (a split matrix never polishes);
+        ``f64_refactor`` where and how the explicit float64 KKT inverse
+        of these factors is rebuilt when rho moves (``"unrolled"`` /
+        ``"blocked"`` / ``"library"``: inside the solve program;
+        ``"host"``: numpy, between device calls, §3f, §3h),
         None where the factor is no float64 inverse; ``f64_loop`` the
         shape of the loop that adapts rho inside the program
         (``"resident"``: the rebuild once a four-check period between

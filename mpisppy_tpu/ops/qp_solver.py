@@ -483,7 +483,7 @@ def _ruiz_equilibrate(P_diag, A, iters=15):
     return D, E, Eb
 
 
-def _factorize(factors: QPFactors, rho_scale):
+def _factorize(factors: QPFactors, rho_scale, keep=None):
     """EXPLICIT INVERSE of M = diag(P_s) + sigma I + A_sᵀ diag(ρ_A) A_s
     + diag(g²ρ_b). Shared mode (A_s (m,n), rho_scale scalar) returns one
     (n, n) inverse.
@@ -504,9 +504,11 @@ def _factorize(factors: QPFactors, rho_scale):
     blocks, doc/kernels.md §prepared factor); batched f32 factors stay
     raw. The ill-conditioned penalty systems in the POLISH always use
     honest Cholesky solves. The per-scenario (3-D) float64 inverse is
-    the library pair (``_kkt_inverse_library``) or, in the TPU lowering
-    at n <= ``_POLISH_UNROLL_MAX_N``, the unrolled recurrences
-    (``_kkt_inverse_unrolled``, ``f64_refactor_form``)."""
+    the library pair (``_kkt_inverse_library``) or, in the TPU
+    lowering, the unrolled recurrences at n <= ``_POLISH_UNROLL_MAX_N``
+    and the blocked ones above (``f64_refactor_form``). ``keep``: at a
+    REbuild of such a stack, ``(moved, old)``: the rows whose rho moved
+    and the inverse built before it did (``_in_row_chunks``)."""
     A_s, P_s = factors.A_s, factors.P_s
     g = factors.Eb * factors.D
     n = A_s.shape[-1]
@@ -540,7 +542,7 @@ def _factorize(factors: QPFactors, rho_scale):
     # lowered each way on this backend (f64_refactor_form)
     obs.counter_add(f"kernel.f64_refactor_{f64_refactor_form(A_s)}")
     with jax.named_scope("qp.refactor"):
-        return _kkt_inverse(A_s)(A_s, rA, factors.sigma, diag)
+        return _kkt_inverse(A_s)(A_s, rA, factors.sigma, diag, keep)
 
 
 def _factorize_split(factors: QPFactors, rho_scale):
@@ -751,51 +753,275 @@ def _penalty_factor_library(A_b, rpA, diag):
     return jnp.linalg.cholesky(Mp)
 
 
-def _penalty_factor_unrolled(A_b, rpA, diag):
+def _penalty_matrix(A_b, rpA, diag):
     eye = jnp.eye(A_b.shape[-1], dtype=diag.dtype)
-    Mp = _gram_reduce(A_b, rpA) + diag[:, :, None] * eye
-    return _unrolled_linv(_unrolled_cholesky(Mp))
+    return _gram_reduce(A_b, rpA) + diag[:, :, None] * eye
+
+
+def _penalty_factor_unrolled(A_b, rpA, diag):
+    return _unrolled_linv(_unrolled_cholesky(_penalty_matrix(A_b, rpA, diag)))
+
+
+def _tpu_stack_form(A_s) -> str | None:
+    """Static, the ONE shape test of the TPU's own per-scenario float64
+    linalg (the polish's factor-and-substitute side and the explicit KKT
+    inverse alike): ``"unrolled"`` for a 3-D float64 stack no wider than
+    ``_POLISH_UNROLL_MAX_N``, ``"blocked"`` for a wider one whose (S, n,
+    n) float64 array stays under ``_F64_BLOCKED_MAX_BYTES``, None for
+    everything else (a shared 2-D matrix, f32, a split matrix, a stack
+    too large to rebuild on the device)."""
+    if isinstance(A_s, SplitMatrix) or A_s.ndim != 3 \
+            or A_s.dtype != jnp.float64:
+        return None
+    S, _, n = A_s.shape
+    if n <= _POLISH_UNROLL_MAX_N:
+        return "unrolled"
+    return "blocked" if 8 * S * n * n <= _F64_BLOCKED_MAX_BYTES else None
 
 
 def _polish_unrollable(A_s) -> bool:
-    """Static: whether the TPU lowering of a polish over these factors
-    takes the unrolled forms (per-scenario float64 matrices no wider
-    than ``_POLISH_UNROLL_MAX_N``)."""
-    return (A_s.ndim == 3 and A_s.dtype == jnp.float64
-            and A_s.shape[-1] <= _POLISH_UNROLL_MAX_N)
+    """Static: whether the TPU lowering of the linalg over these factors
+    takes the fully unrolled forms (and the rho loop its "resident"
+    shape, ``f64_loop_form``)."""
+    return _tpu_stack_form(A_s) == "unrolled"
+
+
+# ---- the same linalg, BLOCKED, for stacks wider than that (ISSUE 45) ----
+# Above ``_POLISH_UNROLL_MAX_N`` the unrolled program no longer compiles
+# in any useful time, the library's batched float64 calls are the v5e's
+# row loops (and wrong at some shapes, _device_f64_linalg_trusted), and
+# numpy between device calls costs a round trip a rho move. The blocked
+# forms run the SAME recurrences on the diagonal blocks only (one
+# ``_unrolled_cholesky`` and one ``_unrolled_linv`` of a (S, b, b) block,
+# b = ``_F64_BLOCK``, in the body of a ``fori_loop`` over block rows, so
+# the program's size does not grow with n), and everything off the
+# diagonal as reduce-form products of whole (S, b, n) row panels:
+# element-wise float64 throughout, no ``cholesky`` / ``triangular_solve``
+# / ``dot`` for the compiler to expand. The factor is kept as U = Lᵀ,
+# so that every panel read or written is a block of ROWS (the column
+# panel of L is the row panel of U; a dynamic slice of the minor axis is
+# only ever taken of a (S, b, n) panel). doc/kernels.md §3h: the chip's
+# seconds and residuals by spelling, block width, n and S.
+
+# Width of a diagonal block: the widest stack the unrolled recurrences
+# compile at (the rule that stops them is the rule that sizes them)
+_F64_BLOCK = _POLISH_UNROLL_MAX_N
+
+# Largest (S, n, n) float64 array the blocked forms take. A solve keeps
+# the inverse it came with and the one it hands back, the other mode's
+# state its own, and a polish one U⁻¹ a candidate: five or six such
+# arrays on a chip of 16 GB (the builds' own temporaries are row chunks,
+# ``_F64_BUILD_BYTES``). Above it (the scenario hospital's UC-width
+# batches: (4, 13056, 13056) is 5.5 GB an array) the inverse stays
+# numpy's, between device calls (``f64_refactor_form`` "host").
+_F64_BLOCKED_MAX_BYTES = 2 << 30
+
+
+def _pad_spd(M, b):
+    """M (S, n, n) padded by an identity block to the next multiple of
+    ``b`` (its factor and inverse are then the originals padded the
+    same way)."""
+    n = M.shape[-1]
+    pad = (-n) % b
+    if not pad:
+        return M
+    M = jnp.pad(M, ((0, 0), (0, pad), (0, pad)))
+    tail = (jnp.arange(n + pad) >= n).astype(M.dtype)
+    return M + tail[None, :, None] * jnp.eye(n + pad, dtype=M.dtype)
+
+
+def _blocked_cholesky(M):
+    """``(U, Dinv)`` of M (S, n, n), n a multiple of b = ``_F64_BLOCK``:
+    the upper Cholesky factor U = Lᵀ (M = UᵀU), right-looking, one block
+    row a trip, and the inverses of L's diagonal blocks stacked by rows
+    ((S, n, b)). A trip reads block row k of the (symmetric) trailing
+    matrix, factors its (b, b) diagonal block by the unrolled
+    recurrences, turns the row panel into U's by one product with the
+    block's L⁻¹, subtracts the panel's gram from the whole matrix and
+    writes the panel back in the row block it read: M's own buffer
+    becomes U. Like the library, a non-positive-definite matrix gives
+    NaN from its first bad pivot on."""
+    S, n, _ = M.shape
+    b = _F64_BLOCK
+    col = jnp.arange(n)
+    within = jnp.arange(b)
+
+    def trip(k, carry):
+        M, Dinv = carry
+        kb = k * b
+        R = jax.lax.dynamic_slice_in_dim(M, kb, b, axis=1)
+        Linv = _unrolled_linv(_unrolled_cholesky(
+            jax.lax.dynamic_slice_in_dim(R, kb, b, axis=2)))
+        # U's rows kb .. kb+b: L_kk⁻¹ R, zero left of the diagonal
+        P = jnp.sum(Linv[:, :, :, None] * R[:, None, :, :], axis=2)
+        P = jnp.where(col[None, None, :] >= kb + within[None, :, None],
+                      P, 0.0)
+        M = M - jnp.sum(P[:, :, :, None] * P[:, :, None, :], axis=1)
+        return (jax.lax.dynamic_update_slice_in_dim(M, P, kb, axis=1),
+                jax.lax.dynamic_update_slice_in_dim(Dinv, Linv, kb, axis=1))
+
+    return jax.lax.fori_loop(
+        0, n // b, trip, (M, jnp.zeros((S, n, b), M.dtype)))
+
+
+def _blocked_uinv(U, Dinv):
+    """W = U⁻¹ (upper) of ``_blocked_cholesky``'s pair, by the backward
+    substitution on the identity, one block row a trip from the last:
+    row block k is L_kk⁻ᵀ (E_k − U[k, :] W), W's rows from k on still
+    zero, so the product runs over the whole width."""
+    S, n, _ = U.shape
+    b = _F64_BLOCK
+    nb = n // b
+    col = jnp.arange(n)
+    within = jnp.arange(b)
+
+    def trip(t, W):
+        kb = (nb - 1 - t) * b
+        Urow = jax.lax.dynamic_slice_in_dim(U, kb, b, axis=1)
+        Linv = jax.lax.dynamic_slice_in_dim(Dinv, kb, b, axis=1)
+        E = (col[None, :] == kb + within[:, None]).astype(U.dtype)
+        T = E[None] - jnp.sum(Urow[:, :, :, None] * W[:, None, :, :], axis=2)
+        Wrow = jnp.sum(Linv[:, :, :, None] * T[:, :, None, :], axis=1)
+        return jax.lax.dynamic_update_slice_in_dim(W, Wrow, kb, axis=1)
+
+    return jax.lax.fori_loop(0, nb, trip, jnp.zeros_like(U))
+
+
+def _blocked_factor_inverse(M):
+    """W = U⁻¹ of an SPD stack M (S, n, n) of any n: M⁻¹ = W Wᵀ."""
+    n = M.shape[-1]
+    W = _blocked_uinv(*_blocked_cholesky(_pad_spd(M, _F64_BLOCK)))
+    return W[:, :n, :n]
+
+
+def _uinv_pair_solve(W, b):
+    """x = W (Wᵀ b) = M⁻¹ b from W = U⁻¹ (S, n, n): ``_linv_pair_solve``
+    with the factor transposed (the first product over W's own row
+    axis, no transposed copy)."""
+    return _matvec_reduce(W, _rmatvec_reduce(W, b))
+
+
+def _spd_inverse_from_uinv(W):
+    """M⁻¹ = W Wᵀ as a multiply and a sum, a block of rows at a time
+    (the (S, n, n, n) product is never one fusion's to hold)."""
+    S, n, _ = W.shape
+    b = _F64_BLOCK
+    Wp = jnp.pad(W, ((0, 0), (0, (-n) % b), (0, 0)))
+
+    def rows(k, out):
+        Wk = jax.lax.dynamic_slice_in_dim(Wp, k * b, b, axis=1)
+        blk = jnp.sum(Wk[:, :, None, :] * W[:, None, :, :], axis=-1)
+        return jax.lax.dynamic_update_slice_in_dim(out, blk, k * b, axis=1)
+
+    out = jax.lax.fori_loop(0, Wp.shape[1] // b, rows,
+                            jnp.zeros((S, Wp.shape[1], n), W.dtype))
+    return out[:, :n]
+
+
+# Bytes of ONE (rows, n, n) float64 array a build works on at a time.
+# A build holds about four such arrays (the matrix that becomes U, U⁻¹,
+# the trailing update's temporary, the product): for the whole stack at
+# once 4.7 GB of temporaries at (1024, 384, 384), in an iter-0 program
+# of 14 GB (compiled for a v5e); in chunks of 64 rows the hot program's
+# temporaries are 2.7 GB. The trips cost nothing that shows beside a
+# chunk's ~0.2 s of soft-float.
+_F64_BUILD_BYTES = 128 << 20
+
+
+def _in_row_chunks(build, *ops, keep=None):
+    """``build(*ops)`` over the scenario axis, a chunk of rows at a
+    time, the chunk sized by ``_F64_BUILD_BYTES``: per-scenario
+    arithmetic, so the result is the whole stack's. A first build
+    (``keep`` None) ``lax.map``s over the chunks in order (the last one
+    padded with copies of row 0, trimmed after). A REbuild (``keep =
+    (moved, old)``: the (S,) rows whose operands changed since ``old``
+    (S, n, n) was built from them) gathers the moved rows, a chunk a
+    trip of a loop whose count is the number of chunks they fill,
+    builds those and writes them into ``old``: it costs the rows that
+    moved and not the stack (the last chunk is filled up with rows that
+    did not move, which rebuild to what they were)."""
+    S, n = ops[0].shape[0], ops[0].shape[-1]
+    trips = -(-S // max(1, _F64_BUILD_BYTES // (8 * n * n)))
+    if trips <= 1:
+        return build(*ops)
+    # a count that divides S, if one is near: no padded copy of a stack
+    trips = next((t for t in range(trips, 2 * trips) if S % t == 0), trips)
+    chunk = -(-S // trips)
+    if keep is not None:
+        moved, old = keep
+        order = jnp.argsort(jnp.logical_not(moved), stable=True)
+
+        def rebuild(t, out):
+            idx = jax.lax.dynamic_slice_in_dim(order, t * chunk, chunk)
+            return out.at[idx].set(build(*(a[idx] for a in ops)))
+
+        return jax.lax.fori_loop(
+            0, (jnp.sum(moved) + chunk - 1) // chunk, rebuild, old)
+    pad = trips * chunk - S
+    if pad:
+        ops = tuple(jnp.concatenate(
+            [a, jnp.broadcast_to(a[:1], (pad,) + a.shape[1:])]) for a in ops)
+    out = jax.lax.map(lambda t: build(*t), tuple(
+        a.reshape((trips, chunk) + a.shape[1:]) for a in ops))
+    return out.reshape((trips * chunk,) + out.shape[2:])[:S]
+
+
+def _penalty_uinv(A_b, rpA, diag):
+    return _blocked_factor_inverse(_penalty_matrix(A_b, rpA, diag))
+
+
+def _penalty_factor_blocked(A_b, rpA, diag):
+    return _in_row_chunks(_penalty_uinv, A_b, rpA, diag)
+
+
+def _kkt_inverse_blocked(A_s, rA, sigma, diag, keep=None):
+    return _in_row_chunks(
+        lambda A, r, d: _spd_inverse_from_uinv(_penalty_uinv(A, r, d)),
+        A_s, rA, diag + sigma, keep=keep)
 
 
 def f64_polish_form(A_s) -> str | None:
-    """``"unrolled"`` / ``"library"``: how THIS process's backend runs
-    the factor-and-substitute side of a float64 polish over the scaled
-    matrix ``A_s``; None where there is none (a SplitMatrix never
-    polishes, an f32 polish is not float64 linalg). "unrolled" only on
-    the TPU, for per-scenario (3-D) matrices with n <=
-    ``_POLISH_UNROLL_MAX_N``; a shared 2-D matrix, a wider n, and every
-    other backend keep ``jnp.linalg.cholesky`` and the
-    ``triangular_solve`` pair."""
+    """``"unrolled"`` / ``"blocked"`` / ``"library"``: how THIS
+    process's backend runs the factor-and-substitute side of a float64
+    polish over the scaled matrix ``A_s``; None where there is none (a
+    SplitMatrix never polishes, an f32 polish is not float64 linalg).
+    On the TPU a per-scenario (3-D) stack takes its own spelling
+    (``_tpu_stack_form``: unrolled to n = ``_POLISH_UNROLL_MAX_N``,
+    blocked above); a shared 2-D matrix, a stack too large for the
+    blocked build, and every other backend keep
+    ``jnp.linalg.cholesky`` and the ``triangular_solve`` pair."""
     if isinstance(A_s, SplitMatrix) or A_s.dtype != jnp.float64:
         return None
-    if _polish_unrollable(A_s) and jax.default_backend() == "tpu":
-        return "unrolled"
+    if jax.default_backend() == "tpu":
+        return _tpu_stack_form(A_s) or "library"
     return "library"
+
+
+# the TPU's (factorize, solve) pair of each stack form: F is L⁻¹ under
+# the unrolled pair and U⁻¹ under the blocked one
+_TPU_PENALTY_PAIR = {
+    "unrolled": (_penalty_factor_unrolled, _linv_pair_solve),
+    "blocked": (_penalty_factor_blocked, _uinv_pair_solve)}
 
 
 def _polish_linalg(A_s):
     """``(factorize, solve)`` of the polish's penalty systems over
     ``A_s``: ``F = factorize(A_b, rpA, diag)`` and ``x = solve(F, b)``.
-    Where the TPU would unroll, the pair is chosen at lowering time
-    per platform, as the batched products are (CPU and GPU keep the
-    library calls, and a program compiled HERE for a described TPU
-    takes the TPU form); F is then L on one platform and L⁻¹ on the
-    other, which only the pair's own solve ever reads."""
-    if not _polish_unrollable(A_s):
+    Where the TPU has a spelling of its own, the pair is chosen at
+    lowering time per platform, as the batched products are (CPU and
+    GPU keep the library calls, and a program compiled HERE for a
+    described TPU takes the TPU form); F is then L on one platform and
+    an explicit triangular inverse on the other, which only the pair's
+    own solve ever reads."""
+    form = _tpu_stack_form(A_s)
+    if form is None:
         return _penalty_factor_library, _tri_solve
+    factorize, solve = _TPU_PENALTY_PAIR[form]
     return (lambda A_b, rpA, diag: jax.lax.platform_dependent(
-                A_b, rpA, diag, tpu=_penalty_factor_unrolled,
+                A_b, rpA, diag, tpu=factorize,
                 default=_penalty_factor_library),
             lambda F, b: jax.lax.platform_dependent(
-                F, b, tpu=_linv_pair_solve, default=_tri_solve))
+                F, b, tpu=solve, default=_tri_solve))
 
 
 # ---- the per-scenario float64 KKT inverse (ISSUE 42) ----
@@ -822,7 +1048,7 @@ def _kkt_factor_library(A_s, rA, sigma, diag):
     return jnp.linalg.cholesky(M)
 
 
-def _kkt_inverse_library(A_s, rA, sigma, diag):
+def _kkt_inverse_library(A_s, rA, sigma, diag, keep=None):
     L = _kkt_factor_library(A_s, rA, sigma, diag)
     eye = jnp.broadcast_to(jnp.eye(L.shape[-1], dtype=L.dtype), L.shape)
     w = jax.lax.linalg.triangular_solve(L, eye, left_side=True, lower=True)
@@ -830,30 +1056,32 @@ def _kkt_inverse_library(A_s, rA, sigma, diag):
                                            lower=True, transpose_a=True)
 
 
-def _kkt_inverse_unrolled(A_s, rA, sigma, diag):
+def _kkt_inverse_unrolled(A_s, rA, sigma, diag, keep=None):
     Linv = _penalty_factor_unrolled(A_s, rA, diag + sigma)
     # L⁻ᵀ L⁻¹ as a multiply and a sum over L⁻¹'s own row axis
     return jnp.sum(Linv[:, :, :, None] * Linv[:, :, None, :], axis=1)
 
 
 def f64_refactor_form(A_s) -> str | None:
-    """``"unrolled"`` / ``"host"`` / ``"library"``: where and how THIS
-    process's backend builds the explicit KKT inverse of float64
-    factors over the scaled matrix ``A_s``; None where the factor is no
-    float64 inverse (a SplitMatrix, an f32 matrix). A shared 2-D matrix
-    always takes the device library (one unbatched factor, trusted on
-    every backend measured). A per-scenario (3-D) one takes it where
-    the batched f64 linalg is trusted (_device_f64_linalg_trusted);
-    elsewhere "unrolled" on the TPU at n <= ``_POLISH_UNROLL_MAX_N``
-    (the polish's shape test: compile seconds set the width, there as
-    here) and "host" (numpy between device calls, _factorize_host) for
-    everything wider or on a backend nobody has measured."""
+    """``"unrolled"`` / ``"blocked"`` / ``"host"`` / ``"library"``:
+    where and how THIS process's backend builds the explicit KKT
+    inverse of float64 factors over the scaled matrix ``A_s``; None
+    where the factor is no float64 inverse (a SplitMatrix, an f32
+    matrix). A shared 2-D matrix always takes the device library (one
+    unbatched factor, trusted on every backend measured). A
+    per-scenario (3-D) one takes it where the batched f64 linalg is
+    trusted (_device_f64_linalg_trusted); elsewhere the TPU's own
+    spelling of its shape (``_tpu_stack_form``, the polish's test:
+    "unrolled" at n <= ``_POLISH_UNROLL_MAX_N``, "blocked" above while
+    the stack fits a rebuild) and "host" (numpy between device calls,
+    _factorize_host) for a stack too large for that or on a backend
+    nobody has measured."""
     if isinstance(A_s, SplitMatrix) or A_s.dtype != jnp.float64:
         return None
     if A_s.ndim == 2 or _device_f64_linalg_trusted():
         return "library"
-    if _polish_unrollable(A_s) and jax.default_backend() == "tpu":
-        return "unrolled"
+    if jax.default_backend() == "tpu":
+        return _tpu_stack_form(A_s) or "host"
     return "host"
 
 
@@ -868,25 +1096,34 @@ def f64_loop_form(A_s) -> str | None:
     four-check period, BETWEEN two inner loops that hold the inverse as
     a loop-invariant operand, so that no loop carrying it holds a
     ``conditional``. "conditional": the rebuild under a ``lax.cond`` in
-    the loop's one body (a shared 2-D inverse, a wider stack). Read
-    from the shape alone: every backend traces the same structure, and
-    the platform only chooses HOW the inverse is built
-    (``f64_refactor_form``)."""
+    the loop's one body (a shared 2-D inverse; a wider stack, whose
+    blocked rebuild costs a thousand ADMM iterations and must not run
+    where rho did not move). Read from the shape alone: every backend
+    traces the same structure, and the platform only chooses HOW the
+    inverse is built (``f64_refactor_form``)."""
     if f64_refactor_form(A_s) in (None, "host"):
         return None
     return "resident" if _polish_unrollable(A_s) else "conditional"
 
 
+_TPU_KKT_INVERSE = {"unrolled": _kkt_inverse_unrolled,
+                    "blocked": _kkt_inverse_blocked}
+
+
 def _kkt_inverse(A_s):
-    """``inverse(A_s, rA, sigma, diag)`` of _factorize's per-scenario
-    float64 branch; like _polish_linalg, the TPU form is chosen at
-    lowering time per platform (CPU and GPU keep the library calls bit
-    for bit, and a program compiled HERE for a described TPU takes the
-    TPU form)."""
-    if not _polish_unrollable(A_s):
+    """``inverse(A_s, rA, sigma, diag, keep)`` of _factorize's
+    per-scenario float64 branch; like _polish_linalg, the TPU form is
+    chosen at lowering time per platform (CPU and GPU keep the library
+    calls bit for bit, and a program compiled HERE for a described TPU
+    takes the TPU form). ``keep`` (None, or ``(moved, old)`` at a
+    REbuild: ``_in_row_chunks``) is the blocked form's to use; the
+    others build every row, which gives the rows that did not move what
+    they were."""
+    form = _tpu_stack_form(A_s)
+    if form is None:
         return _kkt_inverse_library
     return lambda *ops: jax.lax.platform_dependent(
-        *ops, tpu=_kkt_inverse_unrolled, default=_kkt_inverse_library)
+        *ops, tpu=_TPU_KKT_INVERSE[form], default=_kkt_inverse_library)
 
 
 # Block size of the prepared substitution: the one XLA's triangular-
@@ -1134,7 +1371,7 @@ def make_l_inv(L) -> LInv:
 make_l_inv.lower = _l_inv_jit.lower
 
 
-def _refactor_like(factors, rho_scale, like):
+def _refactor_like(factors, rho_scale, like, moved=None):
     """In-loop refactorization that preserves the CONTAINER of the
     carried factor: a state running the L⁻¹-matmul x-update must get a
     fresh L⁻¹ when rho adaptation refactorizes mid-solve, or the
@@ -1142,8 +1379,11 @@ def _refactor_like(factors, rho_scale, like):
     carry needs no help: _factorize returns a freshly prepared one
     (that IS the hoist — the preparation runs here, once per
     refactorization). The isinstance test is trace-time (pytree
-    structure is static)."""
-    L_new = _factorize(factors, rho_scale)
+    structure is static). ``moved`` (per-scenario rho only): the rows
+    whose ``rho_scale`` differs from the one ``like`` was built at."""
+    keep = None if moved is None or isinstance(like, (LInv, PreparedFactor)) \
+        else (moved, like)
+    L_new = _factorize(factors, rho_scale, keep)
     if isinstance(like, LInv):
         return _make_l_inv(L_new)
     return L_new
@@ -1400,7 +1640,15 @@ def _cold_state_impl(factors: QPFactors, data: QPData) -> QPState:
 _cold_state_jit = compile_serialized(jax.jit(_cold_state_impl))
 
 
-def qp_cold_state(factors: QPFactors, data: QPData) -> QPState:
+def qp_cold_state(factors: QPFactors, data: QPData,
+                  build_log=None) -> QPState:
+    """A mode's cold state: zeros and the factor at ``rho_scale`` 1.
+    Where that factor is a per-scenario float64 inverse built on the
+    device, the build is the span ``qp.f64_refactor_build``; it waits
+    for the inverse (a cold state's first solve follows, which nothing
+    overlaps), so its seconds are the build's, and ``build_log`` (the
+    plan's ``KernelPlan.f64_build``) adds them up as {builds, seconds,
+    rows, n}."""
     if _needs_host_factor(factors):
         # host-exact inverse (see _device_f64_linalg_trusted) — not
         # worth a device program that would compute (and discard) the
@@ -1409,7 +1657,18 @@ def qp_cold_state(factors: QPFactors, data: QPData) -> QPState:
         rho_scale = jnp.ones((S,), factors.A_s.dtype)
         return _zero_state(factors, data,
                            factorize_dispatch(factors, rho_scale))
-    return _cold_state_jit(factors, data)
+    A_s = factors.A_s
+    if f64_refactor_form(A_s) is None or A_s.ndim != 3:
+        return _cold_state_jit(factors, data)
+    shape = {"rows": int(A_s.shape[0]), "n": int(A_s.shape[-1])}
+    with obs.span("qp.f64_refactor_build", cat="qp", args=shape) as sp:
+        st = _cold_state_jit(factors, data)
+        # lint: ok[SYNC001] a cold state's one eager build, ahead of its first solve: the span's seconds are the build's only if it waits
+        jax.block_until_ready(st.L)
+    if build_log is not None:
+        build_log.update(shape, builds=build_log.get("builds", 0) + 1,
+                         seconds=build_log.get("seconds", 0.0) + sp.seconds)
+    return st
 
 
 def _scaled_problem(factors: QPFactors, data: QPData, q):
@@ -1565,7 +1824,7 @@ def _solve_impl(factors: QPFactors, data: QPData, q, state: QPState,
     def check(vals, L, refactor):
         """One residual check of the loop: ``check_every`` iterations on
         the factor ``L``, the residuals, the exit tests and the rho
-        adaptation's decision. ``refactor(need, rho_scale, L)`` gives
+        adaptation's decision. ``refactor(need, rho_scale, L, moved)`` gives
         the factor the loop's carry holds next. Returns the carry's
         values (``L`` apart), that factor, and ``need`` / ``adapt_now``
         (whether rho moved; whether this was a period's fourth check:
@@ -1588,7 +1847,7 @@ def _solve_impl(factors: QPFactors, data: QPData, q, state: QPState,
             best_pri = jnp.minimum(best_pri, pri)
             best_dua = jnp.minimum(best_dua, dua)
         rho_changed = jnp.zeros_like(conv_ok)   # per-scenario where possible
-        need = adapt_now = None
+        need = adapt_now = moved = None
         # ``adaptive_rho``: a python bool (a jit static — False leaves
         # the adaptation out of the program) or a TRACED flag (the fused
         # df32 program: one executable serves the hot loop and the
@@ -1627,8 +1886,8 @@ def _solve_impl(factors: QPFactors, data: QPData, q, state: QPState,
                     # restart their stall window — an unrelated scenario's
                     # refactorize must not postpone another's plateau exit
                     # (ADVICE r2)
-                    rho_changed = mask
-                L = refactor(need, rho_scale, L)
+                    rho_changed = moved = mask
+                L = refactor(need, rho_scale, L, moved)
                 nref = nref + need.astype(nref.dtype)
         if stall_rel:
             # a rho refactorize resets the window (the residual jump is
@@ -1655,8 +1914,8 @@ def _solve_impl(factors: QPFactors, data: QPData, q, state: QPState,
     def body(carry):
         vals, L, _, _ = check(
             *apart(carry),
-            lambda need, rho_scale, L: jax.lax.cond(
-                need, lambda: _refactor_like(factors, rho_scale, L),
+            lambda need, rho_scale, L, moved: jax.lax.cond(
+                need, lambda: _refactor_like(factors, rho_scale, L, moved),
                 lambda: L))
         return whole(vals, L)
 
@@ -1682,7 +1941,7 @@ def _solve_impl(factors: QPFactors, data: QPData, q, state: QPState,
 
         def one_check(c):
             vals, _, need, adapt_now = check(
-                c[0], L, lambda need, rho_scale, L: L)
+                c[0], L, lambda need, rho_scale, L, moved: L)
             return vals, need, adapt_now
 
         vals, need, _ = jax.lax.while_loop(

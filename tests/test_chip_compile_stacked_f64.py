@@ -143,14 +143,15 @@ def test_stacked_f64_polish_has_only_its_three_scans_on_v5e(
     assert not _hlo_lines(hlo, "dynamic-update-slice")
 
 
-def test_the_polish_keeps_the_library_calls_above_the_width_on_v5e(
+def test_the_polish_takes_the_blocked_forms_above_the_width_on_v5e(
         stacked_farmer_segment, one_chip, no_persistent_cache):
-    """Above ``_POLISH_UNROLL_MAX_N`` (here n = 24: the unrolled
-    program's compile seconds turn between 16 and 24) the library path
-    is still what is lowered: the compiler's loops of the batched
-    float64 ``cholesky`` and ``triangular_solve`` are there. So a
-    compiler that learns float64 linalg, or a width that moves, shows
-    up here."""
+    """Above ``_POLISH_UNROLL_MAX_N`` (here n = 24) the polish lowers
+    the BLOCKED forms since ISSUE 45 (doc/kernels.md §3h): under
+    ``qp.polish`` the program holds its own three scans and the block
+    rows' ``fori_loop``s of the three factorizations, and nothing of
+    the library: no loop of a ``cholesky``, a ``triangular_solve`` or
+    the Gram ``dot_general``. Until then the library path was what was
+    lowered there (the compiler's row loops, PR 40)."""
     from mpisppy_tpu.ops.qp_solver import _POLISH_UNROLL_MAX_N
     fn, args, kw = stacked_farmer_segment
     kw = dict(kw, max_iter=0, polish=True)
@@ -158,9 +159,11 @@ def test_the_polish_keeps_the_library_calls_above_the_width_on_v5e(
     hlo = fn.lower(*_widened(args, 3, 2, one_chip), **kw).compile() \
         .as_text()
     loops, expansions = _polish_loops(hlo)
-    assert len(loops) > 3
-    assert any("cholesky" in ln for ln in expansions)
-    assert any("triangular_solve" in ln for ln in expansions)
+    # three scans, and a Cholesky and a U^-1 loop a factorization
+    # (the compiler merges the first and the third: the same active set)
+    assert 3 + 2 <= len(loops) <= 3 + 3 * 2 and not expansions
+    assert not _hlo_lines(hlo, "cholesky")
+    assert not _hlo_lines(hlo, "triangular-solve")
 
 
 # ---------------- the in-program refactorization (ISSUE 42) ------------
@@ -249,17 +252,24 @@ def test_a_conditional_in_the_loop_keeps_its_matrices_in_hbm_on_v5e(
     assert all(resident == 0 for _reads, resident in carrying), carrying
 
 
-def test_the_refactorization_keeps_the_library_pair_above_the_width_on_v5e(
+def test_the_refactorization_takes_the_blocked_forms_above_the_width_on_v5e(
         stacked_farmer_segment, one_chip, no_persistent_cache):
-    """Above ``_POLISH_UNROLL_MAX_N`` (n = 24) ``_factorize`` lowers the
-    library pair, and the compiler's loops of it are there: what the
-    rule keeps away from the TPU by sending such factors to the host
-    (``_needs_host_factor``; this program is never launched there)."""
+    """Above ``_POLISH_UNROLL_MAX_N`` (n = 24) ``_factorize`` lowers
+    the BLOCKED inverse since ISSUE 45 (doc/kernels.md §3h), under the
+    loop's ``conditional``: the block rows' three ``fori_loop``s under
+    ``qp.refactor`` (Cholesky, U⁻¹, the product by row blocks), and no
+    loop of a ``cholesky``, a ``triangular_solve`` or a batched
+    ``dot_general``. Until then the library pair was what was lowered
+    there, and the rule sent such factors to the host."""
     from mpisppy_tpu.ops.qp_solver import _POLISH_UNROLL_MAX_N
     fn, args, kw = stacked_farmer_segment
     assert 12 * 2 > _POLISH_UNROLL_MAX_N
     hlo = fn.lower(*_widened(args, 3, 2, one_chip),
                    **dict(kw, adaptive_rho=True)).compile().as_text()
     loops = _refactor_loops(hlo)
-    assert any("cholesky" in ln for ln in loops)
-    assert any("triangular_solve" in ln for ln in loops)
+    assert len(loops) == 3
+    assert not any(k in ln for ln in loops
+                   for k in ("cholesky", "triangular_solve", "dot_general"))
+    assert not _hlo_lines(hlo, "cholesky")
+    assert not _hlo_lines(hlo, "triangular-solve")
+    assert len(_hlo_lines(hlo, "conditional")) == 1

@@ -104,10 +104,11 @@ def test_a_matrix_that_is_not_positive_definite_gives_nan():
 
 
 def test_the_rule_by_platform_dtype_ndim_and_n(monkeypatch):
-    """Unrolled only on the TPU, for a per-scenario float64 matrix with
-    n up to the width the compile seconds set (doc/kernels.md §3e);
-    the library calls for a shared 2-D matrix, a wider n and every
-    other backend; None where no float64 polish can run. Shapes and
+    """The TPU's own forms only on the TPU, for a per-scenario float64
+    matrix: unrolled with n up to the width the compile seconds set
+    (doc/kernels.md §3e), blocked above it (§3h); the library calls
+    for a shared 2-D matrix, a stack too large to factor on the device
+    and every other backend; None where no float64 polish can run. Shapes and
     dtype only: the rule reads nothing else of its operand."""
     f8 = lambda *sh: jax.ShapeDtypeStruct(sh, jnp.float64)
     assert _POLISH_UNROLL_MAX_N == 16
@@ -117,9 +118,11 @@ def test_the_rule_by_platform_dtype_ndim_and_n(monkeypatch):
             (7, 12): "library"}
     on_tpu = {(3, 7, 12): "unrolled", (24, 7, 12): "unrolled",
               (192, 7, 12): "unrolled", (24, 9, 16): "unrolled",
-              (24, 10, 17): "library", (24, 14, 24): "library",
-              (24, 28, 48): "library",
-              (24, 700, 1200): "library", (7, 12): "library"}
+              (24, 10, 17): "blocked", (24, 14, 24): "blocked",
+              (24, 28, 48): "blocked", (1024, 193, 384): "blocked",
+              (24, 700, 1200): "blocked",
+              # too large to factor on the device: the library stays
+              (4, 26016, 13056): "library", (7, 12): "library"}
     for shape, want in here.items():
         assert f64_polish_form(f8(*shape)) == want, shape
     f32 = jax.ShapeDtypeStruct((24, 7, 12), jnp.float32)
@@ -189,7 +192,7 @@ def test_the_switch_keeps_the_library_calls_off_the_tpu(polish_calls,
     args, kw = calls[0]
     assert qps._polish_unrollable(args[0].A_s)
     through_switch = _polish(args, kw)
-    monkeypatch.setattr(qps, "_polish_unrollable", lambda A_s: False)
+    monkeypatch.setattr(qps, "_tpu_stack_form", lambda A_s: None)
     library_only = _polish(args, kw)
     for k, v in through_switch.items():
         np.testing.assert_array_equal(v, library_only[k], err_msg=k)

@@ -97,7 +97,7 @@ def test_the_switch_keeps_the_library_pair_off_the_tpu(stack_factors,
     assert qps._polish_unrollable(fac.A_s)
     through_switch = np.asarray(jax.jit(
         lambda f, r: _factorize(f, r))(fac, rs))
-    monkeypatch.setattr(qps, "_polish_unrollable", lambda A_s: False)
+    monkeypatch.setattr(qps, "_tpu_stack_form", lambda A_s: None)
     library_only = np.asarray(jax.jit(
         lambda f, r: _factorize(f, r))(fac, rs))
     np.testing.assert_array_equal(through_switch, library_only)
@@ -115,8 +115,11 @@ _RES, _CND = "resident", "conditional"
 _RULE = [
     ("tpu", 3, _F8, 12, "unrolled", False, "fused", _RES),
     ("tpu", 3, _F8, 16, "unrolled", False, "fused", _RES),
-    ("tpu", 3, _F8, 17, "host", True, "segmented", None),
-    ("tpu", 3, _F8, 768, "host", True, "segmented", None),
+    ("tpu", 3, _F8, 17, "blocked", False, "fused", _CND),
+    ("tpu", 3, _F8, 384, "blocked", False, "fused", _CND),
+    ("tpu", 3, _F8, 768, "blocked", False, "fused", _CND),
+    # the hospital's UC-width batch: too large to rebuild on the device
+    ("tpu", 3, _F8, 13056, "host", True, "segmented", None),
     ("tpu", 2, _F8, 12, "library", False, "fused", _CND),
     ("tpu", 2, _F8, 768, "library", False, "fused", _CND),
     ("tpu", 3, _F4, 12, None, False, "fused", None),
@@ -139,10 +142,12 @@ _RULE = [
 def test_the_rule_by_backend_ndim_dtype_and_n(monkeypatch, backend, ndim,
                                               dtype, n, form, host, mode,
                                               loop):
-    """ONE rule, read from shapes, dtype and platform alone: the host
-    inverts only per-scenario float64 stacks that are wider than the
-    polish's unroll width (or on a backend nobody measured); those and
-    only those solve in host-driven segments under ``auto``; and the
+    """ONE rule, read from shapes, dtype and platform alone: on the TPU
+    a per-scenario float64 stack is inverted by the unrolled forms to
+    n = 16 and by the blocked ones above (ISSUE 45); the host inverts
+    only a stack too large to rebuild on the device (or on a backend
+    nobody measured); those and only those solve in host-driven
+    segments under ``auto``; and the
     loop that adapts rho inside a program takes its shape from ndim,
     dtype and n (``f64_loop_form``, what the plan's descriptor tells)."""
     assert _POLISH_UNROLL_MAX_N == 16

@@ -38,6 +38,11 @@ def _configs():
 
 
 CONFIGS = _configs()
+# a per-scenario float64 stack: its ``kernel`` block names the forms,
+# which the platform chooses (held below, with the TPU's answers)
+STACKS = {n: c for n, c in CONFIGS.items()
+          if c["recipe"]["subproblem_precision"] == "native"}
+CONFIGS = {n: c for n, c in CONFIGS.items() if n not in STACKS}
 # rows per device call and the plan, as PERF.md section 4 states them
 STATED = {"uc90x48_df32": (64, False), "uc90x48_df32_mesh4": (64, False),
           "uc90x48_df32_aph": (64, False),
@@ -72,3 +77,37 @@ def test_plan_at_the_stated_shape_is_the_stated_one(name):
     got = plan.descriptor()
     assert {k: got[k] for k in want} == want
     assert plan.A_lo is mat         # the bulk's operand: the f32 hi half
+
+
+def test_every_native_configuration_is_held():
+    assert set(STACKS) == {"farmer_cm32_f64"}
+
+
+@pytest.mark.parametrize("name", sorted(STACKS))
+def test_a_stacks_kernel_block_is_what_the_rules_choose_on_the_tpu(
+        name, monkeypatch):
+    """A configuration whose scenarios carry their own matrices states
+    the mode AND the float64 forms (ISSUE 45): what ``prepare`` answers
+    on the TPU for a (scenarios, m, n) float64 stack with both kernel
+    options at ``auto``, and what the cell's driver holds the program
+    to before iter-0. On this backend the same shape keeps the library
+    calls, in the same one fused program."""
+    from mpisppy_tpu.ops import qp_solver
+    cfg = STACKS[name]
+    recipe, shape = cfg["recipe"], cfg["shape"]
+    assert cfg["subproblem_chunk"] == 0 and cfg["outer_dtype"] == "float64"
+    assert not any(k.startswith("subproblem_kernel") for k in recipe)
+    fac = qp_solver.QPFactors(*[None] * len(qp_solver.QPFactors._fields)) \
+        ._replace(A_s=jax.ShapeDtypeStruct(
+            (cfg["scenarios"], shape["m"], shape["n"]), jnp.float64))
+    want = cfg["kernel"]
+    assert set(want) == {"mode", "f64_products", "f64_polish",
+                         "f64_refactor", "f64_loop"}
+    assert want["mode"] == "fused" and not (
+        {"host", "library"} & set(want.values()))
+    here = kernels.prepare(fac).descriptor()
+    assert (here["mode"], here["f64_refactor"], here["f64_polish"]) \
+        == ("fused", "library", "library")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    got = kernels.prepare(fac).descriptor()
+    assert {k: got[k] for k in want} == want
