@@ -37,7 +37,8 @@ from dataclasses import dataclass, field
 from ...utils.config import FUSED_IR_SWEEPS
 from ..qp_solver import (PackedMatrix, SplitMatrix, _needs_host_factor,
                          _trace_seg, f64_polish_form, f64_product_form,
-                         f64_loop_form, f64_refactor_form, qp_solve)
+                         f64_loop_form, f64_refactor_form,
+                         f64_stack_block_rows, qp_solve)
 from .reference import fused_mixed_solve, l_inv_profitable
 
 
@@ -75,6 +76,7 @@ class KernelPlan:
     f64_polish: str | None = None     # qp_solver.f64_polish_form(A_s)
     f64_refactor: str | None = None   # qp_solver.f64_refactor_form(A_s)
     f64_loop: str | None = None       # qp_solver.f64_loop_form(A_s)
+    f64_stack_block: int | None = None  # qp_solver.f64_stack_block_rows(A_s)
     # the eager explicit-inverse builds of this plan's solves (span
     # ``qp.l_inv_build``): {builds, seconds, n, panels}, totals; empty
     # until one ran. The plan outlives ``reset_phase_timing``, so a
@@ -108,13 +110,17 @@ class KernelPlan:
         (``"resident"``: the rebuild once a four-check period between
         two inner loops, no ``conditional``; ``"conditional"``: under a
         ``lax.cond`` in the loop's body, §3g), None where no program
-        rebuilds a float64 inverse."""
+        rebuilds a float64 inverse; ``f64_stack_block`` the rows of a
+        block where the ADMM scan walks a wide per-scenario float64
+        stack in blocks of scenarios (§3i), None where it is one scan
+        over all rows."""
         return {"mode": self.mode, "backend": "reference",
                 "l_inv": bool(self.l_inv), "block_dtype": "f32",
                 "f64_products": self.f64_products,
                 "f64_polish": self.f64_polish,
                 "f64_refactor": self.f64_refactor,
-                "f64_loop": self.f64_loop}
+                "f64_loop": self.f64_loop,
+                "f64_stack_block": self.f64_stack_block}
 
 
 def prepare(factors, *, mode="auto", l_inv="auto", precision="native",
@@ -131,7 +137,8 @@ def prepare(factors, *, mode="auto", l_inv="auto", precision="native",
     forms = dict(f64_products=f64_product_form(factors.A_s),
                  f64_polish=f64_polish_form(factors.A_s),
                  f64_refactor=f64_refactor_form(factors.A_s),
-                 f64_loop=f64_loop_form(factors.A_s))
+                 f64_loop=f64_loop_form(factors.A_s),
+                 f64_stack_block=f64_stack_block_rows(factors.A_s))
     if int(ir_sweeps) not in FUSED_IR_SWEEPS:
         if mode == "fused":
             raise ValueError(

@@ -1106,6 +1106,41 @@ def f64_loop_form(A_s) -> str | None:
     return "resident" if _polish_unrollable(A_s) else "conditional"
 
 
+# Bytes of the two float64 operands ((B, m, n) matrix, (B, n, n)
+# inverse) that ONE block of scenarios may hold where the ADMM scan of
+# a wide stack runs block by block (``f64_stack_block_rows``): half of
+# the v5e's 128 MiB of VMEM. Step 0 of ISSUE 46, on the chip at (1024,
+# 193, 384) (doc/kernels.md §3i): up to it (B <= 32, 57 MB) the
+# compiler keeps every operand of a block on chip for the block's
+# ``check_every`` iterations and a 1024-row ADMM iteration costs
+# 4.28-4.44 ms where the whole-stack scan costs 6.24; over it part of a
+# block stays in HBM (B = 64: 4.57) and by B = 128 most of the gain is
+# gone (5.38, B = 256: 5.65).
+_F64_LOOP_BLOCK_BYTES = 64 << 20
+
+
+def f64_stack_block_rows(A_s) -> int | None:
+    """Rows B of a block where ``_solve_impl``'s ADMM scan walks a
+    per-scenario float64 stack in blocks of scenarios (doc/kernels.md
+    §3i): the largest divisor of S whose rows' two operands fit
+    ``_F64_LOOP_BLOCK_BYTES``. None where the scan stays ONE over all
+    rows: everything that is not a wide stack (``_tpu_stack_form``
+    "blocked": 3-D float64, n > ``_POLISH_UNROLL_MAX_N``, rebuilt on
+    the device), a stack that fits the budget whole, a row that alone
+    is over it, and an S whose largest divisor under the budget fills
+    less than half of it (a prime S: blocks of one row, whose launches
+    cost what the shape buys). Read from the shape alone, like
+    ``f64_loop_form``: every backend traces the same structure."""
+    if _tpu_stack_form(A_s) != "blocked":
+        return None
+    S, m, n = A_s.shape
+    fit = _F64_LOOP_BLOCK_BYTES // (8 * (m * n + n * n))
+    if not 0 < fit < S:
+        return None
+    B = next(B for B in range(fit, 0, -1) if S % B == 0)
+    return B if 2 * B >= fit else None
+
+
 _TPU_KKT_INVERSE = {"unrolled": _kkt_inverse_unrolled,
                     "blocked": _kkt_inverse_blocked}
 
@@ -1792,27 +1827,50 @@ def _solve_impl(factors: QPFactors, data: QPData, q, state: QPState,
         F_plain = _prepare_factor(L.tri) \
             if isinstance(L, LInv) and not split_mode else L
 
-        def one(carry, _):
-            x, yA, yB, zA, zB = carry
-            rhs = sigma * x - q_s + _ATy(A_s, rA * zA - yA) \
-                + g * (rB * zB - yB)
-            with jax.named_scope("qp.kkt_solve"):
-                x_t = _m_solve_ir(L, rhs, rA, rB) if split_mode \
-                    else _chol_solve(F_plain, rhs)
-            x_new = alpha * x_t + (1 - alpha) * x
-            zA_t = _Ax(A_s, x_t)
-            zA_mix = alpha * zA_t + (1 - alpha) * zA
-            zA_new = jnp.clip(zA_mix + yA / rA, l_s, u_s)
-            yA_new = yA + rA * (zA_mix - zA_new)
-            zB_t = g * x_t
-            zB_mix = alpha * zB_t + (1 - alpha) * zB
-            zB_new = jnp.clip(zB_mix + yB / rB, lb_s, ub_s)
-            yB_new = yB + rB * (zB_mix - zB_new)
-            return (x_new, yA_new, yB_new, zA_new, zB_new), None
+        def iterations(carry, rows):
+            """``check_every`` ADMM iterations on the scenarios whose
+            operands ``rows`` holds: the whole batch, or one block of a
+            wide float64 stack (below)."""
+            A_s, F_plain, q_s, g, rA, rB, l_s, u_s, lb_s, ub_s = rows
 
-        (x, yA, yB, zA, zB), _ = jax.lax.scan(one, (x, yA, yB, zA, zB), None,
-                                              length=check_every)
-        return x, yA, yB, zA, zB
+            def one(carry, _):
+                x, yA, yB, zA, zB = carry
+                rhs = sigma * x - q_s + _ATy(A_s, rA * zA - yA) \
+                    + g * (rB * zB - yB)
+                with jax.named_scope("qp.kkt_solve"):
+                    x_t = _m_solve_ir(L, rhs, rA, rB) if split_mode \
+                        else _chol_solve(F_plain, rhs)
+                x_new = alpha * x_t + (1 - alpha) * x
+                zA_t = _Ax(A_s, x_t)
+                zA_mix = alpha * zA_t + (1 - alpha) * zA
+                zA_new = jnp.clip(zA_mix + yA / rA, l_s, u_s)
+                yA_new = yA + rA * (zA_mix - zA_new)
+                zB_t = g * x_t
+                zB_mix = alpha * zB_t + (1 - alpha) * zB
+                zB_new = jnp.clip(zB_mix + yB / rB, lb_s, ub_s)
+                yB_new = yB + rB * (zB_mix - zB_new)
+                return (x_new, yA_new, yB_new, zA_new, zB_new), None
+
+            return jax.lax.scan(one, carry, None, length=check_every)[0]
+
+        carry = (x, yA, yB, zA, zB)
+        rows = (A_s, F_plain, q_s, g, rA, rB, l_s, u_s, lb_s, ub_s)
+        B = f64_stack_block_rows(A_s)
+        if B is None:
+            return iterations(carry, rows)
+        # a wide per-scenario float64 stack: the same iterations, a
+        # block of B scenarios at a time (doc/kernels.md §3i). Between
+        # two checks the scenarios are independent (rho is a row's own;
+        # residuals, exits and the rebuild sit in ``check``), so block
+        # by block the iterates are the whole-stack scan's numbers.
+        # The blocks are a reshape of the leading axis (no copy of an
+        # (S, n, n) array); a block's matrices are loop-invariant
+        # inputs of an inner scan that holds no ``conditional``
+        obs.counter_add("kernel.f64_stack_blocked")
+        blocks = jax.tree.map(
+            lambda a: a.reshape((-1, B) + a.shape[1:]), (carry, rows))
+        out = jax.lax.map(lambda t: iterations(*t), blocks)
+        return jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), out)
 
     def residuals(x, yA, yB, zA, zB):
         return _unscaled_residuals(A_s, P_s, g, D, E, Eb, csx, q_s,

@@ -96,7 +96,8 @@ def test_unchunked_sslp_df32_solve_compiles_for_v5e(sslp_calls, one_chip,
                                   "f64_products": None,
                                   "f64_polish": None,
                                   "f64_refactor": None,
-                                  "f64_loop": None}
+                                  "f64_loop": None,
+                                  "f64_stack_block": None}
     solves = sslp_calls["_fused_mixed_jit_donated"]
     assert len(solves) == 3
     assert len(sslp_calls["make_l_inv"]) == 2      # iter-0's and hot's

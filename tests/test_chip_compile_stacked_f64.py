@@ -54,17 +54,21 @@ def _product_loops(hlo):
             if any(s + "/" in ln for s in _PRODUCT_SCOPES)]
 
 
-def _widened(tree, S, scale, sharding):
-    """The recorded (24, 7, 12) operands as shapes on the described
-    chip: the scenario axis at ``S`` rows, m and n times ``scale``."""
-    dims = {24: S, 7: 7 * scale, 12: 12 * scale}
-
+def _resized(tree, dims, sharding):
+    """Recorded operands as shapes on the described chip, every axis
+    of length d at ``dims[d]``."""
     def leaf(a):
         if not (hasattr(a, "shape") and hasattr(a, "dtype")):
             return a
         return jax.ShapeDtypeStruct(tuple(dims[d] for d in a.shape),
                                     a.dtype, sharding=sharding)
     return jax.tree.map(leaf, tree)
+
+
+def _widened(tree, S, scale, sharding):
+    """The recorded (24, 7, 12) operands: the scenario axis at ``S``
+    rows, m and n times ``scale``."""
+    return _resized(tree, {24: S, 7: 7 * scale, 12: 12 * scale}, sharding)
 
 
 # (S, scale): the served stack, a solo wheel, and the largest shape the
@@ -80,13 +84,20 @@ def test_stacked_f64_segment_has_no_emulated_dot_loops_on_v5e(
     ``dynamic-update-slice`` (at (24, 7, 12): 34 loops and 74
     update-slices before ISSUE 38, 14 of the loops in the ADMM scan
     body). One answer, held by a compile at each pinned shape."""
+    import mpisppy_tpu.ops.qp_solver as qps
     fn, args, kw = stacked_farmer_segment
-    hlo = fn.lower(*_widened(args, S, scale, one_chip), **kw).compile() \
-        .as_text()
+    wide = _widened(args, S, scale, one_chip)
+    hlo = fn.lower(*wide, **kw).compile().as_text()
     assert f"f64[{S},{7 * scale},{12 * scale}]" in hlo
     assert not _product_loops(hlo)
-    assert len(_hlo_lines(hlo, "while")) == 2
-    assert not _hlo_lines(hlo, "dynamic-update-slice")
+    # the widest shape is over the budget of ISSUE 46's blocks (18 MB a
+    # scenario: three rows a block), so its scan sits in one more loop,
+    # whose results are placed by the only update-slices
+    blocks = qps.f64_stack_block_rows(wide[0].A_s)
+    assert blocks == (3 if scale == 100 else None)
+    assert len(_hlo_lines(hlo, "while")) == (3 if blocks else 2)
+    if not blocks:
+        assert not _hlo_lines(hlo, "dynamic-update-slice")
 
 
 def test_the_emulated_dot_is_a_loop_nest_on_v5e(one_chip,
@@ -176,6 +187,15 @@ def _refactor_loops(hlo):
     return [ln for ln in _hlo_lines(hlo, "while") if "qp.refactor/" in ln]
 
 
+def _while_bodies(hlo):
+    """{body name: text} of every ``while`` of a compiled module."""
+    out = {}
+    for body in re.findall(r"\bwhile\(.*?body=%?([\w.\-]+)", hlo):
+        out[body] = re.search(r"\n%?" + re.escape(body) + r" \(.*?\n\}",
+                              hlo, re.S).group(0)
+    return out
+
+
 def _loops_carrying_halves(hlo, S):
     """For every ``while`` of the compiled program whose body reads an
     f32[S,7,12] / f32[S,12,12] array out of its carry (the two f32
@@ -183,9 +203,7 @@ def _loops_carrying_halves(hlo, S):
     such reads the body holds, and how many of them the compiler placed
     in ``S(1)`` (VMEM), as ``(reads, resident)`` pairs."""
     out = []
-    for body in re.findall(r"\bwhile\(.*?body=%?([\w.\-]+)", hlo):
-        text = re.search(r"\n%?" + re.escape(body) + r" \(.*?\n\}", hlo,
-                         re.S).group(0)
+    for text in _while_bodies(hlo).values():
         reads = [ln for ln in text.splitlines()
                  if "get-tuple-element(" in ln
                  and re.search(rf"f32\[{S},(7|12),12\]", ln)]
@@ -273,3 +291,94 @@ def test_the_refactorization_takes_the_blocked_forms_above_the_width_on_v5e(
     assert not _hlo_lines(hlo, "cholesky")
     assert not _hlo_lines(hlo, "triangular-solve")
     assert len(_hlo_lines(hlo, "conditional")) == 1
+
+
+# ---------------- the ADMM scan of a WIDE stack, in blocks (ISSUE 46) --
+
+@pytest.fixture(scope="module")
+def wide_stack_hot_solve():
+    """The stack cell's hot solve (``farmer_cm32_s1024_hub_hot``: one
+    native-f64 fused call of all rows, rho adapted in the program) as
+    the engine calls it, recorded from a CPU pass of the farmer at
+    ``crops_multiplier`` 2 over 8 scenarios ((8, 13, 24) float64) under
+    the cell's recipe: ``(fn, args, kw)`` of the last, hot call."""
+    import mpisppy_tpu.ops.qp_solver as qps
+    from mpisppy_tpu.core.ph import PHBase
+    from mpisppy_tpu.ir.batch import build_batch
+    from mpisppy_tpu.models import farmer
+    from stacked_farmer import recorded_qp_solves
+    batch = build_batch(farmer.scenario_creator, farmer.make_tree(8),
+                        creator_kwargs={"crops_multiplier": 2})
+    ph = PHBase(batch, {"subproblem_precision": "native",
+                        "defaultPHrho": 1.0, "subproblem_eps_hot": 1e-4,
+                        "subproblem_eps_dua_hot": 1e-2,
+                        "subproblem_polish_hot": False}, dtype=jnp.float64)
+    with recorded_qp_solves() as calls:
+        ph.solve_loop(w_on=False, prox_on=False)
+        ph.W = ph.W_new
+        ph.solve_loop(w_on=True, prox_on=True)
+    args, kw = calls[-1]
+    assert args[0].A_s.shape == (8, 13, 24) and not kw["polish"] \
+        and kw["adaptive_rho"]
+
+    def impl(factors, data, q, state, **k):         # a trace of its own
+        return qps._solve_impl(factors, data, q, state, **k)
+    return jax.jit(impl, static_argnames=qps._SOLVE_STATICS), args, kw
+
+
+def test_a_wide_stacks_hot_program_scans_block_by_block_on_v5e(
+        wide_stack_hot_solve, one_chip, no_persistent_cache):
+    """The cell's hot program at its own operands ((1024, 193, 384)
+    float64 and the (1024, 384, 384) inverse) compiles for the v5e, and
+    its ADMM scan runs a block of ``f64_stack_block_rows`` scenarios at
+    a time (doc/kernels.md §3i): the loop over the blocks holds the
+    scan and nothing else that loops or branches; the scan's body holds
+    no ``conditional`` and no ``while`` (none of the dot emulation under
+    the three product scopes either), and reads the block's matrices,
+    the f32 halves of (B, 193, 384) and (B, 384, 384), out of its carry;
+    the one ``conditional`` of the program stays the rebuild's, in the
+    outer loop's body."""
+    import mpisppy_tpu.ops.qp_solver as qps
+    fn, args, kw = wide_stack_hot_solve
+    wide = _resized(args, {8: 1024, 13: 193, 24: 384}, one_chip)
+    B = qps.f64_stack_block_rows(wide[0].A_s)
+    assert B and 1024 % B == 0
+    hlo = fn.lower(*wide, **kw).compile().as_text()
+    assert "f64[1024,193,384]" in hlo
+    assert not _product_loops(hlo)
+    assert len(_hlo_lines(hlo, "conditional")) == 1
+    halves = re.compile(rf"f32\[{B},(193|384),384\]")
+    scans = {name: text for name, text in _while_bodies(hlo).items()
+             if any("get-tuple-element(" in ln and halves.search(ln)
+                    for ln in text.splitlines())}
+    # the scan over a block's iterations, and the loop over the blocks
+    # around it (which hands the scan its block)
+    inner = [t for t in scans.values() if not _hlo_lines(t, "while")]
+    outer = [t for t in scans.values() if _hlo_lines(t, "while")]
+    assert len(inner) == 1 and len(outer) == 1, sorted(scans)
+    assert not _hlo_lines(inner[0], "conditional")
+    assert all(s in inner[0] for s in _PRODUCT_SCOPES)
+    assert len(_hlo_lines(outer[0], "while")) == 1 \
+        and not _hlo_lines(outer[0], "conditional")
+
+
+def test_the_served_stack_keeps_one_scan_over_all_rows_on_v5e(
+        stacked_farmer_segment, one_chip, no_persistent_cache, monkeypatch):
+    """The rule leaves the served (24, 7, 12) stack whole: its solve
+    program, lowered for the v5e, is text-equal to the one traced with
+    the rule answering None for every operand (the parent's single
+    scan), and holds the three loops of doc/kernels.md §3g."""
+    import mpisppy_tpu.ops.qp_solver as qps
+    _fn, args, kw = stacked_farmer_segment
+    kw = dict(kw, adaptive_rho=True)
+    assert qps.f64_stack_block_rows(args[0].A_s) is None
+
+    def lowered():
+        def impl(factors, data, q, state, **k):     # a trace of its own
+            return qps._solve_impl(factors, data, q, state, **k)
+        return jax.jit(impl, static_argnames=qps._SOLVE_STATICS).lower(
+            *_widened(args, 24, 1, one_chip), **kw)
+    mine = lowered()
+    monkeypatch.setattr(qps, "f64_stack_block_rows", lambda A_s: None)
+    assert mine.as_text() == lowered().as_text()
+    assert len(_hlo_lines(mine.compile().as_text(), "while")) == 3

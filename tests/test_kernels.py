@@ -561,7 +561,10 @@ def test_prepare_resolution(monkeypatch, mode, kind, ir_sweeps):
         # where no program rebuilds a float64 inverse
         "f64_loop": {"split-df32": None,
                      "per-scenario-f64-host": None}.get(kind,
-                                                        "conditional")}
+                                                        "conditional"),
+        # none of these is a wide per-scenario stack rebuilt on the
+        # device (tests/test_f64_rule_table.py)
+        "f64_stack_block": None}
     if want == "S":
         assert plan.A_lo is None
     elif kind == "split-df32":

@@ -185,7 +185,10 @@ def test_phase_timing_contract(layout, precision):
                             else "library",
                             # rebuilt under a ``lax.cond`` in the loop
                             "f64_loop": None if precision == "df32"
-                            else "conditional"}
+                            else "conditional",
+                            # one shared matrix: no stack to walk in
+                            # blocks of scenarios
+                            "f64_stack_block": None}
     assert (pt["mode"], pt["devices"]) == (
         "sharded" if ndev > 1 else "host", ndev)
     shape = pt["solve_shape"]

@@ -233,7 +233,7 @@ def test_normal_path_runs_the_configuration(ref):
     assert pt["kernel"] == dict(cfg["kernel"], backend="reference",
                                 block_dtype="f32", f64_products=None,
                                 f64_polish=None, f64_refactor=None,
-                                f64_loop=None)
+                                f64_loop=None, f64_stack_block=None)
     assert pt["runs"]["count"] == 1
     ik = cfg["instance"]
     inst = ref.instance(ik["num_servers"], ik["num_clients"],
