@@ -795,17 +795,35 @@ def _polish_unrollable(A_s) -> bool:
 # ``_unrolled_cholesky`` and one ``_unrolled_linv`` of a (S, b, b) block,
 # b = ``_F64_BLOCK``, in the body of a ``fori_loop`` over block rows, so
 # the program's size does not grow with n), and everything off the
-# diagonal as reduce-form products of whole (S, b, n) row panels:
-# element-wise float64 throughout, no ``cholesky`` / ``triangular_solve``
-# / ``dot`` for the compiler to expand. The factor is kept as U = Lᵀ,
-# so that every panel read or written is a block of ROWS (the column
-# panel of L is the row panel of U; a dynamic slice of the minor axis is
-# only ever taken of a (S, b, n) panel). doc/kernels.md §3h: the chip's
-# seconds and residuals by spelling, block width, n and S.
+# diagonal as reduce-form products of (S, b, n) row panels with what
+# is already built: element-wise float64 throughout, no ``cholesky`` /
+# ``triangular_solve`` / ``dot`` for the compiler to expand. Every n^3
+# product is spelled as a multiply and a sum over the operands' common
+# ROW axis (the fastest spelling the chip's sweeps saw), and runs over
+# the part of its operands that is not stored zeros: the block rows are
+# walked in ``_F64_GROUPS`` static groups whose slices are static (a
+# mask saves nothing on a SIMD unit; a static extent does), and the
+# explicit inverse is computed for one triangle of blocks and mirrored.
+# doc/kernels.md §3h: the chip's seconds and residuals by spelling,
+# group count, n and S.
 
 # Width of a diagonal block: the widest stack the unrolled recurrences
 # compile at (the rule that stops them is the rule that sizes them)
 _F64_BLOCK = _POLISH_UNROLL_MAX_N
+
+# Static groups the block rows of a blocked build are walked in. A
+# group's products work on the extent that is nonzero for ITS block
+# rows, so the n^3 stages do (G + 1)(G + 2) / 6G^2 (the Cholesky, the
+# explicit inverse) and (G + 1)(2G + 1) / 6G^2 (the substitution) of the
+# full square's work, against 1/6 and 1/3 at a group a block row. A
+# group is one more branch of the Cholesky's ``lax.switch``, one more
+# substitution loop and one more strip of the product: small bodies (the
+# unrolled diagonal factor stays in the program once). 3: the count the
+# cell's runs were held to ``correct`` at; iter-0 stops at its cap, so
+# another count is another summation order and another end state
+# (doc/kernels.md §3h: device seconds, compile seconds and iter-0's
+# compared numbers by G).
+_F64_GROUPS = 3
 
 # Largest (S, n, n) float64 array the blocked forms take. A solve keeps
 # the inverse it came with and the one it hands back, the other mode's
@@ -830,65 +848,101 @@ def _pad_spd(M, b):
     return M + tail[None, :, None] * jnp.eye(n + pad, dtype=M.dtype)
 
 
+def _block_row_groups(n):
+    """Static ``(s, e)`` element extents of the groups the n / b block
+    rows are walked in (``_F64_GROUPS`` of them, fewer where there are
+    fewer block rows; none empty)."""
+    nb = -(-n // _F64_BLOCK)
+    G = min(_F64_GROUPS, nb)
+    cuts = [min(nb * g // G * _F64_BLOCK, n) for g in range(G + 1)]
+    return list(zip(cuts, cuts[1:]))
+
+
 def _blocked_cholesky(M):
     """``(U, Dinv)`` of M (S, n, n), n a multiple of b = ``_F64_BLOCK``:
-    the upper Cholesky factor U = Lᵀ (M = UᵀU), right-looking, one block
+    the upper Cholesky factor U = Lᵀ (M = UᵀU), left-looking, one block
     row a trip, and the inverses of L's diagonal blocks stacked by rows
-    ((S, n, b)). A trip reads block row k of the (symmetric) trailing
-    matrix, factors its (b, b) diagonal block by the unrolled
-    recurrences, turns the row panel into U's by one product with the
-    block's L⁻¹, subtracts the panel's gram from the whole matrix and
-    writes the panel back in the row block it read: M's own buffer
-    becomes U. Like the library, a non-positive-definite matrix gives
-    NaN from its first bad pivot on."""
+    ((S, n, b)). A trip takes block row k of M less the product of U's
+    block column k with the rows of U above it, factors the (b, b)
+    diagonal block by the unrolled recurrences and turns the row panel
+    into U's by one product with the block's L⁻¹. The product runs on
+    rows [0, e) and columns [s, n) of the trip's group (U's rows from k
+    on are still zero, and so are its columns left of the diagonal): a
+    ``lax.switch`` on the block row's group around the product ALONE,
+    so that the loop, and the unrolled factor in its body, is in the
+    program once. Reads M's upper triangle of blocks. Like the library,
+    a non-positive-definite matrix gives NaN from its first bad pivot
+    on."""
     S, n, _ = M.shape
     b = _F64_BLOCK
-    col = jnp.arange(n)
     within = jnp.arange(b)
+    col = jnp.arange(n)
+    groups = _block_row_groups(n)
+    ends = jnp.asarray([e for _s, e in groups])
+
+    def above(s, e):
+        def product(U, kb):
+            Ucol = jax.lax.dynamic_slice_in_dim(U[:, :e], kb, b, axis=2)
+            return jnp.pad(
+                jnp.sum(Ucol[:, :, :, None] * U[:, :e, None, s:], axis=1),
+                ((0, 0), (0, 0), (s, 0)))
+        return product
+
+    branches = [above(s, e) for s, e in groups]
 
     def trip(k, carry):
-        M, Dinv = carry
+        U, Dinv = carry
         kb = k * b
-        R = jax.lax.dynamic_slice_in_dim(M, kb, b, axis=1)
+        R = jax.lax.dynamic_slice_in_dim(M, kb, b, axis=1) \
+            - jax.lax.switch(jnp.sum(kb >= ends), branches, U, kb)
         Linv = _unrolled_linv(_unrolled_cholesky(
             jax.lax.dynamic_slice_in_dim(R, kb, b, axis=2)))
         # U's rows kb .. kb+b: L_kk⁻¹ R, zero left of the diagonal
         P = jnp.sum(Linv[:, :, :, None] * R[:, None, :, :], axis=2)
         P = jnp.where(col[None, None, :] >= kb + within[None, :, None],
                       P, 0.0)
-        M = M - jnp.sum(P[:, :, :, None] * P[:, :, None, :], axis=1)
-        return (jax.lax.dynamic_update_slice_in_dim(M, P, kb, axis=1),
+        return (jax.lax.dynamic_update_slice_in_dim(U, P, kb, axis=1),
                 jax.lax.dynamic_update_slice_in_dim(Dinv, Linv, kb, axis=1))
 
     return jax.lax.fori_loop(
-        0, n // b, trip, (M, jnp.zeros((S, n, b), M.dtype)))
+        0, n // b, trip,
+        (jnp.zeros_like(M), jnp.zeros((S, n, b), M.dtype)))
 
 
 def _blocked_uinv(U, Dinv):
     """W = U⁻¹ (upper) of ``_blocked_cholesky``'s pair, by the backward
     substitution on the identity, one block row a trip from the last:
-    row block k is L_kk⁻ᵀ (E_k − U[k, :] W), W's rows from k on still
-    zero, so the product runs over the whole width."""
+    row block k is L_kk⁻ᵀ (E_k − U[k, :] W), the product summed over
+    the rows of W and of L = Uᵀ (one transposed copy a build: block row
+    k of U is then a block column, as in the factorization), on rows
+    and columns [s, n) of the trip's group (W is upper, and its rows up
+    to k are still zero)."""
     S, n, _ = U.shape
     b = _F64_BLOCK
-    nb = n // b
-    col = jnp.arange(n)
     within = jnp.arange(b)
+    L = jnp.swapaxes(U, 1, 2)
+    W = jnp.zeros_like(U)
+    for s, e in reversed(_block_row_groups(n)):
+        col = jnp.arange(s, n)
+        Ls = L[:, s:]
 
-    def trip(t, W):
-        kb = (nb - 1 - t) * b
-        Urow = jax.lax.dynamic_slice_in_dim(U, kb, b, axis=1)
-        Linv = jax.lax.dynamic_slice_in_dim(Dinv, kb, b, axis=1)
-        E = (col[None, :] == kb + within[:, None]).astype(U.dtype)
-        T = E[None] - jnp.sum(Urow[:, :, :, None] * W[:, None, :, :], axis=2)
-        Wrow = jnp.sum(Linv[:, :, :, None] * T[:, :, None, :], axis=1)
-        return jax.lax.dynamic_update_slice_in_dim(W, Wrow, kb, axis=1)
+        def trip(t, W, s=s, e=e, col=col, Ls=Ls):
+            kb = e - (t + 1) * b
+            Lcol = jax.lax.dynamic_slice_in_dim(Ls, kb, b, axis=2)
+            Linv = jax.lax.dynamic_slice_in_dim(Dinv, kb, b, axis=1)
+            E = (col[None, :] == kb + within[:, None]).astype(U.dtype)
+            T = E[None] - jnp.sum(
+                Lcol[:, :, :, None] * W[:, s:, None, s:], axis=1)
+            Wrow = jnp.sum(Linv[:, :, :, None] * T[:, :, None, :], axis=1)
+            return jax.lax.dynamic_update_slice(W, Wrow, (0, kb, s))
 
-    return jax.lax.fori_loop(0, nb, trip, jnp.zeros_like(U))
+        W = jax.lax.fori_loop(0, (e - s) // b, trip, W)
+    return W
 
 
 def _blocked_factor_inverse(M):
-    """W = U⁻¹ of an SPD stack M (S, n, n) of any n: M⁻¹ = W Wᵀ."""
+    """W = U⁻¹ of an SPD stack M (S, n, n) of any n (its upper triangle
+    of blocks is read): M⁻¹ = W Wᵀ."""
     n = M.shape[-1]
     W = _blocked_uinv(*_blocked_cholesky(_pad_spd(M, _F64_BLOCK)))
     return W[:, :n, :n]
@@ -902,29 +956,31 @@ def _uinv_pair_solve(W, b):
 
 
 def _spd_inverse_from_uinv(W):
-    """M⁻¹ = W Wᵀ as a multiply and a sum, a block of rows at a time
-    (the (S, n, n, n) product is never one fusion's to hold)."""
-    S, n, _ = W.shape
-    b = _F64_BLOCK
-    Wp = jnp.pad(W, ((0, 0), (0, (-n) % b), (0, 0)))
-
-    def rows(k, out):
-        Wk = jax.lax.dynamic_slice_in_dim(Wp, k * b, b, axis=1)
-        blk = jnp.sum(Wk[:, :, None, :] * W[:, None, :, :], axis=-1)
-        return jax.lax.dynamic_update_slice_in_dim(out, blk, k * b, axis=1)
-
-    out = jax.lax.fori_loop(0, Wp.shape[1] // b, rows,
-                            jnp.zeros((S, Wp.shape[1], n), W.dtype))
-    return out[:, :n]
+    """M⁻¹ = W Wᵀ = VᵀV, summed over the rows of V = Wᵀ (one transposed
+    copy; V is lower): the UPPER triangle of blocks only, a column
+    strip a group of block rows, each the rows down to its own end and
+    summed from the strip's start on (V's rows above hold zeros
+    there); the blocks below are the mirror image, bit for bit."""
+    n = W.shape[-1]
+    V = jnp.swapaxes(W, 1, 2)
+    groups = _block_row_groups(n)
+    X = jnp.concatenate([
+        jnp.pad(jnp.sum(V[:, s:, :e, None] * V[:, s:, None, s:e], axis=1),
+                ((0, 0), (0, n - e), (0, 0)))
+        for s, e in groups], axis=2)
+    group = sum((jnp.arange(n) >= e).astype(jnp.int32) for _s, e in groups)
+    return jnp.where((group[:, None] <= group[None, :])[None],
+                     X, jnp.swapaxes(X, 1, 2))
 
 
 # Bytes of ONE (rows, n, n) float64 array a build works on at a time.
-# A build holds about four such arrays (the matrix that becomes U, U⁻¹,
-# the trailing update's temporary, the product): for the whole stack at
-# once 4.7 GB of temporaries at (1024, 384, 384), in an iter-0 program
-# of 14 GB (compiled for a v5e); in chunks of 64 rows the hot program's
-# temporaries are 2.7 GB. The trips cost nothing that shows beside a
-# chunk's ~0.2 s of soft-float.
+# A build holds about six such arrays (the matrix, U and its transposed
+# copy, U⁻¹ and its transposed copy, the product's strips): for the
+# whole stack at once over 7 GB of temporaries at (1024, 384, 384); in
+# chunks of 64 rows the hot program's temporaries are 3.3 GB and the
+# iter-0 program's 8.5 GB (compiled for a v5e; 2.6 and 8.4 GB with
+# PR 45's four arrays a build). The trips cost nothing that shows
+# beside a chunk's ~0.1–0.2 s of soft-float.
 _F64_BUILD_BYTES = 128 << 20
 
 

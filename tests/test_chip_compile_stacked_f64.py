@@ -159,20 +159,25 @@ def test_the_polish_takes_the_blocked_forms_above_the_width_on_v5e(
     """Above ``_POLISH_UNROLL_MAX_N`` (here n = 24) the polish lowers
     the BLOCKED forms since ISSUE 45 (doc/kernels.md §3h): under
     ``qp.polish`` the program holds its own three scans and the block
-    rows' ``fori_loop``s of the three factorizations, and nothing of
-    the library: no loop of a ``cholesky``, a ``triangular_solve`` or
-    the Gram ``dot_general``. Until then the library path was what was
-    lowered there (the compiler's row loops, PR 40)."""
-    from mpisppy_tpu.ops.qp_solver import _POLISH_UNROLL_MAX_N
+    rows' ``fori_loop``s of the three factorizations (since ISSUE 47
+    ONE Cholesky loop, and a U⁻¹ loop a static group of block rows),
+    and nothing of the library: no loop of a ``cholesky``, a
+    ``triangular_solve`` or the Gram ``dot_general``. Until then the
+    library path was what was lowered there (the compiler's row loops,
+    PR 40)."""
+    import mpisppy_tpu.ops.qp_solver as qps
     fn, args, kw = stacked_farmer_segment
     kw = dict(kw, max_iter=0, polish=True)
-    assert 12 * 2 > _POLISH_UNROLL_MAX_N
+    assert 12 * 2 > qps._POLISH_UNROLL_MAX_N
     hlo = fn.lower(*_widened(args, 3, 2, one_chip), **kw).compile() \
         .as_text()
     loops, expansions = _polish_loops(hlo)
-    # three scans, and a Cholesky and a U^-1 loop a factorization
-    # (the compiler merges the first and the third: the same active set)
-    assert 3 + 2 <= len(loops) <= 3 + 3 * 2 and not expansions
+    # three scans, and a factorization's one Cholesky loop and a U^-1
+    # loop a group of block rows (n = 24 pads to two block rows: two
+    # groups); the compiler merges the first and the third
+    # factorization: the same active set
+    per = 1 + len(qps._block_row_groups(32))
+    assert 3 + 2 * per <= len(loops) <= 3 + 3 * per and not expansions
     assert not _hlo_lines(hlo, "cholesky")
     assert not _hlo_lines(hlo, "triangular-solve")
 
@@ -274,23 +279,65 @@ def test_the_refactorization_takes_the_blocked_forms_above_the_width_on_v5e(
         stacked_farmer_segment, one_chip, no_persistent_cache):
     """Above ``_POLISH_UNROLL_MAX_N`` (n = 24) ``_factorize`` lowers
     the BLOCKED inverse since ISSUE 45 (doc/kernels.md §3h), under the
-    loop's ``conditional``: the block rows' three ``fori_loop``s under
-    ``qp.refactor`` (Cholesky, U⁻¹, the product by row blocks), and no
-    loop of a ``cholesky``, a ``triangular_solve`` or a batched
-    ``dot_general``. Until then the library pair was what was lowered
-    there, and the rule sent such factors to the host."""
-    from mpisppy_tpu.ops.qp_solver import _POLISH_UNROLL_MAX_N
+    loop's ``conditional``: the block rows' ``fori_loop``s under
+    ``qp.refactor`` (since ISSUE 47 ONE Cholesky loop, the static
+    extents of its product under a ``lax.switch`` on the block row's
+    group, and a U⁻¹ loop a group; the Gram matrix and the product
+    W Wᵀ are strips with no loop), and no loop of a ``cholesky``, a
+    ``triangular_solve`` or a batched ``dot_general``. Until then the
+    library pair was what was lowered there, and the rule sent such
+    factors to the host."""
+    import mpisppy_tpu.ops.qp_solver as qps
     fn, args, kw = stacked_farmer_segment
-    assert 12 * 2 > _POLISH_UNROLL_MAX_N
+    assert 12 * 2 > qps._POLISH_UNROLL_MAX_N
     hlo = fn.lower(*_widened(args, 3, 2, one_chip),
                    **dict(kw, adaptive_rho=True)).compile().as_text()
     loops = _refactor_loops(hlo)
-    assert len(loops) == 3
+    assert len(loops) == 1 + len(qps._block_row_groups(32)) == 3
     assert not any(k in ln for ln in loops
                    for k in ("cholesky", "triangular_solve", "dot_general"))
     assert not _hlo_lines(hlo, "cholesky")
     assert not _hlo_lines(hlo, "triangular-solve")
-    assert len(_hlo_lines(hlo, "conditional")) == 1
+    # the loop's one ``conditional`` (the rebuild's), and under it the
+    # Cholesky's ``lax.switch`` on the block row's group
+    conds = _hlo_lines(hlo, "conditional")
+    assert len([ln for ln in conds if "qp.refactor/" not in ln]) == 1
+    assert len(conds) == 2
+
+
+def _instructions(hlo):
+    return [ln for ln in hlo.splitlines()
+            if re.match(r"^\s*(ROOT )?%?[\w.\-]+ = ", ln)]
+
+
+# the program of PR 46 (one loop a stage whatever n): instructions of
+# the whole compiled solve, and of them under ``qp.refactor``, at
+# n = 24 and at n = 96 (described v5e, this repo's installation)
+_PARENT_REFACTOR_SIZE = {2: (29697, 14894), 8: (29458, 14695)}
+
+
+@pytest.mark.parametrize("scale", [2, 8])
+def test_static_groups_hold_the_refactorization_programs_size_on_v5e(
+        stacked_farmer_segment, one_chip, no_persistent_cache, scale):
+    """The unrolled (16, 16) diagonal factor is ~14,000 of the parent's
+    ~14,900 instructions under ``qp.refactor``, and compile seconds
+    follow the instruction count: it stays in the program ONCE (one
+    Cholesky loop whatever the group count: only the small bodies of
+    the substitution and the strips are copied a group). The solve
+    program with its rebuild stays under 1.25 times the parent's, a
+    literal that does not follow ``_F64_GROUPS``, at n = 24 (two block
+    rows) and at n = 96 (six): a second copy of the factor's body, or
+    a group count that grows the program, fails here until someone
+    measures again (doc/kernels.md §3h: device seconds and compile
+    seconds by form; 30,759 and 31,562 instructions at PR 47)."""
+    fn, args, kw = stacked_farmer_segment
+    hlo = fn.lower(*_widened(args, 3, scale, one_chip),
+                   **dict(kw, adaptive_rho=True)).compile().as_text()
+    whole, refactor = _PARENT_REFACTOR_SIZE[scale]
+    ins = _instructions(hlo)
+    under = [ln for ln in ins if "qp.refactor/" in ln]
+    assert refactor <= len(under) <= 1.25 * refactor
+    assert len(ins) <= 1.25 * whole
 
 
 # ---------------- the ADMM scan of a WIDE stack, in blocks (ISSUE 46) --
@@ -336,8 +383,9 @@ def test_a_wide_stacks_hot_program_scans_block_by_block_on_v5e(
     no ``conditional`` and no ``while`` (none of the dot emulation under
     the three product scopes either), and reads the block's matrices,
     the f32 halves of (B, 193, 384) and (B, 384, 384), out of its carry;
-    the one ``conditional`` of the program stays the rebuild's, in the
-    outer loop's body."""
+    the one ``conditional`` of the solve stays the rebuild's, in the
+    outer loop's body (the rebuild's own, the Cholesky's ``lax.switch``,
+    is under ``qp.refactor``)."""
     import mpisppy_tpu.ops.qp_solver as qps
     fn, args, kw = wide_stack_hot_solve
     wide = _resized(args, {8: 1024, 13: 193, 24: 384}, one_chip)
@@ -346,7 +394,8 @@ def test_a_wide_stacks_hot_program_scans_block_by_block_on_v5e(
     hlo = fn.lower(*wide, **kw).compile().as_text()
     assert "f64[1024,193,384]" in hlo
     assert not _product_loops(hlo)
-    assert len(_hlo_lines(hlo, "conditional")) == 1
+    assert len([ln for ln in _hlo_lines(hlo, "conditional")
+                if "qp.refactor/" not in ln]) == 1
     halves = re.compile(rf"f32\[{B},(193|384),384\]")
     scans = {name: text for name, text in _while_bodies(hlo).items()
              if any("get-tuple-element(" in ln and halves.search(ln)

@@ -1,5 +1,7 @@
 """The BLOCKED float64 forms of a per-scenario stack wider than 16
-(ISSUE 45; ``ops/qp_solver.py``: ``_blocked_cholesky``, ``_blocked_uinv``,
+(ISSUE 45; since ISSUE 47 every n^3 product summed over rows and cut to
+the extents that are not stored zeros; ``ops/qp_solver.py``:
+``_block_row_groups``, ``_blocked_cholesky``, ``_blocked_uinv``,
 ``_spd_inverse_from_uinv``, ``_uinv_pair_solve``), called directly and
 held against numpy: what the TPU lowering of a wide stack's explicit KKT
 inverse and of its polish runs, run here on the CPU (the forms are plain
@@ -15,7 +17,9 @@ import pytest
 
 import mpisppy_tpu.ops.qp_solver as qps
 
-WIDTHS = (17, 24, 40, 96)
+# in block rows of 16: 2 (17 pads to two: fewer than the groups), 2, 3,
+# 6, and 7 and 8 (which the group count does not divide)
+WIDTHS = (17, 24, 40, 96, 112, 128)
 STACKS = (1, 5)
 # SPD stacks of two makes: a Gram matrix on a heavy diagonal (cond ~10),
 # and the polish's own make, a penalty matrix rho_big A'A + sigma I
@@ -43,6 +47,10 @@ def _forms(S, n):
     del S, n    # the cache key; jit keys on the operands' shapes itself
 
     def all_forms(M, b):
+        # the factorization reads M's upper triangle of blocks: what
+        # lies below must not matter
+        blk = jnp.arange(M.shape[-1]) // qps._F64_BLOCK
+        M = jnp.where(blk[:, None] > blk[None, :], jnp.nan, M)
         U, Dinv = qps._blocked_cholesky(qps._pad_spd(M, qps._F64_BLOCK))
         W = qps._blocked_uinv(U, Dinv)
         k = M.shape[-1]
@@ -101,8 +109,9 @@ def test_explicit_inverse_against_numpy(n, S, cond):
     off = np.abs(M @ Minv - np.eye(n)).max(axis=(1, 2))
     room = 1e-13 if cond == "well" else 1e-14 * np.linalg.cond(M)
     assert (off <= room).all()
-    assert np.abs(Minv - Minv.transpose(0, 2, 1)).max() \
-        <= 1e-12 * np.abs(Minv).max()
+    # one triangle of blocks is computed and the other is its mirror
+    # image: symmetric to the last bit
+    np.testing.assert_array_equal(Minv, Minv.transpose(0, 2, 1))
 
 
 @pytest.mark.parametrize("cond", CONDS)
@@ -116,6 +125,43 @@ def test_pair_solve_residual(n, S, cond):
     res = np.abs(np.einsum("sij,sj->si", M, x) - b).max(-1)
     scale = np.abs(M).sum(-1).max(-1) * np.abs(x).max(-1)
     assert (res / scale).max() <= 1e-13
+
+
+@pytest.mark.parametrize("n,groups", [
+    (17, [(0, 16), (16, 17)]), (32, [(0, 16), (16, 32)]),
+    (40, [(0, 16), (16, 32), (32, 40)]),
+    (112, [(0, 32), (32, 64), (64, 112)]),
+    (384, [(0, 128), (128, 256), (256, 384)])])
+def test_the_block_rows_static_groups(n, groups):
+    """The extents the builds' loops and strips are cut to: whole block
+    rows, ``_F64_GROUPS`` groups or a block row each where there are
+    fewer, none empty, the last one ending at n."""
+    assert qps._F64_GROUPS == 3
+    assert qps._block_row_groups(n) == groups
+
+
+@pytest.mark.parametrize("S", STACKS)
+@pytest.mark.parametrize("n", WIDTHS)
+def test_the_whole_build_from_its_operands_against_numpy(n, S):
+    """``_kkt_inverse_blocked`` and ``_penalty_factor_blocked`` from
+    (A, r, d), the Gram matrix included (``_penalty_matrix``, the square:
+    Step 0 of ISSUE 47 read no gain in its upper triangle alone): M M⁻¹
+    = I to rounding, M⁻¹ symmetric to the last bit, and W = U⁻¹ upper
+    with W Wᵀ the same inverse."""
+    rng = np.random.default_rng(n + S)
+    m = n // 2 + 1
+    A = jnp.asarray(rng.standard_normal((S, m, n)))
+    r = jnp.asarray(rng.random((S, m)) + 0.1)
+    d = jnp.asarray(rng.random((S, n)) + 0.5)
+    M = np.asarray(jax.jit(qps._penalty_matrix)(A, r, d))
+    Minv = np.asarray(jax.jit(
+        lambda A, r, d: qps._kkt_inverse_blocked(A, r, 0.0, d))(A, r, d))
+    W = np.asarray(jax.jit(qps._penalty_factor_blocked)(A, r, d))
+    assert np.abs(M @ Minv - np.eye(n)).max() <= 1e-12
+    np.testing.assert_array_equal(Minv, Minv.transpose(0, 2, 1))
+    assert (np.tril(W, -1) == 0.0).all()
+    assert np.abs(W @ W.transpose(0, 2, 1) - Minv).max() \
+        <= 1e-13 * np.abs(Minv).max()
 
 
 @pytest.mark.parametrize("n", [8, 12, 16])
@@ -197,13 +243,14 @@ def test_kkt_inverse_blocked_on_recorded_farmer_factors():
         assert np.abs(M @ Minv - np.eye(24)).max() <= 1e-10
 
 
-def test_a_rebuild_builds_the_rows_that_moved(monkeypatch):
+@pytest.mark.parametrize("n", [24, 112])
+def test_a_rebuild_builds_the_rows_that_moved(monkeypatch, n):
     """``keep = (moved, old)``: the moved rows are gathered a chunk at
     a time and rebuilt into ``old`` (here marked, so that it shows); a
     chunk is filled up with rows that did not move, which come out what
     a full build gives them; every other row is ``old``'s."""
     rng = np.random.default_rng(3)
-    S, m, n = 7, 9, 24
+    S, m = 7, 9
     A = jnp.asarray(rng.standard_normal((S, m, n)))
     r = jnp.asarray(rng.random((S, m)) + 0.1)
     d = jnp.asarray(rng.random((S, n)) + 0.5)
