@@ -47,6 +47,7 @@ CONFIGS = {n: c for n, c in CONFIGS.items() if n not in STACKS}
 STATED = {"uc90x48_df32": (64, False), "uc90x48_df32_mesh4": (64, False),
           "uc90x48_df32_aph": (64, False),
           "uc90x48_df32_wheel": (64, False),
+          "uc90x48_df32_fwph": (64, False),
           "uc90x48_df32_chunk128": (128, False),
           "sslp_10_50_df32": (2000, True)}
 
