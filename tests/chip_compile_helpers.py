@@ -1,7 +1,9 @@
 """What the ``tests/test_chip_compile*.py`` files share: the described
 ``v5e:2x2`` (on-chip-measurement guide §2.3), the switch that keeps
-the persistent compile cache away from it, shapes-for-arrays helpers
-and readers of a compiled module's text.
+the persistent compile cache away from it, shapes-for-arrays helpers,
+readers of a compiled module's text, and the recorded segment program
+of the stacked native-f64 solve (the three ``_stacked_f64*`` files
+build it once each).
 
 The topology is described inside a module-scoped fixture that skips
 when it cannot be — never at import, never in a ``skipif`` /
@@ -24,6 +26,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -114,3 +117,71 @@ def _at_rows(tree, rows, S, sharding):
             shape = (S,) + shape[1:]
         return jax.ShapeDtypeStruct(shape, a.dtype, sharding=sharding)
     return jax.tree.map(leaf, tree)
+
+
+# ---------------- the stacked native-f64 solve (ISSUE 38) --------------
+
+@pytest.fixture(scope="module")
+def stacked_farmer_segment():
+    """The served cell's segment program as the chip's plan runs it
+    (``_needs_host_factor``: ``polish=False``, ``adaptive_rho=False``,
+    segments of 500) at a full stack's operands, recorded from a CPU
+    pass of eight stacked three-scenario farmers: A_s (24, 7, 12)
+    float64, the factor the explicit (24, 12, 12) float64 inverse."""
+    import mpisppy_tpu.ops.qp_solver as qps
+    from stacked_farmer import record_stacked_farmer_calls
+    calls, _plan = record_stacked_farmer_calls()
+    args, kw = calls[-1]
+    assert args[0].A_s.shape == (24, 7, 12) \
+        and args[0].A_s.dtype == np.float64
+    assert args[3].L.shape == (24, 12, 12) and args[3].L.dtype == np.float64
+    kw = {k: v for k, v in kw.items() if k != "_segmented_caller"}
+    kw.update(max_iter=500, polish=False, adaptive_rho=False)
+    fn = jax.jit(qps._solve_impl, static_argnames=qps._SOLVE_STATICS)
+    return fn, args, kw
+
+
+_PRODUCT_SCOPES = ("qp.Ax", "qp.ATy", "qp.kkt_solve")
+
+
+def _product_loops(hlo):
+    """The ``while`` instructions whose ``op_name`` lies under one of
+    the three product scopes: the compiler's emulation of a batched
+    float64 ``dot_general`` (eight f32 limbs, nested loops)."""
+    return [ln for ln in _hlo_lines(hlo, "while")
+            if any(s + "/" in ln for s in _PRODUCT_SCOPES)]
+
+
+def _resized(tree, dims, sharding):
+    """Recorded operands as shapes on the described chip, every axis
+    of length d at ``dims[d]``."""
+    def leaf(a):
+        if not (hasattr(a, "shape") and hasattr(a, "dtype")):
+            return a
+        return jax.ShapeDtypeStruct(tuple(dims[d] for d in a.shape),
+                                    a.dtype, sharding=sharding)
+    return jax.tree.map(leaf, tree)
+
+
+def _widened(tree, S, scale, sharding):
+    """The recorded (24, 7, 12) operands: the scenario axis at ``S``
+    rows, m and n times ``scale``."""
+    return _resized(tree, {24: S, 7: 7 * scale, 12: 12 * scale}, sharding)
+
+
+def _polish_loops(hlo):
+    """The ``while`` instructions under ``qp.polish``, and those of
+    them that are the compiler's expansion of a batched float64
+    ``cholesky`` / ``triangular_solve`` / Gram ``dot_general``."""
+    loops = [ln for ln in _hlo_lines(hlo, "while") if "qp.polish/" in ln]
+    return loops, [ln for ln in loops
+                   if any(k in ln for k in ("cholesky", "triangular_solve",
+                                            "dot_general"))]
+
+
+def _refactor_loops(hlo):
+    """The ``while`` instructions under ``qp.refactor`` (the rebuild of
+    the explicit float64 inverse inside ``qp.rho_adapt``): the
+    compiler's expansions of the batched float64 ``cholesky`` /
+    ``triangular_solve`` pair and of the product in front of them."""
+    return [ln for ln in _hlo_lines(hlo, "while") if "qp.refactor/" in ln]

@@ -3,8 +3,9 @@
 (24 scenarios, per-scenario (7, 12) float64 matrices), taken through
 iter-0 and one hot PH pass on the CPU while recording every call of
 the native-f64 solve: the operands ``core/ph`` hands
-``qp_solver._solve_impl`` (shared by tests/test_f64_products.py and
-tests/test_chip_compile_stacked_f64.py)."""
+``qp_solver._solve_impl`` (shared by tests/test_f64_products.py and,
+through tests/chip_compile_helpers.py, the three
+tests/test_chip_compile_stacked_f64*.py)."""
 
 import contextlib
 

@@ -22,11 +22,12 @@ import numpy as np
 import pytest
 
 from mpisppy_tpu import obs
-from mpisppy_tpu.core.aph import APH, _aph_update
+from mpisppy_tpu.core.aph import APH, _aph_step, _aph_update
 from mpisppy_tpu.ir.batch import build_batch
 from mpisppy_tpu.models import uc
 from mpisppy_tpu.ops.dispatch import (GATE_HEAD, dispatch_gate,
-                                      dispatch_select, scalar_gate)
+                                      dispatch_select, gather_chunks,
+                                      place_chunks, scalar_gate)
 
 S, CHUNK, FRAC, ITERS = 16, 4, 0.25, 7
 SOLVED = int(np.ceil(FRAC * S))
@@ -77,36 +78,52 @@ def before(aph):
 @pytest.fixture(scope="module")
 def stepped(ref):
     """One engine stepped ITERS times from outside; per iteration the
-    state before it, the reference's answer, the engine's, and the
-    entry names of every backend compile the process had made by its
-    end (jax's duration event carries ``fun_name``)."""
-    from jax import monitoring
+    state before it, the reference's answer and the engine's."""
     assert jax.config.jax_enable_x64 and not obs.enabled()
+    aph = engine()
+    iter0(aph)
+    trail = []
+    for it in range(1, ITERS + 1):
+        b = before(aph)
+        assert aph.iterate(it) is True
+        want = ref.aph_step(b["xn"], b["W"], b["z"], b["y_aph"],
+                            b["prob"], b["rho"], b["dispatched"],
+                            b["last"], aph.nu, aph.gamma, it, FRAC)
+        trail.append((it, b, want, before(aph),
+                      {k: getattr(aph, k)
+                       for k in ("tau", "phi", "theta", "conv")},
+                      dict(aph._aph_status),
+                      np.asarray(aph.phis).copy()))
+    return aph, trail
+
+
+@pytest.fixture
+def compile_log():
+    """{iteration: entry names of the backend compiles made since the
+    engine's iter-0} of an engine of its own (jax's duration event
+    carries ``fun_name``), stepped with the traces of the three programs
+    the count is of dropped first: what the worker compiled before, in
+    this file or in another, is not in the count."""
+    from jax import monitoring
     compiled = []
 
     def on(name, _secs, **kw):
         if name == "/jax/core/compile/backend_compile_duration":
             compiled.append(str(kw.get("fun_name")))
 
+    aph = engine()
+    iter0(aph)
+    for program in (_aph_step, gather_chunks, place_chunks):
+        program.clear_cache()
+    logs = {}
     monitoring.register_event_duration_secs_listener(on)
     try:
-        aph = engine()
-        iter0(aph)
-        trail = []
         for it in range(1, ITERS + 1):
-            b = before(aph)
             assert aph.iterate(it) is True
-            want = ref.aph_step(b["xn"], b["W"], b["z"], b["y_aph"],
-                                b["prob"], b["rho"], b["dispatched"],
-                                b["last"], aph.nu, aph.gamma, it, FRAC)
-            trail.append((it, b, want, before(aph),
-                          {k: getattr(aph, k)
-                           for k in ("tau", "phi", "theta", "conv")},
-                          dict(aph._aph_status),
-                          np.asarray(aph.phis).copy(), list(compiled)))
+            logs[it] = list(compiled)
     finally:
         monitoring.unregister_event_duration_listener(on)
-    return aph, trail
+    return logs
 
 
 def rel(got, want):
@@ -117,7 +134,7 @@ def rel(got, want):
 def test_engine_against_the_plain_reference(stepped):
     _aph, trail = stepped
     assert len(trail) >= 6
-    for it, b, want, after, scalars, status, phis, _log in trail:
+    for it, b, want, after, scalars, status, phis in trail:
         # float64 against float64: the limit is the order of the sums
         assert rel(after["W"], want["W"]) <= 1e-12, it
         assert rel(after["z"], want["z"]) <= 1e-12, it
@@ -145,18 +162,17 @@ def test_engine_against_the_plain_reference(stepped):
     assert any(not t[2]["mask"][:SOLVED].all() for t in trail[1:])
 
 
-def test_the_iteration_number_is_an_operand(stepped):
+def test_the_iteration_number_is_an_operand(compile_log):
     """``it`` reaches the step program as a traced scalar (it stamps
     the dispatched rows): the step compiles for iteration 1 (every row,
     z := x̄) and for the first partial pass, and iterations 3 .. ITERS
     compile nothing at all, the store's gather and placement included."""
-    _aph, trail = stepped
-    logs = {t[0]: t[-1] for t in trail}
     assert ITERS >= 7
-    assert sum("_aph_step" in n for n in logs[2]) == 2, logs[2]
+    by2 = compile_log[2]
+    assert sum("_aph_step" in n for n in by2) == 2, by2
     for name in ("gather_chunks", "place_chunks"):
-        assert sum(name in n for n in logs[2]) == 1, logs[2]
-    assert logs[ITERS] == logs[2], logs[ITERS][len(logs[2]):]
+        assert sum(name in n for n in by2) == 1, by2
+    assert compile_log[ITERS] == by2, compile_log[ITERS][len(by2):]
 
 
 def test_iterate_from_outside_is_aph_main(stepped):
@@ -193,7 +209,7 @@ def test_one_chip_pool_is_a_ranks_share(stepped, ref, rank):
     count."""
     _aph, trail = stepped
     rows = slice(4 * rank, 4 * rank + 4)
-    for it, b, _want, _after, _sc, _st, phis, _log in trail[1:]:
+    for it, b, _want, _after, _sc, _st, phis in trail[1:]:
         # what this iteration's selection read: its post-step φ and
         # the stamps from before its pass
         p, last = phis[rows], b["last"][rows]

@@ -1,7 +1,8 @@
 """Compile the main path's step programs for the chip — from a
 sandbox that has none (on-chip-measurement guide §2.3): the UC df32
-chunk solve, the consensus reduce, the sharded solve and the chunk
-staging programs.
+chunk solve, the consensus reduce, the sharded solve, the chunk
+staging programs and the explicit inverse's build in column panels at
+the UC cells' OWN width (n = 13,056).
 
 The TPU compiler is installed here and compiles for a chip that is
 DESCRIBED (``v5e:2x2``), not attached: what it refuses here, the chip's
@@ -186,3 +187,34 @@ def test_sharded_chunk_staging_is_local_over_four_chips(
     outs = jax.tree.leaves(compiled.output_shardings)
     assert len(outs) == 4 * 9           # l u lb ub q c c0 P0 W, a chunk
     assert all(s.spec == PartitionSpec(SCEN_AXIS) for s in outs)
+
+
+# ---------------- the explicit inverse at UC width (ISSUE 41) ----------
+
+@pytest.mark.parametrize("container", ["bare", "prepared"])
+def test_l_inv_build_compiles_at_uc_width_for_v5e(one_chip,
+                                                  no_persistent_cache,
+                                                  container):
+    """``jit(_make_l_inv)`` at (13056, 13056) f32, as the eager wrap
+    hands it a bare factor and the fused program's handoff and in-loop
+    refactorization a prepared one. As ONE n-RHS ``triangular_solve``
+    against ``eye(n)`` the v5e compiler was asked for 32.65 GB (chip
+    run, PR 25) and every path that built an inverse died there; in
+    column panels (``qp_solver._l_inv_by_panels``) the output (the
+    inverse and the factor riding along: 2 x 0.68 GB) and the
+    temporaries stay under 2.5 GB, and the program is one loop a panel,
+    not 102 unrolled block steps a panel."""
+    import mpisppy_tpu.ops.qp_solver as qs
+    n = _UC["n"]
+    L = jax.ShapeDtypeStruct((n, n), jnp.float32, sharding=one_chip)
+    arg = L
+    if container == "prepared":
+        nb = -(-n // qs._TRI_BLOCK)
+        arg = qs.PreparedFactor(L, jax.ShapeDtypeStruct(
+            (nb, qs._TRI_BLOCK, qs._TRI_BLOCK), jnp.float32,
+            sharding=one_chip))
+    compiled = jax.jit(qs._make_l_inv).lower(arg).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes + mem.temp_size_in_bytes < 2.5e9
+    assert qs.l_inv_panels(n) == 6
+    assert len(_hlo_lines(compiled.as_text(), "while")) == 6

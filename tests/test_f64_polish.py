@@ -10,8 +10,8 @@ ordinary fusions when they are recurrences unrolled over the static n
 CPU, the polish keeps the library calls; the unrolled forms are called
 directly, and a whole polish is steered onto them by handing the solver
 the unrolled helpers in place of the library ones
-(tests/test_chip_compile_stacked_f64.py holds what the TPU compiler
-makes of each form)."""
+(tests/test_chip_compile_stacked_f64.py and its ``_blocked`` twin hold
+what the TPU compiler makes of each form)."""
 
 import jax
 import jax.numpy as jnp

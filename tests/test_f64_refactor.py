@@ -9,8 +9,8 @@ The form is picked per platform at lowering time
 keeps the library pair; the unrolled form is called directly, and a
 whole wheel is steered onto it by handing the solver the unrolled
 helper in place of the library one
-(tests/test_chip_compile_stacked_f64.py holds what the TPU compiler
-makes of the solve program)."""
+(tests/test_chip_compile_stacked_f64_loop.py holds what the TPU
+compiler makes of the solve program)."""
 
 import os
 import sys
