@@ -3161,6 +3161,20 @@ class PHBase(SPBase):
                 tot[k] += v
         return tot
 
+    def phase_booked(self, key=True):
+        """The raw running totals behind ``phase_timing(key)`` (zeros
+        where the key never ran): ``solve_loop`` calls, the four
+        phases' seconds, ADMM iterations (bulk + tail), in-program
+        refactorizations and solves that ran their whole tail budget.
+        Two of these differ by what the mode booked in between
+        (serve/manager: one wheel's share of a leased engine)."""
+        ent = self._phase_times.get(key) or _new_phase_entry()
+        admm = ent["admm"]
+        return {"calls": ent["calls"], **ent["acc"],
+                "admm_iters": admm["bulk"] + admm["tail"],
+                "refactors": admm["refactors"],
+                "capped": ent["exits"]["tail_capped"]}
+
     def residual_summary(self, key=True):
         """Host summary of the last solve's relative residuals for one
         mode key (None when that mode never ran). Reading the state

@@ -141,10 +141,12 @@ class _ServeHTTPServer(ThreadingHTTPServer):
         obs.counter_add("serve.http_requests")
         svc = self._service
         if path.startswith("/result/"):
-            rec = svc.result(path[len("/result/"):])
-            if rec is None:
-                return _json_body(404, {"error": "unknown request id"})
-            return _json_body(200, rec)
+            with obs.span("serve.http.result", cat="serve"):
+                rec = svc.result(path[len("/result/"):])
+                if rec is None:
+                    return _json_body(404,
+                                      {"error": "unknown request id"})
+                return _json_body(200, rec)
         if path == "/queue":
             return _json_body(200, svc.queue_snapshot())
         if path == "/status":
@@ -176,23 +178,25 @@ class _ServeHTTPServer(ThreadingHTTPServer):
                 raise BadRequest(f"invalid JSON body: {e}") from None
 
         if path == "/solve":
-            draining = getattr(svc, "_draining", False)
-            if svc._preempting or svc._stop or draining:
-                body = {"error": "service draining" if draining
-                                 else "service stopping"}
-                peer = svc.peer_hint() if draining else None
-                if peer:
-                    body["peer"] = peer
-                return _json_body(503, body) + ({"Retry-After": "2"},)
-            payload = _parse()
-            try:
-                req = svc.submit(payload)
-            except QueueFull as e:
-                return _json_body(429, {"error": str(e)}) \
-                    + ({"Retry-After": "1"},)
-            return _json_body(202, {"request_id": req.id,
-                                    "bucket": req.bucket,
-                                    "batchable": req.batchable})
+            with obs.span("serve.http.solve", cat="serve"):
+                draining = getattr(svc, "_draining", False)
+                if svc._preempting or svc._stop or draining:
+                    body = {"error": "service draining" if draining
+                                     else "service stopping"}
+                    peer = svc.peer_hint() if draining else None
+                    if peer:
+                        body["peer"] = peer
+                    return _json_body(503, body) \
+                        + ({"Retry-After": "2"},)
+                payload = _parse()
+                try:
+                    req = svc.submit(payload)
+                except QueueFull as e:
+                    return _json_body(429, {"error": str(e)}) \
+                        + ({"Retry-After": "1"},)
+                return _json_body(202, {"request_id": req.id,
+                                        "bucket": req.bucket,
+                                        "batchable": req.batchable})
         if path == "/shutdown":
             if self._on_shutdown is not None:
                 self._on_shutdown()

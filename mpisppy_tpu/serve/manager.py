@@ -51,8 +51,12 @@ from .migrate import (MigrationClient, MigrationError, MigrationReceiver,
                       PeerRegistry, read_endpoint,
                       resolve_interrupted_migration)
 from .queue import AdmissionQueue, Request, RequestStore
+from .timing import PH_FIELDS, STEPS, ServeTiming
 
 _CONSENSUS_FEAS_TOL = 1e-4
+# the solve modes a served wheel runs (``PHBase.phase_timing`` keys):
+# iter-0, the hot iterations, the results' fixed evaluation
+_WHEEL_MODES = (False, True, ("fixed", False))
 
 
 # ---------------------------------------------------------------- engine
@@ -145,6 +149,18 @@ def install_batch(engine, stacked):
     return engine
 
 
+def ph_booked(engine):
+    """What the engine has booked so far over the modes a served wheel
+    runs, as totals (``PH_FIELDS``, ``PHBase.phase_booked``). A leased
+    engine's accumulators span wheels, so a wheel's own numbers are the
+    difference of two of these (``_wheel_steps``)."""
+    tot = dict.fromkeys(PH_FIELDS, 0)
+    for key in _WHEEL_MODES:
+        for k, v in engine.phase_booked(key).items():
+            tot[k] += v
+    return tot
+
+
 def consensus_results(engine, blocks, feas_tol=_CONSENSUS_FEAS_TOL):
     """Per-request results from a finished (possibly stacked) wheel:
     fix every scenario at its own node's consensus (integer nonant
@@ -216,6 +232,9 @@ class ServeService:
         self.store = RequestStore(cfg.state_dir)
         self.queue = AdmissionQueue(cfg.queue_limit)
         self.cache = WarmCache(cfg.cache_buckets)
+        # the layer's own always-on account of requests and wheels
+        # (serve/timing; ``GET /status`` -> ``timing``)
+        self.timing = ServeTiming()
         self._requests: dict[str, Request] = {}
         self._req_lock = threading.Lock()
         self._base_batches: dict[str, object] = {}   # bucket -> base batch
@@ -282,6 +301,7 @@ class ServeService:
     # ---- lifecycle ----
     def start(self):
         self._started_unix = time.time()
+        self.timing.start()
         self._sweep_terminal()
         self._recover()
         obs.event("serve.start",
@@ -397,6 +417,7 @@ class ServeService:
                       batchable=batchable,
                       deadline=payload.get("deadline",
                                            self.cfg.default_deadline))
+        req.marks["t_submit"] = time.perf_counter()
         self.store.save(req)
         with self._req_lock:
             self._requests[req.id] = req
@@ -445,7 +466,8 @@ class ServeService:
                 "queue_depth": len(self.queue),
                 "requests": counts,
                 "wheels": wheels,
-                "cache": self.cache.status()}
+                "cache": self.cache.status(),
+                "timing": self.timing.summary()}
 
     def queue_snapshot(self) -> dict:
         with self._req_lock:
@@ -781,6 +803,9 @@ class ServeService:
 
     # ---- the wheel workers ----
     def _worker_loop(self):
+        # the queue's marks of this worker's cycle (serve/timing): kept
+        # across idle time-outs, handed to the wheel that ends the wait
+        cycle = {}
         while not self._stop:
             self.receiver.sweep()   # reclaim offers from dead donors
             group = None
@@ -792,17 +817,20 @@ class ServeService:
             if group is None:
                 group = self.queue.pop_group(self.cfg.batch_window,
                                              self.cfg.batch_max,
-                                             timeout=0.5)
+                                             timeout=0.5, cycle=cycle)
+            else:
+                cycle = {}      # a recovered group never waited here
             if not group:
                 continue
+            cycle, popped = {}, cycle
             group = self._settle_expired(group)
             if not group:
                 continue
             try:
                 if "chain" in group[0].payload:
-                    self._run_chain(group[0])
+                    self._run_chain(group[0], popped)
                 else:
-                    self._run_group(group)
+                    self._run_group(group, popped)
             except Exception as e:   # a torn wheel must not kill the loop
                 self._fail_group(group, e)
 
@@ -829,6 +857,7 @@ class ServeService:
         if error is not None:
             req.error = str(error)
         req.finished_unix = time.time()
+        req.timeline = self.timing.close_request(req.marks)
         req.status = status
         self.store.save(req)
         if status == "done":
@@ -878,14 +907,42 @@ class ServeService:
         nonant_cols[np.asarray(base.nonant_idx)] = True
         return bool((np.asarray(base.integer) & ~nonant_cols).any())
 
-    def _run_group(self, group):
+    def _run_group(self, group, cycle=None):
+        with obs.span("serve.group.prepare", cat="serve"):
+            plan = self._prepare_group(group)
+        if plan is None:
+            return
+        group, gid, wheel_kw = plan
+        wheel = self._run_wheel(**wheel_kw, cycle=cycle)
+        rec = wheel["timing"]
+        try:
+            for r in group:
+                r.marks.update(t_wheel0=rec["t_wheel0"],
+                               t_wheel1=rec["t_wheel1"],
+                               wheel_seq=rec["seq"])
+                if r.marks["t_submit"] is not None:
+                    obs.histogram_observe(
+                        "serve.queue_wait_seconds",
+                        rec["t_wheel0"] - r.marks["t_submit"])
+            obs.histogram_observe("serve.batch.occupancy", rec["stack"])
+            with obs.span("serve.finish", cat="serve"):
+                self._settle_group(group, gid, wheel)
+        finally:
+            self.timing.close_wheel(rec)
+
+    def _prepare_group(self, group):
+        """From a popped group to its wheel's arguments: who runs (a
+        preempting or draining service parks or hands off instead),
+        the base batch, the group file, the members flipped to
+        ``running``, their instances stacked. ``None``: nothing to
+        run; else ``(group, gid, _run_wheel's keyword arguments)``."""
         if self._preempting:
             # popped in the race window around the preemption notice:
             # park (or hand off) instead of launching a wheel the
             # shutdown would kill
             for r in group:
                 self._park_or_migrate(r)
-            return
+            return None
         if self._draining:
             # drain-for-deploy: queued work leaves BEFORE spending a
             # wheel on it; whatever no peer takes runs here, solo
@@ -899,7 +956,7 @@ class ServeService:
                     keep.append(r)
             group = keep
             if not group:
-                return
+                return None
         bucket = group[0].bucket
         base = self._base_batch(bucket, group[0].payload)
         rec_ints = self._has_recourse_integers(base)
@@ -925,28 +982,30 @@ class ServeService:
             r.group = gid
             r.status = "running"
             r.started_unix = now
-            if obs.enabled():
-                obs.histogram_observe("serve.queue_wait_seconds",
-                                      max(0.0, now - r.submitted_unix))
             self.store.save(r)
-        obs.histogram_observe("serve.batch.occupancy", len(group))
         resume_from = group[0].resume_from if gid is None \
             else (group[0].resume_from if all(r.resumed for r in group)
                   else None)
         fingerprint = config_fingerprint(
             {"bucket": bucket, "stack": [r.id for r in group]}
             if gid else {"bucket": bucket, "request": group[0].id})
-        with obs.span("serve.stack", cat="serve"):
+        with obs.span("serve.stack", cat="serve") as sp:
             stacked, blocks = sbatch.stack_instances(
                 [sbatch.apply_patch(base, r.payload.get("patch"))
                  for r in group])
-        wheel = self._run_wheel(ns, bucket, len(group), stacked,
-                                group[0].payload, fingerprint,
-                                resume_from,
-                                deadline=self._group_deadline(group),
-                                solo_incumbent=dive_incumbent_result
-                                if (gid is None and rec_ints)
-                                else None)
+        return group, gid, dict(
+            ns=ns, bucket=bucket, stack=len(group), stacked=stacked,
+            payload=group[0].payload, fingerprint=fingerprint,
+            resume_from=resume_from,
+            deadline=self._group_deadline(group),
+            solo_incumbent=dive_incumbent_result
+            if (gid is None and rec_ints) else None,
+            stack_s=sp.seconds)
+
+    def _settle_group(self, group, gid, wheel):
+        """A finished wheel's members: parked or handed off
+        (preempted), re-queued solo or failed (deadline missed), or
+        done with their results persisted."""
         if wheel["preempted"]:
             # the donor half of a live handoff: the hub's forced final
             # bundle (handle_preemption) is exactly what the peer
@@ -998,11 +1057,13 @@ class ServeService:
 
     def _run_wheel(self, ns, bucket, stack, stacked, payload,
                    fingerprint, resume_from, deadline=None,
-                   solo_incumbent=None):
+                   solo_incumbent=None, stack_s=None, cycle=None):
         """One wheel over a (possibly warm) engine: checkout/install
         or build+admit, hub-only cylinder with checkpointing under the
         request namespace, per-request deadline timer, results from
-        the consensus. Returns the wheel record.
+        the consensus. Returns the wheel record; its ``"timing"`` is
+        the wheel's serve/timing record, open until the caller has
+        settled the members (``ServeTiming.close_wheel``).
 
         The wheel and its five steps are spans (``serve.wheel`` >
         ``.engine`` / ``.hub_setup`` / ``.main`` / ``.finalize`` /
@@ -1013,16 +1074,19 @@ class ServeService:
                       if obs.enabled() else None):
             return self._wheel_steps(ns, bucket, stack, stacked, payload,
                                      fingerprint, resume_from, deadline,
-                                     solo_incumbent)
+                                     solo_incumbent, stack_s, cycle)
 
     def _wheel_steps(self, ns, bucket, stack, stacked, payload,
-                     fingerprint, resume_from, deadline, solo_incumbent):
+                     fingerprint, resume_from, deadline, solo_incumbent,
+                     stack_s, cycle):
         from ..cylinders.hub import PHHub
         from ..cylinders.supervisor import WheelDeadline
 
         algo = sbatch.request_algo(payload)
         ekey = sbatch.engine_key(bucket, stack)
-        t0 = time.perf_counter()
+        rec = self.timing.open_wheel(cycle)
+        steps = rec["steps"]
+        steps["stack"] = stack_s
         compiles0 = obs.counter_value("jax.compiles")
         ent = None
         watchdog = None
@@ -1034,7 +1098,7 @@ class ServeService:
             # worker behind another tenant's wheel (the documented
             # lease semantics — the jit caches are process-global, so
             # the twin only re-pays the factorization)
-            with obs.span("serve.wheel.engine", cat="serve"):
+            with obs.span("serve.wheel.engine", cat="serve") as sp:
                 leased = self.cache.checkout(ekey, wait=False)
                 cache_hit = leased is not None
                 if leased is None:
@@ -1046,6 +1110,8 @@ class ServeService:
                 else:
                     ent = leased
                     engine = install_batch(ent.engine, stacked)
+            steps["engine"] = sp.seconds
+            booked0 = ph_booked(engine)
             hub_opts = {"checkpoint_dir": self._ckpt_ns(ns),
                         "checkpoint_interval":
                             self.cfg.checkpoint_interval,
@@ -1056,10 +1122,11 @@ class ServeService:
                 hub_opts["resume_from"] = resume_from
             if deadline is not None:
                 hub_opts["wheel_deadline"] = max(0.1, float(deadline))
-            with obs.span("serve.wheel.hub_setup", cat="serve"):
+            with obs.span("serve.wheel.hub_setup", cat="serve") as sp:
                 hub = PHHub(engine, spokes=[], options=hub_opts)
                 hub.make_windows()
                 hub.setup_hub()
+            steps["hub_setup"] = sp.seconds
             with self._hub_lock:
                 self._active_hubs[ns] = hub
             if deadline is not None:
@@ -1076,10 +1143,12 @@ class ServeService:
                 # iteration
                 self._fault_injector.on_wheel_start()
             resumed_iter = int(getattr(engine, "_iter", 0) or 0)
-            with obs.span("serve.wheel.main", cat="serve"):
+            with obs.span("serve.wheel.main", cat="serve") as sp:
                 hub.main()
-            with obs.span("serve.wheel.finalize", cat="serve"):
+            steps["main"] = sp.seconds
+            with obs.span("serve.wheel.finalize", cat="serve") as sp:
                 outer, inner = hub.hub_finalize()
+            steps["finalize"] = sp.seconds
             preempted = bool(hub._preempted)
             deadline_missed = bool(hub._watchdog_fired) \
                 and not preempted
@@ -1088,7 +1157,7 @@ class ServeService:
             # this engine the moment it frees
             results = []
             if not (preempted or deadline_missed):
-                with obs.span("serve.wheel.results", cat="serve"):
+                with obs.span("serve.wheel.results", cat="serve") as sp:
                     if solo_incumbent is not None:
                         results = [solo_incumbent(engine)]
                     else:
@@ -1096,6 +1165,9 @@ class ServeService:
                                         (k + 1) * (stacked.S // stack))
                                   for k in range(stack)]
                         results = consensus_results(engine, blocks)
+                steps["results"] = sp.seconds
+            booked = ph_booked(engine)
+            rec["ph"] = {k: booked[k] - booked0[k] for k in booked}
             final_iter = int(getattr(engine, "_iter", 0) or 0)
             final_conv = obs.finite_or_none(
                 float(engine.conv) if engine.conv is not None else None)
@@ -1118,9 +1190,10 @@ class ServeService:
         if compiles:
             obs.counter_add(f"serve.bucket.compiles.{ekey}",
                             int(compiles))
-        seconds = time.perf_counter() - t0
-        if obs.enabled():
-            obs.histogram_observe("serve.wheel_seconds", seconds)
+        rec["t_wheel1"] = time.perf_counter()
+        seconds = rec["t_wheel1"] - rec["t_wheel0"]
+        rec.update(stack=stack, cache_hit=cache_hit, seconds=seconds)
+        obs.histogram_observe("serve.wheel_seconds", seconds)
         stamp = {"bucket": bucket, "engine_key": ekey, "stack": stack,
                  "cache_hit": cache_hit,
                  "xla_compiles_delta": int(compiles),
@@ -1129,7 +1202,9 @@ class ServeService:
                  "outer_bound": obs.finite_or_none(outer)
                  if not (preempted or deadline_missed) else None,
                  "conv": final_conv,
-                 "seconds": seconds}
+                 "seconds": seconds,
+                 # the five steps' seconds (spans ``serve.wheel.*``)
+                 "steps": {k: steps[k] for k in STEPS[1:]}}
         # per-wheel forensics (obs/diagnose.py): the wheel's diagnosis
         # verdict + top culprits ride the request stamp — a DNF'd
         # serve request names its stall instead of just timing out
@@ -1141,13 +1216,13 @@ class ServeService:
                 "verdict": snap.get("verdict"),
                 "top_slot": snap.get("top_slot"),
                 "top_scen_share": snap.get("top_scen_share")}
-        return {"stamp": stamp, "results": results,
+        return {"stamp": stamp, "results": results, "timing": rec,
                 "preempted": preempted,
                 "deadline_missed": deadline_missed,
                 "outer": outer, "inner": inner}
 
     # ---- rolling-horizon chains ----
-    def _run_chain(self, req):
+    def _run_chain(self, req, cycle=None):
         """First-class rolling-horizon request: one wheel per step,
         each warm-started from the previous step's bundle through the
         resume path; the committed head (stage-1 consensus) of every
@@ -1177,25 +1252,42 @@ class ServeService:
                 ns, req.bucket, 1, stepb, req.payload, fingerprint,
                 resume_from, deadline=req.deadline_remaining(),
                 solo_incumbent=dive_incumbent_result
-                if self._has_recourse_integers(base) else None)
-            if wheel["preempted"]:
-                self._park_or_migrate(req)
-                return
-            if wheel["deadline_missed"]:
-                obs.counter_add("serve.requests.deadline_missed")
-                self._finish(req, "failed",
-                             error=f"deadline exceeded at chain step "
-                                   f"{j}")
-                return
-            res = wheel["results"][0]
-            obs.counter_add("serve.chain.steps")
-            req.chain_results.append(
-                {"step": j, "committed_head": res["xhat"],
-                 "objective": res["objective"],
-                 "warm_started": bool(resume_from),
-                 "wheel": wheel["stamp"]})
-            self.store.save(req)       # commit the head durably per step
+                if self._has_recourse_integers(base) else None,
+                cycle=cycle if j == start else None)
+            rec = wheel["timing"]
+            try:
+                if req.marks["t_wheel0"] is None:
+                    req.marks["t_wheel0"] = rec["t_wheel0"]
+                req.marks.update(t_wheel1=rec["t_wheel1"],
+                                 wheel_seq=rec["seq"])
+                with obs.span("serve.finish", cat="serve"):
+                    if not self._settle_chain_step(req, j, wheel,
+                                                   bool(resume_from)):
+                        return
+            finally:
+                self.timing.close_wheel(rec)
         self._finish(req, "done", result={"steps": req.chain_results})
+
+    def _settle_chain_step(self, req, j, wheel, warm_started) -> bool:
+        """One chain step's wheel: the request parked or failed (False:
+        the chain ends here), or the step's head committed (True)."""
+        if wheel["preempted"]:
+            self._park_or_migrate(req)
+            return False
+        if wheel["deadline_missed"]:
+            obs.counter_add("serve.requests.deadline_missed")
+            self._finish(req, "failed",
+                         error=f"deadline exceeded at chain step {j}")
+            return False
+        res = wheel["results"][0]
+        obs.counter_add("serve.chain.steps")
+        req.chain_results.append(
+            {"step": j, "committed_head": res["xhat"],
+             "objective": res["objective"],
+             "warm_started": warm_started,
+             "wheel": wheel["stamp"]})
+        self.store.save(req)       # commit the head durably per step
+        return True
 
 
 # ------------------------------------------------------------- CLI

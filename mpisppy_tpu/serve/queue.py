@@ -32,6 +32,7 @@ import time
 
 from .. import obs
 from ..ckpt.bundle import atomic_write_json
+from .timing import new_request_marks
 
 REQUEST_SCHEMA = 1
 
@@ -82,6 +83,12 @@ class Request:
         self.recoveries = 0
         self.peer = None
         self.migrated_from = None
+        # this process's perf_counter marks of the request's life
+        # (serve/timing: not persisted, a clock read means nothing to
+        # another process) and, once it finished, where it waited
+        # (``timing.timeline``: persisted with the result)
+        self.marks = new_request_marks(self.id)
+        self.timeline = None
 
     def deadline_remaining(self, now=None) -> float | None:
         if self.deadline_unix is None:
@@ -98,7 +105,8 @@ class Request:
                 "finished_unix": self.finished_unix,
                 "deadline_unix": self.deadline_unix,
                 "group": self.group, "result": self.result,
-                "error": self.error, "resumed": self.resumed,
+                "error": self.error, "timeline": self.timeline,
+                "resumed": self.resumed,
                 "chain_results": self.chain_results,
                 "recoveries": self.recoveries, "peer": self.peer,
                 "migrated_from": self.migrated_from}
@@ -116,6 +124,7 @@ class Request:
         req.group = d.get("group")
         req.result = d.get("result")
         req.error = d.get("error")
+        req.timeline = d.get("timeline")
         req.resumed = bool(d.get("resumed", False))
         req.no_batch = bool(d.get("no_batch", False))
         req.chain_results = list(d.get("chain_results") or [])
@@ -235,12 +244,33 @@ class AdmissionQueue:
                 taken.append(r)
         for r in taken:
             self._items.remove(r)
+        self._mark_popped(taken)
         group.extend(taken)
 
+    @staticmethod
+    def _mark_popped(reqs):
+        """``t_pop``: a worker took these requests; what an earlier
+        attempt (a failed group, a missed stack deadline) left of its
+        wheel's marks goes."""
+        now = time.perf_counter()
+        for r in reqs:
+            r.marks.update(t_pop=now, t_wheel0=None, t_wheel1=None,
+                           wheel_seq=None)
+
     def pop_group(self, batch_window: float = 0.0, batch_max: int = 1,
-                  timeout: float | None = None) -> list:
+                  timeout: float | None = None,
+                  cycle: dict | None = None) -> list:
         """Next dispatch unit: ``[request]`` or a same-bucket group.
-        Empty list = queue stopped or ``timeout`` expired idle."""
+        Empty list = queue stopped or ``timeout`` expired idle.
+
+        ``cycle`` (the worker's, kept across idle time-outs) receives
+        the marks of the pop (serve/timing): ``t_pop0`` (the FIRST
+        entry since the worker became free), ``t_first`` (a first
+        request is held: the batch window opens), ``t_group`` (the
+        group is closed). Each wait on the condition is one span,
+        ``serve.queue.idle`` / ``serve.batch.window``."""
+        cycle = {} if cycle is None else cycle
+        cycle.setdefault("t_pop0", time.perf_counter())
         with self._cond:
             deadline = None if timeout is None \
                 else time.monotonic() + timeout
@@ -253,8 +283,11 @@ class AdmissionQueue:
                     else deadline - time.monotonic()
                 if remaining is not None and remaining <= 0:
                     return []
-                self._cond.wait(timeout=remaining)
+                with obs.span("serve.queue.idle", cat="serve"):
+                    self._cond.wait(timeout=remaining)
             first = self._items.pop(0)
+            self._mark_popped([first])
+            cycle["t_first"] = first.marks["t_pop"]
             group = [first]
             if first.batchable and not first.no_batch and batch_max > 1:
                 self._take_same_bucket(first, batch_max, group)
@@ -264,9 +297,11 @@ class AdmissionQueue:
                     remaining = window_end - time.monotonic()
                     if remaining <= 0:
                         break
-                    self._cond.wait(timeout=remaining)
+                    with obs.span("serve.batch.window", cat="serve"):
+                        self._cond.wait(timeout=remaining)
                     self._take_same_bucket(first, batch_max, group)
             obs.gauge_set("serve.queue_depth", len(self._items))
+            cycle["t_group"] = time.perf_counter()
             return group
 
     def snapshot(self) -> list:

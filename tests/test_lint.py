@@ -433,6 +433,7 @@ def test_jax_free_modules_import_without_jax():
         "import mpisppy_tpu.serve.queue\n"
         "import mpisppy_tpu.serve.batch\n"
         "import mpisppy_tpu.serve.http\n"
+        "import mpisppy_tpu.serve.timing\n"
         "import tools.lint.rules\n"
         "import tools.regression_gate\n"
         "print('JAXFREE')\n")

@@ -182,6 +182,7 @@ JAX_FREE_DEFAULT = (
     "mpisppy_tpu/serve/batch.py",
     "mpisppy_tpu/serve/http.py",
     "mpisppy_tpu/serve/migrate.py",
+    "mpisppy_tpu/serve/timing.py",
     # the diagnosis engine (ISSUE 19, doc/forensics.md): the hub
     # status plane, bench's signal handler, and serve read its
     # snapshots as plain dict lookups — it must never pull in jax
